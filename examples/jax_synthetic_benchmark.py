@@ -18,6 +18,7 @@ import time
 
 import jax
 import optax
+from jax.sharding import NamedSharding
 
 from horovod_tpu import models, training
 from horovod_tpu.parallel import GradSyncConfig, MeshSpec, build_mesh
@@ -63,6 +64,8 @@ def main() -> int:
     trainer = training.Trainer(
         model, tx, mesh,
         sync=GradSyncConfig(axes=("dp",), op="average", compression=wire))
+    # Placed once, one shard per device.
+    batch = jax.device_put(batch, NamedSharding(mesh, trainer.batch_spec))
     state = trainer.init(jax.random.key(0), batch)
 
     for _ in range(max(args.num_warmup, 1)):  # >=1 keeps compile untimed

@@ -5,16 +5,19 @@ into one XLA program over the chip mesh.
 
     python examples/spmd_resnet50_train.py --steps 20 --batch-size 128
 
-Multi-host: launch one copy per host under horovodrun-tpu with
-HOROVOD_JAX_DISTRIBUTED=1 and the dp axis spans every chip in the pod.
+One process drives every chip of the host.  Multi-host: launch one copy per
+host under horovodrun-tpu (one slot per host) and the dp axis spans every
+chip in the pod.
 """
 import argparse
 import time
 
 import jax
 import optax
+from jax.sharding import NamedSharding
 
 from horovod_tpu import models, training
+from horovod_tpu.common.compile_cache import configure_compile_cache
 from horovod_tpu.parallel import GradSyncConfig, MeshSpec, build_mesh
 
 
@@ -31,6 +34,7 @@ def main() -> None:
                         help="Adasum (scale-adaptive) gradient combine")
     args = parser.parse_args()
 
+    configure_compile_cache()
     n = len(jax.devices())
     mesh = build_mesh(MeshSpec(dp=n))
     trainer = training.Trainer(
@@ -41,8 +45,12 @@ def main() -> None:
             op="adasum" if args.adasum else "average",
             compression=None if args.wire == "none" else args.wire))
 
-    batch = training.synthetic_image_batch(args.batch_size * n,
-                                           image_size=args.image_size)
+    # Placed once, one shard per chip; the default-device array would be
+    # re-sharded from chip 0 on every step.
+    batch = jax.device_put(
+        training.synthetic_image_batch(args.batch_size * n,
+                                       image_size=args.image_size),
+        NamedSharding(mesh, trainer.batch_spec))
     state = trainer.init(jax.random.key(0), batch)
     state, metrics = trainer.step(state, batch)   # compile
     jax.block_until_ready(metrics)

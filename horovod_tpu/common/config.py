@@ -276,9 +276,9 @@ PERF_PEAK_MBPS = register(
 PERF_PEAK_FLOPS = register(
     "HOROVOD_PERF_PEAK_FLOPS", 0.0, float,
     "Peak per-chip dense FLOP/s the MFU ledger divides by.  0 = the "
-    "published per-device_kind table (telemetry/perfmodel.py), with a "
-    "nominal 1e12 for unknown kinds (CPU dev boxes) so the MFU "
-    "trajectory stays populated and self-comparable.")
+    "published per-device_kind table (telemetry/perfmodel.py); a device "
+    "kind the table does not know (CPU dev boxes) has no peak, and no "
+    "MFU gauge is set there.")
 PERF_TOLERANCE_PCT = register(
     "HOROVOD_PERF_TOLERANCE_PCT", 10.0, float,
     "Regression-gate tolerance: telemetry.perfcheck fails (exit 1, "
@@ -792,14 +792,6 @@ AUTOTUNE_PIPELINE = register(
     "active streams, bounded by HOROVOD_NUM_STREAMS) by measured "
     "allreduce throughput before the Bayesian phase, broadcasting the "
     "winner to every rank.")
-BENCH_PROBE_BUDGET_S = register(
-    "HOROVOD_BENCH_PROBE_BUDGET_S", 25.0, float,
-    "Per-probe timeout for bench.py's accelerator probe (seconds).  A "
-    "probe that runs to this timeout means jax.devices() itself wedged "
-    "— after 2 consecutive timed-out probes the absence is definitive "
-    "and the CPU fallback starts immediately (2 x default 25 s keeps "
-    "it under a minute).  Probe CRASHES stay retryable on the watcher "
-    "schedule; only timeouts are terminal.")
 TRACK_ACCURACY = register(
     "HOROVOD_TRACK_ACCURACY", True, _parse_bool,
     "Compute the per-step training-accuracy metric in Trainer.step. "
@@ -819,12 +811,16 @@ def parse_tristate(value: str) -> bool | None:
 JAX_DISTRIBUTED = register(
     "HOROVOD_JAX_DISTRIBUTED", "auto", str,
     "Form the multi-process JAX world at init (jax.distributed.initialize "
-    "via the rendezvous KV): 1 | 0 | auto (yes on accelerator backends).")
+    "via the rendezvous KV): 1 | 0 | auto.  auto forms it on accelerator "
+    "hosts with ONE worker per host (that worker drives every local "
+    "chip); workers that share a host never open the accelerator — a "
+    "chip belongs to one process at a time — and ride the shm/TCP host "
+    "planes (1 is an error there).  Processes pinned to JAX_PLATFORMS=cpu "
+    "form a world only on 1.")
 JAX_HEARTBEAT_TIMEOUT_SECONDS = register(
     "HOROVOD_JAX_HEARTBEAT_TIMEOUT_SECONDS", 100.0, float,
     "jax.distributed coordinator heartbeat timeout passed through to "
-    "jax.distributed.initialize when the installed jaxlib accepts it "
-    "(parallel/multihost.py filters kwargs by signature).")
+    "jax.distributed.initialize.")
 JAX_TEARDOWN_GRACE_SECONDS = register(
     "HOROVOD_JAX_TEARDOWN_GRACE_SECONDS", 30.0, float,
     "Grace window for jax.distributed.shutdown at world teardown "

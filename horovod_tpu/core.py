@@ -359,7 +359,7 @@ def init(*, rank: int | None = None, size: int | None = None,
             # below touches jax) — the analogue of GlooContext rendezvous
             # at init (reference: gloo/gloo_context.cc:136-152).
             from .parallel import multihost
-            if multihost.should_init(size):
+            if multihost.should_init(size, local_size):
                 multihost.init_jax_distributed(
                     rank, size, kv=kv,
                     timeout=max(timeout, 120.0))

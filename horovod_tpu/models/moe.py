@@ -78,8 +78,7 @@ class MoEMLP(nn.Module):
         # Router logits compute outside the shard_map (replicated weights,
         # batch-parallel math); only dispatch + expert FFN go manual.
         logits = router(x)                                    # [B, T, E]
-        from ..common.jax_compat import shard_map
-        return shard_map(
+        return jax.shard_map(
             partial(_expert_parallel_moe_with_logits,
                     axis=self.ep_axis, axis_size=n_ep,
                     capacity_factor=self.capacity_factor,

@@ -172,10 +172,9 @@ def _make_attention(cfg: TransformerConfig) -> Callable:
             return inner
 
         def dispatch(q, k, v):
-            from ..common.jax_compat import shard_map
-            return shard_map(inner, mesh=cfg.mesh,
-                             in_specs=(spec, spec, spec),
-                             out_specs=spec, check_vma=True)(q, k, v)
+            return jax.shard_map(inner, mesh=cfg.mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=True)(q, k, v)
         return dispatch
     raise ValueError(f"Unknown attention impl: {cfg.attention}")
 

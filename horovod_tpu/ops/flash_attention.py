@@ -16,7 +16,6 @@ also be exercised anywhere via ``interpret=True`` (used by the unit tests).
 from __future__ import annotations
 
 import functools
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -33,16 +32,8 @@ def _out_struct(shape, dtype, *like):
     """ShapeDtypeStruct carrying the union of the inputs' varying mesh axes
     (vma) — required for pallas_call inside shard_map regions with
     check_vma=True."""
-    aval_of = getattr(jax, "typeof", None) or jax.core.get_aval
-    vma: frozenset = frozenset()
-    for x in like:
-        v = getattr(aval_of(x), "vma", None)
-        if v:
-            vma |= frozenset(v)
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:   # older jax without vma support
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = frozenset().union(*(jax.typeof(x).vma for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # ===========================================================================
@@ -440,13 +431,21 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def _fit_block(t: int, block: int) -> int:
-    """Largest block <= requested that divides the sequence length (the
-    kernels assume exact tiling; odd lengths degrade granularity instead of
-    failing)."""
-    block = min(block, t)
-    while t % block:
-        block -= 1
-    return block
+    """Largest block <= requested that tiles the sequence exactly AND that
+    the Pallas TPU lowering accepts: a divisor of ``t`` that is a multiple
+    of 8 (the sublane tile), or the whole sequence.  Raises on every
+    backend, so a length the chip would refuse (T=2000 used to degrade to
+    a 125-row block) fails on the CPU paths and in tests too."""
+    if block >= t:
+        return t
+    for cand in range(block - block % 8, 0, -8):
+        if t % cand == 0:
+            return cand
+    raise ValueError(
+        f"flash attention cannot tile a sequence of {t} with blocks of at "
+        f"most {block}: a block must divide the sequence length and be a "
+        f"multiple of 8 (the TPU sublane tile), or span the whole "
+        f"sequence; pad the sequence or pass a larger block")
 
 
 def _check_dtypes(q: jax.Array, k: jax.Array, v: jax.Array) -> None:

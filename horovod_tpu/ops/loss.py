@@ -4,8 +4,7 @@ Large-vocab LM heads pay more for the loss than for the matmul that
 produced the logits: the naive path materializes a second fp32
 ``[tokens, vocab]`` tensor for ``log_softmax`` (6.6 GB at
 batch 16 x seq 2048 x vocab 50304) and its fp32 gradient — all pure HBM
-traffic. Measured on the v5e benchmark config, the naive loss costs
-18.7 ms of a 411 ms step (docs/PERFORMANCE.md "Step decomposition").
+traffic.
 
 This op computes the same mean cross entropy (with optional label
 smoothing) without ever materializing an fp32 logits-sized tensor:

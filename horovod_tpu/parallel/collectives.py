@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..common.jax_compat import shard_map
-
 
 def _axes(axis: str | Sequence[str]) -> tuple[str, ...]:
     return (axis,) if isinstance(axis, str) else tuple(axis)
@@ -167,6 +165,6 @@ def device_collective(fn, mesh: Mesh, axis: str | Sequence[str] = "dp",
     def wrapper(*args):
         return fn(*args)
 
-    mapped = shard_map(wrapper, mesh=mesh, in_specs=in_spec,
-                       out_specs=out_spec, check_vma=False)
+    mapped = jax.shard_map(wrapper, mesh=mesh, in_specs=in_spec,
+                           out_specs=out_spec, check_vma=False)
     return jax.jit(mapped)

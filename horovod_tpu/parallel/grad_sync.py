@@ -517,13 +517,11 @@ def build_grad_sync(mesh, config: GradSyncConfig = GradSyncConfig()):
     API."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
-
     spec = P(config.axes)
 
     def _sync(grads):
         return sync_gradients(grads, config)
 
-    mapped = shard_map(_sync, mesh=mesh, in_specs=spec, out_specs=spec,
-                       check_vma=False)
+    mapped = jax.shard_map(_sync, mesh=mesh, in_specs=spec,
+                           out_specs=spec, check_vma=False)
     return jax.jit(mapped)

@@ -27,6 +27,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from ..common.logging import logger
+
 # outermost → innermost
 DEFAULT_AXES: tuple[str, ...] = ("pp", "dp", "fsdp", "ep", "sp", "tp")
 
@@ -115,7 +117,15 @@ def build_mesh(spec: MeshSpec | None = None,
         try:
             dev_array = mesh_utils.create_device_mesh(shape,
                                                       devices=devices)
-        except (ValueError, AssertionError):
+        except (ValueError, AssertionError) as exc:
+            # Enumeration order is correct but not torus-aware: say so,
+            # because collectives over the outer axes then cross more
+            # ICI hops than they need to.
+            logger.warning(
+                "build_mesh: create_device_mesh%s failed (%s: %s); laying "
+                "the %d devices out in enumeration order, which ignores "
+                "the physical ICI topology", shape, type(exc).__name__,
+                exc, len(devices))
             dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, DEFAULT_AXES)
 

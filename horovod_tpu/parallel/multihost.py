@@ -74,66 +74,38 @@ def init_jax_distributed(rank: int, size: int, kv: Any = None,
             # Cross-process collectives on the CPU backend need the gloo
             # implementation (the virtual-mesh test path; real deployments
             # ride ICI/DCN through the TPU runtime instead).
-            try:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-            except Exception:  # noqa: BLE001 - older jaxlib: no such knob
-                pass
-            if not (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                    or jax.config.jax_compilation_cache_dir):
-                # The compile→barrier→dispatch pattern (Trainer.step →
-                # kv_barrier) only shrinks skew if the post-barrier
-                # dispatch can reload the AOT compile from a persistent
-                # cache — lower().compile() does not seed jit's
-                # in-memory executable cache. Configure a host-shared
-                # cache when the caller hasn't.
-                try:
-                    jax.config.update("jax_compilation_cache_dir",
-                                      "/tmp/horovod_tpu_jax_cache")
-                except Exception:  # noqa: BLE001 - knob absent
-                    pass
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
+            # The compile→barrier→dispatch pattern (Trainer.step →
+            # kv_barrier) only shrinks skew if the post-barrier dispatch
+            # can reload the AOT compile from a persistent cache —
+            # lower().compile() does not seed jit's in-memory executable
+            # cache.
+            from ..common.compile_cache import configure_compile_cache
+            configure_compile_cache()
             # JAX declines to persist programs that compiled faster than
             # jax_persistent_cache_min_compile_time_secs (default 1s), so
             # a fast-compiling step would silently repeat its AOT compile
             # after the barrier — exactly the skew the compile→barrier→
             # dispatch pattern exists to remove.  Persist everything.
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0)
-            except Exception:  # noqa: BLE001 - older jax: knob absent
-                pass
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0)
 
         # Elastic worlds must SURVIVE peer death: without recoverability
         # the coordination service FATALs the surviving processes when the
         # shutdown barrier fails (absl fatal, not an exception), killing
         # the elastic retry loop before it can re-rendezvous.
         if os.environ.get("HOROVOD_ELASTIC"):
-            try:
-                jax.config.update("jax_enable_recoverability", True)
-            except Exception:  # noqa: BLE001 - older jax: knob absent
-                pass
+            jax.config.update("jax_enable_recoverability", True)
         heartbeat = int(os.environ.get(
             "HOROVOD_JAX_HEARTBEAT_TIMEOUT_SECONDS", "100"))
         logger.debug("jax.distributed.initialize rank=%d size=%d coord=%s",
                      rank, size, coordinator_address)
-        # Older jaxlibs lack some tuning kwargs (e.g. 0.4.x has no
-        # heartbeat_timeout_seconds): filter by the actual signature so
-        # world formation works across the supported jax range.
-        import inspect
-        init_kwargs = dict(
+        jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=size, process_id=rank,
             local_device_ids=local_device_ids,
             heartbeat_timeout_seconds=heartbeat,
             initialization_timeout=int(timeout))
-        try:
-            accepted = set(inspect.signature(
-                jax.distributed.initialize).parameters)
-            init_kwargs = {k: v for k, v in init_kwargs.items()
-                           if k in accepted}
-        except (TypeError, ValueError):  # C-level signature: keep all
-            pass
-        jax.distributed.initialize(**init_kwargs)
         if cpu_gloo:
             # Eagerly form the gloo transport pairs while every process
             # is still in init lockstep (reference parity: the gloo
@@ -375,18 +347,50 @@ def _clear_backends() -> None:
         logger.warning("clear_backends failed: %s", exc)
 
 
-def should_init(size: int) -> bool:
-    """Policy for the `auto` knob: form the JAX world on multi-process
-    launches unless the process is pinned to the CPU backend (tests pin
-    JAX_PLATFORMS=cpu and drive multi-process JAX explicitly)."""
+def should_init(size: int, local_size: int = 1) -> bool:
+    """Whether `hvd.init()` forms the multi-process JAX world.
+
+    A TPU chip belongs to one process at a time, and a worker that
+    initializes the TPU runtime opens EVERY local chip.  So workers that
+    share a host (``local_size > 1``) must not each open the accelerator:
+    under ``auto`` they form no JAX world, JAX in them is held to the CPU
+    backend, and their tensors ride the shm/TCP host planes; forcing the
+    world with ``HOROVOD_JAX_DISTRIBUTED=1`` is an error.  The supported
+    way to compute on the chips of one host is ONE SPMD process driving
+    all of them (``Trainer`` over ``build_mesh``); one process per host
+    then forms the world across hosts.  A process pinned to the CPU
+    backend (``JAX_PLATFORMS=cpu``: the tests) has no chip to contend
+    for: ``auto`` forms no world there, ``1`` forms a gloo one."""
     from ..common import config
     mode = config.parse_tristate(config.JAX_DISTRIBUTED.get())
-    if mode is True:
-        return size > 1
-    if mode is False:
+    if mode is False or size <= 1:
         return False
-    # auto: a real accelerator backend will be used
-    return size > 1 and os.environ.get("JAX_PLATFORMS", "") != "cpu"
+    cpu_pinned = os.environ.get("JAX_PLATFORMS", "") == "cpu"
+    if mode is True:
+        if local_size > 1 and not cpu_pinned:
+            raise RuntimeError(
+                f"HOROVOD_JAX_DISTRIBUTED=1 with {local_size} workers on "
+                "one host: each worker would initialize the accelerator "
+                "runtime over every local chip, and a chip belongs to one "
+                "process at a time.  Run one SPMD process per host "
+                "(Trainer over build_mesh drives all local chips), or "
+                "leave HOROVOD_JAX_DISTRIBUTED=auto so these workers ride "
+                "the shm/TCP host planes.")
+        return True
+    if cpu_pinned:
+        return False
+    if local_size > 1:
+        import jax
+        jax.config.update("jax_platforms", "cpu")
+        logger.warning(
+            "%d workers share this host and a TPU chip belongs to one "
+            "process at a time: this worker forms no JAX world and does "
+            "not open the accelerator (JAX here is held to the CPU "
+            "backend); eager tensors ride the shm/TCP host planes.  To "
+            "compute on the chips run one SPMD process for all local "
+            "chips (Trainer over build_mesh).", local_size)
+        return False
+    return True
 
 
 def make_global_array(mesh, spec, array):

@@ -311,6 +311,8 @@ def main(argv: list[str] | None = None) -> int:
     args = make_parser().parse_args(argv)
     if args.slo_ms == 0.0:
         args.slo_ms = None
+    from ..common.compile_cache import configure_compile_cache
+    configure_compile_cache()
     run(args)
     return 0
 

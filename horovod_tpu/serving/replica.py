@@ -171,11 +171,7 @@ class ReplicaExecutor:
         self._stop_requested = False
         self._configure_groups()
 
-        model_cfg = self.cfg.model_cfg
-        if model_cfg is None:
-            model_cfg = tfm.gpt_tiny(dtype=jnp.float32)
-        model_cfg = dataclasses.replace(model_cfg, decode=True,
-                                        max_seq_len=self.cfg.max_seq)
+        model_cfg = _decode_model_cfg(self.cfg)
         if self.cfg.paged:
             model_cfg = dataclasses.replace(
                 model_cfg, paged=True,
@@ -186,9 +182,7 @@ class ReplicaExecutor:
             # Seeded, deterministic: every replica materializes identical
             # weights without a broadcast (replace with a checkpoint
             # restore or hvd.broadcast_object for real weights).
-            params = self.model.init(
-                jax.random.PRNGKey(self.cfg.seed),
-                jnp.zeros((1, 8), jnp.int32))["params"]
+            params = _seeded_params(self.model, self.cfg.seed)
         self.params = params
 
         self.slots: list[_Slot | None] = [None] * self.cfg.slots
@@ -201,9 +195,8 @@ class ReplicaExecutor:
         # misclassified as lost (front dedups via batcher membership).
         self._unreported: list[dict] = []
         # perfscope serve ledger (telemetry/perfmodel.py): smoothed
-        # accepted-tokens/s and the cached per-chip peak for serve MFU.
+        # accepted-tokens/s.
         self._perf_tps = 0.0
-        self._peak_flops: float | None = None
         self.stats = {"offered": 0, "expired": 0, "served": 0,
                       "served_slo": 0, "lost": 0,
                       "latencies_ms": [], "completed_at": [],
@@ -260,6 +253,9 @@ class ReplicaExecutor:
             self._decode_jit = jax.jit(self._decode_impl)
             self._prefill_jit = jax.jit(self._prefill_impl)
         self._kvstream = None
+        # Jitted like every other model call here: un-jitted, each of its
+        # hundreds of small ops compiles and dispatches on its own.
+        self._init_cache_jit = jax.jit(self._init_cache_impl)
         self._init_cache()
         self._warmup()
         if self.prefill_rank_list:
@@ -349,21 +345,24 @@ class ReplicaExecutor:
                 cache)
 
     def _init_cache(self) -> None:
+        self._cache = self._init_cache_jit(self.params)
+
+    def _init_cache_impl(self, params):
+        """A fresh KV cache: one apply creates the cache collection (its
+        only writes land at position 0, or in the paged sink row)."""
+        from flax.core import unfreeze
         if self.cfg.paged:
-            zeros = jnp.zeros((1, 1), jnp.int32)
             _, mut = self.model.apply(
-                {"params": self.params}, zeros,
+                {"params": params}, jnp.zeros((1, 1), jnp.int32),
                 block_tables=jnp.full((1, self.cfg.table_width),
                                       self._sink, jnp.int32),
                 cursors=jnp.zeros((1,), jnp.int32),
                 mutable=["cache"])
-            from flax.core import unfreeze
-            self._cache = unfreeze(mut["cache"])
-            return
-        zeros = jnp.zeros((self.cfg.slots, 1), jnp.int32)
-        _, mut = self.model.apply({"params": self.params}, zeros,
-                                  mutable=["cache"])
-        self._cache = tfm._with_cache_index(mut["cache"], 0)
+            return unfreeze(mut["cache"])
+        _, mut = self.model.apply(
+            {"params": params}, jnp.zeros((self.cfg.slots, 1), jnp.int32),
+            mutable=["cache"])
+        return tfm._with_cache_index(mut["cache"], 0)
 
     def _warmup(self) -> None:
         if self.cfg.paged:
@@ -769,7 +768,9 @@ class ReplicaExecutor:
                    # (docs/fleet.md).
                    "weights": self.weight_version,
                    "weights_stale_steps": stale}
-            self.completed[s.rid] = rec
+            # The local record also keeps the token stream itself (the
+            # answer); only counts ride the completions allgather.
+            self.completed[s.rid] = {**rec, "generated": list(s.generated)}
             if self.group_leader:
                 # Every group member frees slots identically; only the
                 # leader reports, so completions appear exactly once.
@@ -1029,13 +1030,6 @@ class ReplicaExecutor:
         if not tm.enabled or tokens <= 0 or dt_s <= 0.0:
             return
         from ..telemetry import perfmodel
-        if self._peak_flops is None:
-            kind = ""
-            try:
-                kind = jax.local_devices()[0].device_kind
-            except Exception:  # noqa: BLE001 - backend probing only
-                pass
-            self._peak_flops = perfmodel.peak_flops(kind)
         tps = tokens / dt_s
         # EMA over steps: a serve step is milliseconds, and the raw
         # per-step rate whipsaws with batch occupancy.
@@ -1045,8 +1039,11 @@ class ReplicaExecutor:
             self.model.cfg, ctx_sum / tokens)
         tm.gauge("horovod_serve_tokens_per_sec").set(self._perf_tps)
         tm.gauge("horovod_serve_flops_per_token").set(flops_per_token)
-        tm.gauge("horovod_serve_mfu").set(
-            self._perf_tps * flops_per_token / self._peak_flops)
+        # A device kind without a known peak gets no MFU gauge.
+        peak = perfmodel.peak_flops(jax.local_devices()[0].device_kind)
+        if peak is not None:
+            tm.gauge("horovod_serve_mfu").set(
+                self._perf_tps * flops_per_token / peak)
 
     # -- the loop --------------------------------------------------------
     def _serve_step(self) -> bool:
@@ -1230,20 +1227,29 @@ class ReplicaExecutor:
             self.pool.close()
 
 
+def _decode_model_cfg(cfg: ServeConfig):
+    model_cfg = cfg.model_cfg
+    if model_cfg is None:
+        model_cfg = tfm.gpt_tiny(dtype=jnp.float32)
+    return dataclasses.replace(model_cfg, decode=True,
+                               max_seq_len=cfg.max_seq)
+
+
+def _seeded_params(model, seed: int):
+    """The seed's weights, as one compiled program (un-jitted, flax init
+    compiles and dispatches each of its small ops on its own)."""
+    return jax.jit(model.init)(jax.random.PRNGKey(seed),
+                               jnp.zeros((1, 8), jnp.int32))["params"]
+
+
 def serving_params_template(cfg: ServeConfig) -> dict:
     """The state tree a serving joiner offers to ``join_world``: the
     model's parameter pytree (shapes/dtypes only matter — values are
     replaced by the streamed image)."""
     import horovod_tpu  # noqa: F401 - jax config side effects
 
-    model_cfg = cfg.model_cfg
-    if model_cfg is None:
-        model_cfg = tfm.gpt_tiny(dtype=jnp.float32)
-    model_cfg = dataclasses.replace(model_cfg, decode=True,
-                                    max_seq_len=cfg.max_seq)
-    model = tfm.TransformerLM(model_cfg)
-    params = model.init(jax.random.PRNGKey(cfg.seed),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
+    params = _seeded_params(tfm.TransformerLM(_decode_model_cfg(cfg)),
+                            cfg.seed)
     return {"params": jax.tree_util.tree_map(np.asarray, params)}
 
 

@@ -68,9 +68,8 @@ def size_bucket(nbytes: float) -> str:
 # ---------------------------------------------------------------------------
 # Peak dense bf16 FLOP/s per chip, by substring of device_kind.
 # Public numbers from cloud.google.com/tpu/docs (v2-v6e system
-# architecture pages).  Order matters: first match wins.  (Moved here
-# from bench.py so the Trainer, the serving replica and the bench all
-# read one table.)
+# architecture pages).  Order matters: first match wins.  The Trainer,
+# the serving replica and the bench all read this one table.
 # ---------------------------------------------------------------------------
 PEAK_FLOPS_TABLE = (
     ("v6", 918e12),       # Trillium / v6e
@@ -83,15 +82,11 @@ PEAK_FLOPS_TABLE = (
     ("v2", 45e12),
 )
 
-# Unknown device kinds (CPU runs, emulators) get a nominal 1 TFLOP/s so
-# the MFU *trajectory* is still populated and comparable run-over-run;
-# only runs on a recognized TPU kind report an absolute utilization.
-NOMINAL_PEAK_FLOPS = 1e12
-
-
-def peak_flops(device_kind: str) -> float:
-    """Peak dense FLOP/s for a device kind; NOMINAL_PEAK_FLOPS when the
-    kind is unknown (override via HOROVOD_PERF_PEAK_FLOPS)."""
+def peak_flops(device_kind: str) -> float | None:
+    """Peak dense FLOP/s for a device kind (override via
+    HOROVOD_PERF_PEAK_FLOPS).  None for a kind the table does not know
+    (CPU runs, emulators): an MFU needs a real peak, so callers set no
+    MFU gauge there instead of dividing by a made-up one."""
     from ..common import config
     knob = float(config.PERF_PEAK_FLOPS.get())
     if knob > 0.0:
@@ -100,7 +95,7 @@ def peak_flops(device_kind: str) -> float:
     for key, peak in PEAK_FLOPS_TABLE:
         if key in kind:
             return peak
-    return NOMINAL_PEAK_FLOPS
+    return None
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,6 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import optax
-from .common.jax_compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .common.logging import logger
@@ -79,7 +78,10 @@ def _device_memory_bytes() -> int | None:
     return _device_memory_cache
 
 
-def _ce_threshold() -> int:
+def ce_streaming_threshold() -> int:
+    """Logit count at and above which `cross_entropy_loss` streams over
+    the vocab axis: HOROVOD_STREAMING_CE_MIN_ELEMENTS, else a sixteenth
+    of the local device's reported memory in bytes, else 2^30."""
     # Read per call (trace-time Python, so this is free): the documented
     # env override must work even when set after `import horovod_tpu`.
     raw = os.environ.get("HOROVOD_STREAMING_CE_MIN_ELEMENTS")
@@ -104,7 +106,7 @@ def _track_accuracy() -> bool:
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        label_smoothing: float = 0.0) -> jax.Array:
     """Mean softmax cross entropy over integer labels (fp32 math)."""
-    if logits.size >= _ce_threshold():
+    if logits.size >= ce_streaming_threshold():
         from .ops.loss import streaming_softmax_cross_entropy
         return streaming_softmax_cross_entropy(logits, labels,
                                                label_smoothing)
@@ -154,7 +156,6 @@ class Trainer:
         # throughput — blocking on the result here would serialize the
         # async dispatch the fit loop is careful to preserve).
         self._step_flops: float | None = None
-        self._peak_flops: float | None = None
         self._last_dispatch: float | None = None
         # Fleet continuous deployment (fleet/deploy.py): rank 0 wires a
         # WeightPublisher in via attach_fleet_publisher; the host-side
@@ -336,7 +337,7 @@ class Trainer:
         # size-1 tp/pp/sp axes would raise "cannot be automatically
         # partitioned" on TPU. Models that embed their own shard_map
         # regions use the pure-GSPMD mode above instead.
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(state_specs, self.batch_spec),
             out_specs=(state_specs, P()),
@@ -363,22 +364,17 @@ class Trainer:
                 image_size=int(x.shape[1]) if ndim == 4 else 224,
                 train=True)
             tm.gauge("horovod_train_step_flops").set(self._step_flops)
-        if self._peak_flops is None:
-            kind = ""
-            try:
-                kind = jax.local_devices()[0].device_kind
-            except Exception:  # noqa: BLE001 - backend probing only
-                pass
-            # The step consumes the GLOBAL batch, so the denominator is
-            # the whole mesh's peak, not one chip's.
-            self._peak_flops = perfmodel.peak_flops(kind) \
-                * max(jax.device_count(), 1)
         if first or prev is None:
             return
         dt = now - prev
         tm.histogram("horovod_train_step_ms").observe(dt * 1e3)
-        tm.gauge("horovod_train_mfu").set(
-            perfmodel.mfu(self._step_flops, dt, self._peak_flops))
+        # The step consumes the GLOBAL batch, so the denominator is the
+        # whole mesh's peak, not one chip's.  A device kind without a
+        # known peak gets no MFU gauge.
+        peak = perfmodel.peak_flops(self.mesh.devices.flat[0].device_kind)
+        if peak is not None:
+            tm.gauge("horovod_train_mfu").set(perfmodel.mfu(
+                self._step_flops, dt, peak * self.mesh.size))
 
     # -- fleet continuous deployment (fleet/) ------------------------------
     def attach_fleet_publisher(self, publisher) -> None:

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.common.jax_compat import shard_map
+from jax import shard_map
 
 from horovod_tpu.ops.flash_attention import (flash_attention,
                                              flash_attention_with_lse,

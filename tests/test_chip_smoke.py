@@ -1,0 +1,244 @@
+"""The chip check's CPU half: chip_smoke.py's control flow, the no-TPU
+exits, the compile-cache placement, and the flash kernels compiled for a
+v5e without one (libtpu's compile-only topology).
+
+Every subprocess this file needs starts together in one fixture and the
+dry run is awaited last, so the file costs its slowest child, not the sum
+of the children and the in-process compiles.
+"""
+from __future__ import annotations
+
+import importlib
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MOSAIC = 'custom_call_target="tpu_custom_call"'
+_CACHE_PROBE = ("from horovod_tpu.common.compile_cache import "
+                "configure_compile_cache as c; import jax; "
+                "print(c()); print(jax.config.jax_compilation_cache_dir)")
+
+fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+
+def _git_status() -> str | None:
+    """What git sees outside .gitignore; None where the checkout is not a
+    repository."""
+    out = subprocess.run(["git", "status", "--porcelain"], cwd=REPO,
+                         capture_output=True, text=True)
+    return out.stdout if out.returncode == 0 else None
+
+
+class _Children:
+    """Subprocesses started together; ``result(name)`` waits for one."""
+
+    def __init__(self) -> None:
+        base = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        no_cache = {k: v for k, v in base.items()
+                    if k != "JAX_COMPILATION_CACHE_DIR"}
+        self.tree_before = _git_status()
+        self.started = time.monotonic()
+        self._done: dict[str, tuple[int, str, str]] = {}
+        self._procs = {
+            "smoke_no_tpu": self._spawn(["chip_smoke.py"], base),
+            "bench_no_tpu": self._spawn(["bench.py", "--model", "gpt"],
+                                        base),
+            "cache_a": self._spawn(["-c", _CACHE_PROBE], no_cache),
+            "cache_b": self._spawn(["-c", _CACHE_PROBE], no_cache),
+            # Persist even its sub-second compiles: a warm suite cache
+            # then halves the slowest child.
+            "dry_run": self._spawn(
+                ["chip_smoke.py", "--dry-run-cpu"],
+                {**base,
+                 "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}),
+        }
+
+    @staticmethod
+    def _spawn(argv: list[str], env: dict) -> subprocess.Popen:
+        return subprocess.Popen([sys.executable, *argv], cwd=REPO, env=env,
+                                stdout=subprocess.PIPE,
+                                stderr=subprocess.PIPE, text=True)
+
+    def result(self, name: str, timeout: float) -> tuple[int, str, str]:
+        if name not in self._done:
+            proc = self._procs[name]
+            out, err = proc.communicate(timeout=timeout)
+            self._done[name] = (proc.returncode, out, err)
+        return self._done[name]
+
+    def close(self) -> None:
+        for proc in self._procs.values():
+            if proc.poll() is None:
+                proc.kill()
+            proc.communicate()
+
+
+@pytest.fixture
+def hvd_log(caplog):
+    """The repo logger does not propagate to caplog's root handler."""
+    from horovod_tpu.common.logging import logger
+    logger.addHandler(caplog.handler)
+    try:
+        yield caplog
+    finally:
+        logger.removeHandler(caplog.handler)
+
+
+@pytest.fixture(scope="module")
+def children():
+    kids = _Children()
+    try:
+        yield kids
+    finally:
+        kids.close()
+
+
+@pytest.mark.parametrize("name", ["smoke_no_tpu", "bench_no_tpu"])
+def test_no_tpu_is_a_fast_named_failure(children, name):
+    """Without a TPU neither script falls back to the CPU: non-zero exit
+    within 10 s, one line naming the missing TPU, no result line."""
+    rc, out, err = children.result(name, timeout=30)
+    elapsed = time.monotonic() - children.started
+    assert rc != 0
+    assert "no TPU" in err and "'cpu'" in err, err[-500:]
+    assert out.strip() == "", out
+    assert elapsed < 10.0, elapsed
+
+
+def test_cache_helper_leaves_a_set_variable_alone(monkeypatch, tmp_path):
+    from horovod_tpu.common.compile_cache import configure_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_cache_default_is_one_fixed_place_in_the_checkout(children):
+    """Unset, the cache is <checkout>/.jax_cache in every process: no
+    tempfile, pid or clock in the path, so a second process hits."""
+    want = os.path.join(REPO, ".jax_cache")
+    for name in ("cache_a", "cache_b"):
+        rc, out, err = children.result(name, timeout=60)
+        assert rc == 0, err[-2000:]
+        assert out.split() == [want, want], out
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of libtpu's compile-only v5e:2x2 topology: lowers and
+    compiles (Mosaic included) for the chip, runs nothing."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+    except Exception as exc:  # noqa: BLE001 - any libtpu load failure
+        pytest.skip(f"compile-only v5e:2x2 topology unavailable: "
+                    f"{type(exc).__name__}: {exc}")
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)),
+                         PartitionSpec())
+
+
+def _compile_attention_grad(v5e, monkeypatch, shape, **blocks) -> str:
+    # The public entry point picks the kernels by the DEFAULT backend,
+    # which is the CPU here; the compile target is the v5e.
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, **blocks)
+        return out.astype(jnp.float32).sum()
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    return grad.lower(qkv, qkv, qkv).compile().as_text()
+
+
+def test_flash_kernels_compile_for_v5e_at_smoke_shapes(v5e, monkeypatch):
+    """Forward, dq and dk/dv kernels at chip_smoke's GPT-small shapes
+    (batch 8, seq 2048, 12 heads of 64, bf16, blocks 1024/1024) fit the
+    v5e's scoped VMEM and lower through Mosaic."""
+    text = _compile_attention_grad(v5e, monkeypatch, (8, 2048, 12, 64),
+                                   block_q=1024, block_k=1024)
+    assert text.count(MOSAIC) == 3
+
+
+def test_flash_odd_length_compiles_for_v5e(v5e, monkeypatch):
+    """T=2000 used to degrade to a 125-row block, which the TPU lowering
+    refuses while the CPU path passes; the fitted block (80) compiles."""
+    text = _compile_attention_grad(v5e, monkeypatch, (1, 2000, 1, 64))
+    assert text.count(MOSAIC) == 3
+
+
+def test_fit_block_follows_the_tpu_tiling_rule():
+    assert fa._fit_block(2048, 1024) == 1024
+    assert fa._fit_block(2000, 128) == 80       # not 125
+    assert fa._fit_block(96, 128) == 96         # whole sequence
+    assert fa._fit_block(100, 128) == 100
+    for t, block in ((2001, 128), (2000, 4), (100, 64)):
+        with pytest.raises(ValueError, match="multiple of 8"):
+            fa._fit_block(t, block)
+    # Same refusal through the public entry point, on the CPU.
+    q = jnp.zeros((1, 100, 1, 8), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        fa.flash_attention(q, q, q, block_q=64, block_k=64)
+
+
+def test_workers_sharing_a_host_do_not_form_a_jax_world(monkeypatch,
+                                                        hvd_log):
+    """One process per chip: off the CPU pin, `auto` keeps local workers
+    on the host planes and says so; forcing the world is an error that
+    names the cause.  One worker per host still forms it."""
+    from horovod_tpu.parallel import multihost
+
+    monkeypatch.delenv("HOROVOD_JAX_DISTRIBUTED", raising=False)
+    assert multihost.should_init(4, local_size=4) is False   # cpu-pinned
+    monkeypatch.setenv("JAX_PLATFORMS", "")
+    assert multihost.should_init(4, local_size=4) is False
+    assert "does not open the accelerator" in hvd_log.text
+    assert multihost.should_init(4, local_size=1) is True
+    assert multihost.should_init(1, local_size=1) is False
+    monkeypatch.setenv("HOROVOD_JAX_DISTRIBUTED", "1")
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        multihost.should_init(4, local_size=2)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")               # the tests
+    assert multihost.should_init(4, local_size=2) is True
+
+
+def test_mesh_fallback_names_the_lost_topology(monkeypatch, hvd_log):
+    from jax.experimental import mesh_utils
+
+    from horovod_tpu.parallel import MeshSpec, build_mesh
+
+    def refuse(*_, **__):
+        raise ValueError("no torus for you")
+
+    monkeypatch.setattr(mesh_utils, "create_device_mesh", refuse)
+    mesh = build_mesh(MeshSpec(dp=8))
+    assert mesh.shape["dp"] == 8
+    assert "ValueError: no torus for you" in hvd_log.text
+    assert "enumeration order" in hvd_log.text
+
+
+def test_dry_run_exercises_every_leg(children):
+    """--dry-run-cpu walks all three legs plus the multi-device checks on
+    two virtual devices, and every line says it is a dry run — the last
+    line is therefore NOT the bare JSON a chip run ends with."""
+    rc, out, err = children.result("dry_run", timeout=240)
+    assert rc == 0, err[-3000:]
+    lines = out.strip().splitlines()
+    assert all(ln.startswith("DRY RUN (cpu) ") for ln in lines), lines
+    legs = [ln.split()[4] for ln in lines if "smoke-observation" in ln]
+    assert legs == ["gpt_train", "gpt_cross_check", "resnet_train",
+                    "before_serve", "serve_dense", "serve_paged",
+                    "serve_layouts_agree"]
+    assert lines[-1].endswith('{"ok": true, "device": {"platform": "cpu", '
+                              '"kind": "cpu", "count": 2}}')
+    # The run leaves nothing behind that .gitignore does not list.
+    assert _git_status() == children.tree_before

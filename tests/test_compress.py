@@ -522,7 +522,7 @@ def test_grad_sync_ef_training_within_5pct_of_fp32():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import (GradSyncConfig, init_error_feedback,
                                       sync_gradients, sync_gradients_ef)
 

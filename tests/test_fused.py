@@ -314,7 +314,7 @@ def _ring_world_run(world, grads, params, tx, cfg):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import (init_ring_optimizer_state,
                                       sync_and_apply)
 
@@ -362,7 +362,7 @@ def test_optimizer_in_ring_matches_sync_then_update(world):
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import GradSyncConfig, sync_gradients
 
     rng = np.random.default_rng(20 + world)
@@ -403,7 +403,7 @@ def test_optimizer_in_ring_int8_gradient_leg():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import GradSyncConfig, sync_gradients
 
     world = 4
@@ -518,7 +518,7 @@ def test_fused_scale_clip_matches_optax():
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import GradSyncConfig, sync_gradients
 
     world, S, C = 4, 256.0, 0.75
@@ -553,7 +553,7 @@ def test_fused_scale_only_unscales():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import GradSyncConfig, sync_gradients
 
     world, S = 2, 64.0
@@ -578,7 +578,7 @@ def test_fused_scale_clip_threads_through_ef():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel import (GradSyncConfig, init_error_feedback,
                                       sync_gradients_ef)
 

@@ -1,6 +1,6 @@
 """Streaming (vocab-chunked) cross entropy == dense log_softmax CE.
 
-The streaming op only engages above the training._ce_threshold() size in the
+The streaming op only engages above the training.ce_streaming_threshold() size in the
 trainer path; these tests call it directly on small shapes so the
 chunked math (online logsumexp, chunked backward, label smoothing) is
 pinned against the dense reference at test scale.

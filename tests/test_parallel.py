@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.common.jax_compat import shard_map
+from jax import shard_map
 
 from horovod_tpu.ops.adasum import adasum_reference
 from horovod_tpu.parallel import (GradSyncConfig, MeshSpec, adasum_allreduce,

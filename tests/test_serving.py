@@ -435,25 +435,6 @@ def _solo_world():
     return hvd
 
 
-class _Recorder:
-    """Capture every completed slot's generated token stream (the
-    completion record only carries counts)."""
-
-    def __init__(self):
-        self.streams = {}
-
-    def install(self, ex):
-        orig = ex._collect_completions
-
-        def wrapped():
-            for s in ex.slots:
-                if s is not None and s.pending is None \
-                        and s.remaining == 0:
-                    self.streams[s.rid] = list(s.generated)
-            orig()
-        ex._collect_completions = wrapped
-
-
 def _paged_cfg(**kw):
     from horovod_tpu.serving import ServeConfig
     base = dict(max_batch=2, token_budget=64, max_seq=64,
@@ -476,8 +457,6 @@ def test_paged_serve_parity_prefix_hits_and_refcount_census():
     for paged in (False, True):
         hvd = _solo_world()
         ex = ReplicaExecutor(_paged_cfg(paged=paged))
-        rec = _Recorder()
-        rec.install(ex)
         rng = random.Random(7)
         prompts = [[rng.randrange(2, 256)
                     for _ in range(rng.randint(2, 12))]
@@ -495,7 +474,8 @@ def test_paged_serve_parity_prefix_hits_and_refcount_census():
             assert kv["cow_copies"] > 0, kv       # shared tails COWed
             assert kv["prefill_skipped"] > 0, kv  # full hits skip prefill
             assert kv["max_concurrent_seqs"] > ex.cfg.max_batch
-        streams[paged] = dict(rec.streams)
+        streams[paged] = {rid: rec["generated"]
+                          for rid, rec in ex.completed.items()}
         ex.close()
         hvd.shutdown()
     assert streams[False] == streams[True]        # bitwise token parity
@@ -514,8 +494,6 @@ def test_paged_eviction_then_readmission_stays_correct():
     # prompts force LRU eviction of the cached ones.
     ex = ReplicaExecutor(_paged_cfg(paged=True, paged_slots=2,
                                     pool_blocks=8))
-    rec = _Recorder()
-    rec.install(ex)
     rng = random.Random(11)
     prompts = [[rng.randrange(2, 256) for _ in range(9)]
                for _ in range(4)]
@@ -534,8 +512,8 @@ def test_paged_eviction_then_readmission_stays_correct():
     assert kv["active"] == 0, kv
     # Re-admissions (same prompt, wave 2) reproduced wave-1 streams.
     by_prompt = {}
-    for rid, stream in sorted(rec.streams.items()):
-        by_prompt.setdefault(rid_prompt[rid], []).append(stream)
+    for rid, rec in sorted(ex.completed.items()):
+        by_prompt.setdefault(rid_prompt[rid], []).append(rec["generated"])
     for p, gens in by_prompt.items():
         assert len(gens) == 2 and gens[0] == gens[1], p
     ex.close()

@@ -393,7 +393,7 @@ class TestRingCheckpoint:
         from jax.sharding import Mesh
         from jax.sharding import PartitionSpec as P
 
-        from horovod_tpu.common.jax_compat import shard_map
+        from jax import shard_map
         from horovod_tpu.parallel import (init_ring_optimizer_state,
                                           sync_and_apply)
 
