@@ -324,11 +324,13 @@ def stop_profiler() -> None:
     jax.profiler.stop_trace()
 
 
-def profiler_annotation(name: str):
+def profiler_annotation(name: str, **args):
     """Context manager labelling a region in device traces (the NVTX-range
-    analogue, reference: common/nvtx_op_range.h)."""
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+    analogue, reference: common/nvtx_op_range.h): the span ``hvd.<name>``
+    on the profiler's clock, like the program's own
+    (telemetry/spans.py; docs/observability.md)."""
+    from .telemetry.spans import span
+    return span(name, **args)
 
 
 def allgather_object(obj: Any, name: str | None = None) -> list:

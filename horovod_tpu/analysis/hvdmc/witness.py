@@ -37,7 +37,7 @@ __all__ = ["GENERIC_KINDS", "WitnessReport", "check", "load_dumps",
 GENERIC_KINDS = frozenset({
     "enqueue", "dispatch", "done", "error", "ranks-failed",
     "fingerprint-divergence", "sigterm", "lock-order", "mark-failed",
-    "deadline-convert", "autoscale",
+    "deadline-convert", "autoscale", "serve_slow_step",
 })
 
 

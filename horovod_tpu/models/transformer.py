@@ -238,10 +238,12 @@ class Attention(nn.Module):
                     raise ValueError(
                         "paged decode needs block_tables [B, M] and "
                         "cursors [B] on every apply")
-                out = self._decode_attend_paged(q, k, v, block_tables,
-                                                cursors, lengths)
+                with jax.named_scope("hvd.decode_attend"):
+                    out = self._decode_attend_paged(
+                        q, k, v, block_tables, cursors, lengths)
             else:
-                out = self._decode_attend(q, k, v)
+                with jax.named_scope("hvd.decode_attend"):
+                    out = self._decode_attend(q, k, v)
         else:
             if cfg.attention in ("ring", "ulysses") and \
                     _axis_is_manual(cfg.sp_axis) and \

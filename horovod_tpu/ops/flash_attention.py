@@ -158,6 +158,7 @@ def _flash_fwd_pallas(q, k, v, *, sm_scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd.flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -303,6 +304,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, sm_scale, causal,
         out_shape=_out_struct((bh, tq, d), q.dtype, q, k, v, do),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="hvd.flash_bwd",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -331,6 +333,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, sm_scale, causal,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="hvd.flash_bwd",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -388,13 +391,16 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     # The residual keeps lse at [BH, T]: holding the kernels' lane-
     # broadcast [BH, T, _LANE] layout across fwd→bwd would pin 128× the
     # HBM for the whole backward span; the backward re-broadcasts it.
-    if _on_tpu() or interpret:
-        o, lse = _flash_fwd_pallas(q, k, v, sm_scale=sm_scale,
-                                   causal=causal, block_q=block_q,
-                                   block_k=block_k, interpret=interpret)
-        lse = lse[:, :, 0]
-    else:
-        o, lse = _blockwise_jax(q, k, v, sm_scale=sm_scale, causal=causal)
+    with jax.named_scope("hvd.flash_fwd"):
+        if _on_tpu() or interpret:
+            o, lse = _flash_fwd_pallas(q, k, v, sm_scale=sm_scale,
+                                       causal=causal, block_q=block_q,
+                                       block_k=block_k,
+                                       interpret=interpret)
+            lse = lse[:, :, 0]
+        else:
+            o, lse = _blockwise_jax(q, k, v, sm_scale=sm_scale,
+                                    causal=causal)
     return o, (q, k, v, o, lse)
 
 
@@ -413,17 +419,18 @@ def _flash_bwd_rule(sm_scale, causal, block_q, block_k,
     # independently (r3 found fwd 1024/1024 optimal while 1024/2048
     # exceeded the 16 MiB scoped-vmem limit).
     q, k, v, o, lse = res
-    if _on_tpu() or interpret:
-        dq, dk, dv = _flash_bwd_pallas(
-            q, k, v, o, lse, g, sm_scale=sm_scale, causal=causal,
-            block_q=block_q_bwd or block_q,
-            block_k=block_k_bwd or block_k, interpret=interpret)
-    else:
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _blockwise_jax(q_, k_, v_,
-                                              sm_scale=sm_scale,
-                                              causal=causal)[0], q, k, v)
-        dq, dk, dv = vjp(g)
+    with jax.named_scope("hvd.flash_bwd"):
+        if _on_tpu() or interpret:
+            dq, dk, dv = _flash_bwd_pallas(
+                q, k, v, o, lse, g, sm_scale=sm_scale, causal=causal,
+                block_q=block_q_bwd or block_q,
+                block_k=block_k_bwd or block_k, interpret=interpret)
+        else:
+            _, vjp = jax.vjp(
+                lambda q_, k_, v_: _blockwise_jax(
+                    q_, k_, v_, sm_scale=sm_scale, causal=causal)[0],
+                q, k, v)
+            dq, dk, dv = vjp(g)
     return dq, dk, dv
 
 

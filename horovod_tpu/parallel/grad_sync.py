@@ -127,7 +127,8 @@ def sync_gradients(grads: Any, config: GradSyncConfig = GradSyncConfig()
                    ) -> Any:
     """Reduce a gradient pytree over the mesh axes. Call inside a
     shard_mapped / jitted train step."""
-    out, _ = _sync_impl(grads, config, None)
+    with jax.named_scope("hvd.grad_sync"):
+        out, _ = _sync_impl(grads, config, None)
     return out
 
 
@@ -148,7 +149,8 @@ def sync_gradients_ef(grads: Any, residuals: Any,
     residuals pass through untouched."""
     if _quantized_codec(config.compression) is None:
         return sync_gradients(grads, config), residuals
-    return _sync_impl(grads, config, residuals)
+    with jax.named_scope("hvd.grad_sync"):
+        return _sync_impl(grads, config, residuals)
 
 
 def _sync_impl(grads: Any, config: GradSyncConfig,
@@ -334,6 +336,7 @@ def init_ring_optimizer_state(tx, params: Any, world_size: int,
     return tx.init(jnp.zeros((chunk,), jnp.float32))
 
 
+@jax.named_scope("hvd.grad_sync")
 def sync_and_apply(tx, grads: Any, params: Any, opt_state: Any,
                    config: GradSyncConfig) -> tuple[Any, Any]:
     """Fused gradient sync + optimizer update (optimizer-in-ring): call

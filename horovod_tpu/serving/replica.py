@@ -46,10 +46,15 @@ Execution model (ISSUE 9 tentpole, extended by ISSUE 14):
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import gc
+import json
 import os
+import statistics
 import threading
 import time
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +64,7 @@ from ..common import config
 from ..common.exceptions import RanksFailedError
 from ..common.logging import logger
 from ..models import transformer as tfm
+from ..telemetry.spans import StepParts, span
 from .admission import AdmissionController
 from .batcher import Assignment, BatchPlan, ContinuousBatcher
 from .kvpool import FNV_SEED, KVBlockPool, chain_hash
@@ -202,7 +208,19 @@ class ReplicaExecutor:
                       "latencies_ms": [], "completed_at": [],
                       "shrinks": [], "grows": [],
                       "prefill_streams": 0, "prefill_fallbacks": 0,
-                      "prefill_skipped": 0, "weight_swaps": []}
+                      "prefill_skipped": 0, "weight_swaps": [],
+                      # Always-on part timers of the serve step
+                      # (telemetry/spans.py), by kind of step: "admit"
+                      # steps prefilled at least one request here,
+                      # "decode" steps none.
+                      "steps": {"admit": 0, "decode": 0},
+                      "step_parts_s": {"admit": {}, "decode": {}},
+                      "slow_steps": [], "slow_steps_total": 0}
+        # Host seconds of the last steps of each kind: a step slower
+        # than _SLOW_FACTOR times their median leaves a record.
+        self._recent_steps = {
+            kind: collections.deque(maxlen=_RECENT_STEPS)
+            for kind in ("admit", "decode")}
         # Elastic grow mid-serve (statesync/): attach_statesync wires a
         # membership service in; None = the pre-ISSUE-10 behavior with
         # zero extra collectives.
@@ -315,13 +333,12 @@ class ReplicaExecutor:
     def _decode_impl(self, params, cache, tokens):
         logits, cache = tfm.decode_step(self.model, {"params": params},
                                         cache, tokens)
-        return (jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
-                cache)
+        return _sample(logits[:, -1, :]), cache
 
     def _prefill_impl(self, params, tokens, n):
         logits, cache = tfm.prefill(self.model, {"params": params},
                                     tokens, lengths=n)
-        return (jnp.argmax(logits[0, n - 1, :]).astype(jnp.int32), cache)
+        return _sample(logits[0, n - 1, :]), cache
 
     def _paged_impl(self, params, cache, tokens, tables, cursors):
         """One paged decode step for the whole slot array: inactive
@@ -330,8 +347,7 @@ class ReplicaExecutor:
         logits, cache = tfm.paged_apply(
             self.model, {"params": params}, cache, tokens, tables,
             cursors)
-        return (jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32),
-                cache)
+        return _sample(logits[:, -1, :]), cache
 
     def _paged_prefill_impl(self, params, cache, tokens, table, cursor,
                             n):
@@ -341,8 +357,7 @@ class ReplicaExecutor:
         logits, cache = tfm.paged_apply(
             self.model, {"params": params}, cache, tokens, table,
             cursor, lengths=n)
-        return (jnp.argmax(logits[0, n[0] - 1, :]).astype(jnp.int32),
-                cache)
+        return _sample(logits[0, n[0] - 1, :]), cache
 
     def _init_cache(self) -> None:
         self._cache = self._init_cache_jit(self.params)
@@ -431,8 +446,11 @@ class ReplicaExecutor:
                 plan, root_rank=self.front,
                 name=f"serve.plan.g{self._gen}.{self._step}")
 
-    def _apply_plan(self, plan: BatchPlan) -> None:
+    def _apply_plan(self, plan: BatchPlan, parts: StepParts) -> int:
+        """Execute the plan's assignments; returns how many requests
+        this replica admitted into a slot."""
         now = time.monotonic()
+        admits = 0
         if plan.swap_version:
             self._fleet_swap(plan.swap_version)
         for a in plan.assign:
@@ -443,27 +461,37 @@ class ReplicaExecutor:
             if a.replica != self.group:
                 continue
             slot = next(i for i, s in enumerate(self.slots) if s is None)
-            if a.prefill >= 0:
-                self._admit_disaggregated(slot, a, now)
-            elif self.cfg.paged:
-                self._prefill_slot_paged(slot, a, now)
-            else:
-                self._prefill_slot(slot, a, now)
+            admits += 1
+            with parts("admit", rid=a.rid,
+                       bucket=self._prompt_bucket(
+                           len(self._clamped_tokens(a)))):
+                if a.prefill >= 0:
+                    self._admit_disaggregated(slot, a, now)
+                elif self.cfg.paged:
+                    self._prefill_slot_paged(slot, a, now)
+                else:
+                    self._prefill_slot(slot, a, now)
+        return admits
+
+    def _prompt_bucket(self, n: int) -> int:
+        """The compiled prefill shape ``n`` prompt tokens pad to."""
+        return min(self._bucket(n), self.cfg.max_seq)
 
     # -- dense prefill (the PR 9 path, unchanged) ------------------------
     def _prefill_slot(self, slot: int, a: Assignment, now: float) -> None:
-        # Clamp so prompt + generation always fits the KV cache.
-        limit = self.cfg.max_seq - a.max_new_tokens
-        toks = a.tokens[:max(1, limit)]
-        bucket = min(self._bucket(len(toks)), self.cfg.max_seq)
-        padded = np.zeros((1, bucket), np.int32)
+        toks = self._clamped_tokens(a)
+        padded = np.zeros((1, self._prompt_bucket(len(toks))), np.int32)
         padded[0, :len(toks)] = toks
-        first, cache1 = self._prefill_jit(
-            self.params, jnp.asarray(padded), jnp.int32(len(toks)))
-        self._cache = jax.tree_util.tree_map(
-            lambda big, small: big.at[slot].set(small[0]),
-            self._cache, cache1)
-        self._activate_slot(slot, a, now, int(first))
+        with span("serve.prefill_dispatch"):
+            first, cache1 = self._prefill_jit(
+                self.params, jnp.asarray(padded), jnp.int32(len(toks)))
+        with span("serve.cache_insert"):
+            self._cache = jax.tree_util.tree_map(
+                lambda big, small: big.at[slot].set(small[0]),
+                self._cache, cache1)
+        with span("serve.first_token_fetch"):
+            first = int(first)         # waits for the device
+        self._activate_slot(slot, a, now, first)
 
     def _activate_slot(self, slot: int, a: Assignment, now: float,
                        first: int, blocks: list | None = None,
@@ -478,6 +506,7 @@ class ReplicaExecutor:
 
     # -- paged prefill + prefix cache ------------------------------------
     def _clamped_tokens(self, a: Assignment) -> list[int]:
+        # Clamp so prompt + generation always fits the KV cache.
         limit = self.cfg.max_seq - a.max_new_tokens
         return a.tokens[:max(1, limit)]
 
@@ -550,18 +579,24 @@ class ReplicaExecutor:
         j0 = pos // bt
         self._ensure_writable(blocks, j0)
         rem = toks[pos:]
-        bucket = min(self._bucket(len(rem)), self.cfg.max_seq)
-        padded = np.zeros((1, bucket), np.int32)
+        padded = np.zeros((1, self._prompt_bucket(len(rem))), np.int32)
         padded[0, :len(rem)] = rem
         row = np.full(self.cfg.table_width, self._sink, np.int32)
         row[:total] = blocks
-        first, self._cache = self._paged_prefill_jit(
-            self.params, self._cache, jnp.asarray(padded),
-            jnp.asarray(row[None]), jnp.asarray([pos], np.int32),
-            jnp.asarray([len(rem)], np.int32))
-        self._publish_prompt(toks, blocks)
-        self._tables[slot] = row
-        self._activate_slot(slot, a, now, int(first), blocks=blocks,
+        with span("serve.prefill_dispatch"):
+            first, self._cache = self._paged_prefill_jit(
+                self.params, self._cache, jnp.asarray(padded),
+                jnp.asarray(row[None]), jnp.asarray([pos], np.int32),
+                jnp.asarray([len(rem)], np.int32))
+        # The paged program wrote the pool rows itself; what is left of
+        # the insert is the host's: publish the blocks, point the slot's
+        # table at them.
+        with span("serve.cache_insert"):
+            self._publish_prompt(toks, blocks)
+            self._tables[slot] = row
+        with span("serve.first_token_fetch"):
+            first = int(first)         # waits for the device
+        self._activate_slot(slot, a, now, first, blocks=blocks,
                             seq_len=len(toks))
 
     # -- disaggregated prefill/decode ------------------------------------
@@ -599,8 +634,7 @@ class ReplicaExecutor:
         nblk = -(-len(toks) // bt)
         row = np.full(self.cfg.table_width, self._sink, np.int32)
         row[:nblk] = np.arange(nblk)
-        bucket = min(self._bucket(len(toks)), self.cfg.max_seq)
-        padded = np.zeros((1, bucket), np.int32)
+        padded = np.zeros((1, self._prompt_bucket(len(toks))), np.int32)
         padded[0, :len(toks)] = toks
         first, self._cache = self._paged_prefill_jit(
             self.params, self._cache, jnp.asarray(padded),
@@ -717,31 +751,38 @@ class ReplicaExecutor:
             pending=None, pending_since=0.0)
 
     # -- decode ----------------------------------------------------------
-    def _decode_once(self) -> None:
-        active = [i for i, s in enumerate(self.slots)
-                  if s is not None and s.pending is None
-                  and s.remaining > 0]
-        if not active:
-            return
-        if self.cfg.paged:
-            bt = self.cfg.block_tokens
-            for i in active:
-                s = self.slots[i]
-                # COW guard: the write position may sit in a published
-                # tail (the first divergent write of a shared prefix).
-                if self._ensure_writable(s.blocks, s.seq_len // bt):
-                    self._tables[i][s.seq_len // bt] = \
-                        s.blocks[s.seq_len // bt]
-                self._cursors[i] = s.seq_len
-            nxt, self._cache = self._paged_jit(
-                self.params, self._cache,
-                jnp.asarray(self._last_tokens[:, None]),
-                jnp.asarray(self._tables), jnp.asarray(self._cursors))
-        else:
-            nxt, self._cache = self._decode_jit(
-                self.params, self._cache,
-                jnp.asarray(self._last_tokens[:, None]))
-        nxt = np.asarray(nxt)
+    def _decode_once(self, parts: StepParts) -> tuple[list[int], Any]:
+        """Enqueue one decode step for the active slots and fetch its
+        tokens: (active slots, the slot array's next tokens)."""
+        with parts("decode_dispatch"):
+            active = [i for i, s in enumerate(self.slots)
+                      if s is not None and s.pending is None
+                      and s.remaining > 0]
+            if not active:
+                return active, None
+            if self.cfg.paged:
+                bt = self.cfg.block_tokens
+                for i in active:
+                    s = self.slots[i]
+                    # COW guard: the write position may sit in a
+                    # published tail (the first divergent write of a
+                    # shared prefix).
+                    if self._ensure_writable(s.blocks, s.seq_len // bt):
+                        self._tables[i][s.seq_len // bt] = \
+                            s.blocks[s.seq_len // bt]
+                    self._cursors[i] = s.seq_len
+                nxt, self._cache = self._paged_jit(
+                    self.params, self._cache,
+                    jnp.asarray(self._last_tokens[:, None]),
+                    jnp.asarray(self._tables), jnp.asarray(self._cursors))
+            else:
+                nxt, self._cache = self._decode_jit(
+                    self.params, self._cache,
+                    jnp.asarray(self._last_tokens[:, None]))
+        with parts("token_fetch"):
+            return active, np.asarray(nxt)     # waits for the device
+
+    def _advance_slots(self, active: list[int], nxt) -> None:
         for i in active:
             s = self.slots[i]
             tok = int(nxt[i])
@@ -1047,33 +1088,100 @@ class ReplicaExecutor:
 
     # -- the loop --------------------------------------------------------
     def _serve_step(self) -> bool:
-        t0 = time.monotonic()
-        plan = self._assemble() if self.rank == self.front else None
-        plan = self._exchange_plan(plan)
-        self._step += 1
-        if plan.stop:
-            return False
-        self._apply_plan(plan)
-        decoded = ctx_sum = 0
-        if not self.is_prefill:
-            if self.cfg.paged and self.prefill_rank_list:
-                self._integrate_prefills()
-            self._decode_once()
-            for s in self.slots:
-                if s is not None and s.pending is None:
-                    decoded += 1
-                    ctx_sum += s.seq_len
-            self._collect_completions()
-        completions = self._exchange_completions()
-        self._account(completions)
-        if self.statesync is not None:
-            self._statesync_boundary()
-        dt = time.monotonic() - t0
-        self.admission.observe_step_ms(dt * 1e3)
-        self._note_perf(decoded, ctx_sum, dt)
-        if self._fleet_gauge is not None and self.rank == self.front:
-            self._fleet_gauge(self)
+        gc2 = gc.get_stats()[2]["collections"]
+        step = self._step
+        admits = decoded = ctx_sum = 0
+        parts = StepParts("serve", step=step)
+        try:
+            plan = None
+            if self.rank == self.front:
+                with parts("assemble"):
+                    plan = self._assemble()
+            with parts("plan_exchange"):
+                plan = self._exchange_plan(plan)
+            self._step += 1
+            if plan.stop:
+                return False
+            admits = self._apply_plan(plan, parts)
+            if not self.is_prefill:
+                if self.cfg.paged and self.prefill_rank_list:
+                    self._integrate_prefills()
+                active, nxt = self._decode_once(parts)
+                with parts("slot_update"):
+                    self._advance_slots(active, nxt)
+                    for s in self.slots:
+                        if s is not None and s.pending is None:
+                            decoded += 1
+                            ctx_sum += s.seq_len
+                    self._collect_completions()
+            with parts("completion_exchange"):
+                completions = self._exchange_completions()
+            with parts("account"):
+                self._account(completions)
+                if self.statesync is not None:
+                    self._statesync_boundary()
+                # The step so far: what is left of it is the tail of
+                # this part, some microseconds.
+                dt = parts.elapsed()
+                self.admission.observe_step_ms(dt * 1e3)
+                self._note_perf(decoded, ctx_sum, dt)
+                if self._fleet_gauge is not None \
+                        and self.rank == self.front:
+                    self._fleet_gauge(self)
+        finally:
+            seconds = parts.close(admits=admits, decoded=decoded)
+        self._note_step_parts(step, seconds, admits, gc2)
         return True
+
+    def _note_step_parts(self, step: int, seconds: dict, admits: int,
+                         gc2_before: int) -> None:
+        """Fold one finished step's part timers into the always-on
+        counters, and keep the record of a step that was slow for its
+        kind: more than ``_SLOW_FACTOR`` times the median of the last
+        ``_RECENT_STEPS`` such steps (the threshold is the data's)."""
+        kind = "admit" if admits else "decode"
+        total = seconds["total"]
+        self.stats["steps"][kind] += 1
+        sums = self.stats["step_parts_s"][kind]
+        for part, s in seconds.items():
+            sums[part] = sums.get(part, 0.0) + s
+        recent = self._recent_steps[kind]
+        slow = len(recent) >= _MIN_RECENT_STEPS \
+            and total > _SLOW_FACTOR * statistics.median(recent)
+        recent.append(total)
+        from ..telemetry import metrics as telemetry_metrics
+        tm = telemetry_metrics()
+        if tm.enabled:
+            for part, s in seconds.items():
+                if part != "total":    # that is horovod_serve_step_ms
+                    tm.histogram(
+                        "horovod_serve_step_part_ms",
+                        "Host time of one part of a serve step",
+                        labels={"part": part}).observe(s * 1e3)
+        if not slow:
+            return
+        parts_ms = {part: round(s * 1e3, 3) for part, s in seconds.items()
+                    if part != "total"}
+        record = {
+            "step": step, "kind": kind, "wall_time": time.time(),
+            "total_ms": round(total * 1e3, 3), "parts_ms": parts_ms,
+            "slowest": max(parts_ms, key=parts_ms.get), "admits": admits,
+            # Did a full (generation-2) collection of Python's garbage
+            # collector run inside the step?
+            "gc2": gc.get_stats()[2]["collections"] > gc2_before}
+        kept = self.stats["slow_steps"]
+        if len(kept) >= _SLOW_STEPS_KEPT:
+            del kept[0]                # the newest are kept
+        kept.append(record)
+        self.stats["slow_steps_total"] += 1
+        tm.counter("horovod_serve_slow_steps_total",
+                   "Serve steps slower than three times the running "
+                   "median of their kind").inc()
+        from ..telemetry import flight
+        rec = flight.recorder()
+        if rec.enabled:
+            rec.record("serve_slow_step", name=f"step {step}",
+                       detail=json.dumps(record))
 
     def serve_loop(self, *, stop_when=None, max_steps: int | None = None,
                    idle_sleep: float = 0.002) -> None:
@@ -1216,7 +1324,13 @@ class ReplicaExecutor:
         """Release the serving resources this executor owns: the
         kvstream mesh (drain threads + sockets) and the KV block pool
         (hvdlife HVD702/704 — the pool must not outlive the executor
-        across elastic reinit cycles)."""
+        across elastic reinit cycles).  Leaves the part timers' totals
+        and the slow steps in the log."""
+        if any(self.stats["steps"].values()):
+            logger.info("serving: step parts %s", json.dumps(
+                {key: self.stats[key]
+                 for key in ("steps", "step_parts_s", "slow_steps_total",
+                             "slow_steps")}))
         if self._fleet_puller is not None:
             self._fleet_puller.close()
             self._fleet_puller = None
@@ -1225,6 +1339,21 @@ class ReplicaExecutor:
             self._kvstream = None
         if self.pool is not None:
             self.pool.close()
+
+
+# Slow-step records (``stats["slow_steps"]``): a step is slow when its
+# host time passes _SLOW_FACTOR times the median of the last
+# _RECENT_STEPS steps of its kind, once _MIN_RECENT_STEPS of them are in.
+_SLOW_FACTOR = 3.0
+_RECENT_STEPS = 64
+_MIN_RECENT_STEPS = 8
+_SLOW_STEPS_KEPT = 32
+
+
+def _sample(logits):
+    """Greedy sampling: the arg-max over the vocabulary axis."""
+    with jax.named_scope("hvd.sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 def _decode_model_cfg(cfg: ServeConfig):

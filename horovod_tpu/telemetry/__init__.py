@@ -16,7 +16,10 @@ Module surface:
 - :class:`~.straggler.StragglerAggregator` — coordinator-side windowed
   negotiation-skew statistics naming the slowest rank.
 - ``python -m horovod_tpu.telemetry.report`` — offline summarizer for
-  dumps and timeline traces.
+  dumps, timeline traces and profiler sessions.
+- :mod:`.spans` — ``hvd.*`` spans on the JAX profiler's clock and the
+  always-on part timers of a step (the chip path: serving replica,
+  Trainer).
 - :mod:`.flight` — the always-on failure flight recorder
   (``HOROVOD_FLIGHT``): bounded ring of recent trace events dumped on
   every structured failure (ISSUE 7).

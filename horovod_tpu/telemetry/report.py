@@ -1,24 +1,34 @@
-"""Offline summarizer for metrics dumps and timeline traces.
+"""Offline summarizer for metrics dumps, timeline traces and profiler
+sessions.
 
 CLI::
 
     python -m horovod_tpu.telemetry.report DUMP_OR_TIMELINE.json [...]
+    python -m horovod_tpu.telemetry.report SESSION.xplane.pb
 
-Accepts either artifact the runtime produces and answers "where did the
+Accepts every artifact the runtime produces and answers "where did the
 milliseconds go" as a per-activity table:
 
 - a **metrics dump** (HOROVOD_METRICS_FILE JSON): counters/gauges as-is,
   histograms as count/mean/p50/p99/max rows;
 - a **Chrome-trace timeline** (HOROVOD_TIMELINE JSON): per-activity
   total/mean/max span durations aggregated over every tensor lane, plus
-  the final value of each counter track ("ph":"C").
+  the final value of each counter track ("ph":"C");
+- a **profiler session** (``hvd.start_profiler``'s ``.xplane.pb``): the
+  program's ``hvd.*`` spans (telemetry/spans.py) by the step they lie
+  in, each with its count, median, tail, and self time (its duration
+  less what its children on the same thread cover), and, where the
+  session has device planes, each device's idle gaps between ``XLA Ops``
+  by the innermost ``hvd.*`` span the host was in.
 
 Output goes to stdout as aligned plain text (one table per input file).
 """
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
+import statistics
 import sys
 from pathlib import Path
 
@@ -152,7 +162,123 @@ def summarize_timeline(events: list[dict]) -> str:
     return "\n\n".join(parts)
 
 
+def _tail(values: list[float]) -> tuple[float, float]:
+    """(percentile, value) of the highest percentile that still has ten
+    samples beyond it; the maximum where that would lie under the
+    median."""
+    ordered = sorted(values)
+    if len(ordered) <= 20:
+        return 100.0, ordered[-1]
+    return 100.0 * (1 - 10 / len(ordered)), ordered[-11]
+
+
+def _nest(events: list[tuple]) -> list[dict]:
+    """One thread's (start, end, name, stats) events as spans that know
+    their self time and the outermost span they lie in."""
+    spans, stack = [], []
+    for start, end, name, stats in sorted(events,
+                                          key=lambda e: (e[0], -e[1])):
+        while stack and start >= stack[-1]["end"]:
+            stack.pop()
+        if stack:
+            stack[-1]["self"] -= end - start
+        stack.append({"name": name, "start": start, "end": end,
+                      "self": end - start, "stats": stats,
+                      "root": stack[0] if stack else None})
+        spans.append(stack[-1])
+    return spans
+
+
+def _group(span: dict) -> str:
+    """Spans are told apart by the step they lie in, and steps by
+    whether they admitted anything."""
+    root = span["root"] or span
+    admits = root["stats"].get("admits")
+    return root["name"] if admits is None \
+        else f"{root['name']} [admits {'> 0' if admits else '= 0'}]"
+
+
+def summarize_xplane(path: str, top: int = 10) -> str:
+    """The ``hvd.*`` spans of one profiler session and, where it traced
+    a device, the device's idle gaps by the span the host was in."""
+    from jax.profiler import ProfileData
+
+    spans: list[dict] = []
+    devices: dict[str, list] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            if not plane.name.startswith("/device:"):
+                spans += _nest([
+                    (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+                     dict(ev.stats)) for ev in line.events
+                    if ev.name.startswith("hvd.")])
+            elif line.name == "XLA Ops":
+                devices[plane.name] = sorted(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns)
+                    for ev in line.events)
+    if not spans:
+        return "(no hvd.* spans in the session)"
+    spans.sort(key=lambda s: s["start"])
+    groups: dict[str, dict[str, list]] = {}
+    for span in spans:
+        groups.setdefault(_group(span), {}).setdefault(
+            span["name"], []).append(span)
+    parts = []
+    for group, names in sorted(groups.items()):
+        rows = []
+        for name, found in names.items():
+            ms = [(s["end"] - s["start"]) / 1e6 for s in found]
+            pct, tail = _tail(ms)
+            rows.append([
+                name, str(len(ms)), f"{statistics.median(ms):.3f}",
+                f"{tail:.3f}", f"p{pct:.1f}", f"{max(ms):.3f}",
+                f"{statistics.median(s['self'] / 1e6 for s in found):.3f}",
+                f"{sum(ms):.1f}"])
+        parts.append(f"spans in {group}\n" + _fmt_table(
+            rows, ["span", "count", "p50_ms", "tail_ms", "tail_at", "max_ms",
+                   "self_p50_ms", "total_ms"]))
+    parts += [f"{device}: " + idle_gaps(ops, spans, top)
+              for device, ops in sorted(devices.items())]
+    return "\n\n".join(parts)
+
+
+def idle_gaps(ops: list[tuple], spans: list[dict], top: int = 10) -> str:
+    """Where one device waited: the gaps between its operations
+    (``ops``: (start, end) by start), each put down to the innermost of
+    ``spans`` (by start) that holds its middle."""
+    starts = [s["start"] for s in spans]
+    gaps, edge, busy = [], ops[0][0], 0
+    for start, end in ops:
+        if start > edge:
+            gaps.append((start - edge, (edge + start) / 2))
+        busy += max(0, end - max(edge, start))
+        edge = max(edge, end)
+    named = []                     # the longest gaps, by innermost span
+    for length, mid in sorted(gaps, reverse=True)[:1000]:
+        at = bisect.bisect_right(starts, mid) - 1
+        while at >= 0 and spans[at]["end"] <= mid:
+            at -= 1                # a sibling that ended: look outwards
+        named.append((spans[at]["name"] if at >= 0
+                      else "outside_hvd_spans", length / 1e6))
+    totals: dict[str, list] = {}
+    for name, ms in named:
+        totals.setdefault(name, []).append(ms)
+    window = edge - ops[0][0]
+    return (f"busy {busy / 1e9:.4f} s of the {window / 1e9:.4f} s from its "
+            f"first to its last operation, idle "
+            f"{100 * (1 - busy / window):.2f}%\n" + _fmt_table(
+                [[name, str(len(ms)), f"{sum(ms):.1f}",
+                  f"{statistics.median(ms):.3f}"] for name, ms in sorted(
+                      totals.items(), key=lambda kv: -sum(kv[1]))],
+                ["idle gaps by innermost span", "gaps", "total_ms",
+                 "p50_ms"]) + "\n\n" + _fmt_table(
+                [[name, f"{ms:.3f}"] for name, ms in named[:top]],
+                ["longest idle gaps", "ms"]))
+
+
 def summarize_file(path: str) -> str:
+    if path.endswith(".pb"):
+        return f"== {path} (profiler session) ==\n{summarize_xplane(path)}\n"
     payload = json.loads(Path(path).read_text())
     if isinstance(payload, list):
         body = summarize_timeline(payload)
@@ -166,11 +292,13 @@ def summarize_file(path: str) -> str:
 def main(argv: list[str] | None = None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m horovod_tpu.telemetry.report",
-        description="Summarize a HOROVOD_METRICS_FILE dump or a "
-                    "HOROVOD_TIMELINE trace into per-activity tables "
+        description="Summarize a HOROVOD_METRICS_FILE dump, a "
+                    "HOROVOD_TIMELINE trace or a profiler session's "
+                    ".xplane.pb into per-activity tables "
                     "(docs/observability.md).")
     parser.add_argument("paths", nargs="+",
-                        help="metrics dump(s) and/or timeline file(s)")
+                        help="metrics dump(s), timeline file(s) and/or "
+                             "profiler session(s)")
     args = parser.parse_args(argv)
     rc = 0
     for path in args.paths:

@@ -1,0 +1,86 @@
+"""Spans on the chip path, on the profiler's own clock.
+
+``span(name, **args)`` opens ``jax.profiler.TraceAnnotation("hvd." +
+name)``: the event lands in the profiler session's host plane, on the
+calling thread and on the clock of the device trace, so it nests inside
+whatever span encloses it and can be laid beside the device's
+operations (``python -m horovod_tpu.telemetry.report x.xplane.pb``).
+With no session open an enter and exit find tracing off and cost well
+under a microsecond.
+
+``timed(slots, key, name)`` is a span that also adds its seconds to
+``slots[key]``, whether or not anybody traces (``Trainer.stats``).
+
+``StepParts`` is the recorder one step owns.  It is the step's span
+(``hvd.<family>.step``) and its only clock: ``with parts("token_fetch"):``
+opens ``hvd.<family>.token_fetch`` and adds the ``time.perf_counter()``
+difference to that part's slot, so the part timers are on whether or not
+anybody traces.  ``close()`` returns ``{part: seconds}`` with the step's
+``total`` and the remainder as ``other``: the parts always sum to the
+step.
+"""
+from __future__ import annotations
+
+import time
+
+import jax
+
+PREFIX = "hvd."
+
+
+def span(name: str, **args):
+    """A context manager around one region: ``hvd.<name>`` in the
+    profiler's trace, with ``args`` as the event's stats (more can be
+    added before the exit with the yielded object's ``set_metadata``)."""
+    return jax.profiler.TraceAnnotation(PREFIX + name, **args)
+
+
+class timed:
+    """``span(name, **args)`` that also adds its ``time.perf_counter()``
+    seconds to ``slots[key]``, traced or not."""
+    __slots__ = ("_slots", "_key", "_span", "_t0")
+
+    def __init__(self, slots: dict, key: str, name: str, **args) -> None:
+        self._slots, self._key = slots, key
+        self._span = span(name, **args)
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self._span
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._slots[self._key] = self._slots.get(self._key, 0.0) + dt
+        return self._span.__exit__(*exc)
+
+
+class StepParts:
+    """The span and the part timers of one step of ``family``."""
+    __slots__ = ("_family", "_seconds", "_span", "_t0")
+
+    def __init__(self, family: str, **args) -> None:
+        self._family = family
+        self._seconds: dict[str, float] = {}
+        self._span = span(family + ".step", **args)
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __call__(self, part: str, **args) -> timed:
+        return timed(self._seconds, part, f"{self._family}.{part}", **args)
+
+    def elapsed(self) -> float:
+        """Seconds since the step began."""
+        return time.perf_counter() - self._t0
+
+    def close(self, **args) -> dict[str, float]:
+        """End the step's span (``args`` become its stats) and return
+        the parts, ``other`` and ``total``, in seconds."""
+        total = self.elapsed()
+        if args:
+            self._span.set_metadata(**args)
+        self._span.__exit__(None, None, None)
+        parts = dict(self._seconds)
+        parts["other"] = total - sum(parts.values())
+        parts["total"] = total
+        return parts

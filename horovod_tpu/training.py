@@ -31,6 +31,7 @@ from .parallel.grad_sync import (GradSyncConfig, init_ring_optimizer_state,
                                  sync_and_apply, sync_gradients)
 from .parallel.mesh import data_axes
 from .parallel.sharding import ShardingRules
+from .telemetry.spans import timed
 
 
 @jax.tree_util.register_dataclass
@@ -98,6 +99,9 @@ def ce_streaming_threshold() -> int:
     return 1 << 30
 
 
+_NO_BATCH = object()        # what ``next`` gives past the last batch
+
+
 def _track_accuracy() -> bool:
     from .common import config
     return bool(config.TRACK_ACCURACY.get())
@@ -151,12 +155,17 @@ class Trainer:
         # directly so the warm-up compile is never repeated (see step()).
         self._compiled: Callable | None = None
         # perfscope MFU ledger (telemetry/perfmodel.py): analytic FLOPs
-        # per step, resolved once from the first batch's shape, timed by
-        # the wall clock between step() dispatches (steady-state pipeline
-        # throughput — blocking on the result here would serialize the
-        # async dispatch the fit loop is careful to preserve).
+        # per step, resolved once from the first batch's shape.  A step
+        # is timed only where the host waits for the device (``fit``:
+        # an epoch's metrics fetch), as that interval over the steps
+        # dispatched in it: the distance between two dispatches says
+        # nothing of the device, and blocking in ``step`` would
+        # serialize the async dispatch.
         self._step_flops: float | None = None
-        self._last_dispatch: float | None = None
+        # Cumulative host seconds: waiting for the next batch, and
+        # inside the callbacks (``fit``); enqueueing steps (``step``).
+        self.stats = {"steps": 0, "dispatch_s": 0.0, "data_wait_s": 0.0,
+                      "callbacks_s": 0.0}
         # Fleet continuous deployment (fleet/deploy.py): rank 0 wires a
         # WeightPublisher in via attach_fleet_publisher; the host-side
         # step counter drives the publish cadence (the device step
@@ -265,7 +274,8 @@ class Trainer:
                 out = self.model.apply(variables, _model_input(batch),
                                        train=True, mutable=mutable)
                 logits, updated = out if mutable else (out, {})
-                loss = self.loss_fn(logits, batch["label"])
+                with jax.named_scope("hvd.loss"):
+                    loss = self.loss_fn(logits, batch["label"])
                 return loss, (logits, updated)
 
             grad_fn = jax.value_and_grad(loss_of, has_aux=True)
@@ -289,10 +299,11 @@ class Trainer:
                 # gradient pytree over the data axes.
                 grads = sync_gradients(grads, sync_cfg)
 
-                updates, opt_state = self.tx.update(grads,
-                                                    state.opt_state,
-                                                    state.params)
-                params = optax.apply_updates(state.params, updates)
+                with jax.named_scope("hvd.optimizer"):
+                    updates, opt_state = self.tx.update(grads,
+                                                        state.opt_state,
+                                                        state.params)
+                    params = optax.apply_updates(state.params, updates)
 
             metrics = {"loss": allreduce(loss, sync_cfg.axes, "average")}
             if _track_accuracy():
@@ -345,34 +356,40 @@ class Trainer:
             check_vma=False)
         return jax.jit(mapped, donate_argnums=(0,))
 
-    def _note_step(self, batch: dict, first: bool) -> None:
-        """Fold one dispatched step into the MFU ledger gauges.  The
-        first call (carrying the compile) only arms the clock."""
+    def _note_step_flops(self, batch: dict) -> None:
+        """Resolve the step's analytic FLOPs from the first batch."""
+        if self._step_flops is not None:
+            return
         from .telemetry import metrics as _telemetry_metrics
         tm = _telemetry_metrics()
         if not tm.enabled:
             return
         from .telemetry import perfmodel
-        now = time.monotonic()
-        prev, self._last_dispatch = self._last_dispatch, now
-        if self._step_flops is None:
-            x = _model_input(batch)
-            ndim = getattr(x, "ndim", 0)
-            self._step_flops = perfmodel.model_step_flops(
-                self.model, int(x.shape[0]) if ndim else 1,
-                seq=int(x.shape[1]) if ndim == 2 else 0,
-                image_size=int(x.shape[1]) if ndim == 4 else 224,
-                train=True)
-            tm.gauge("horovod_train_step_flops").set(self._step_flops)
-        if first or prev is None:
+        x = _model_input(batch)
+        ndim = getattr(x, "ndim", 0)
+        self._step_flops = perfmodel.model_step_flops(
+            self.model, int(x.shape[0]) if ndim else 1,
+            seq=int(x.shape[1]) if ndim == 2 else 0,
+            image_size=int(x.shape[1]) if ndim == 4 else 224,
+            train=True)
+        tm.gauge("horovod_train_step_flops").set(self._step_flops)
+
+    def _note_interval(self, seconds: float, steps: int) -> None:
+        """Fold an interval that ended in a host fetch, and the steps
+        dispatched in it, into the step-time histogram and the MFU
+        gauge."""
+        from .telemetry import metrics as _telemetry_metrics
+        tm = _telemetry_metrics()
+        if not tm.enabled or steps <= 0 or seconds <= 0.0:
             return
-        dt = now - prev
+        from .telemetry import perfmodel
+        dt = seconds / steps
         tm.histogram("horovod_train_step_ms").observe(dt * 1e3)
         # The step consumes the GLOBAL batch, so the denominator is the
         # whole mesh's peak, not one chip's.  A device kind without a
         # known peak gets no MFU gauge.
         peak = perfmodel.peak_flops(self.mesh.devices.flat[0].device_kind)
-        if peak is not None:
+        if peak is not None and self._step_flops is not None:
             tm.gauge("horovod_train_mfu").set(perfmodel.mfu(
                 self._step_flops, dt, peak * self.mesh.size))
 
@@ -398,7 +415,6 @@ class Trainer:
                         "step %d", version, self._fleet_step)
 
     def step(self, state: TrainState, batch: dict):
-        first = self._step_fn is None
         if self._step_fn is None:
             self._step_fn = self._build(state)
             from .parallel import multihost
@@ -418,22 +434,40 @@ class Trainer:
                                                          batch).compile()
                 finally:
                     multihost.kv_barrier("trainer-step-compile")
+        self._note_step_flops(batch)
+        with self._timed("dispatch"):
+            result = self._dispatch(state, batch)
+        self.stats["steps"] += 1
+        self._fleet_publish(result[0])
+        return result
+
+    def _timed(self, what: str) -> timed:
+        """``hvd.train.<what>`` in a profiler session, and its host
+        seconds added to ``stats["<what>_s"]`` always."""
+        return timed(self.stats, what + "_s", "train." + what)
+
+    def _timed_batches(self, batches):
+        """``batches``, with the wait for each one timed."""
+        batches = iter(batches)
+        while True:
+            with self._timed("data_wait"):
+                batch = next(batches, _NO_BATCH)
+            if batch is _NO_BATCH:
+                return
+            yield batch
+
+    def _dispatch(self, state: TrainState, batch: dict):
+        """Enqueue one step on the executable."""
         if self._compiled is not None:
             try:
-                result = self._compiled(state, batch)
-                self._note_step(batch, first)
-                self._fleet_publish(result[0])
-                return result
+                return self._compiled(state, batch)
             except TypeError:
                 # Shape/dtype drift vs the AOT signature (e.g. a ragged
                 # final batch): the executable rejects the call before
                 # dispatch (donated buffers untouched), so fall back to
                 # the jit path, which re-specializes per signature.
                 self._compiled = None
-        result = self._step_fn(state, batch)
-        self._note_step(batch, first)
-        self._fleet_publish(result[0])
-        return result
+        return self._step_fn(state, batch)
 
     # -- fit loop with callbacks ------------------------------------------
     def fit(self, state: TrainState, data, epochs: int = 1,
@@ -465,18 +499,29 @@ class Trainer:
                 batches = data(epoch) if callable(data) else data
                 sums: dict[str, Any] = {}
                 count = 0
-                for i, batch in enumerate(batches):
+                # The interval the epoch's steps are timed over: it ends
+                # where the host fetches the metrics, and starts after
+                # the first step's dispatch where that one compiles.
+                compiles = self._step_fn is None
+                began = time.perf_counter()
+                for i, batch in enumerate(self._timed_batches(batches)):
                     if steps_per_epoch is not None \
                             and i >= steps_per_epoch:
                         break
-                    for cb in callbacks:
-                        cb.on_batch_begin(i)
+                    if callbacks:
+                        with self._timed("callbacks"):
+                            for cb in callbacks:
+                                cb.on_batch_begin(i)
                     state, metrics = self.step(state, batch)
+                    if compiles:
+                        compiles, began = False, time.perf_counter()
                     # Keep metrics as device arrays through the epoch:
                     # float() here would sync host↔device every step and
                     # serialize the async dispatch pipeline.
-                    for cb in callbacks:
-                        cb.on_batch_end(i, metrics)
+                    if callbacks:
+                        with self._timed("callbacks"):
+                            for cb in callbacks:
+                                cb.on_batch_end(i, metrics)
                     for k, v in metrics.items():
                         sums[k] = v if k not in sums else sums[k] + v
                     count += 1
@@ -487,10 +532,13 @@ class Trainer:
                             trainer_gauges)
                 epoch_logs = {k: float(v) / max(count, 1)
                               for k, v in sums.items()}
-                for cb in callbacks:
-                    if hasattr(cb, "set_state"):
-                        cb.set_state(state)
-                    cb.on_epoch_end(epoch, epoch_logs)
+                if sums:               # the fetch above waited for them
+                    self._note_interval(time.perf_counter() - began, count)
+                with self._timed("callbacks"):
+                    for cb in callbacks:
+                        if hasattr(cb, "set_state"):
+                            cb.set_state(state)
+                        cb.on_epoch_end(epoch, epoch_logs)
                 history.append(epoch_logs)
             for cb in callbacks:
                 cb.on_train_end()

@@ -151,47 +151,143 @@ def test_perfscope_2rank_world(tmp_path, capsys):
     assert finding["size_bucket"] in ("4KiB", "64KiB", "1MiB")
 
 
-def test_trainer_mfu_needs_a_known_peak(monkeypatch):
-    """The Trainer sets no horovod_train_mfu gauge on a device kind the
-    peak table does not know (the CPU here) and reports an MFU once
-    HOROVOD_PERF_PEAK_FLOPS names a peak.  MFU needs two steps: the
-    first dispatch only arms the inter-dispatch clock."""
-    from horovod_tpu import telemetry, training
+def _toy_trainer(**model_overrides):
+    from horovod_tpu import training
     from horovod_tpu.models.transformer import TransformerLM, gpt_tiny
     from horovod_tpu.parallel import GradSyncConfig, MeshSpec, build_mesh
 
+    mesh = build_mesh(MeshSpec(dp=8))
+    model = TransformerLM(gpt_tiny(dtype=jnp.float32, **model_overrides))
+    trainer = training.Trainer(
+        model, optax.adamw(1e-3), mesh,
+        sync=GradSyncConfig(axes=("dp",), op="average"))
+    batch = training.synthetic_text_batch(8, seq_len=16, vocab_size=256)
+    return trainer, trainer.init(jax.random.key(0), batch), batch
+
+
+def test_trainer_mfu_needs_a_known_peak(monkeypatch):
+    """Step time and MFU are set only from an interval that ends in a
+    host fetch, which `fit` has at every epoch's end: a loop of bare
+    `step` calls (two dispatches are microseconds apart, the device's
+    steps are not) sets neither.  On a device kind the peak table does
+    not know (the CPU here) `fit` sets no horovod_train_mfu either, and
+    reports one once HOROVOD_PERF_PEAK_FLOPS names a peak."""
+    from horovod_tpu import telemetry
+
     monkeypatch.setenv("HOROVOD_METRICS", "on")
-    monkeypatch.delenv("HOROVOD_PERF_PEAK_FLOPS", raising=False)
+    monkeypatch.setenv("HOROVOD_PERF_PEAK_FLOPS", "1e12")
     reg = telemetry.configure()
     try:
-        mesh = build_mesh(MeshSpec(dp=8))
-        model = TransformerLM(gpt_tiny(dtype=jnp.float32))
-        trainer = training.Trainer(
-            model, optax.adamw(1e-3), mesh,
-            sync=GradSyncConfig(axes=("dp",), op="average"))
-        batch = training.synthetic_text_batch(8, seq_len=16,
-                                              vocab_size=256)
-        state = trainer.init(jax.random.key(0), batch)
+        trainer, state, batch = _toy_trainer()
         state, _ = trainer.step(state, batch)
         state, metrics = trainer.step(state, batch)
         jax.block_until_ready(metrics)
-        assert perfmodel.peak_flops(jax.devices()[0].device_kind) is None
         snap = {m["name"]: m for m in reg.snapshot()["metrics"]}
         assert "horovod_train_mfu" not in snap
-        assert snap["horovod_train_step_ms"]["count"] >= 1
+        assert "horovod_train_step_ms" not in snap
+        assert not hasattr(trainer, "_last_dispatch")
         # The analytic FLOPs match the model card.
         assert reg.gauge("horovod_train_step_flops").value \
             == pytest.approx(perfmodel.transformer_train_flops(
-                model.cfg, 8, 16))
+                trainer.model.cfg, 8, 16))
+
+        monkeypatch.delenv("HOROVOD_PERF_PEAK_FLOPS")
+        assert perfmodel.peak_flops(jax.devices()[0].device_kind) is None
+        state, _ = trainer.fit(state, [batch] * 3, epochs=2)
+        snap = {m["name"]: m for m in reg.snapshot()["metrics"]}
+        assert "horovod_train_mfu" not in snap
+        assert snap["horovod_train_step_ms"]["count"] == 2    # an epoch
 
         monkeypatch.setenv("HOROVOD_PERF_PEAK_FLOPS", "1e12")
-        state, metrics = trainer.step(state, batch)
-        jax.block_until_ready(metrics)
+        state, _ = trainer.fit(state, [batch] * 3)
         mfu = reg.gauge("horovod_train_mfu").value
         assert 0.0 < mfu < 1.0, mfu
     finally:
         monkeypatch.delenv("HOROVOD_METRICS", raising=False)
         telemetry.configure()
+
+
+def test_fit_times_an_epoch_over_its_steps(monkeypatch):
+    """`fit` observes horovod_train_step_ms once an epoch, as the
+    interval that ends in the epoch's metrics fetch over the steps
+    dispatched in it, and keeps the host seconds spent waiting for
+    batches and inside callbacks."""
+    import time
+
+    from horovod_tpu import telemetry
+
+    monkeypatch.setenv("HOROVOD_METRICS", "on")
+    reg = telemetry.configure()
+    try:
+        trainer, state, batch = _toy_trainer()
+        state, _ = trainer.step(state, batch)      # the compile
+
+        def slow_loader(epoch):
+            for _ in range(4):
+                time.sleep(0.03)
+                yield batch
+
+        class Sleepy:
+            def __getattr__(self, name):           # every hook: no-op
+                if not name.startswith("on_"):
+                    raise AttributeError(name)
+                return lambda *a: None
+
+            def on_batch_end(self, i, metrics):
+                time.sleep(0.01)
+
+        began = time.perf_counter()
+        state, history = trainer.fit(state, slow_loader, epochs=2,
+                                     callbacks=[Sleepy()])
+        wall = time.perf_counter() - began
+        assert len(history) == 2
+        hist = reg.histogram("horovod_train_step_ms")
+        assert hist.count == 2
+        # 4 steps an epoch, each behind 30 ms of loader and 10 ms of
+        # callback: an interval over its steps is at least 40 ms, and
+        # the two epochs' intervals fit into the wall time.
+        assert hist.sum >= 2 * 40.0
+        assert hist.sum * 4 <= wall * 1e3
+        assert trainer.stats["steps"] == 9
+        assert trainer.stats["data_wait_s"] >= 8 * 0.03
+        assert trainer.stats["callbacks_s"] >= 8 * 0.01
+        assert 0 < trainer.stats["dispatch_s"] < wall + 60
+    finally:
+        monkeypatch.delenv("HOROVOD_METRICS", raising=False)
+        telemetry.configure()
+
+
+def _operations(hlo: str) -> list:
+    """(shape, opcode) of every instruction of a compiled module, in
+    order: what it computes, without the names XLA gives instructions
+    (some of which it derives from the name stack)."""
+    import re
+    found = [re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+             for line in hlo.splitlines()]
+    return [m.groups() for m in found if m]
+
+
+def test_scope_names_are_metadata_and_change_no_operation(monkeypatch):
+    """The five scopes of the train step are in the lowered program's
+    debug info and nowhere else: the compiled step has the same
+    operations with and without them."""
+    import contextlib
+
+    texts = {}
+    for scoped in (True, False):
+        if not scoped:
+            monkeypatch.setattr(jax, "named_scope",
+                                lambda name: contextlib.nullcontext())
+        trainer, state, batch = _toy_trainer(attention="flash")
+        lowered = trainer._build(state).lower(state, batch)
+        named = lowered.as_text(debug_info=True)
+        for scope in ("hvd.flash_fwd", "hvd.flash_bwd", "hvd.loss",
+                      "hvd.grad_sync", "hvd.optimizer"):
+            assert (scope in named) == scoped, scope
+        assert "hvd." not in lowered.as_text()
+        texts[scoped] = _operations(lowered.compile().as_text())
+    assert len(texts[True]) > 100
+    assert texts[True] == texts[False]
 
 
 def test_summary_stamps_perf_ledger(monkeypatch):

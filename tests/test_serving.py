@@ -573,3 +573,204 @@ def test_serving_disagg_prefill_decode_2rank():
     outputs = _run_world(2, "serving_disagg", timeout=240.0)
     assert "served via streamed prefill" in outputs[0], outputs[0]
     assert "rank 1 streamed" in outputs[1], outputs[1]
+
+
+# --- spans and part timers inside the serve step (ISSUE 26) -----------------
+STEP_PARTS = ("assemble", "plan_exchange", "decode_dispatch", "token_fetch",
+              "slot_update", "completion_exchange", "account")
+ADMIT_CHILDREN = ("prefill_dispatch", "cache_insert", "first_token_fetch")
+
+
+def _toy_executor(requests=2, max_new=8, **kw):
+    """A dense two-slot replica on a world of one, with ``requests``
+    five-token prompts waiting in its queue."""
+    import random
+
+    from horovod_tpu.serving import ReplicaExecutor
+
+    hvd = _solo_world()
+    ex = ReplicaExecutor(_paged_cfg(paged=False, **kw))
+    rng = random.Random(3)
+    for _ in range(requests):
+        ex.stats["offered"] += 1
+        assert ex.queue.submit([rng.randrange(2, 256) for _ in range(5)],
+                               max_new) is not None
+    return hvd, ex
+
+
+def test_step_parts_sum_to_every_step_and_feed_the_counters(monkeypatch):
+    """The parts and ``other`` of every step of a toy replica sum to its
+    total; stats keep them by kind of step, and with metrics on the same
+    numbers feed horovod_serve_step_part_ms{part}."""
+    from horovod_tpu import telemetry
+
+    monkeypatch.setenv("HOROVOD_METRICS", "on")
+    hvd, ex = _toy_executor(requests=3, max_new=6)
+    reg = telemetry.metrics()
+    assert reg.enabled
+    seen = []
+    note = ex._note_step_parts
+    monkeypatch.setattr(
+        ex, "_note_step_parts",
+        lambda step, seconds, admits, gc2: (
+            seen.append((dict(seconds), admits)),
+            note(step, seconds, admits, gc2)))
+    try:
+        ex.serve_loop(stop_when=lambda: True)
+        assert ex.stats["served"] == 3
+        assert len(seen) >= 8
+        for seconds, admits in seen:
+            total = seconds.pop("total")
+            assert total > 0 and seconds["other"] >= 0
+            assert sum(seconds.values()) == pytest.approx(total, abs=1e-9)
+            assert set(STEP_PARTS) <= set(seconds)
+            assert ("admit" in seconds) == bool(admits)
+        kinds = ex.stats["steps"]
+        assert kinds["admit"] == sum(bool(a) for _, a in seen) >= 2
+        assert kinds["decode"] == len(seen) - kinds["admit"]
+        for kind in ("admit", "decode"):
+            sums = ex.stats["step_parts_s"][kind]
+            assert sum(v for k, v in sums.items() if k != "total") \
+                == pytest.approx(sums["total"], abs=1e-9)
+        hist = {m["labels"]["part"]: m["count"]
+                for m in reg.snapshot()["metrics"]
+                if m["name"] == "horovod_serve_step_part_ms"}
+        assert hist["token_fetch"] == hist["other"] == len(seen)
+        assert hist["admit"] == kinds["admit"]
+        assert "total" not in hist
+    finally:
+        ex.close()
+        hvd.shutdown()
+        monkeypatch.delenv("HOROVOD_METRICS")
+        telemetry.configure()
+
+
+def test_serve_spans_nest_in_a_profiler_session(tmp_path):
+    """A jax.profiler session around five steps holds every span of the
+    serve step once a step, each inside hvd.serve.step on one thread,
+    and the admit span with its three children on the admit step only."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    hvd, ex = _toy_executor(requests=2, max_new=8)
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        for _ in range(5):
+            assert ex._serve_step()
+        jax.profiler.stop_trace()
+    finally:
+        ex.close()
+        hvd.shutdown()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    lines = [[ev for ev in line.events if ev.name.startswith("hvd.")]
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines]
+    (events,) = [evs for evs in lines if evs]          # one thread
+    by_name: dict = {}
+    for ev in events:
+        by_name.setdefault(ev.name, []).append(
+            (ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    steps = by_name["hvd.serve.step"]
+    assert [s[2]["step"] for s in steps] == list(range(5))
+    assert [s[2]["admits"] for s in steps] == [2, 0, 0, 0, 0]
+    assert [s[2]["decoded"] for s in steps] == [2] * 5
+
+    def holders(spans, inner):
+        return [i for i, (lo, hi, _) in enumerate(spans)
+                if lo <= inner[0] and inner[1] <= hi]
+
+    for part in STEP_PARTS:
+        found = by_name["hvd.serve." + part]
+        assert [holders(steps, f) for f in found] == [[i] for i in range(5)]
+    admits = by_name["hvd.serve.admit"]
+    assert [holders(steps, a) for a in admits] == [[0], [0]]
+    assert sorted(a[2]["rid"] for a in admits) == [0, 1]
+    assert all(a[2]["bucket"] == 8 for a in admits)
+    for child in ADMIT_CHILDREN:
+        found = by_name["hvd.serve." + child]
+        assert [holders(admits, f) for f in found] == [[0], [1]]
+    assert set(by_name) == {"hvd.serve." + n for n in
+                            ("step", "admit") + STEP_PARTS + ADMIT_CHILDREN}
+
+
+def test_a_slow_step_leaves_one_record_that_names_its_part(monkeypatch):
+    """A stall patched into _exchange_completions at one step yields
+    exactly one slow-step record that names completion_exchange; a
+    patched gc.collect(2) sets its flag; the list is bounded."""
+    import gc
+
+    from horovod_tpu.serving import replica
+    from horovod_tpu.telemetry import flight
+
+    hvd, ex = _toy_executor(requests=2, max_new=40)
+    plan, done = ex._exchange_plan, ex._exchange_completions
+
+    def slow_plan(p):
+        time.sleep(0.05)       # a steady 50 ms step: noise stays under 3x
+        return plan(p)
+
+    def stalling_done():
+        if ex._step == 21:     # _step is already the next step's number
+            time.sleep(0.5)
+        if ex._step == 31:
+            time.sleep(0.5)
+            gc.collect(2)
+        return done()
+
+    monkeypatch.setattr(ex, "_exchange_plan", slow_plan)
+    monkeypatch.setattr(ex, "_exchange_completions", stalling_done)
+    gc.disable()               # no collection but the patched one
+    try:
+        ex.serve_loop(stop_when=lambda: True)
+        assert ex.stats["served"] == 2
+        assert len(ex.stats["slow_steps"]) == 2, ex.stats["slow_steps"]
+        first, second = ex.stats["slow_steps"]
+        assert (first["step"], second["step"]) == (20, 30)
+        for record in (first, second):
+            assert record["kind"] == "decode" and record["admits"] == 0
+            assert record["slowest"] == "completion_exchange"
+            assert record["parts_ms"]["completion_exchange"] >= 500
+            assert record["total_ms"] == pytest.approx(
+                sum(record["parts_ms"].values()), abs=0.01)
+        assert (first["gc2"], second["gc2"]) == (False, True)
+        assert ex.stats["slow_steps_total"] == 2
+        kinds = [e["kind"] for e in flight.recorder().snapshot()]
+        assert kinds.count("serve_slow_step") == 2
+
+        # Bounded: 50 more slow steps (one in three, so that the median
+        # stays a fast step's) keep the newest 32.
+        fast = {"token_fetch": 0.01, "other": 0.0, "total": 0.01}
+        slow = {"token_fetch": 0.5, "other": 0.0, "total": 0.5}
+        for n in range(150):
+            ex._note_step_parts(
+                1000 + n, dict(slow if n % 3 == 2 else fast), 0,
+                gc.get_stats()[2]["collections"])
+        assert len(ex.stats["slow_steps"]) == replica._SLOW_STEPS_KEPT == 32
+        assert ex.stats["slow_steps"][-1]["step"] == 1149
+        assert ex.stats["slow_steps_total"] == 52
+    finally:
+        gc.enable()
+        ex.close()
+        hvd.shutdown()
+
+
+def test_decode_program_carries_the_scope_names():
+    """hvd.decode_attend and hvd.sample are in the lowered decode and
+    prefill programs' debug info (and nowhere in the program itself)."""
+    import jax.numpy as jnp
+
+    hvd, ex = _toy_executor(requests=0)
+    try:
+        lowered = ex._decode_jit.lower(
+            ex.params, ex._cache, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
+        for program in (lowered, ex._prefill_jit.lower(
+                ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))):
+            named = program.as_text(debug_info=True)
+            assert "hvd.decode_attend" in named and "hvd.sample" in named
+            assert "hvd." not in program.as_text()
+    finally:
+        ex.close()
+        hvd.shutdown()

@@ -446,3 +446,129 @@ def test_metrics_on_world_records(monkeypatch):
         assert "stream_busy_ms" in summ
     finally:
         hvd.shutdown()
+
+
+# --- spans on the profiler's clock (ISSUE 26) -------------------------------
+def test_step_parts_time_every_part_and_the_remainder():
+    import time
+
+    from horovod_tpu.telemetry.spans import StepParts
+
+    parts = StepParts("serve", step=7)
+    with parts("a"):
+        time.sleep(0.01)
+    for _ in range(2):                 # a part met twice adds up
+        with parts("b", rid=1):
+            time.sleep(0.005)
+    time.sleep(0.002)                  # nobody's: the remainder
+    assert parts.elapsed() >= 0.022
+    seconds = parts.close(admits=0)
+    assert list(seconds) == ["a", "b", "other", "total"]
+    assert seconds["a"] >= 0.01 and seconds["b"] >= 0.01
+    assert seconds["other"] >= 0.002
+    assert seconds["a"] + seconds["b"] + seconds["other"] \
+        == pytest.approx(seconds["total"], abs=1e-12)
+
+
+def test_profiler_annotation_is_the_span_helper():
+    import horovod_tpu as hvd
+    from horovod_tpu.telemetry.spans import span
+
+    assert type(hvd.profiler_annotation("x", k=1)) is type(span("x"))
+
+
+def _session_spans(path):
+    """name -> [(start, end)] of the hvd.* events of a session file."""
+    from jax.profiler import ProfileData
+    found: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("hvd."):
+                    found.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return found
+
+
+def test_report_reads_a_profiler_session(tmp_path):
+    """telemetry.report on a CPU session's .xplane.pb prints every span
+    with its count, by kind of step, and a self time of the step that is
+    its duration less its children's."""
+    import glob
+    import time
+
+    import jax
+
+    from horovod_tpu.telemetry.spans import StepParts, span
+
+    jax.profiler.start_trace(str(tmp_path))
+    for step in range(4):
+        parts = StepParts("serve", step=step)
+        with parts("plan_exchange"):
+            time.sleep(0.002)
+        if step == 0:
+            with parts("admit", rid=step, bucket=8):
+                with span("serve.cache_insert"):
+                    time.sleep(0.003)
+        with parts("token_fetch"):
+            time.sleep(0.004)
+        time.sleep(0.001)
+        parts.close(admits=int(step == 0), decoded=2)
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    text = summarize_file(path)
+    assert "(profiler session)" in text
+    tables = text.split("spans in ")[1:]
+    assert [t.splitlines()[0] for t in tables] == [
+        "hvd.serve.step [admits = 0]", "hvd.serve.step [admits > 0]"]
+    rows = [{line.split()[0]: line.split() for line in t.splitlines()
+             if line.startswith("hvd.")} for t in tables]
+    assert list(rows[0]) == ["hvd.serve.step", "hvd.serve.plan_exchange",
+                             "hvd.serve.token_fetch"]
+    assert list(rows[1]) == ["hvd.serve.step", "hvd.serve.plan_exchange",
+                             "hvd.serve.admit", "hvd.serve.cache_insert",
+                             "hvd.serve.token_fetch"]
+    assert [rows[0][name][1] for name in rows[0]] == ["3", "3", "3"]
+    assert [rows[1][name][1] for name in rows[1]] == ["1"] * 5
+    # The admit step's self time: its duration less its three children
+    # (cache_insert is admit's child, not the step's).
+    spans = _session_spans(path)
+    step0 = spans["hvd.serve.step"][0]
+    children = [spans["hvd.serve." + n][0]
+                for n in ("plan_exchange", "admit", "token_fetch")]
+    own = (step0[1] - step0[0]) - sum(e - s for s, e in children)
+    header = tables[1].splitlines()[1].split()
+    assert float(rows[1]["hvd.serve.step"][header.index("self_p50_ms")]) \
+        == pytest.approx(own / 1e6, abs=0.001)
+    assert own / 1e6 >= 1.0            # the sleep that is nobody's
+    admit = rows[1]["hvd.serve.admit"]
+    assert float(admit[header.index("self_p50_ms")]) < 1.0 \
+        < float(admit[header.index("p50_ms")])
+
+
+def test_report_names_idle_gaps_by_the_innermost_span():
+    """A device's idle gaps go to the innermost span that holds their
+    middle, a gap between two steps to nobody."""
+    from horovod_tpu.telemetry.report import _nest, idle_gaps
+
+    ms = 1_000_000
+    spans = _nest([(0, 100 * ms, "hvd.serve.step", {"admits": 0}),
+                   (10 * ms, 30 * ms, "hvd.serve.plan_exchange", {}),
+                   (40 * ms, 90 * ms, "hvd.serve.token_fetch", {}),
+                   (200 * ms, 300 * ms, "hvd.serve.step", {"admits": 0})])
+    ops = [(5 * ms, 20 * ms), (35 * ms, 50 * ms), (50 * ms, 60 * ms),
+           (95 * ms, 96 * ms), (150 * ms, 160 * ms), (215 * ms, 290 * ms)]
+    text = idle_gaps(ops, spans)
+    assert "busy 0.1260 s of the 0.2850 s" in text
+    assert "idle 55.79%" in text
+    by_span, longest = text.split("longest idle gaps")
+    rows = {line.split()[0]: line.split()[1:]
+            for line in by_span.splitlines()[3:] if line.strip()}
+    assert rows == {"outside_hvd_spans": ["2", "109.0", "54.500"],
+                    "hvd.serve.token_fetch": ["1", "35.0", "35.000"],
+                    "hvd.serve.plan_exchange": ["1", "15.0", "15.000"]}
+    assert [line.split() for line in longest.splitlines()[2:]] == [
+        ["outside_hvd_spans", "55.000"], ["outside_hvd_spans", "54.000"],
+        ["hvd.serve.token_fetch", "35.000"],
+        ["hvd.serve.plan_exchange", "15.000"]]
