@@ -209,6 +209,9 @@ class ReplicaExecutor:
                       "shrinks": [], "grows": [],
                       "prefill_streams": 0, "prefill_fallbacks": 0,
                       "prefill_skipped": 0, "weight_swaps": [],
+                      # The slot cache, and how much of it the compiled
+                      # decode program updates in place (set by warm-up).
+                      "cache_bytes": 0, "cache_aliased_bytes": 0,
                       # Always-on part timers of the serve step
                       # (telemetry/spans.py), by kind of step: "admit"
                       # steps prefilled at least one request here,
@@ -264,12 +267,15 @@ class ReplicaExecutor:
                                     self.cfg.table_width),
                                    self._sink, np.int32)
             self._cursors = np.zeros(self.cfg.slots, np.int32)
-            self._paged_jit = jax.jit(self._paged_impl)
-            self._paged_prefill_jit = jax.jit(self._paged_prefill_impl)
-            self._copy_block_jit = jax.jit(tfm.paged_copy_block)
+            self._paged_jit = jax.jit(self._paged_impl, donate_argnums=1)
+            self._paged_prefill_jit = jax.jit(self._paged_prefill_impl,
+                                              donate_argnums=1)
+            self._copy_block_jit = jax.jit(tfm.paged_copy_block,
+                                           donate_argnums=0)
         else:
-            self._decode_jit = jax.jit(self._decode_impl)
+            self._decode_jit = jax.jit(self._decode_impl, donate_argnums=1)
             self._prefill_jit = jax.jit(self._prefill_impl)
+            self._insert_jit = jax.jit(self._insert_impl, donate_argnums=0)
         self._kvstream = None
         # Jitted like every other model call here: un-jitted, each of its
         # hundreds of small ops compiles and dispatches on its own.
@@ -340,6 +346,15 @@ class ReplicaExecutor:
                                     tokens, lengths=n)
         return _sample(logits[0, n - 1, :]), cache
 
+    @staticmethod
+    def _insert_impl(cache, cache1, slot):
+        """Row ``slot`` of every leaf of the slot cache becomes the
+        prefilled request's only row (keys, values and write cursor
+        alike); ``slot`` is traced, so all slots share one program."""
+        return jax.tree_util.tree_map(
+            lambda big, small: jax.lax.dynamic_update_slice_in_dim(
+                big, small, slot, axis=0), cache, cache1)
+
     def _paged_impl(self, params, cache, tokens, tables, cursors):
         """One paged decode step for the whole slot array: inactive
         slots' tables point at the pool sink row, so their writes land
@@ -380,37 +395,57 @@ class ReplicaExecutor:
         return tfm._with_cache_index(mut["cache"], 0)
 
     def _warmup(self) -> None:
+        """Compile every program the serve loop runs.  Each of them takes
+        the cache donated, so each call's result is rebound: the leaves
+        a program was given are deleted once it is enqueued."""
         if self.cfg.paged:
             table1 = jnp.full((1, self.cfg.table_width), self._sink,
                               jnp.int32)
             for bucket in self.cfg.warmup_buckets:
                 if bucket > self.cfg.max_seq:
                     continue
-                tok, _ = self._paged_prefill_jit(
+                tok, self._cache = self._paged_prefill_jit(
                     self.params, self._cache,
                     jnp.zeros((1, bucket), jnp.int32), table1,
                     jnp.zeros((1,), jnp.int32),
                     jnp.ones((1,), jnp.int32))
                 jax.block_until_ready(tok)
-            nxt, _ = self._paged_jit(
-                self.params, self._cache,
-                jnp.asarray(self._last_tokens[:, None]),
-                jnp.asarray(self._tables), jnp.asarray(self._cursors))
-            jax.block_until_ready(nxt)
-            self._init_cache()         # discard warmup sink writes
-            return
-        for bucket in self.cfg.warmup_buckets:
-            if bucket > self.cfg.max_seq:
-                continue
-            tok, cache1 = self._prefill_jit(
-                self.params, jnp.zeros((1, bucket), jnp.int32),
-                jnp.int32(1))
-            jax.block_until_ready(tok)
-        nxt, _ = self._decode_jit(
-            self.params, self._cache,
-            jnp.asarray(self._last_tokens[:, None]))
+            decode, extra = self._paged_jit, (jnp.asarray(self._tables),
+                                              jnp.asarray(self._cursors))
+        else:
+            for bucket in self.cfg.warmup_buckets:
+                if bucket > self.cfg.max_seq:
+                    continue
+                tok, cache1 = self._prefill_jit(
+                    self.params, jnp.zeros((1, bucket), jnp.int32),
+                    jnp.int32(1))
+                # One shape whatever the bucket: compiled once.
+                self._cache = self._insert_jit(self._cache, cache1,
+                                               np.int32(0))
+                jax.block_until_ready(tok)
+            decode, extra = self._decode_jit, ()
+        args = (self.params, self._cache,
+                jnp.asarray(self._last_tokens[:, None]), *extra)
+        self._note_cache_aliasing(decode.lower(*args).compile())
+        nxt, self._cache = decode(*args)
         jax.block_until_ready(nxt)
+        self._cache = None             # one copy at a time
         self._init_cache()             # discard warmup cache writes
+
+    def _note_cache_aliasing(self, decode_program) -> None:
+        """How much of the cache the compiled decode program updates in
+        place: the cache is its only donated argument, so what it
+        aliases from input to output is cache."""
+        self.stats["cache_bytes"] = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self._cache))
+        self.stats["cache_aliased_bytes"] = \
+            decode_program.memory_analysis().alias_size_in_bytes
+        logger.info("serving: slot cache %.2f of %.2f GB aliased by the "
+                    "decode program (%d of %d bytes)",
+                    self.stats["cache_aliased_bytes"] / 1e9,
+                    self.stats["cache_bytes"] / 1e9,
+                    self.stats["cache_aliased_bytes"],
+                    self.stats["cache_bytes"])
 
     @staticmethod
     def _bucket(n: int) -> int:
@@ -477,7 +512,7 @@ class ReplicaExecutor:
         """The compiled prefill shape ``n`` prompt tokens pad to."""
         return min(self._bucket(n), self.cfg.max_seq)
 
-    # -- dense prefill (the PR 9 path, unchanged) ------------------------
+    # -- dense prefill ---------------------------------------------------
     def _prefill_slot(self, slot: int, a: Assignment, now: float) -> None:
         toks = self._clamped_tokens(a)
         padded = np.zeros((1, self._prompt_bucket(len(toks))), np.int32)
@@ -485,10 +520,9 @@ class ReplicaExecutor:
         with span("serve.prefill_dispatch"):
             first, cache1 = self._prefill_jit(
                 self.params, jnp.asarray(padded), jnp.int32(len(toks)))
-        with span("serve.cache_insert"):
-            self._cache = jax.tree_util.tree_map(
-                lambda big, small: big.at[slot].set(small[0]),
-                self._cache, cache1)
+        with span("serve.cache_insert"):     # a dispatch: nothing waits
+            self._cache = self._insert_jit(self._cache, cache1,
+                                           np.int32(slot))
         with span("serve.first_token_fetch"):
             first = int(first)         # waits for the device
         self._activate_slot(slot, a, now, first)

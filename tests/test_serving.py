@@ -774,3 +774,197 @@ def test_decode_program_carries_the_scope_names():
     finally:
         ex.close()
         hvd.shutdown()
+
+
+# --- the slot cache is updated in place (ISSUE 27) --------------------------
+def _executor(paged: bool, **kw):
+    from horovod_tpu.serving import ReplicaExecutor
+
+    hvd = _solo_world()
+    return hvd, ReplicaExecutor(_paged_cfg(paged=paged, **kw))
+
+
+def _cache_leaves(ex):
+    import jax
+    return jax.tree_util.tree_leaves(ex._cache)
+
+
+def _submit(ex, prompts, max_new):
+    for prompt in prompts:
+        ex.stats["offered"] += 1
+        assert ex.queue.submit(list(prompt), max_new) is not None
+
+
+def _reference(ex):
+    """What the replica has to generate for a request, as a function of
+    (prompt, max_new): a loop over tfm.prefill and tfm.decode_step on a
+    cache of the request's own, nothing donated, the prompt padded to
+    the executor's bucket."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.serving.replica import _decode_model_cfg
+
+    # The dense model: the paged replica's own reads the block pool.
+    model = tfm.TransformerLM(_decode_model_cfg(ex.cfg))
+    variables = {"params": ex.params}
+    prefill = jax.jit(lambda v, t, n: tfm.prefill(model, v, t, lengths=n))
+    decode = jax.jit(lambda v, c, t: tfm.decode_step(model, v, c, t))
+
+    def stream(prompt, max_new):
+        padded = np.zeros((1, ex._prompt_bucket(len(prompt))), np.int32)
+        padded[0, :len(prompt)] = prompt
+        logits, cache = prefill(variables, jnp.asarray(padded),
+                                jnp.int32(len(prompt)))
+        out = [int(jnp.argmax(logits[0, len(prompt) - 1]))]
+        while len(out) < max_new:
+            logits, cache = decode(variables, cache,
+                                   jnp.asarray([[out[-1]]], jnp.int32))
+            out.append(int(jnp.argmax(logits[0, -1])))
+        return out
+    return stream
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_every_program_that_writes_the_cache_deletes_the_one_it_got(paged):
+    """An admission (dense: prefill then the jitted insert; paged: the
+    prefill into the pool, with a copy-on-write for the repeated prompt)
+    and a decode-only step each leave every leaf of the cache they
+    started from deleted: the executor holds one copy, and the compiled
+    decode program aliases all of it."""
+    hvd, ex = _executor(paged)
+    try:
+        assert ex.stats["cache_bytes"] \
+            == sum(leaf.nbytes for leaf in _cache_leaves(ex)) > 0
+        assert ex.stats["cache_aliased_bytes"] == ex.stats["cache_bytes"]
+        prompt = [5, 9, 200, 31, 77, 3, 18, 64, 120]
+        _submit(ex, [prompt, prompt], 6)
+        kinds = []
+        while ex.batcher.inflight_count() or ex.queue.depth():
+            before = _cache_leaves(ex)
+            admits = ex.stats["steps"]["admit"]
+            assert ex._serve_step()
+            kinds.append(ex.stats["steps"]["admit"] > admits)
+            assert all(leaf.is_deleted() for leaf in before), kinds
+            assert not any(leaf.is_deleted() for leaf in _cache_leaves(ex))
+        assert True in kinds and False in kinds     # both kinds of step
+        if paged:
+            assert ex.kv_stats()["cow_copies"] > 0
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+def test_one_insert_program_writes_any_slot_like_the_eager_insert():
+    """The jitted insert, compiled once by warm-up, equals
+    ``big.at[slot].set(small[0])`` leaf by leaf for every slot, the
+    write cursors included, and leaves the other slots' rows alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.serving import ReplicaExecutor
+
+    # The insert needs nothing of an executor, so its compiled programs
+    # are shared by every executor of the process: count from here.
+    compiled = jax.jit(ReplicaExecutor._insert_impl,
+                       donate_argnums=0)._cache_size
+    before = compiled()
+    hvd, ex = _executor(False, max_batch=4, max_seq=96)
+    try:
+        assert len(ex.cfg.warmup_buckets) == 2
+        assert compiled() == before + 1             # whatever the bucket
+        rng = np.random.default_rng(27)
+
+        def noise(leaf):
+            if jnp.issubdtype(leaf.dtype, jnp.integer):
+                return rng.integers(1, 60, leaf.shape).astype(leaf.dtype)
+            return rng.standard_normal(leaf.shape).astype(leaf.dtype)
+
+        big = jax.tree_util.tree_map(noise, ex._cache)
+        _, row = ex._prefill_jit(
+            ex.params, jnp.asarray(rng.integers(2, 256, (1, 16)), jnp.int32),
+            jnp.int32(11))
+        assert any(leaf.dtype == np.int32 and (np.asarray(leaf) == 11).all()
+                   for leaf in jax.tree_util.tree_leaves(row))
+        for slot in range(ex.cfg.slots):
+            want = jax.tree_util.tree_map(
+                lambda b, s: np.asarray(jnp.asarray(b).at[slot].set(s[0])),
+                big, row)
+            given = jax.tree_util.tree_map(jnp.asarray, big)
+            got = ex._insert_jit(given, row, np.int32(slot))
+            assert all(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(given))
+            for g, w, b in zip(*map(jax.tree_util.tree_leaves,
+                                    (got, want, big))):
+                np.testing.assert_array_equal(np.asarray(g), w)
+                others = np.arange(ex.cfg.slots) != slot
+                np.testing.assert_array_equal(np.asarray(g)[others],
+                                              b[others])
+        assert compiled() == before + 1             # and whatever the slot
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_closed_loop_generates_what_a_loop_that_donates_nothing_does(paged):
+    """A seeded closed loop (more requests than slots, prompts repeated
+    so that the paged replica meets prefix hits and copies on write)
+    generates token for token what tfm.prefill and tfm.decode_step
+    generate for each request alone, nothing donated."""
+    import random
+
+    hvd, ex = _executor(paged)
+    try:
+        rng = random.Random(27)
+        pool = [[rng.randrange(2, 256) for _ in range(rng.randint(3, 13))]
+                for _ in range(3)]
+        prompts = [pool[i % 3] for i in range(7)]
+        _submit(ex, prompts, 7)
+        ex.serve_loop(stop_when=lambda: True)
+        assert ex.stats["served"] == len(prompts)
+        if paged:
+            kv = ex.kv_stats()
+            assert kv["prefix_hits"] > 0 and kv["cow_copies"] > 0, kv
+        reference = _reference(ex)
+        want = {tuple(p): reference(p, 7) for p in pool}
+        # Request ids are handed out in order of submission.
+        rids = sorted(ex.completed)
+        assert len(rids) == len(prompts)
+        for rid, prompt in zip(rids, prompts):
+            assert ex.completed[rid]["generated"] == want[tuple(prompt)], \
+                (rid, prompt)
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_warmup_leaves_a_live_fresh_cache_and_init_cache_works_again(paged):
+    """Warm-up ran every program on a donated cache and rebound each
+    result; what it leaves is live and equal to a fresh cache (zeros but
+    for the one write that creates the collection), and so is a second
+    ``_init_cache()``."""
+    import jax
+    import numpy as np
+
+    hvd, ex = _executor(paged)
+    try:
+        fresh = jax.tree_util.tree_map(np.asarray,
+                                       ex._init_cache_jit(ex.params))
+        for _ in range(2):
+            leaves = _cache_leaves(ex)
+            assert leaves and not any(leaf.is_deleted() for leaf in leaves)
+            for got, want in zip(leaves, jax.tree_util.tree_leaves(fresh)):
+                np.testing.assert_array_equal(np.asarray(got), want)
+                if got.dtype == np.int32:
+                    assert not np.asarray(got).any()     # write cursors
+                elif not paged:
+                    assert not np.asarray(got)[:, 1:].any()
+            ex._init_cache()
+    finally:
+        ex.close()
+        hvd.shutdown()
