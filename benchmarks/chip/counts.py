@@ -66,3 +66,13 @@ def transformer_decode_bytes(cfg: dict, contexts: list[int]) -> int:
     kv = sum(contexts) * 2 * cfg["num_hidden_layers"] * d \
         * dtype_bytes(cfg, "dtype")
     return weights + kv
+
+
+def transformer_decode_flops(cfg: dict, contexts: list[int]) -> int:
+    """Operations one decode step needs for slots whose live contexts
+    are ``contexts``: 2 a matmul weight for each slot's one token, and
+    scores and values over the slot's live context, 2 * 2 * context * d
+    a layer."""
+    d = cfg["hidden_size"]
+    return (2 * transformer_matmul_params(cfg) * len(contexts)
+            + 4 * d * cfg["num_hidden_layers"] * sum(contexts))

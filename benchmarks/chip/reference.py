@@ -9,16 +9,31 @@ with no kernel, cache, batching or bfloat16, and shares nothing with
 ``horovod_tpu/models`` but the names of the parameter tree it is handed.
 
     run.py --workload <cell> --check reference
-        the cell's configuration through the program at the published
-        widths (2 layers, 256 positions) against the reference: for a
-        train configuration logits, loss and two gradients; for a serve
-        configuration prefill into a slot, then 8 rows decoded through
-        the cache, against the reference's full forward pass.
+        the function the cell's configuration names under "reference"
+        (``"reference:check_serve"``; another architecture brings a
+        module of its own beside this one), called with ``(run,
+        config)``: the configuration through the program at the
+        published widths against its plain reference.  It owns its sizes
+        and its tolerance and returns ``error`` (and ``loss_error``,
+        ``grad_error`` where it has them) with ``tolerance``.  The two
+        here run 2 layers at 256 positions: for a train configuration
+        logits, loss and two gradients; for a serve configuration
+        prefill into a slot, then 8 rows decoded through the cache,
+        against the reference's full forward pass.
     run.py --workload <cell> --check mesh
         one global batch on the cell's mesh and on one device: the first
         losses agree (chip_smoke.gpt_cross_check, at the cell's sizes).
 
-Neither is part of a timed run.  The tolerance is on the largest absolute
+Neither is part of a timed run.  What every serving run does compare,
+once its window has closed, is what it served: ``lm_weights`` makes the
+weights the replica is handed (the benchmark's own, from the seed), and
+``lm_served_gap`` runs the reference once over a finished request's
+prompt and served tokens and reads the widest gap by which a served
+token's logit lies below the reference's best (``serve.py`` says which
+requests; ``PERF.md`` section 2 how the limits were set).  Its control is
+the same reference computed in int8 (both operands of every linear map
+rounded to 8 bits), the precision below the configuration's bfloat16.
+The checks' tolerance is on the largest absolute
 difference over the largest absolute reference value; 0.025 admits
 bfloat16 compute (8 bits of mantissa through two layers read 0.007 to
 0.013 in PR 23) and refuses a lower precision or a missing term, which
@@ -32,8 +47,6 @@ import math
 
 TOLERANCE = 0.025
 MESH_RTOL = 2e-2
-LAYERS, POSITIONS = 2, 256
-PROMPT, DECODED = 248, 8
 
 
 def rms_norm(x, scale, eps):
@@ -54,10 +67,26 @@ def rotary(x, theta):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
-def lm_logits(params, tokens, cfg: dict):
-    """tokens [B, T] -> logits [B, T, vocab], float32."""
+def int8(x, axes):
+    """``x`` rounded to 8 bits, one scale for each slice along ``axes``
+    (the axes a matmul sums over): what an int8 matmul is handed."""
+    import jax.numpy as jnp
+    scale = jnp.max(jnp.abs(x), axes, keepdims=True) / 127.0
+    scale = jnp.where(scale == 0, 1.0, scale)
+    return jnp.round(x / scale) * scale
+
+
+def lm_logits(params, tokens, cfg: dict, operands=None):
+    """tokens [B, T] -> logits [B, T, vocab], float32.  ``operands``
+    (``int8``) rounds both operands of every linear map first: the same
+    pass computed in a lower precision, the comparison's control."""
     import jax
     import jax.numpy as jnp
+
+    def linear(spec, x, x_axes, w, w_axes):
+        if operands is not None:
+            x, w = operands(x, x_axes), operands(w, w_axes)
+        return jnp.einsum(spec, x, w)
 
     eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
     x = params["embed"]["embedding"][tokens]
@@ -67,23 +96,108 @@ def lm_logits(params, tokens, cfg: dict):
         layer = params[f"layer_{i}"]
         h = rms_norm(x, layer["attn_norm"]["scale"], eps)
         attn = layer["attn"]
-        q = rotary(jnp.einsum("btd,dhk->bthk", h, attn["wq"]["kernel"]),
-                   theta)
-        k = rotary(jnp.einsum("btd,dhk->bthk", h, attn["wk"]["kernel"]),
-                   theta)
-        v = jnp.einsum("btd,dhk->bthk", h, attn["wv"]["kernel"])
+        q, k, v = (linear("btd,dhk->bthk", h, -1, attn[name]["kernel"], 0)
+                   for name in ("wq", "wk", "wv"))
+        q, k = rotary(q, theta), rotary(k, theta)
         scores = jnp.einsum("bqhk,bshk->bhqs", q, k) \
             / math.sqrt(q.shape[-1])
         scores = jnp.where(causal, scores, -jnp.inf)
         mixed = jnp.einsum("bhqs,bshk->bqhk",
                            jax.nn.softmax(scores, -1), v)
-        x = x + jnp.einsum("bthk,hkd->btd", mixed, attn["wo"]["kernel"])
+        x = x + linear("bthk,hkd->btd", mixed, (-2, -1),
+                       attn["wo"]["kernel"], (0, 1))
         h = rms_norm(x, layer["mlp_norm"]["scale"], eps)
         mlp = layer["mlp"]
-        x = x + (jax.nn.silu(h @ mlp["gate"]["kernel"])
-                 * (h @ mlp["up"]["kernel"])) @ mlp["down"]["kernel"]
+        gated = jax.nn.silu(linear("btd,df->btf", h, -1,
+                                   mlp["gate"]["kernel"], 0)) \
+            * linear("btd,df->btf", h, -1, mlp["up"]["kernel"], 0)
+        x = x + linear("btf,fd->btd", gated, -1, mlp["down"]["kernel"], 0)
     x = rms_norm(x, params["final_norm"]["scale"], eps)
-    return x @ params["lm_head"]["kernel"]
+    return linear("btd,dv->btv", x, -1, params["lm_head"]["kernel"], 0)
+
+
+def lm_weights(run):
+    """The configuration's weights from the seed, in the type it serves,
+    made on the device in one jitted call: a normal law of variance one
+    over the fan-in for a matrix, ones for a norm.  The tree has the
+    names the program's decoder gives its parameters and nothing else of
+    the program."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = run.config
+    dtype = run.resolve(cfg["model"]["args"]["param_dtype"][1:])
+    d, ff, vocab = cfg["hidden_size"], cfg["intermediate_size"], \
+        cfg["vocab_size"]
+    heads = cfg["num_attention_heads"]
+    head = d // heads
+    matrix = lambda *shape: {"kernel": shape}              # noqa: E731
+    layer = {"attn_norm": {"scale": None}, "mlp_norm": {"scale": None},
+             "attn": {"wq": matrix(d, heads, head),
+                      "wk": matrix(d, heads, head),
+                      "wv": matrix(d, heads, head),
+                      "wo": matrix(heads, head, d)},
+             "mlp": {"gate": matrix(d, ff), "up": matrix(d, ff),
+                     "down": matrix(ff, d)}}
+    tree = {"embed": {"embedding": (vocab, d)},
+            "final_norm": {"scale": None}, "lm_head": matrix(d, vocab),
+            **{f"layer_{i}": layer
+               for i in range(cfg["num_hidden_layers"])}}
+    fan_in = {"wo": heads * head, "down": ff}
+
+    def make(key):
+        flat, treedef = jax.tree_util.tree_flatten_with_path(
+            tree, is_leaf=lambda x: x is None or isinstance(x, tuple))
+        leaves = []
+        for at, (path, shape) in enumerate(flat):
+            if shape is None:
+                leaves.append(jnp.ones((d,), dtype))
+                continue
+            std = fan_in.get(path[-2].key, d) ** -0.5
+            leaves.append((std * jax.random.normal(
+                jax.random.fold_in(key, at), shape, jnp.float32)
+            ).astype(dtype))
+        return jax.tree_util.tree_unflatten(treedef, leaves)
+
+    return jax.jit(make)(jax.random.key(run.seed))
+
+
+def lm_served_gap(cfg: dict, control: bool = False):
+    """The comparison of one finished request with the reference, as a
+    jitted function of ``(params, tokens, first, length)``.  ``tokens``
+    [1, T] is the request's prompt, then what the replica served, then
+    padding (causal, so the padding touches nothing before it); the
+    served tokens are ``tokens[0, first:length]``.  For every served
+    token it reads the gap by which its logit lies below the reference's
+    best at its position, and returns the widest, ``gap``, and their
+    sum, ``gap_sum``; with ``control`` also ``control_gap`` and
+    ``control_gap_sum``: the same for the token that the reference
+    computed in int8 puts first."""
+    import jax
+    import jax.numpy as jnp
+
+    def gaps(params, tokens, first, length):
+        full = jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.float32), params)
+        at = jnp.arange(tokens.shape[1])
+        live = (at >= first - 1) & (at < length - 1)   # t predicts t + 1
+        with jax.default_matmul_precision("highest"):
+            logits = lm_logits(full, tokens, cfg)[0]
+            best = jnp.max(logits, -1)
+
+            def read(chosen, name):
+                below = jnp.where(live, best - jnp.take_along_axis(
+                    logits, chosen[:, None], -1)[:, 0], 0.0)
+                return {name: jnp.max(below), name + "_sum": jnp.sum(below)}
+
+            seen = read(jnp.roll(tokens[0], -1), "gap")
+            if control:
+                seen.update(read(jnp.argmax(lm_logits(
+                    full, tokens, cfg, operands=int8)[0], -1),
+                    "control_gap"))
+        return seen
+
+    return jax.jit(gaps)
 
 
 def lm_loss(params, tokens, labels, cfg: dict):
@@ -105,9 +219,11 @@ def check_train(run, cfg: dict) -> dict:
     import jax
     from horovod_tpu import training
 
-    model = run.build_model(num_layers=LAYERS)
+    layers, positions = 2, 256
+    cfg = {**cfg, "num_hidden_layers": layers}
+    model = run.build_model(num_layers=layers)
     tokens = jax.random.randint(jax.random.key(run.seed),
-                                (2, POSITIONS + 1), 0, cfg["vocab_size"])
+                                (2, positions + 1), 0, cfg["vocab_size"])
     inputs, labels = tokens[:, :-1], tokens[:, 1:]
     params = jax.jit(model.init)(jax.random.key(run.seed), inputs)["params"]
 
@@ -125,7 +241,8 @@ def check_train(run, cfg: dict) -> dict:
                       g["layer_0"]["attn"]["wq"]["kernel"])
     return {"compared": f"logits {tuple(logits.shape)}, loss, and the "
                         "gradients of lm_head and layer_0.attn.wq",
-            "error": error(logits, want_logits),
+            "layers": layers, "positions": positions,
+            "tolerance": TOLERANCE, "error": error(logits, want_logits),
             "loss": float(loss), "reference_loss": float(want_loss),
             "loss_error": abs(float(loss) - float(want_loss))
             / abs(float(want_loss)),
@@ -141,17 +258,19 @@ def check_serve(run, cfg: dict) -> dict:
     import jax.numpy as jnp
     from horovod_tpu.models import transformer as tfm
 
+    layers, positions, prompt, decoded = 2, 256, 248, 8
     slots, slot = 4, 3
+    cfg = {**cfg, "num_hidden_layers": layers}
     model = tfm.TransformerLM(run.model_config(
-        num_layers=LAYERS, decode=True, max_seq_len=POSITIONS))
-    tokens = jax.random.randint(jax.random.key(run.seed), (1, POSITIONS),
+        num_layers=layers, decode=True, max_seq_len=positions))
+    tokens = jax.random.randint(jax.random.key(run.seed), (1, positions),
                                 2, cfg["vocab_size"])
     params = jax.jit(model.init)(jax.random.key(run.seed),
                                  jnp.zeros((1, 8), jnp.int32))["params"]
-    padded = tokens.at[:, PROMPT:].set(0)           # the bucket's padding
+    padded = tokens.at[:, prompt:].set(0)           # the bucket's padding
     logits, cache1 = jax.jit(lambda p, t: tfm.prefill(
-        model, {"params": p}, t, lengths=PROMPT))(params, padded)
-    rows = [logits[0, PROMPT - 1]]
+        model, {"params": p}, t, lengths=prompt))(params, padded)
+    rows = [logits[0, prompt - 1]]
     _, empty = jax.jit(lambda p: model.apply(
         {"params": p}, jnp.zeros((slots, 1), jnp.int32),
         mutable=["cache"]))(params)
@@ -160,7 +279,7 @@ def check_serve(run, cfg: dict) -> dict:
         tfm._with_cache_index(empty["cache"], 0), cache1)
     decode = jax.jit(lambda p, c, t: tfm.decode_step(
         model, {"params": p}, c, t))
-    for at in range(PROMPT, PROMPT + DECODED):
+    for at in range(prompt, prompt + decoded):
         fed = jnp.zeros((slots, 1), jnp.int32).at[slot, 0].set(
             tokens[0, at])
         logits, cache = decode(params, cache, fed)
@@ -168,11 +287,13 @@ def check_serve(run, cfg: dict) -> dict:
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda p: lm_logits(p, tokens, cfg))(
             jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params))
-    return {"compared": f"{len(rows)} logit rows (prefill of {PROMPT} "
-                        f"tokens into slot {slot}, then {DECODED} decoded "
+    return {"compared": f"{len(rows)} logit rows (prefill of {prompt} "
+                        f"tokens into slot {slot}, then {decoded} decoded "
                         f"through the cache) x {cfg['vocab_size']}",
+            "layers": layers, "positions": positions,
+            "tolerance": TOLERANCE,
             "error": error(jnp.stack(rows),
-                           want[0, PROMPT - 1:PROMPT + DECODED])}
+                           want[0, prompt - 1:prompt + decoded])}
 
 
 def check_mesh(run) -> dict:
@@ -209,13 +330,8 @@ def check_mesh(run) -> dict:
 def check(run, which: str) -> int:
     """Run one check, print its one JSON line, exit 0 only if it holds."""
     cfg = run.config
-    if which == "mesh":
-        seen = check_mesh(run)
-    else:
-        cfg = {**cfg, "num_hidden_layers": LAYERS}
-        seen = (check_serve if cfg["driver"] == "serve"
-                else check_train)(run, cfg)
-        seen.update(layers=LAYERS, positions=POSITIONS, tolerance=TOLERANCE)
+    seen = check_mesh(run) if which == "mesh" \
+        else run.resolve(cfg["reference"])(run, cfg)
     worst = max(seen["error"], seen.get("grad_error", 0.0),
                 seen.get("loss_error", 0.0))
     seen.update(config=cfg["name"], device=run.devices[0].device_kind,

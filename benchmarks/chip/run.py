@@ -20,8 +20,13 @@ Other modes, never part of a timed run:
                      devices; the line is stamped cpu and is no
                      measurement
   --check reference  the configuration against its plain float32
-                     reference at the published widths (reference.py)
+                     reference at the published widths: the function
+                     its file names under "reference"
   --check mesh       mesh against one-device losses on one global batch
+  --check control    a run of the cell whose comparison also reads its
+                     control, the reference in the precision below the
+                     configuration's: exit 0 only if the run is correct
+                     and the control comes out over the limit
 """
 from __future__ import annotations
 
@@ -32,6 +37,7 @@ T_START = time.perf_counter()      # process start, as near as Python gets
 import argparse                    # noqa: E402
 import importlib                   # noqa: E402
 import json                        # noqa: E402
+import math                        # noqa: E402
 import os                          # noqa: E402
 import re                          # noqa: E402
 import sys                         # noqa: E402
@@ -136,6 +142,8 @@ class Run:
         or byte counts."""
         return resolve(self.config["counts"][name])
 
+    resolve = staticmethod(resolve)    # for a module that a file names
+
     def model_config(self, **overrides):
         """The model's configuration object, where its class takes one
         (and for a driver that hands the program that, not a model)."""
@@ -205,7 +213,7 @@ def parse(argv) -> argparse.Namespace:
                         help="default: run_seconds of BENCHMARK.json")
     parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
     parser.add_argument("--rehearse-cpu", action="store_true")
-    parser.add_argument("--check", choices=("reference", "mesh"))
+    parser.add_argument("--check", choices=("reference", "mesh", "control"))
     return parser.parse_args(argv)
 
 
@@ -259,15 +267,29 @@ def main(argv=None) -> int:
             f"{args.seconds:g} s, trace {args.trace}, compile cache "
             f"{cache_dir}")
     try:
-        if args.check:
+        if args.check in ("reference", "mesh"):
             import reference
             return reference.check(run, args.check)
         driver = importlib.import_module(run.config["driver"])
         result = driver.drive(run)
+        device = device_stamp(run.devices)
+        # The comparison with the plain reference comes once the window
+        # has closed, the peak has been read and the program's state is
+        # freed: a process's peak never falls again.
+        if args.check == "control":
+            result["after"](result, control=True)
+            seen = result["notes"]["served_check"]
+            seen["ok"] = bool(not result["problems"] and any(
+                seen["control_" + key] > limit
+                for key, limit in seen["limits"].items()))
+            run.say("check " + json.dumps(
+                {**seen, "problems": result["problems"]}))
+            return 0 if seen["ok"] else 1
+        if "after" in result:
+            result["after"](result)
         line = {"correct": not result["problems"],
                 "attempted": result["attempted"],
-                "failed": result["failed"]}
-        device = device_stamp(run.devices)
+                "failed": result["attempted"] if result["problems"] else 0}
         if args.trace:
             line["metrics"], busy, line["breakdown"] = \
                 layer_metrics(run, result)
@@ -282,6 +304,16 @@ def main(argv=None) -> int:
         run.say("notes " + json.dumps(
             {"problems": result["problems"], "marks_s": MARKS,
              "allocator": fullest(run.devices), **result["notes"]}))
+        # Each number that decided ``correct`` beside its limit: the last
+        # lines of standard error, and the last key of the result.
+        line["compared"] = {
+            name: {"value": value if math.isfinite(value) else str(value),
+                   "limit": limit}
+            for name, (value, limit) in result["compared"].items()}
+        for name, pair in line["compared"].items():
+            print(f"compared {name}: {pair['value']} (limit "
+                  f"{pair['limit']})", file=sys.stderr)
+        sys.stderr.flush()
         print(json.dumps(line), flush=True)
         return 0
     finally:

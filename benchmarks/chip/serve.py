@@ -16,9 +16,20 @@ deliveries of one stream (the two tokens an admitting step emits for its
 new stream arrive together and make no gap).  The window opens a fixed
 number of steps after the last client's first admit and closes at the
 first step boundary past ``--seconds``.
+
+``correct``.  The weights are the benchmark's own, from the seed, handed
+to the replica as a deployment hands it a checkpoint (the configuration's
+``served_check.weights``).  Once the window has closed, the peak has
+been read and the replica is freed, ``compare_served`` takes a sample,
+drawn from the seed, of the requests that finished inside the window,
+the longest among them, runs the configuration's plain reference once
+over each one's prompt and served tokens, and holds the widest gap by
+which a served token's logit lies below the reference's best to the
+configuration's limit.
 """
 from __future__ import annotations
 
+import functools
 import math
 import random
 import statistics
@@ -60,6 +71,29 @@ def weighted_percentile(pairs: list, q: float) -> float:
         if seen >= rank:
             return value
     raise ValueError("no samples")
+
+
+def per_step_counts(run) -> dict:
+    """The configuration's counts whose key ends in ``_per_step``, key ->
+    function of ``(config, contexts)``: the bytes or operations one
+    decode step needs, for a kernel's share of its roofline."""
+    return {key: run.count(key) for key in run.config.get("counts", {})
+            if key.endswith("_per_step")}
+
+
+def counters(seen: dict, slots: int, per_step: dict, stats: dict) -> dict:
+    """What a ``counter`` reader can name: the driver's slot occupancy,
+    the mean of every ``_per_step`` count over the traced steps that
+    admitted nothing (None where there was none, so that its metric is
+    left out), and every plain number of the executor's ``stats`` as
+    ``stats.<key>``."""
+    found = {"slot_occupancy_pct":
+             100.0 * seen["occupied_slot_steps"] / (seen["steps"] * slots)}
+    found.update((key, statistics.fmean(values) if values else None)
+                 for key, values in per_step.items())
+    found.update((f"stats.{key}", value) for key, value in stats.items()
+                 if type(value) in (int, float))
+    return found
 
 
 def account(log: list, opened: float, closed: float) -> dict:
@@ -112,10 +146,29 @@ class ClosedLoop:
         self.client_of: dict[int, int] = {}      # rid -> client
         self.expected: dict[int, int] = {}       # rid -> output length
         self.prompt_len: dict[int, int] = {}
+        self.index_of: dict[int, int] = {}       # rid -> place in the table
         self.delivered: dict[int, int] = {}      # rid -> tokens so far
         self.finished_at: dict[int, float] = {}  # rid -> host clock
         self.shed = 0
         self.log: list[Step] = []
+
+    def sample(self, opened: float, closed: float, count: int) -> list:
+        """``count`` of the requests that finished inside the window, as
+        (prompt tokens, served tokens): the longest and a draw from the
+        seed among the others."""
+        done = sorted(rid for rid, at in self.finished_at.items()
+                      if opened < at <= closed)
+        size = lambda rid: self.prompt_len[rid] + self.expected[rid]  # noqa
+        if not done:
+            return []
+        longest = max(done, key=size)
+        others = [rid for rid in done if rid != longest]
+        picked = [longest] + random.Random(self.run.seed).sample(
+            others, min(count - 1, len(others)))
+        return [(request_tokens(self.run.seed, self.index_of[rid],
+                                self.prompt_len[rid], self.vocab),
+                 list(self.executor.completed[rid]["generated"]))
+                for rid in picked]
 
     def submit(self, client: int) -> None:
         prompt, output = self.table[self.issued % len(self.table)]
@@ -130,7 +183,7 @@ class ClosedLoop:
             self.shed += 1
             return
         self.client_of[rid], self.expected[rid] = client, output
-        self.prompt_len[rid] = prompt
+        self.prompt_len[rid], self.index_of[rid] = prompt, self.issued - 1
 
     def step(self) -> Step:
         """One serve step, what it delivered, and the clients' answers."""
@@ -166,7 +219,9 @@ class ClosedLoop:
         return [self.prompt_len[s.rid] + len(s.generated)
                 for s in self.executor.slots if s is not None]
 
-    def problems(self) -> list[str]:
+    def problems(self) -> tuple[list[str], dict]:
+        """What went wrong, in words, and each number that was compared
+        beside its limit."""
         ex, found = self.executor, []
         wrong = {rid: ex.completed[rid]["tokens"] for rid in self.finished_at
                  if ex.completed[rid]["tokens"] != self.expected[rid]}
@@ -180,7 +235,53 @@ class ClosedLoop:
                 "lost": ex.stats["lost"]}
         if any(lost.values()):
             found.append(f"requests shed, expired or lost: {lost}")
-        return found
+        return found, {"requests_off_their_length": [len(wrong), 0],
+                       "requests_shed_expired_lost":
+                           [int(sum(lost.values())), 0]}
+
+
+def compare_served(run, params, sample: list, result: dict,
+                   control: bool = False) -> None:
+    """The sampled requests against the configuration's plain reference:
+    the widest and the mean gap by which a served token's logit lies
+    below the reference's best, each beside its limit in
+    ``result["compared"]`` and a problem where it is over.  ``control``
+    also reads the gaps of the tokens that the precision below the
+    configuration's would put first, which have to come out over a
+    limit."""
+    import numpy as np
+
+    check = run.config["served_check"]
+    gap_of = run.resolve(check["gap"])(run.config, control)
+    # One shape: the longest request the traffic's table can make.
+    pad = -(-max(p + o for p, o in run.traffic["requests"]) // 256) * 256
+    served = sum(len(tokens) for _, tokens in sample)
+    widest: dict[str, float] = {}
+    total: dict[str, float] = {}
+    for prompt, tokens in sample:
+        padded = np.zeros((1, pad), np.int32)
+        padded[0, :len(prompt) + len(tokens)] = prompt + tokens
+        read = gap_of(params, padded, np.int32(len(prompt)),
+                      np.int32(len(prompt) + len(tokens)))
+        for key, value in read.items():
+            if key.endswith("_sum"):
+                total[key] = total.get(key, 0.0) + float(value)
+            else:
+                widest[key] = max(widest.get(key, 0.0), float(value))
+    # No finished request is nothing compared, and reads as a fault.
+    numbers = {**widest, **{key[:-4] + "_mean": value / served
+                            for key, value in total.items()}} \
+        or {"gap": math.inf, "gap_mean": math.inf}
+    result["notes"]["served_check"] = {
+        **numbers, "limits": check["limits"], "requests": len(sample),
+        "served_tokens": served}
+    for key, limit in check["limits"].items():
+        result["compared"]["served_logit_" + key] = [numbers[key], limit]
+        if not numbers[key] <= limit:
+            result["problems"].append(
+                f"served tokens' logits lie below the reference's best "
+                f"by a {key} of {numbers[key]}, over the limit of {limit} "
+                f"({len(sample)} requests, {served} tokens)")
 
 
 def drive(run) -> dict:
@@ -191,13 +292,15 @@ def drive(run) -> dict:
     serve = cfg["serve"]
     hvd.init()        # size 1, no rendezvous: the exchanges stay local
     run.mark("hvd")
+    check = cfg["served_check"]
+    params = run.resolve(check["weights"])(run)
+    run.mark("weights")
+    # The whole ``serve`` group: a configuration sets any field the
+    # program's ServeConfig has.
     executor = ReplicaExecutor(ServeConfig(
         model_cfg=run.model_config(), seed=run.seed,
-        max_batch=serve["max_batch"], max_seq=serve["max_seq"],
-        token_budget=serve["token_budget"], paged=serve["paged"],
-        eos_id=serve["eos_id"], slo_ms=serve["slo_ms"],
-        queue_depth=serve["queue_depth"],
-        warmup_buckets=tuple(serve["warmup_buckets"])))
+        **{**serve, "warmup_buckets": tuple(serve["warmup_buckets"])}),
+        params=params)
     run.mark("executor")
     try:
         loop = ClosedLoop(run, executor, serve["slo_ms"])
@@ -214,7 +317,9 @@ def drive(run) -> dict:
         compiles0 = run.compiles.count
         opened = loop.log[-1].end
         first = len(loop.log)
-        decode_bytes = []      # of the traced steps that admit nothing
+        count_of = per_step_counts(run)
+        # Of the traced steps that admit nothing, count by count.
+        per_step: dict[str, list] = {key: [] for key in count_of}
         with run.tracer.window([d.id for d in run.devices]):
             while True:
                 step = loop.step()
@@ -223,36 +328,36 @@ def drive(run) -> dict:
                         break
                     continue
                 if not step.admits:
-                    decode_bytes.append(run.count("decode_bytes_per_step")(
-                        cfg, loop.contexts()))
+                    contexts = loop.contexts()
+                    for key, count in count_of.items():
+                        per_step[key].append(count(cfg, contexts))
                 if len(loop.log) - first >= traffic["trace_steps"]:
                     break
         closed = loop.log[-1].end
         compiled_inside = run.compiles.count - compiles0
 
         seen = account(loop.log, opened, closed)
-        problems = loop.problems()
+        problems, compared = loop.problems()
+        compared["compilations_in_window"] = [compiled_inside, 0]
         if compiled_inside:
             problems.append(f"{compiled_inside} compilations inside the "
                             "window")
         attempted = sum(opened < at <= closed
                         for at in loop.finished_at.values())
+        sample = loop.sample(opened, closed, check["requests"])
     finally:
         executor.close()
         hvd.shutdown()
-    slots = len(executor.slots)
-    counters = {
-        "slot_occupancy_pct":
-            100.0 * seen["occupied_slot_steps"] / (seen["steps"] * slots),
-        "decode_bytes_per_step":
-            statistics.fmean(decode_bytes) if decode_bytes else None}
+    counted = counters(seen, len(executor.slots), per_step, executor.stats)
+    submitted, completed = loop.issued, len(loop.finished_at)
+    del executor, loop        # the cache goes before the reference runs
     end_to_end = {"serve_total_tokens_per_s": seen["total_tokens_per_s"],
                   "serve_itl_ms_p95": seen["itl_ms_p95"],
                   "setup_s": opened - run.t_start}
     return {"problems": problems, "attempted": attempted,
-            "failed": attempted if problems else 0,
-            "end_to_end": end_to_end,
-            "counters": counters,
-            "notes": {**seen, **counters, "submitted": loop.issued,
-                      "completed": len(loop.finished_at),
+            "end_to_end": end_to_end, "compared": compared,
+            "after": functools.partial(compare_served, run, params, sample),
+            "counters": counted,
+            "notes": {**seen, **counted, "submitted": submitted,
+                      "completed": completed,
                       "compiles_total": run.compiles.count}}

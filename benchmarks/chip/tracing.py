@@ -4,6 +4,8 @@ Recording.  ``Tracer.window`` runs one profiler session around a short
 steady window and ``Tracer.span`` puts a ``bench.*`` annotation on the
 profiler's own clock (``jax.profiler.TraceAnnotation``), so host spans and
 device operations share one timeline.  With tracing off both do nothing.
+The program's own spans (``hvd.*``, ``horovod_tpu/telemetry/spans.py``)
+land in the same session and are kept beside the benchmark's.
 
 Reduction.  ``load`` reads the sessions' ``.xplane.pb`` files into one
 ``Timeline`` (later sessions are shifted past earlier ones; times inside
@@ -17,12 +19,21 @@ Reader kinds (every reader may carry ``"scale"``):
   counter      {"key": k}               a number the driver counted
   peak         {"key": k}               peaks.json, for the device run on
   metric       {"name": n}              another per-layer metric's value
-  span_stat    {"span": s, "label": l, "stat": "median|sum|count"}
+  span_stat    {"span": s, "label": l, "within": {"span": s, "label": l},
+                "stat": "median|sum|count"}
   device_time  {"lines": {line: regex on "<opcode> <name>"}, "device": 0,
                 "within": {"span": s, "label": l}, "reduce":
                 "median|sum|busy", "per": {"span": s, "label": l}}
   ratio        {"num": reader, "den": reader}
   diff         {"a": reader, "b": reader}
+A span is named without its prefix when it is the benchmark's
+(``serve.step`` is ``bench.serve.step``) and in full when it is the
+program's (``hvd.serve.token_fetch``).  The driver's labels pair with the
+calls of a ``bench.*`` span only, so a program span is narrowed by
+``within``: the spans that start inside a selected frame.  A device
+operation is selected by ``<opcode> <name>`` alone: ``ProfileData`` does
+not show the ``tf_op`` name stack, so a kernel that wants a device-time
+metric of its own carries its name (as ``hvd.flash_fwd`` does).
 A reader that finds nothing to read returns None and the metric is left
 out of the line.
 """
@@ -39,6 +50,7 @@ import tempfile
 from typing import NamedTuple
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "hvd."            # the program's spans, kept as they are
 WINDOW_SPAN = "bench.window"
 NAME_LIMIT = 80
 _SHIFT_S = 10.0          # gap put between two sessions on the timeline
@@ -59,7 +71,7 @@ class Span(NamedTuple):
 
 
 class Timeline(NamedTuple):
-    spans: list          # bench.* host annotations, by start
+    spans: list          # bench.* and hvd.* host annotations, by start
     device: dict         # device id -> line name -> [Span] by start
 
 
@@ -155,7 +167,8 @@ def load(paths: list[str]) -> Timeline:
                 continue
             for line in plane.lines:
                 for ev in line.events:
-                    if not match and not ev.name.startswith(SPAN_PREFIX):
+                    if not match and not ev.name.startswith(
+                            (SPAN_PREFIX, PROGRAM_PREFIX)):
                         continue
                     start = ev.start_ns * 1e-9 + shift
                     name, opcode = short_name(ev.name) if match \
@@ -194,9 +207,11 @@ def clipped(intervals, lo: float, hi: float) -> list[tuple[float, float]]:
 
 def select_spans(tl: Timeline, labels: dict, name: str,
                  label: str | None = None) -> list[Span]:
-    """The spans called ``bench.<name>``, in order; with ``label`` only
-    the calls the driver labelled so (labels pair with calls by order)."""
-    full = name if name.startswith(SPAN_PREFIX) else SPAN_PREFIX + name
+    """The spans called ``bench.<name>``, or ``name`` itself where it is
+    one of the program's (``hvd.*``), in order; with ``label`` only the
+    calls the driver labelled so (labels pair with calls by order)."""
+    full = name if name.startswith((SPAN_PREFIX, PROGRAM_PREFIX)) \
+        else SPAN_PREFIX + name
     found = [s for s in tl.spans if s.name == full]
     if label is None:
         return found
@@ -213,6 +228,17 @@ def covering(frames: list[Span], starts: list[float],
     ``starts`` are their starts."""
     at = bisect.bisect_right(starts, t) - 1
     return frames[at] if at >= 0 and t < frames[at].end else None
+
+
+def started_within(items: list[Span], tl: Timeline, labels: dict,
+           within: dict | None) -> list[Span]:
+    """The items that start inside one of the frames ``within`` selects
+    (``{"span", "label"}``); all of them where it is None."""
+    if not within:
+        return items
+    frames = select_spans(tl, labels, within["span"], within.get("label"))
+    starts = [f.start for f in frames]
+    return [it for it in items if covering(frames, starts, it.start)]
 
 
 def _ops(tl: Timeline, device_id: int) -> list[Span]:
@@ -238,7 +264,8 @@ def breakdown(tl: Timeline, device_id: int = 0, top: int = 10) -> dict:
     """Where one device's time went inside the last traced window (the
     reported part's, where a cell has several): the operations that took
     most of it, as ``<op>_in_<module>``, and the longest idle gaps, by
-    the innermost benchmark span they fall in."""
+    the innermost span, the benchmark's or the program's, that holds
+    their middle."""
     modules = tl.device.get(device_id, {}).get("XLA Modules", [])
     module_starts = [m.start for m in modules]
     totals: dict[str, float] = {}
@@ -297,8 +324,10 @@ def _metric(reader, facts):
 
 
 def _span_stat(reader, facts):
-    spans = select_spans(facts["timeline"], facts["labels"],
-                         reader["span"], reader.get("label"))
+    tl, labels = facts["timeline"], facts["labels"]
+    spans = started_within(
+        select_spans(tl, labels, reader["span"], reader.get("label")),
+        tl, labels, reader.get("within"))
     if not spans and reader["stat"] != "count":
         return None
     return _STATS[reader["stat"]]([s.end - s.start for s in spans])
@@ -309,13 +338,7 @@ def _device_time(reader, facts):
     lines = tl.device.get(reader.get("device", 0), {})
     events = [ev for line, pattern in reader["lines"].items()
               for ev in lines.get(line, []) if re.search(pattern, ev.text)]
-    within = reader.get("within")
-    if within:
-        frames = select_spans(tl, labels, within["span"],
-                              within.get("label"))
-        starts = [f.start for f in frames]
-        events = [ev for ev in events
-                  if covering(frames, starts, ev.start)]
+    events = started_within(events, tl, labels, reader.get("within"))
     if not events:
         return None
     if reader["reduce"] == "median":

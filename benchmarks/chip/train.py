@@ -173,14 +173,24 @@ def run_part(run, part: dict, share_s: float) -> dict:
     if compiled_inside:
         problems.append(f"{part['name']}: {compiled_inside} compilations "
                         "inside the window")
-    if len(devices) > 1:
-        problems += layout_problems(run, devices, batch, state.params)
+    layout = layout_problems(run, devices, batch, state.params) \
+        if len(devices) > 1 else []
+    problems += layout
+    name = part["name"]
+    # Each number that was compared, beside its limit (the most it may be).
+    compared = {
+        f"{name}.losses_not_finite":
+            [sum(not math.isfinite(x) for x in losses), 0],
+        f"{name}.loss_last_less_first": [losses[-1] - losses[0], 0],
+        f"{name}.state_step_off": [abs(int(state.step) - len(losses)), 0],
+        f"{name}.compilations_in_window": [compiled_inside, 0],
+        f"{name}.layout_problems": [len(layout), 0]}
     elapsed = closed - opened
     return {"devices": len(devices), "steps": steps, "elapsed_s": elapsed,
             "opened": opened, "closed": closed,
             "items_per_s_per_chip": steps * items_a_step / elapsed,
             "loss_first": losses[0], "loss_last": losses[-1],
-            "problems": problems}
+            "problems": problems, "compared": compared}
 
 
 def drive(run) -> dict:
@@ -191,7 +201,8 @@ def drive(run) -> dict:
             run, part, run.seconds / len(traffic["parts"]))
         gc.collect()     # the part's state is gone before the next is built
         run.say(f"part {part['name']}: " + str(
-            {k: v for k, v in seen.items() if k != "problems"}))
+            {k: v for k, v in seen.items()
+             if k not in ("problems", "compared")}))
     reported = parts[traffic["report_part"]]
     last_close = max(p["closed"] for p in parts.values())
     in_windows = sum(p["elapsed_s"] for p in parts.values())
@@ -207,9 +218,11 @@ def drive(run) -> dict:
                 "model_flops_per_s_per_chip":
                     flops * reported["items_per_s_per_chip"]}
     problems = [p for seen in parts.values() for p in seen.pop("problems")]
+    compared = {name: pair for seen in parts.values()
+                for name, pair in seen.pop("compared").items()}
     steps = sum(p["steps"] for p in parts.values())
     return {"problems": problems, "attempted": steps,
-            "failed": steps if problems else 0,
-            "end_to_end": end_to_end, "counters": counters,
+            "end_to_end": end_to_end, "compared": compared,
+            "counters": counters,
             "notes": {"flops_per_item": flops, "parts": parts,
                       "compiles_total": run.compiles.count}}
