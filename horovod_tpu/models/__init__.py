@@ -8,6 +8,7 @@ examples/pytorch/pytorch_imagenet_resnet50.py). These are TPU-native
 re-implementations in flax, bf16-first, designed so every FLOP-heavy op
 lands on the MXU.
 """
+from .hybrid import HybridConfig, HybridLM
 from .inception import InceptionV3
 from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet101,
                      ResNet152)
@@ -16,6 +17,7 @@ from .transformer import (TransformerConfig, TransformerLM, gpt_medium,
 from .vgg import VGG, VGG16, VGG19
 
 __all__ = ["ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101",
-           "ResNet152", "TransformerConfig", "TransformerLM", "gpt_small",
+           "ResNet152", "HybridConfig", "HybridLM", "TransformerConfig",
+           "TransformerLM", "gpt_small",
            "gpt_medium", "gpt_tiny", "VGG", "VGG16", "VGG19",
            "InceptionV3"]

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
+from .family import ModelFamily
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -94,6 +96,11 @@ class TransformerConfig:
     @property
     def ff_dim(self) -> int:
         return self.d_ff if self.d_ff is not None else 4 * self.d_model
+
+    @property
+    def family(self):
+        """What the serving replica asks of a model (models/family.py)."""
+        return FAMILY
 
 
 # ---------------------------------------------------------------------------
@@ -481,6 +488,15 @@ def prefill(model: TransformerLM, variables: dict, tokens: jax.Array,
     return logits, cache
 
 
+def fresh_cache(model: TransformerLM, params, slots: int) -> dict:
+    """A dense slot cache of ``slots`` empty rows: one apply creates the
+    cache collection (its only writes land at position 0), and the write
+    cursors go back to 0."""
+    _, mut = model.apply({"params": params},
+                         jnp.zeros((slots, 1), jnp.int32), mutable=["cache"])
+    return _with_cache_index(mut["cache"], 0)
+
+
 def decode_step(model: TransformerLM, variables: dict, cache: dict,
                 tokens: jax.Array) -> tuple[jax.Array, dict]:
     """One incremental step of a ``decode=True`` model: ``tokens``
@@ -528,6 +544,16 @@ def paged_copy_block(cache: dict, src: int, dst: int) -> dict:
                 for key, val in node.items()}
     from flax.core import unfreeze
     return fix(unfreeze(cache))
+
+
+def _decode_flops(cfg: TransformerConfig, context: float) -> float:
+    from ..telemetry import perfmodel
+    return perfmodel.transformer_decode_flops(cfg, context)
+
+
+FAMILY = ModelFamily(name="transformer", build=TransformerLM,
+                     fresh_cache=fresh_cache, prefill=prefill,
+                     decode_step=decode_step, decode_flops=_decode_flops)
 
 
 # ---------------------------------------------------------------------------

@@ -82,7 +82,11 @@ class ServeConfig:
     queue_depth: int = 1024
     eos_id: int = -1                   # -1 disables EOS stopping
     seed: int = 0
-    model_cfg: object | None = None    # TransformerConfig; None = tiny LM
+    # The model's configuration object; None = a tiny TransformerLM.
+    # Its ``family`` (models/family.py) says how to build the model, a
+    # fresh cache, prefill and a decode step: TransformerConfig or
+    # HybridConfig today.
+    model_cfg: object | None = None
     # Paged KV cache (ISSUE 14): blocks of block_tokens from a
     # pool_blocks pool; 0 = auto (max_batch x ceil(max_seq/bt), the
     # dense layout's token memory).  paged_slots (0 = auto: 2 x
@@ -178,12 +182,19 @@ class ReplicaExecutor:
         self._configure_groups()
 
         model_cfg = _decode_model_cfg(self.cfg)
+        self.family = model_cfg.family
         if self.cfg.paged:
+            if self.family.paged_missing:
+                raise ValueError(
+                    f"ServeConfig.paged=True (and with it prefix reuse, "
+                    f"disaggregated prefill and kvstream) cannot serve a "
+                    f"{self.family.name} configuration yet; missing: "
+                    f"{self.family.paged_missing}")
             model_cfg = dataclasses.replace(
                 model_cfg, paged=True,
                 kv_pool_blocks=self.cfg.resolved_pool_blocks,
                 kv_block_tokens=self.cfg.block_tokens)
-        self.model = tfm.TransformerLM(model_cfg)
+        self.model = self.family.build(model_cfg)
         if params is None:
             # Seeded, deterministic: every replica materializes identical
             # weights without a broadcast (replace with a checkpoint
@@ -210,8 +221,11 @@ class ReplicaExecutor:
                       "prefill_streams": 0, "prefill_fallbacks": 0,
                       "prefill_skipped": 0, "weight_swaps": [],
                       # The slot cache, and how much of it the compiled
-                      # decode program updates in place (set by warm-up).
+                      # decode program updates in place (set by warm-up);
+                      # cache_bytes = kv_bytes (keys, values, cursors) +
+                      # state_bytes (a family's recurrent state).
                       "cache_bytes": 0, "cache_aliased_bytes": 0,
+                      "kv_bytes": 0, "state_bytes": 0,
                       # Always-on part timers of the serve step
                       # (telemetry/spans.py), by kind of step: "admit"
                       # steps prefilled at least one request here,
@@ -337,20 +351,22 @@ class ReplicaExecutor:
 
     # -- model plumbing --------------------------------------------------
     def _decode_impl(self, params, cache, tokens):
-        logits, cache = tfm.decode_step(self.model, {"params": params},
-                                        cache, tokens)
+        logits, cache = self.family.decode_step(
+            self.model, {"params": params}, cache, tokens)
         return _sample(logits[:, -1, :]), cache
 
     def _prefill_impl(self, params, tokens, n):
-        logits, cache = tfm.prefill(self.model, {"params": params},
-                                    tokens, lengths=n)
+        logits, cache = self.family.prefill(
+            self.model, {"params": params}, tokens, lengths=n)
         return _sample(logits[0, n - 1, :]), cache
 
     @staticmethod
     def _insert_impl(cache, cache1, slot):
         """Row ``slot`` of every leaf of the slot cache becomes the
-        prefilled request's only row (keys, values and write cursor
-        alike); ``slot`` is traced, so all slots share one program."""
+        prefilled request's only row (keys, values, write cursor and a
+        family's recurrent state alike, so nothing of the slot's last
+        occupant is left); ``slot`` is traced, so all slots share one
+        program."""
         return jax.tree_util.tree_map(
             lambda big, small: jax.lax.dynamic_update_slice_in_dim(
                 big, small, slot, axis=0), cache, cache1)
@@ -378,8 +394,9 @@ class ReplicaExecutor:
         self._cache = self._init_cache_jit(self.params)
 
     def _init_cache_impl(self, params):
-        """A fresh KV cache: one apply creates the cache collection (its
-        only writes land at position 0, or in the paged sink row)."""
+        """A fresh cache: the paged pools (one apply creates them, its
+        only writes land in the sink row) or the family's dense slot
+        cache."""
         from flax.core import unfreeze
         if self.cfg.paged:
             _, mut = self.model.apply(
@@ -389,10 +406,7 @@ class ReplicaExecutor:
                 cursors=jnp.zeros((1,), jnp.int32),
                 mutable=["cache"])
             return unfreeze(mut["cache"])
-        _, mut = self.model.apply(
-            {"params": params}, jnp.zeros((self.cfg.slots, 1), jnp.int32),
-            mutable=["cache"])
-        return tfm._with_cache_index(mut["cache"], 0)
+        return self.family.fresh_cache(self.model, params, self.cfg.slots)
 
     def _warmup(self) -> None:
         """Compile every program the serve loop runs.  Each of them takes
@@ -436,8 +450,13 @@ class ReplicaExecutor:
         """How much of the cache the compiled decode program updates in
         place: the cache is its only donated argument, so what it
         aliases from input to output is cache."""
-        self.stats["cache_bytes"] = sum(
-            leaf.nbytes for leaf in jax.tree_util.tree_leaves(self._cache))
+        leaves = jax.tree_util.tree_flatten_with_path(self._cache)[0]
+        self.stats["cache_bytes"] = sum(leaf.nbytes for _, leaf in leaves)
+        self.stats["state_bytes"] = sum(
+            leaf.nbytes for path, leaf in leaves
+            if path[-1].key in self.family.state_leaves)
+        self.stats["kv_bytes"] = \
+            self.stats["cache_bytes"] - self.stats["state_bytes"]
         self.stats["cache_aliased_bytes"] = \
             decode_program.memory_analysis().alias_size_in_bytes
         logger.info("serving: slot cache %.2f of %.2f GB aliased by the "
@@ -525,7 +544,7 @@ class ReplicaExecutor:
                                            np.int32(slot))
         with span("serve.first_token_fetch"):
             first = int(first)         # waits for the device
-        self._activate_slot(slot, a, now, first)
+        self._activate_slot(slot, a, now, first, seq_len=len(toks))
 
     def _activate_slot(self, slot: int, a: Assignment, now: float,
                        first: int, blocks: list | None = None,
@@ -1110,8 +1129,8 @@ class ReplicaExecutor:
         # per-step rate whipsaws with batch occupancy.
         self._perf_tps = tps if self._perf_tps <= 0.0 \
             else 0.8 * self._perf_tps + 0.2 * tps
-        flops_per_token = perfmodel.transformer_decode_flops(
-            self.model.cfg, ctx_sum / tokens)
+        flops_per_token = self.family.decode_flops(self.model.cfg,
+                                                   ctx_sum / tokens)
         tm.gauge("horovod_serve_tokens_per_sec").set(self._perf_tps)
         tm.gauge("horovod_serve_flops_per_token").set(flops_per_token)
         # A device kind without a known peak gets no MFU gauge.
@@ -1411,8 +1430,8 @@ def serving_params_template(cfg: ServeConfig) -> dict:
     replaced by the streamed image)."""
     import horovod_tpu  # noqa: F401 - jax config side effects
 
-    params = _seeded_params(tfm.TransformerLM(_decode_model_cfg(cfg)),
-                            cfg.seed)
+    model_cfg = _decode_model_cfg(cfg)
+    params = _seeded_params(model_cfg.family.build(model_cfg), cfg.seed)
     return {"params": jax.tree_util.tree_map(np.asarray, params)}
 
 

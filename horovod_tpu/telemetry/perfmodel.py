@@ -16,7 +16,8 @@ models/.  Three layers share it:
   self-calibrated to the best cell in the window) discounted by each
   algorithm's wire-byte overhead versus the bandwidth-optimal ring;
 - **MFU accounting**: analytic FLOPs for TransformerLM (train and
-  paged/dense decode) and the conv models, against the per-chip peak
+  paged/dense decode), HybridLM (decode) and the conv models, against
+  the per-chip peak
   (arXiv:1909.09756 attributes MLPerf scaling exactly this way).
 
 Reference formulas (S = payload bytes, N = ranks):
@@ -234,6 +235,23 @@ def transformer_decode_flops(cfg: Any, context_len: float,
     for the dense and paged KV layouts, which move the same bytes)."""
     p = n_params if n_params else transformer_param_count(cfg)
     return 2.0 * p + 4.0 * cfg.num_layers * cfg.d_model * context_len
+
+
+def hybrid_decode_flops(cfg: Any, context_len: float) -> float:
+    """FLOPs of ONE generated token of a HybridLM config (models/
+    hybrid.py) at context ``context_len``: 2 a matmul weight (the tied
+    head once), the state update's 6*H*P*N a Mamba layer, and scores and
+    values over the context in the attention layers alone."""
+    d, ff = cfg.d_model, cfg.ff_dim
+    mamba = sum(kind == "mamba" for kind in cfg.layer_types)
+    attn = len(cfg.layer_types) - mamba
+    kv_width = cfg.num_kv_heads * cfg.head_dim
+    weights = (mamba * (d * (2 * cfg.d_inner + 2 * cfg.mamba_state
+                             + cfg.mamba_heads) + cfg.d_inner * d)
+               + attn * (2 * d * d + 2 * d * kv_width)
+               + len(cfg.layer_types) * 3 * d * ff + d * cfg.vocab_size)
+    update = 6.0 * cfg.mamba_heads * cfg.mamba_head_dim * cfg.mamba_state
+    return 2.0 * weights + mamba * update + 4.0 * attn * d * context_len
 
 
 # ---------------------------------------------------------------------------

@@ -176,6 +176,29 @@ def test_flash_odd_length_compiles_for_v5e(v5e, monkeypatch):
     assert text.count(MOSAIC) == 3
 
 
+def test_ssm_update_compiles_for_v5e_at_published_shapes(v5e):
+    """hvd.ssm_update at granite-4.0-h-micro's decode shapes (32 slots,
+    64 heads of 64, state 128: a stored state of [32, 32, 128, 128]
+    float32, blocks of 16 rows) lowers through Mosaic, fits the v5e's
+    scoped VMEM, and writes the donated state in place."""
+    from horovod_tpu.ops import ssm
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)
+
+    slots, heads, p, n = 32, 64, 64, 128
+    assert ssm.state_shape(slots, heads, p, n) == (32, 32, 128, 128)
+    update = jax.jit(lambda *operands: ssm._ssm_update_pallas(
+        *operands, block_groups=16, interpret=False), donate_argnums=0)
+    compiled = update.lower(
+        shaped(*ssm.state_shape(slots, heads, p, n)), shaped(slots, heads, p),
+        shaped(slots, heads), shaped(heads), shaped(slots, n),
+        shaped(slots, n), shaped(heads)).compile()
+    assert compiled.as_text().count(MOSAIC) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == slots * heads * p * n * 4
+
+
 def test_fit_block_follows_the_tpu_tiling_rule():
     assert fa._fit_block(2048, 1024) == 1024
     assert fa._fit_block(2000, 128) == 80       # not 125
