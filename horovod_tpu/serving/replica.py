@@ -9,7 +9,11 @@ Execution model (ISSUE 9 tentpole, extended by ISSUE 14):
   (``hvd.broadcast_object`` — a real negotiated collective on the data
   plane).  Because every rank executes the identical plan sequence,
   replicas can never diverge on a collective: the broadcast IS the
-  schedule.
+  schedule.  A replica that is **alone in its world** (``self.size ==
+  1``, read at every step) has nobody to send it to: the plan and the
+  completions stay in the process, and the step runs no eager
+  collective until a grow makes the world two ranks
+  (``stats["local_exchanges"]`` of ``stats["exchanges"]``).
 - Each **replica group** (``HOROVOD_SERVE_GROUP_SIZE`` ranks; 1 = pure
   data-parallel) prefills newly assigned requests into free KV-cache
   slots and advances every in-flight slot by one greedy token per step
@@ -231,6 +235,9 @@ class ReplicaExecutor:
                       # steps prefilled at least one request here,
                       # "decode" steps none.
                       "steps": {"admit": 0, "decode": 0},
+                      # Plan and completion exchanges begun, and those of
+                      # them that stayed in the process (world size 1).
+                      "exchanges": 0, "local_exchanges": 0,
                       "step_parts_s": {"admit": {}, "decode": {}},
                       "slow_steps": [], "slow_steps_total": 0}
         # Host seconds of the last steps of each kind: a step slower
@@ -493,6 +500,13 @@ class ReplicaExecutor:
         return plan
 
     def _exchange_plan(self, plan: BatchPlan | None) -> BatchPlan:
+        self.stats["exchanges"] += 1
+        if self.size == 1:
+            # Alone, this rank is the front and the plan is its own: the
+            # broadcast would hand the root this same object back.  The
+            # size is the one shrink and grow maintain, read every step.
+            self.stats["local_exchanges"] += 1
+            return plan
         from ..resilience import deadline_scope
         deadlines = [s.deadline for s in self.slots if s is not None]
         with deadline_scope(min(deadlines) if deadlines else None):
@@ -883,17 +897,24 @@ class ReplicaExecutor:
         self.slots[i] = None
 
     def _exchange_completions(self) -> list[dict]:
-        from ..resilience import deadline_scope
         # Completions plus this rank's staged weight versions ride one
         # allgather: the front learns the version set every rank holds
         # with zero extra collectives, exactly like completions ride
         # the step.
         mine = {"done": list(self._unreported),
                 "staged": self._fleet_staged_versions()}
-        deadlines = [s.deadline for s in self.slots if s is not None]
-        with deadline_scope(min(deadlines) if deadlines else None):
-            per_rank = self.hvd.allgather_object(
-                mine, name=f"serve.done.g{self._gen}.{self._step}")
+        self.stats["exchanges"] += 1
+        if self.size == 1:
+            # Alone, the gathered list is this rank's own entry: no
+            # pickle, no wait on a peer and so no deadline to bound it.
+            self.stats["local_exchanges"] += 1
+            per_rank = [mine]
+        else:
+            from ..resilience import deadline_scope
+            deadlines = [s.deadline for s in self.slots if s is not None]
+            with deadline_scope(min(deadlines) if deadlines else None):
+                per_rank = self.hvd.allgather_object(
+                    mine, name=f"serve.done.g{self._gen}.{self._step}")
         self._unreported.clear()       # acknowledged by the exchange
         common = set.intersection(
             *(set(p.get("staged") or ()) for p in per_rank))

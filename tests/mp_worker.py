@@ -2240,6 +2240,9 @@ def battery_serving_paged(hvd, rank, size):
     missing = ex.prefilled - set(ex.completed)
     assert not missing, \
         f"survivor {rank} failed admitted in-flight requests: {missing}"
+    # Four ranks, then three: no exchange stayed in the process.
+    assert ex.stats["exchanges"] > 0 \
+        and ex.stats["local_exchanges"] == 0, ex.stats
     kv = ex.kv_stats()
     assert kv["active"] == 0, f"rank {rank} leaked KV blocks: {kv}"
     print(f"serving_paged: rank {rank} kv census clean "
@@ -2295,6 +2298,10 @@ def battery_serving_disagg(hvd, rank, size):
 
     ex.serve_loop(stop_when=lambda: True)
 
+    # Two ranks broadcast every plan and gather every completion list:
+    # only a replica alone in its world keeps them in the process.
+    assert ex.stats["exchanges"] > 0 \
+        and ex.stats["local_exchanges"] == 0, ex.stats
     if rank == 0:
         st = ex.stats
         kv = ex.kv_stats()
@@ -2801,6 +2808,7 @@ def battery_statesync_serve(hvd, rank, size):
     if ex.rank == ex.front:
         _serve_grow_submit(ex, 13, 12)
     ex.serve_loop(stop_when=lambda: True)
+    assert ex.stats["local_exchanges"] == 0, ex.stats   # 2, then 3 ranks
     if rank == 0:
         st = ex.stats
         assert st["served"] == st["offered"] == 36, st
@@ -3081,6 +3089,9 @@ def _battery_fleet_serve(port):
     assert ex.size == 2 and st["grows"], (ex.size, st["grows"])
     g = st["grows"][0]
     assert g["from"] == 1 and g["to"] == 2, g
+    # Alone until the grow: those steps' exchanges stayed in the process,
+    # every later one went through the collectives.
+    assert 0 < st["local_exchanges"] < st["exchanges"], st
     assert st["offered"] >= 36, st
     assert st["served"] == st["offered"], st
     assert st["lost"] == 0 and st["expired"] == 0, st

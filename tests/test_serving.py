@@ -706,6 +706,7 @@ def test_a_slow_step_leaves_one_record_that_names_its_part(monkeypatch):
     from horovod_tpu.telemetry import flight
 
     hvd, ex = _toy_executor(requests=2, max_new=40)
+    began = time.monotonic()
     plan, done = ex._exchange_plan, ex._exchange_completions
 
     def slow_plan(p):
@@ -737,7 +738,11 @@ def test_a_slow_step_leaves_one_record_that_names_its_part(monkeypatch):
                 sum(record["parts_ms"].values()), abs=0.01)
         assert (first["gc2"], second["gc2"]) == (False, True)
         assert ex.stats["slow_steps_total"] == 2
-        kinds = [e["kind"] for e in flight.recorder().snapshot()]
+        # This run's: a lone replica's steps no longer push four eager
+        # operations each through the process's ring, so the records of
+        # earlier tests are still in it.
+        kinds = [e["kind"] for e in flight.recorder().snapshot()
+                 if e["ts"] >= began]
         assert kinds.count("serve_slow_step") == 2
 
         # Bounded: 50 more slow steps (one in three, so that the median
@@ -965,6 +970,276 @@ def test_warmup_leaves_a_live_fresh_cache_and_init_cache_works_again(paged):
                 elif not paged:
                     assert not np.asarray(got)[:, 1:].any()
             ex._init_cache()
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+# --- a replica alone in its world exchanges in the process (ISSUE 31) -------
+_LONE_PROMPTS = ([5, 9, 200, 31, 77], [3, 18, 64, 120, 7, 11, 250, 2, 90],
+                 [44, 45, 46], [5, 9, 200, 31, 77])
+# The plain numbers of executor.stats that two runs of one request table
+# share whatever the host's speed (the rest are times).
+_PLAIN_STATS = ("offered", "expired", "served", "served_slo", "lost",
+                "steps", "exchanges", "prefill_streams",
+                "prefill_fallbacks", "prefill_skipped", "shrinks", "grows",
+                "weight_swaps")
+
+
+def _patch_object_collectives(patched, hvd, allowed=False):
+    """Patch hvd.broadcast_object and hvd.allgather_object: to raise, or
+    where ``allowed`` to run and leave their names in the list returned."""
+    calls = []
+
+    def patch(name):
+        real = getattr(hvd, name)
+
+        def call(*args, **kwargs):
+            if not allowed:
+                raise AssertionError(f"hvd.{name} in the step of a replica "
+                                     "that is alone in its world")
+            calls.append(name)
+            return real(*args, **kwargs)
+        patched.setattr(hvd, name, call)
+    for name in ("broadcast_object", "allgather_object"):
+        patch(name)
+    return calls
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_a_lone_replica_serves_what_the_collectives_serve(monkeypatch, paged):
+    """One request table twice on a world of one: with both object
+    collectives patched to raise, and with the size the executor reads
+    set to 2, which sends the same steps through the real broadcast and
+    gather.  Same tokens, completions and plain stats; each is what a
+    loop over prefill and decode_step alone generates."""
+    seen = {}
+    for collectives in (False, True):
+        hvd, ex = _executor(paged)
+        try:
+            if collectives:
+                ex.size = 2            # what a grow leaves; hvd.size() is 1
+            with monkeypatch.context() as patched:
+                calls = _patch_object_collectives(patched, hvd, collectives)
+                _submit(ex, _LONE_PROMPTS, 9)
+                ex.serve_loop(stop_when=lambda: True)
+            steps = sum(ex.stats["steps"].values())
+            # A plan and a completion exchange a step, and the stop plan.
+            assert ex.stats["exchanges"] == 2 * steps + 1
+            if collectives:
+                assert ex.stats["local_exchanges"] == 0
+                assert calls.count("broadcast_object") == steps + 1
+                assert calls.count("allgather_object") == steps
+            else:
+                assert calls == []
+                assert ex.stats["local_exchanges"] == ex.stats["exchanges"]
+            assert ex.batcher.inflight == {} and ex._unreported == []
+            seen[collectives] = {
+                "generated": {rid: rec["generated"]
+                              for rid, rec in ex.completed.items()},
+                "completions": {rid: (rec["replica"], rec["tokens"],
+                                      rec["weights"])
+                                for rid, rec in ex.completed.items()},
+                "stats": {key: ex.stats[key] for key in _PLAIN_STATS},
+                "next_step": ex._step}
+            if not collectives:
+                reference = _reference(ex)
+                for rid, prompt in zip(sorted(ex.completed), _LONE_PROMPTS):
+                    assert ex.completed[rid]["generated"] \
+                        == reference(prompt, 9), (rid, prompt)
+        finally:
+            ex.close()
+            hvd.shutdown()
+    assert seen[False] == seen[True]
+    assert seen[False]["stats"]["served"] == len(_LONE_PROMPTS)
+
+
+@pytest.mark.parametrize("size", [1, 2], ids=["alone", "two_ranks"])
+@pytest.mark.parametrize("fails", [False, True], ids=["handed_over", "failed"])
+def test_unreported_completions_are_cleared_only_after_the_hand_over(
+        monkeypatch, size, fails):
+    """_exchange_completions returns the records that were waiting and
+    clears _unreported once they are handed over; an exchange that fails
+    before that (the gather on two ranks, the staged-versions read alone)
+    leaves them for the re-send."""
+    from horovod_tpu.common.exceptions import RanksFailedError
+
+    hvd, ex = _toy_executor(requests=0)
+    try:
+        ex.size = size
+        waiting = [{"rid": 7, "tokens": 3}, {"rid": 9, "tokens": 5}]
+        ex._unreported.extend(waiting)
+
+        def failing(*args, **kwargs):
+            assert ex._unreported == waiting       # not cleared before
+            raise RanksFailedError([1], "gone")
+        if fails and size == 1:
+            monkeypatch.setattr(ex, "_fleet_staged_versions", failing)
+        elif fails:
+            monkeypatch.setattr(hvd, "allgather_object", failing)
+        if fails:
+            with pytest.raises(RanksFailedError):
+                ex._exchange_completions()
+            assert ex._unreported == waiting
+        else:
+            done = ex._exchange_completions()
+            assert done == waiting and done is not ex._unreported
+            assert ex._unreported == []
+            assert ex.stats["exchanges"] == 1
+            assert ex.stats["local_exchanges"] == (size == 1)
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+def test_a_lone_fleet_replica_swaps_the_version_it_staged(monkeypatch):
+    """The version a lone replica staged is reported by the exchange in
+    the process, becomes _fleet_common, is scheduled into the next plan
+    and swapped in at that boundary, once; requests in flight go on
+    under the new weights."""
+    import jax
+    import numpy as np
+
+    hvd, ex = _toy_executor(requests=2, max_new=12)
+    try:
+        _patch_object_collectives(monkeypatch, hvd)
+        assert ex._serve_step() and ex._fleet_common == 0
+        new = jax.tree_util.tree_map(lambda x: np.asarray(x) * 0.5,
+                                     ex.params)
+        with ex._fleet_lock:
+            ex._fleet_staged[3] = (new, 30, 1234)
+        assert ex._serve_step()            # its exchange reports {3}
+        assert ex._fleet_common == 3 and ex.weight_version == 0
+        assert ex._fleet_reported == {3}
+        assert ex._serve_step()            # the plan schedules the swap
+        assert ex.weight_version == 3 and ex._fleet_scheduled == 3
+        assert [(s["version"], s["step"], s["digest"])
+                for s in ex.stats["weight_swaps"]] == [(3, 3, 1234)]
+        assert ex._fleet_staged == {} and ex._fleet_common == 0
+        for got, want in zip(jax.tree_util.tree_leaves(ex.params),
+                             jax.tree_util.tree_leaves(new)):
+            np.testing.assert_array_equal(np.asarray(got), want)
+        ex.serve_loop(stop_when=lambda: True)
+        assert ex.stats["served"] == 2
+        assert len(ex.stats["weight_swaps"]) == 1
+        assert {rec["weights"] for rec in ex.completed.values()} == {3}
+        assert ex.stats["local_exchanges"] == ex.stats["exchanges"] > 0
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+class _TwoRankHvd:
+    """Stands in for the ``hvd`` module an executor holds: records the
+    object collectives asked of it and answers as a world with one more,
+    idle, rank would."""
+
+    def __init__(self):
+        self.calls = []
+
+    def broadcast_object(self, obj, root_rank=0, name=None):
+        self.calls.append(("broadcast_object", name))
+        return obj
+
+    def allgather_object(self, obj, name=None):
+        self.calls.append(("allgather_object", name))
+        idle = {"done": [], "staged": (), "rids": [],
+                "step": obj.get("step", 0), "gen": obj.get("gen", 0)}
+        return [obj, idle]
+
+
+class _GrowAtThirdBoundary:
+    """A statesync service that admits a joiner at its third boundary."""
+    boundaries = 0
+    grow_windows = [(1.0, 1.5)]
+
+    def step_boundary(self):
+        import types
+        self.boundaries += 1
+        if self.boundaries == 3:
+            return types.SimpleNamespace(kind="grow", join_id=7, rank=0,
+                                         size=2)
+        return None
+
+
+def _grown_by_statesync(ex):
+    """1 -> 2 as a grow reaches a rank: the boundary check at the end of
+    the third step calls _grow_resync."""
+    ex.attach_statesync(_GrowAtThirdBoundary())
+    for _ in range(3):
+        assert ex._serve_step()
+    assert ex.statesync.boundaries == 3
+    assert ex.stats["grows"][0]["from"] == 1 \
+        and ex.stats["grows"][0]["to"] == 2
+    return [("allgather_object", "serve.growsync.7")]
+
+
+def _shrunk_then_regrown(ex):
+    """2 -> 1 -> 2 by the value _shrink_and_resume maintains: two steps
+    on two ranks, three alone, then back."""
+    ex.size = 2
+    assert ex._serve_step() and ex._serve_step()
+    ex.size = 1                        # as a shrink to one survivor leaves
+    for _ in range(3):
+        assert ex._serve_step()
+    ex.size = 2
+    return [("broadcast_object", "serve.plan.g0.0"),
+            ("allgather_object", "serve.done.g0.1"),
+            ("broadcast_object", "serve.plan.g0.1"),
+            ("allgather_object", "serve.done.g0.2")]
+
+
+@pytest.mark.parametrize("change", [_grown_by_statesync,
+                                    _shrunk_then_regrown],
+                         ids=["statesync_grow", "shrink_then_grow"])
+def test_the_exchange_follows_the_size_shrink_and_grow_maintain(change):
+    """The size is read at the step: the steps a world of one runs ask
+    nothing of hvd, and the first step after the world has two ranks
+    broadcasts its plan and gathers its completions under the names every
+    larger world uses; local_exchanges counts only the former."""
+    hvd, ex = _toy_executor(requests=2, max_new=12)
+    fake = _TwoRankHvd()
+    ex.hvd = fake
+    try:
+        before = change(ex)
+        assert fake.calls == before
+        local = ex.stats["local_exchanges"]
+        assert local == 6          # three steps alone, either way
+        assert ex.stats["exchanges"] - local == sum(
+            name.startswith(("serve.plan.", "serve.done."))
+            for _, name in before)
+        assert ex.size == 2 and sorted(ex.batcher.inflight) == [0, 1]
+        step, gen = ex._step, ex._gen
+        assert ex._serve_step()
+        assert fake.calls[len(before):] == [
+            ("broadcast_object", f"serve.plan.g{gen}.{step}"),
+            ("allgather_object", f"serve.done.g{gen}.{step + 1}")]
+        ex.serve_loop(stop_when=lambda: True)
+        assert ex.stats["served"] == 2 and ex.stats["lost"] == 0
+        assert ex.stats["local_exchanges"] == local
+        assert all(len(rec["generated"]) == 12
+                   for rec in ex.completed.values())
+    finally:
+        ex.hvd = hvd
+        ex.close()
+        hvd.shutdown()
+
+
+def test_a_stop_plan_ends_a_lone_replicas_loop(monkeypatch):
+    """A drained front's stop plan comes back from the exchange in the
+    process as from the broadcast: the step returns False and advances
+    the step number, nothing is gathered or accounted."""
+    hvd, ex = _toy_executor(requests=0)
+    try:
+        _patch_object_collectives(monkeypatch, hvd)
+        ex.request_stop()
+        assert ex._serve_step() is False
+        assert (ex._step, ex._gen) == (1, 0)
+        assert (ex.stats["exchanges"], ex.stats["local_exchanges"]) == (1, 1)
+        assert ex.stats["steps"] == {"admit": 0, "decode": 0}
+        _submit(ex, [[7, 8, 9]], 3)    # in flight: served before the stop
+        ex.serve_loop(stop_when=lambda: True)
+        assert ex.stats["served"] == 1 and ex.batcher.inflight == {}
     finally:
         ex.close()
         hvd.shutdown()
