@@ -110,7 +110,7 @@ class Smoke:
             self.seq = 2048
             self.gpt_batch = 8
             # 1024/1024: the largest 128-aligned pair that fits the 16 MiB
-            # scoped VMEM at these shapes (bench.py's default).
+            # scoped VMEM at these shapes.
             self.gpt_cfg = dict(block_q=1024, block_k=1024)
             self.gpt_preset = models.gpt_small
             self.resnet = models.ResNet50(num_classes=1000)

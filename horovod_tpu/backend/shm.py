@@ -83,8 +83,8 @@ def _boot_fingerprint() -> str:
     deliberately: it never splits ranks that could otherwise share
     /dev/shm in practice (container setups split mnt/ipc too), and it
     makes a network-namespace boundary behave exactly like a host
-    boundary — which is what netns-based cross-host emulation
-    (benchmarks/shaped_link.py) relies on."""
+    boundary — which is what netns-based cross-host emulation relies
+    on."""
     parts = []
     try:
         with open("/proc/sys/kernel/random/boot_id") as f:

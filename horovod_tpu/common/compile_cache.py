@@ -1,8 +1,8 @@
 """Where the persistent XLA compile cache lives.
 
 One rule for every entry point that compiles a model (chip_smoke.py,
-bench.py, the SPMD example, the serving load harness, multi-process
-world formation): the caller places the cache with
+the SPMD example, the serving load harness, multi-process world
+formation): the caller places the cache with
 ``JAX_COMPILATION_CACHE_DIR``; when that is unset the cache is
 ``<checkout>/.jax_cache``.  The default is a fixed path derived from the
 package's own location, never a temporary directory, a pid or a clock:

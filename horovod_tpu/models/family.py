@@ -6,11 +6,12 @@ through its ``family`` property, a ``ModelFamily``: how to build the
 model, a fresh slot cache, ``prefill`` and ``decode_step``, and what one
 generated token costs.  Besides that the replica needs two fields of the
 configuration itself, ``decode`` and ``max_seq_len`` (it sets both with
-``dataclasses.replace``).
+``dataclasses.replace``).  ``models/kvcache.py`` has the entry points
+and the attention over the cache: a new family writes no cache code.
 
-Every leaf of a family's cache has the slot on axis 0: the replica
-inserts a prefilled request as row ``slot`` of every leaf, and donates
-the whole tree to the decode program.
+Every leaf of a family's cache has the slot on axis 0: the replica's
+slot cache (``serving/slotcache.py``) inserts a prefilled request as row
+``slot`` of every leaf, and donates the whole tree to the decode program.
 
 ``models/transformer.py`` (``TransformerLM``) is the first family,
 ``models/hybrid.py`` (``HybridLM``) the second.
@@ -38,5 +39,9 @@ class ModelFamily:
     # slot, live until replaced) and not keys and values.
     state_leaves: tuple = ()
     # Why the paged cache (serving/kvpool.py) cannot hold this family
-    # yet; empty where it can.
+    # yet; empty where it can, and then the three below are given
+    # (models/kvcache.py says what each takes and returns).
     paged_missing: str = ""
+    paged_apply: Callable[..., tuple] | None = None
+    paged_copy_block: Callable[..., dict] | None = None
+    paged_pool_leaves: Callable[[dict], list] | None = None

@@ -17,8 +17,8 @@ switch there:
 
 RMSNorm and the gated MLP are ``transformer.py``'s.
 
-Serving (``decode=True``): the cache collection keeps ``cached_key`` /
-``cached_value`` / ``cache_index`` for the attention layers and gains,
+Serving (``decode=True``): the cache collection keeps the attention
+layers' keys, values and write cursors (``models/kvcache.py``) and gains,
 for each Mamba layer, ``conv_state`` [B, conv - 1, channels] (the last
 inputs of the convolution, in the activations' type) and ``ssm_state``
 (the H state matrices of P x N, **float32**: the recurrence sums over
@@ -43,7 +43,9 @@ from flax import linen as nn
 
 from ..ops import ssm
 from .family import ModelFamily
-from .transformer import MLP, RMSNorm, _with_cache_index
+from .kvcache import (attend, cached_attention, decode_step, fresh_cache,
+                      prefill)
+from .transformer import MLP, RMSNorm
 
 STATE_LEAVES = ("conv_state", "ssm_state")
 
@@ -116,47 +118,20 @@ class GroupedAttention(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         cfg = self.cfg
-        b, t, _ = x.shape
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype)
-        kv, group, d = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, \
-            cfg.head_dim
+        kv, d = cfg.num_kv_heads, cfg.head_dim
         q = dense(features=(cfg.num_heads, d), name="wq")(x)
         k = dense(features=(kv, d), name="wk")(x)
         v = dense(features=(kv, d), name="wv")(x)
-        positions = jnp.arange(t)[None, :]                     # [1, T]
         if cfg.decode and not self.is_initializing():
-            # Through the cache, as transformer.Attention._decode_attend:
-            # write at each row's own depth, attend over the prefix.
-            s = cfg.max_seq_len
-            cached_k = self.variable("cache", "cached_key", jnp.zeros,
-                                     (b, s, kv, d), cfg.dtype)
-            cached_v = self.variable("cache", "cached_value", jnp.zeros,
-                                     (b, s, kv, d), cfg.dtype)
-            index = self.variable("cache", "cache_index",
-                                  lambda: jnp.zeros((b,), jnp.int32))
-            positions = index.value[:, None] + positions       # [B, T]
-            write = jax.vmap(lambda cache, new, i:
-                             jax.lax.dynamic_update_slice(cache, new,
-                                                          (i, 0, 0)))
-            cached_k.value = write(cached_k.value, k.astype(cfg.dtype),
-                                   index.value)
-            cached_v.value = write(cached_v.value, v.astype(cfg.dtype),
-                                   index.value)
-            index.value = index.value + t
-            k, v = cached_k.value, cached_v.value
-        with jax.named_scope("hvd.decode_attend"):
-            mask = jnp.arange(k.shape[1])[None, None, :] \
-                <= positions[:, :, None]                       # [B|1, T, S]
-            qf = q.astype(jnp.float32).reshape(b, t, kv, group, d)
-            scores = jnp.einsum("bqkgd,bskd->bkgqs", qf,
-                                k.astype(jnp.float32)) \
-                * cfg.attention_multiplier
-            scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
-            out = jnp.einsum("bkgqs,bskd->bqkgd",
-                             jax.nn.softmax(scores, axis=-1),
-                             v.astype(jnp.float32))
-        out = out.reshape(b, t, cfg.num_heads, d).astype(cfg.dtype)
+            out = cached_attention(
+                self, q, k, v, max_seq_len=cfg.max_seq_len,
+                dtype=cfg.dtype, scale=cfg.attention_multiplier)
+        else:
+            out = attend(q, k, v, jnp.arange(x.shape[1])[None, :],
+                         cfg.attention_multiplier)
+        out = out.astype(cfg.dtype)
         return dense(features=cfg.d_model, axis=(-2, -1), name="wo")(out)
 
 
@@ -282,48 +257,8 @@ class HybridLM(nn.Module):
         return embed.attend(x) / cfg.logits_scaling          # a tied head
 
 
-# ---------------------------------------------------------------------------
-# Serving: the three entry points of models/family.py
-# ---------------------------------------------------------------------------
-def prefill(model: HybridLM, variables: dict, tokens: jax.Array,
-            lengths=None) -> tuple[jax.Array, dict]:
-    """The prompt through a ``decode=True`` model: ``(logits [B, T,
-    vocab], cache)``.  With ``lengths`` the recurrent state and the
-    convolution window are those of each row's true length, and the
-    attention layers' write cursors rewind to it as in
-    ``transformer.prefill``."""
-    from flax.core import unfreeze
-    logits, mut = model.apply(variables, tokens, lengths=lengths,
-                              mutable=["cache"])
-    cache = unfreeze(mut["cache"])
-    if lengths is not None:
-        cache = _with_cache_index(cache, lengths)
-    return logits, cache
-
-
-def decode_step(model: HybridLM, variables: dict, cache: dict,
-                tokens: jax.Array) -> tuple[jax.Array, dict]:
-    """One token a row through the cache: ``(logits [B, 1, vocab],
-    updated cache)``."""
-    from flax.core import unfreeze
-    if tokens.ndim == 1:
-        tokens = tokens[:, None]
-    logits, mut = model.apply({**variables, "cache": cache}, tokens,
-                              mutable=["cache"])
-    return logits, unfreeze(mut["cache"])
-
-
-def fresh_cache(model: HybridLM, params, slots: int) -> dict:
-    """``slots`` empty rows: zeros in every leaf, recurrent state too."""
-    from flax.core import unfreeze
-    shapes = jax.eval_shape(
-        lambda p: model.apply({"params": p},
-                              jnp.zeros((slots, 1), jnp.int32),
-                              mutable=["cache"])[1]["cache"], params)
-    return unfreeze(jax.tree_util.tree_map(
-        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes))
-
-
+# Serving: the entry points of models/family.py are models/kvcache.py's;
+# its ``prefill`` hands ``lengths`` to the model (see Mamba2Mixer).
 def _decode_flops(cfg: HybridConfig, context: float) -> float:
     from ..telemetry import perfmodel
     return perfmodel.hybrid_decode_flops(cfg, context)

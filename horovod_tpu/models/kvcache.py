@@ -1,0 +1,218 @@
+"""The model's side of the serving cache: the leaves of the "cache"
+collection an attention layer keeps, the one attention over them, and
+the calls that run a ``decode=True`` model through them.
+
+Two layouts, chosen by the replica (``serving/slotcache.py``):
+
+- dense: ``cached_key`` / ``cached_value`` [B, max_seq_len, KV, D] and
+  ``cache_index`` [B], each row's write cursor (``cached_attention``,
+  ``prefill``, ``decode_step``);
+- paged: ``key_pool`` / ``value_pool`` [blocks + 1, block_tokens, KV, D]
+  shared by every row and addressed through block tables, the last row
+  a write sink for padded positions (``paged_attention``, ``paged_*``).
+
+Both write this call's keys and values, then ``attend``.  A family's
+attention layer (``transformer.Attention``, ``hybrid.GroupedAttention``)
+brings its projections, its positional encoding and its score scale,
+and writes no cache code of its own.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from flax.core import unfreeze
+
+
+def attend(q: jax.Array, keys: jax.Array, values: jax.Array, positions,
+           scale: float) -> jax.Array:
+    """``softmax(scale * q k^T + causal mask) v`` in float32: ``q`` [B, T,
+    H, D] at absolute ``positions`` [B|1, T] over ``keys`` / ``values``
+    [B, S, KV, D]; query head ``h`` reads key-value head ``h // (H //
+    KV)`` (a group of 1 is plain multi-head).  Key ``s`` is visible at
+    position ``p`` when ``s <= p``: right-padded prefill garbage and
+    unwritten positions sit past every live query."""
+    b, t, h, d = q.shape
+    kv = keys.shape[2]
+    with jax.named_scope("hvd.decode_attend"):
+        mask = jnp.arange(keys.shape[1])[None, None, :] \
+            <= positions[:, :, None]                           # [B|1, T, S]
+        qf = q.astype(jnp.float32).reshape(b, t, kv, h // kv, d)
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", qf,
+                            keys.astype(jnp.float32)) * scale
+        scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
+        out = jnp.einsum("bkgqs,bskd->bqkgd",
+                         jax.nn.softmax(scores, axis=-1),
+                         values.astype(jnp.float32))
+    return out.reshape(b, t, h, d)
+
+
+def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
+                     max_seq_len: int, dtype, scale: float,
+                     rotate=None) -> jax.Array:
+    """Incremental attention over ``module``'s dense cache: write this
+    call's K/V at each row's own depth, attend over the cached prefix.
+    ``rotate(x, positions)`` is the family's positional encoding, if it
+    has one; positions are absolute, so the math is the full forward's."""
+    b, t, kv, d = k.shape
+    shape = (b, max_seq_len, kv, d)
+    cached_k = module.variable("cache", "cached_key", jnp.zeros, shape,
+                               dtype)
+    cached_v = module.variable("cache", "cached_value", jnp.zeros, shape,
+                               dtype)
+    index = module.variable("cache", "cache_index",
+                            lambda: jnp.zeros((b,), jnp.int32))
+    idx = index.value                                       # [B]
+    positions = idx[:, None] + jnp.arange(t)[None, :]       # [B, T]
+    if rotate is not None:
+        q, k = rotate(q, positions), rotate(k, positions)
+    write = jax.vmap(lambda cache, new, i:
+                     jax.lax.dynamic_update_slice(cache, new, (i, 0, 0)))
+    cached_k.value = write(cached_k.value, k.astype(dtype), idx)
+    cached_v.value = write(cached_v.value, v.astype(dtype), idx)
+    index.value = idx + t
+    return attend(q, cached_k.value, cached_v.value, positions, scale)
+
+
+def paged_attention(module, q: jax.Array, k: jax.Array, v: jax.Array,
+                    block_tables, cursors, lengths, *, pool_blocks: int,
+                    block_tokens: int, dtype, scale: float,
+                    rotate=None) -> jax.Array:
+    """Incremental attention over the shared block pool (ISSUE 14):
+    this call's K/V scatter into pool rows addressed through each row's
+    block table, then the table gathers the sequence back as [B, M*bt,
+    KV, D] (logical position p of row b lives at pool[tables[b, p//bt],
+    p%bt]) for the dense layout's ``attend``.  ``lengths`` masks a
+    right-padded prefill: padded positions write to the pool's sink row,
+    never a real block, and their logits are garbage the caller ignores."""
+    b, t, kv, d = k.shape
+    bt, sink = block_tokens, pool_blocks         # the sink: the last row
+    key_pool = module.variable("cache", "key_pool", jnp.zeros,
+                               (sink + 1, bt, kv, d), dtype)
+    value_pool = module.variable("cache", "value_pool", jnp.zeros,
+                                 (sink + 1, bt, kv, d), dtype)
+    tables = jnp.asarray(block_tables, jnp.int32)          # [B, M]
+    cursors = jnp.asarray(cursors, jnp.int32)              # [B]
+    m = tables.shape[1]
+    if lengths is None:
+        valid = jnp.ones((b, t), bool)
+    else:
+        valid = jnp.arange(t)[None, :] \
+            < jnp.asarray(lengths, jnp.int32)[:, None]
+    positions = cursors[:, None] + jnp.arange(t)[None, :]   # [B, T]
+    if rotate is not None:
+        q, k = rotate(q, positions), rotate(k, positions)
+    logical = jnp.minimum(positions // bt, m - 1)
+    phys = jnp.take_along_axis(tables, logical, axis=1)     # [B, T]
+    phys = jnp.where(valid, phys, sink).reshape(-1)
+    offs = (positions % bt).reshape(-1)
+    kp = key_pool.value.at[phys, offs].set(
+        k.astype(dtype).reshape(b * t, kv, d))
+    vp = value_pool.value.at[phys, offs].set(
+        v.astype(dtype).reshape(b * t, kv, d))
+    key_pool.value, value_pool.value = kp, vp
+    # Gather each row's sequence back in logical order; positions past
+    # the cursor (stale or sink-backed) are masked by ``attend``.
+    k_seq = jnp.take(kp, tables, axis=0).reshape(b, m * bt, kv, d)
+    v_seq = jnp.take(vp, tables, axis=0).reshape(b, m * bt, kv, d)
+    return attend(q, k_seq, v_seq, positions, scale)
+
+
+# -- a decode=True model through its cache: models/family.py's entry points
+def _with_cache_index(cache: dict, lengths) -> dict:
+    """``cache`` with every layer's write cursor set to ``lengths``
+    (scalar or [B] int32): prefill() rewinds past padding with it."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+
+    def fix(node):
+        if not isinstance(node, dict):
+            return node
+        return {key: (jnp.broadcast_to(lengths, val.shape).astype(val.dtype)
+                      if key == "cache_index" else fix(val))
+                for key, val in node.items()}
+    return fix(unfreeze(cache))
+
+
+def prefill(model, variables: dict, tokens: jax.Array,
+            lengths=None) -> tuple[jax.Array, dict]:
+    """Run the prompt through a ``decode=True`` model and return
+    ``(logits [B, T, vocab], cache)``.  ``lengths`` ([B] or scalar) gives
+    each row's true length when ``tokens`` is right-padded to a bucket:
+    the write cursor rewinds to it, so the first decode_step overwrites
+    the pad garbage and the mask hides the rest of it; the model gets it
+    too (a recurrence cannot be rewound: a family with recurrent state
+    stops there).  Row b's next-token logits are ``logits[b, lengths[b]
+    - 1]``."""
+    logits, mut = model.apply(variables, tokens, lengths=lengths,
+                              mutable=["cache"])
+    cache = unfreeze(mut["cache"])
+    if lengths is not None:
+        cache = _with_cache_index(cache, lengths)
+    return logits, cache
+
+
+def fresh_cache(model, params, slots: int) -> dict:
+    """``slots`` empty rows: zeros in every leaf, write cursors and a
+    family's recurrent state too."""
+    shapes = jax.eval_shape(
+        lambda p: model.apply({"params": p},
+                              jnp.zeros((slots, 1), jnp.int32),
+                              mutable=["cache"])[1]["cache"], params)
+    return unfreeze(jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes))
+
+
+def decode_step(model, variables: dict, cache: dict,
+                tokens: jax.Array) -> tuple[jax.Array, dict]:
+    """One incremental step of a ``decode=True`` model: ``tokens``
+    [B, 1] (or [B]) → ``(logits [B, 1, vocab], updated cache)``.  Each
+    row advances at its own depth, which is what lets continuous
+    batching admit a fresh prefill into a half-decoded batch."""
+    if tokens.ndim == 1:
+        tokens = tokens[:, None]
+    logits, mut = model.apply({**variables, "cache": cache}, tokens,
+                              mutable=["cache"])
+    return logits, unfreeze(mut["cache"])
+
+
+def paged_apply(model, variables: dict, cache: dict, tokens: jax.Array,
+                block_tables, cursors,
+                lengths=None) -> tuple[jax.Array, dict]:
+    """One paged-cache apply (``decode=True, paged=True``): prefill and
+    decode are the SAME call — ``tokens [B, T]`` (T = 1 for a decode
+    step, a padded prompt bucket for prefill) write into the pool
+    through each row's ``block_tables`` entry at its ``cursors``
+    position and attend over the gathered prefix.  ``lengths`` keeps
+    padded positions out of real blocks; an empty ``cache`` makes pools."""
+    if tokens.ndim == 1:
+        tokens = tokens[:, None]
+    logits, mut = model.apply({**variables, "cache": cache}, tokens,
+                              block_tables=block_tables,
+                              cursors=cursors, lengths=lengths,
+                              mutable=["cache"])
+    return logits, unfreeze(mut["cache"])
+
+
+def paged_copy_block(cache: dict, src: int, dst: int) -> dict:
+    """The tensor half of a copy-on-write: pool row ``src`` to ``dst`` in
+    every layer's pools (the id half is serving/kvpool.py's ``cow``)."""
+    cache = unfreeze(cache)
+    for key, node in paged_pool_leaves(cache):
+        node[key] = node[key].at[dst].set(node[key][src])
+    return cache
+
+
+def paged_pool_leaves(cache: dict) -> list:
+    """The per-layer key/value pools as (leaf name, parent dict), in an
+    order that both ends of a block stream share (same cache tree)."""
+    leaves = []
+
+    def walk(node):
+        if not isinstance(node, dict):
+            return
+        for key in sorted(node):
+            if key in ("key_pool", "value_pool"):
+                leaves.append((key, node))
+            else:
+                walk(node[key])
+    walk(cache)
+    return leaves

@@ -29,6 +29,10 @@ from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
 from .family import ModelFamily
+from .kvcache import (_with_cache_index, cached_attention,  # noqa: F401
+                      decode_step, fresh_cache, paged_apply,
+                      paged_attention, paged_copy_block, paged_pool_leaves,
+                      prefill)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,22 +72,15 @@ class TransformerConfig:
     moe_capacity_factor: float = 1.25
     ep_axis: str = "ep"
     # Incremental (KV-cache) decoding for inference serving: each
-    # Attention layer keeps cached_key/cached_value [B, max_seq_len, H, D]
-    # plus a per-batch-element write index in the mutable "cache"
-    # collection, so continuous batching (serving/batcher.py) pays one
-    # token of compute per step instead of re-running the full forward.
-    # Parameters are identical to the decode=False model; see prefill()
-    # and decode_step() below.  Mutually exclusive with ring/ulysses.
+    # Attention layer keeps its keys and values in the mutable "cache"
+    # collection (models/kvcache.py has the layouts and the calls), so
+    # continuous batching pays one token of compute per step.  Same
+    # parameters as decode=False; mutually exclusive with ring/ulysses.
     decode: bool = False
-    # Paged KV cache (ISSUE 14, serving/kvpool.py): with decode=True and
-    # paged=True each layer's KV state is a shared block pool
-    # [kv_pool_blocks + 1, kv_block_tokens, H, D] (the last row is a
-    # write sink for padded positions) instead of dense per-slot
-    # arrays; every apply takes explicit block_tables [B, M] (logical
-    # block i of row b lives in pool row block_tables[b, i]) and
-    # cursors [B] (each row's write position).  Storage scales with
-    # live token residency; parameters are unchanged, and the math is
-    # parity-tested against the dense decode path.
+    # Paged KV cache (ISSUE 14): with decode=True each layer's KV state
+    # is a shared pool of kv_pool_blocks blocks of kv_block_tokens, and
+    # every apply takes block_tables [B, M] and cursors [B]
+    # (kvcache.paged_apply); parity-tested against the dense layout.
     paged: bool = False
     kv_pool_blocks: int = 0
     kv_block_tokens: int = 16
@@ -240,17 +237,28 @@ class Attention(nn.Module):
                     "cfg.decode is incompatible with sequence-parallel "
                     f"attention ('{cfg.attention}'): the KV cache is a "
                     "whole-sequence structure")
+            # Through the cache (models/kvcache.py): RoPE at absolute
+            # positions, fp32 softmax like every other path in this file.
+            scale = 1.0 / math.sqrt(cfg.head_dim)
+            rotate = partial(apply_rope, theta=cfg.rope_theta)
             if cfg.paged:
                 if block_tables is None or cursors is None:
                     raise ValueError(
                         "paged decode needs block_tables [B, M] and "
                         "cursors [B] on every apply")
-                with jax.named_scope("hvd.decode_attend"):
-                    out = self._decode_attend_paged(
-                        q, k, v, block_tables, cursors, lengths)
+                if cfg.kv_pool_blocks <= 0:
+                    raise ValueError(
+                        "cfg.paged needs kv_pool_blocks > 0 (the per-layer "
+                        "block pool size)")
+                out = paged_attention(
+                    self, q, k, v, block_tables, cursors, lengths,
+                    pool_blocks=cfg.kv_pool_blocks,
+                    block_tokens=cfg.kv_block_tokens, dtype=cfg.dtype,
+                    scale=scale, rotate=rotate)
             else:
-                with jax.named_scope("hvd.decode_attend"):
-                    out = self._decode_attend(q, k, v)
+                out = cached_attention(
+                    self, q, k, v, max_seq_len=cfg.max_seq_len,
+                    dtype=cfg.dtype, scale=scale, rotate=rotate)
         else:
             if cfg.attention in ("ring", "ulysses") and \
                     _axis_is_manual(cfg.sp_axis) and \
@@ -274,107 +282,6 @@ class Attention(nn.Module):
             out = attn(q, k, v)                           # [B,T,H,D]
         out = out.astype(cfg.dtype)
         return dense(features=cfg.d_model, axis=(-2, -1), name="wo")(out)
-
-    def _decode_attend(self, q: jax.Array, k: jax.Array,
-                       v: jax.Array) -> jax.Array:
-        """Incremental attention over the mutable KV cache: write this
-        call's K/V at each batch element's own cache depth, attend
-        causally over the cached prefix.  Positions are absolute, so the
-        RoPE math matches the full forward pass exactly; fp32 softmax
-        like every other path in this file."""
-        cfg = self.cfg
-        b, t, h, d = q.shape
-        s = cfg.max_seq_len
-        cached_k = self.variable("cache", "cached_key", jnp.zeros,
-                                 (b, s, h, d), cfg.dtype)
-        cached_v = self.variable("cache", "cached_value", jnp.zeros,
-                                 (b, s, h, d), cfg.dtype)
-        index = self.variable("cache", "cache_index",
-                              lambda: jnp.zeros((b,), jnp.int32))
-        idx = index.value                                   # [B]
-        positions = idx[:, None] + jnp.arange(t)[None, :]   # [B,T]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        write = jax.vmap(lambda cache, new, i:
-                         jax.lax.dynamic_update_slice(cache, new,
-                                                      (i, 0, 0)))
-        cached_k.value = write(cached_k.value, k.astype(cfg.dtype), idx)
-        cached_v.value = write(cached_v.value, v.astype(cfg.dtype), idx)
-        index.value = idx + t
-        # Causal mask over absolute positions.  Right-padded prefill
-        # garbage always sits at key positions strictly greater than the
-        # current query position (prefill() rewinds the write cursor to
-        # the true length, and decode overwrites forward from there), so
-        # key_pos <= q_pos alone keeps it invisible.
-        key_pos = jnp.arange(s)
-        mask = key_pos[None, None, :] <= positions[:, :, None]  # [B,T,S]
-        qf = q.astype(jnp.float32)
-        kf = cached_k.value.astype(jnp.float32)
-        vf = cached_v.value.astype(jnp.float32)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) / math.sqrt(d)
-        logits = jnp.where(mask[:, None, :, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
-
-    def _decode_attend_paged(self, q: jax.Array, k: jax.Array,
-                             v: jax.Array, block_tables, cursors,
-                             lengths) -> jax.Array:
-        """Incremental attention over the shared block pool (ISSUE 14):
-        this call's K/V scatter into pool rows addressed through each
-        row's block table, then the table gathers the sequence back as
-        [B, M*bt, H, D] (one block-table-indexed gather — logical
-        position p of row b lives at pool[tables[b, p//bt], p%bt]) for
-        the same absolute-position causal attention as the dense path.
-        ``lengths`` masks right-padded prefill calls: padded positions
-        write to the pool's sink row (never a real block) and padded
-        logits are garbage the caller ignores, exactly like the dense
-        path's masked tail."""
-        cfg = self.cfg
-        b, t, h, d = q.shape
-        bt = cfg.kv_block_tokens
-        if cfg.kv_pool_blocks <= 0:
-            raise ValueError(
-                "cfg.paged needs kv_pool_blocks > 0 (the per-layer "
-                "block pool size)")
-        sink = cfg.kv_pool_blocks                    # the write sink row
-        key_pool = self.variable("cache", "key_pool", jnp.zeros,
-                                 (sink + 1, bt, h, d), cfg.dtype)
-        value_pool = self.variable("cache", "value_pool", jnp.zeros,
-                                   (sink + 1, bt, h, d), cfg.dtype)
-        tables = jnp.asarray(block_tables, jnp.int32)      # [B, M]
-        cursors = jnp.asarray(cursors, jnp.int32)          # [B]
-        m = tables.shape[1]
-        if lengths is None:
-            valid = jnp.ones((b, t), bool)
-        else:
-            valid = jnp.arange(t)[None, :] \
-                < jnp.asarray(lengths, jnp.int32)[:, None]
-        positions = cursors[:, None] + jnp.arange(t)[None, :]   # [B,T]
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-        logical = jnp.minimum(positions // bt, m - 1)
-        phys = jnp.take_along_axis(tables, logical, axis=1)     # [B,T]
-        phys = jnp.where(valid, phys, sink)
-        offs = positions % bt
-        kp = key_pool.value.at[phys.reshape(-1), offs.reshape(-1)].set(
-            k.astype(cfg.dtype).reshape(b * t, h, d))
-        vp = value_pool.value.at[phys.reshape(-1), offs.reshape(-1)].set(
-            v.astype(cfg.dtype).reshape(b * t, h, d))
-        key_pool.value, value_pool.value = kp, vp
-        # Gather each row's sequence back in logical order; positions
-        # past the cursor (stale or sink-backed) are masked exactly like
-        # the dense path's not-yet-overwritten tail.
-        k_seq = jnp.take(kp, tables, axis=0).reshape(b, m * bt, h, d)
-        v_seq = jnp.take(vp, tables, axis=0).reshape(b, m * bt, h, d)
-        key_pos = jnp.arange(m * bt)
-        mask = key_pos[None, None, :] <= positions[:, :, None]  # [B,T,S]
-        qf = q.astype(jnp.float32)
-        kf = k_seq.astype(jnp.float32)
-        vf = v_seq.astype(jnp.float32)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qf, kf) / math.sqrt(d)
-        logits = jnp.where(mask[:, None, :, :], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, vf)
 
 
 class MLP(nn.Module):
@@ -450,102 +357,8 @@ class TransformerLM(nn.Module):
                         param_dtype=cfg.param_dtype, name="lm_head")(x)
 
 
-# ---------------------------------------------------------------------------
-# KV-cache incremental decoding (inference serving; serving/replica.py)
-# ---------------------------------------------------------------------------
-def _with_cache_index(cache: dict, lengths) -> dict:
-    """Return ``cache`` with every layer's write cursor set to
-    ``lengths`` (scalar or [B] int32) — prefill() rewinds past padding
-    with it, and the serving replica resets recycled batch slots."""
-    lengths = jnp.asarray(lengths, jnp.int32)
-
-    def fix(node):
-        if not isinstance(node, dict):
-            return node
-        return {key: (jnp.broadcast_to(lengths, val.shape).astype(val.dtype)
-                      if key == "cache_index" else fix(val))
-                for key, val in node.items()}
-    from flax.core import unfreeze
-    return fix(unfreeze(cache))
-
-
-def prefill(model: TransformerLM, variables: dict, tokens: jax.Array,
-            lengths=None) -> tuple[jax.Array, dict]:
-    """Run the prompt through a ``decode=True`` model and return
-    ``(logits [B, T, vocab], cache)``.
-
-    ``lengths`` ([B] or scalar) gives each row's true prompt length when
-    ``tokens`` is right-padded to a shared bucket: the KV write cursor
-    rewinds to it so the first decode_step overwrites the pad garbage,
-    and the causal mask keeps the not-yet-overwritten tail invisible
-    (it sits at strictly greater positions than every live query).  The
-    next-token logits of row b are ``logits[b, lengths[b] - 1]``."""
-    from flax.core import unfreeze
-    logits, mut = model.apply(variables, tokens, mutable=["cache"])
-    cache = unfreeze(mut["cache"])
-    if lengths is not None:
-        cache = _with_cache_index(cache, lengths)
-    return logits, cache
-
-
-def fresh_cache(model: TransformerLM, params, slots: int) -> dict:
-    """A dense slot cache of ``slots`` empty rows: one apply creates the
-    cache collection (its only writes land at position 0), and the write
-    cursors go back to 0."""
-    _, mut = model.apply({"params": params},
-                         jnp.zeros((slots, 1), jnp.int32), mutable=["cache"])
-    return _with_cache_index(mut["cache"], 0)
-
-
-def decode_step(model: TransformerLM, variables: dict, cache: dict,
-                tokens: jax.Array) -> tuple[jax.Array, dict]:
-    """One incremental step of a ``decode=True`` model: ``tokens``
-    [B, 1] (or [B]) → ``(logits [B, 1, vocab], updated cache)``.  Each
-    batch element advances at its own cache depth, which is what lets
-    continuous batching admit a fresh prefill into a half-decoded
-    batch."""
-    from flax.core import unfreeze
-    if tokens.ndim == 1:
-        tokens = tokens[:, None]
-    logits, mut = model.apply({**variables, "cache": cache}, tokens,
-                              mutable=["cache"])
-    return logits, unfreeze(mut["cache"])
-
-
-def paged_apply(model: TransformerLM, variables: dict, cache: dict,
-                tokens: jax.Array, block_tables, cursors,
-                lengths=None) -> tuple[jax.Array, dict]:
-    """One paged-cache apply (``decode=True, paged=True``): prefill and
-    decode are the SAME call — ``tokens [B, T]`` (T = 1 for a decode
-    step, a padded prompt bucket for prefill) write into the pool
-    through each row's ``block_tables`` entry at its ``cursors``
-    position and attend over the gathered prefix.  No write-cursor
-    rewinding: ``lengths`` keeps padded positions out of real blocks
-    entirely (they land in the pool's sink row)."""
-    from flax.core import unfreeze
-    if tokens.ndim == 1:
-        tokens = tokens[:, None]
-    logits, mut = model.apply({**variables, "cache": cache}, tokens,
-                              block_tables=block_tables,
-                              cursors=cursors, lengths=lengths,
-                              mutable=["cache"])
-    return logits, unfreeze(mut["cache"])
-
-
-def paged_copy_block(cache: dict, src: int, dst: int) -> dict:
-    """The tensor half of a copy-on-write: copy pool row ``src`` to
-    ``dst`` in every layer's key/value pool (the id half lives in
-    serving/kvpool.py ``cow``)."""
-    def fix(node):
-        if not isinstance(node, dict):
-            return node
-        return {key: (val.at[dst].set(val[src])
-                      if key in ("key_pool", "value_pool") else fix(val))
-                for key, val in node.items()}
-    from flax.core import unfreeze
-    return fix(unfreeze(cache))
-
-
+# Serving: the entry points of models/family.py are models/kvcache.py's
+# (benchmarks/chip/reference.py reads ``_with_cache_index`` from here).
 def _decode_flops(cfg: TransformerConfig, context: float) -> float:
     from ..telemetry import perfmodel
     return perfmodel.transformer_decode_flops(cfg, context)
@@ -553,7 +366,10 @@ def _decode_flops(cfg: TransformerConfig, context: float) -> float:
 
 FAMILY = ModelFamily(name="transformer", build=TransformerLM,
                      fresh_cache=fresh_cache, prefill=prefill,
-                     decode_step=decode_step, decode_flops=_decode_flops)
+                     decode_step=decode_step, decode_flops=_decode_flops,
+                     paged_apply=paged_apply,
+                     paged_copy_block=paged_copy_block,
+                     paged_pool_leaves=paged_pool_leaves)
 
 
 # ---------------------------------------------------------------------------

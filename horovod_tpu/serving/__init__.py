@@ -18,10 +18,10 @@ never produces:
   replicas never diverge on a collective), per-request deadlines
   propagated into resilience per-op deadlines, and elastic shrink
   mid-serve on RanksFailedError (survivors keep serving).
-- :class:`~.kvpool.KVBlockPool` — paged KV blocks (ISSUE 14): free-list
-  allocation with refcounts, FNV-chain prefix caching, copy-on-write
-  and LRU eviction, so concurrency scales with live token residency
-  instead of the batch shape.
+- ``serving/slotcache.py`` — the replica's slot cache, dense or paged,
+  behind one interface; :class:`~.kvpool.KVBlockPool` — the paged one's
+  blocks (ISSUE 14): free-list allocation with refcounts, FNV-chain
+  prefix caching, copy-on-write and LRU eviction.
 - ``serving/kvstream.py`` — disaggregated prefill/decode: prefill-only
   ranks stream finished KV blocks to decode replicas over a dedicated
   PeerMesh (addressed CRC'd chunks, the STATE_MAGIC mold), keeping

@@ -92,8 +92,7 @@ class ContinuousBatcher:
         self._active: list[int] = [0] * num_replicas   # slots in use
         self._blocks: list[int] = [0] * num_replicas   # blocks reserved
         self._req_blocks: dict[int, int] = {}          # rid -> reserve
-        # Peak concurrent in-flight sequences — the number the paged A/B
-        # reports next to SERVE_MAX_BATCH (bench.py --model serve).
+        # Peak concurrent in-flight sequences (kv_stats reports it).
         self.max_concurrent = 0
 
     @property

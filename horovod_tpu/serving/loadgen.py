@@ -140,8 +140,7 @@ def build_report(executor: ReplicaExecutor, *, offered: int,
         "wall_s": wall_s,
         "steps": executor._step,
         "step_metrics_present": bool(reg_snapshot),
-        # Paged-KV residency/reuse (None in dense mode): the A/B
-        # numbers bench.py --model serve reports next to the dense leg.
+        # Paged-KV residency/reuse (None in dense mode).
         "kv": executor.kv_stats(),
         "max_concurrent_seqs": executor.batcher.max_concurrent,
         # Fleet continuous-deployment staleness accounting: which weight

@@ -17,21 +17,17 @@ Execution model (ISSUE 9 tentpole, extended by ISSUE 14):
 - Each **replica group** (``HOROVOD_SERVE_GROUP_SIZE`` ranks; 1 = pure
   data-parallel) prefills newly assigned requests into free KV-cache
   slots and advances every in-flight slot by one greedy token per step
-  (models/transformer.py — continuous batching, not run-to-completion).
-- **Paged KV** (``HOROVOD_SERVE_PAGED``, ISSUE 14): slot KV state lives
-  in fixed-size blocks from a per-replica :class:`~.kvpool.KVBlockPool`
-  instead of dense per-slot arrays, so slot count is bounded by live
-  token residency (the pool), not the batch shape.  Prompt blocks are
-  content-addressed (FNV chain hash): a request whose prefix blocks are
-  already resident bumps refcounts instead of re-prefilling, with
-  copy-on-write on the first divergent write and LRU eviction of
-  refcount-0 cached blocks.
+  (continuous batching, not run-to-completion), through the model's
+  family (models/family.py).
+- **The slot cache** is an object of ``serving/slotcache.py``, chosen
+  once from ``HOROVOD_SERVE_PAGED``: dense per-slot arrays, or paged
+  blocks with prefix reuse (ISSUE 14).  The executor knows neither
+  layout: it admits, decodes and releases through one interface.
 - **Disaggregated prefill/decode** (``HOROVOD_SERVE_PREFILL_RANKS``):
   the highest N ranks run prompt prefill only and stream finished KV
-  blocks to decode replicas over the dedicated kvstream mesh, so a long
-  prompt overlaps decode steps instead of stalling them.  Streaming is
-  point-to-point — the plan broadcast stays the only schedule source
-  and the collective fingerprint stream is identical on every rank.
+  blocks to decode replicas over the kvstream mesh (point-to-point: the
+  plan broadcast stays the only schedule source), so a long prompt
+  overlaps decode steps instead of stalling them.
 - Completions ride back on an **allgather** each step, so the front end
   frees slots and records latencies without any side channel.
 - **Deadline propagation**: the earliest in-flight request deadline
@@ -44,9 +40,8 @@ Execution model (ISSUE 9 tentpole, extended by ISSUE 14):
   heartbeat-confirmed dead set, deterministically renumbers itself,
   rebuilds the world one rank smaller (fresh rendezvous epoch), resyncs
   the in-flight map from ground truth, and keeps serving.  In-flight
-  requests on surviving replicas are untouched — their KV state
-  (dense caches or paged block pools) is process-local and does not
-  care about the mesh.
+  requests on surviving replicas are untouched — their KV state is
+  process-local and does not care about the mesh.
 """
 from __future__ import annotations
 
@@ -67,12 +62,11 @@ import numpy as np
 from ..common import config
 from ..common.exceptions import RanksFailedError
 from ..common.logging import logger
-from ..models import transformer as tfm
-from ..telemetry.spans import StepParts, span
+from ..telemetry.spans import StepParts
 from .admission import AdmissionController
 from .batcher import Assignment, BatchPlan, ContinuousBatcher
-from .kvpool import FNV_SEED, KVBlockPool, chain_hash
 from .queue import RequestQueue
+from .slotcache import DenseSlotCache, PagedSlotCache, prompt_bucket
 
 
 @dataclasses.dataclass
@@ -91,11 +85,10 @@ class ServeConfig:
     # fresh cache, prefill and a decode step: TransformerConfig or
     # HybridConfig today.
     model_cfg: object | None = None
-    # Paged KV cache (ISSUE 14): blocks of block_tokens from a
-    # pool_blocks pool; 0 = auto (max_batch x ceil(max_seq/bt), the
-    # dense layout's token memory).  paged_slots (0 = auto: 2 x
-    # max_batch) is the decode batch width — the pool, not the batch
-    # shape, bounds concurrency.
+    # Paged KV cache (serving/slotcache.py): blocks of block_tokens from
+    # a pool_blocks pool; 0 = auto (the dense layout's token memory).
+    # paged_slots (0 = auto: 2 x max_batch) is the decode batch width:
+    # the pool, not the batch shape, bounds concurrency.
     paged: bool = False
     block_tokens: int = 16
     pool_blocks: int = 0
@@ -158,10 +151,7 @@ class _Slot:
     age_ms: float                      # ingress age when assigned
     slo_ms: float
     generated: list[int]
-    # Paged mode: physical block ids in logical order (each held once
-    # by this slot) and the sequence write cursor.
-    blocks: list = dataclasses.field(default_factory=list)
-    seq_len: int = 0
+    seq_len: int = 0                   # the sequence's write cursor
     # Disaggregated mode: the original assignment while the streamed
     # prefill is still in flight (slot skips decode until it lands or
     # the fallback re-prefills locally), and when it went pending.
@@ -187,7 +177,9 @@ class ReplicaExecutor:
 
         model_cfg = _decode_model_cfg(self.cfg)
         self.family = model_cfg.family
+        layout = DenseSlotCache
         if self.cfg.paged:
+            layout = PagedSlotCache
             if self.family.paged_missing:
                 raise ValueError(
                     f"ServeConfig.paged=True (and with it prefix reuse, "
@@ -274,33 +266,10 @@ class ReplicaExecutor:
                                   default_slo_ms=self.cfg.slo_ms)
         self.admission = AdmissionController(
             queue_depth_limit=self.cfg.queue_depth)
+        # The slot cache in the layout chosen above (serving/slotcache.py).
+        self.cache = layout(self.cfg, self.family, self.model, self.stats)
         self.batcher = self._make_batcher()
-
-        # Paged state: the block pool (id bookkeeping), the per-slot
-        # block tables/cursors (the model's addressing arguments) and
-        # the paged cache (the pools themselves).
-        self.pool: KVBlockPool | None = None
-        if self.cfg.paged:
-            self.pool = KVBlockPool(self.cfg.resolved_pool_blocks,
-                                    self.cfg.block_tokens)
-            self._sink = self.cfg.resolved_pool_blocks
-            self._tables = np.full((self.cfg.slots,
-                                    self.cfg.table_width),
-                                   self._sink, np.int32)
-            self._cursors = np.zeros(self.cfg.slots, np.int32)
-            self._paged_jit = jax.jit(self._paged_impl, donate_argnums=1)
-            self._paged_prefill_jit = jax.jit(self._paged_prefill_impl,
-                                              donate_argnums=1)
-            self._copy_block_jit = jax.jit(tfm.paged_copy_block,
-                                           donate_argnums=0)
-        else:
-            self._decode_jit = jax.jit(self._decode_impl, donate_argnums=1)
-            self._prefill_jit = jax.jit(self._prefill_impl)
-            self._insert_jit = jax.jit(self._insert_impl, donate_argnums=0)
         self._kvstream = None
-        # Jitted like every other model call here: un-jitted, each of its
-        # hundreds of small ops compiles and dispatches on its own.
-        self._init_cache_jit = jax.jit(self._init_cache_impl)
         self._init_cache()
         self._warmup()
         if self.prefill_rank_list:
@@ -337,8 +306,7 @@ class ReplicaExecutor:
         return ContinuousBatcher(
             self.num_groups, slots_per_replica=self.cfg.slots,
             token_budget=self.cfg.token_budget,
-            block_capacity=self.cfg.resolved_pool_blocks
-            if self.cfg.paged else 0,
+            block_capacity=self.cache.block_capacity,
             block_tokens=self.cfg.block_tokens)
 
     def _rebuild_kvstream(self) -> None:
@@ -356,126 +324,12 @@ class ReplicaExecutor:
             _kv_client(), kvstream_scope(base, self._gen), self.rank,
             self.size, self.prefill_rank_list)
 
-    # -- model plumbing --------------------------------------------------
-    def _decode_impl(self, params, cache, tokens):
-        logits, cache = self.family.decode_step(
-            self.model, {"params": params}, cache, tokens)
-        return _sample(logits[:, -1, :]), cache
-
-    def _prefill_impl(self, params, tokens, n):
-        logits, cache = self.family.prefill(
-            self.model, {"params": params}, tokens, lengths=n)
-        return _sample(logits[0, n - 1, :]), cache
-
-    @staticmethod
-    def _insert_impl(cache, cache1, slot):
-        """Row ``slot`` of every leaf of the slot cache becomes the
-        prefilled request's only row (keys, values, write cursor and a
-        family's recurrent state alike, so nothing of the slot's last
-        occupant is left); ``slot`` is traced, so all slots share one
-        program."""
-        return jax.tree_util.tree_map(
-            lambda big, small: jax.lax.dynamic_update_slice_in_dim(
-                big, small, slot, axis=0), cache, cache1)
-
-    def _paged_impl(self, params, cache, tokens, tables, cursors):
-        """One paged decode step for the whole slot array: inactive
-        slots' tables point at the pool sink row, so their writes land
-        in garbage space and their outputs are ignored."""
-        logits, cache = tfm.paged_apply(
-            self.model, {"params": params}, cache, tokens, tables,
-            cursors)
-        return _sample(logits[:, -1, :]), cache
-
-    def _paged_prefill_impl(self, params, cache, tokens, table, cursor,
-                            n):
-        """Paged prefill of ONE request (B=1) straight into the shared
-        pool through the slot's block table; ``cursor`` > 0 resumes
-        past prefix-cache hits and ``n`` masks the padded tail."""
-        logits, cache = tfm.paged_apply(
-            self.model, {"params": params}, cache, tokens, table,
-            cursor, lengths=n)
-        return _sample(logits[0, n[0] - 1, :]), cache
-
+    # -- the slot cache (serving/slotcache.py) ---------------------------
     def _init_cache(self) -> None:
-        self._cache = self._init_cache_jit(self.params)
-
-    def _init_cache_impl(self, params):
-        """A fresh cache: the paged pools (one apply creates them, its
-        only writes land in the sink row) or the family's dense slot
-        cache."""
-        from flax.core import unfreeze
-        if self.cfg.paged:
-            _, mut = self.model.apply(
-                {"params": params}, jnp.zeros((1, 1), jnp.int32),
-                block_tables=jnp.full((1, self.cfg.table_width),
-                                      self._sink, jnp.int32),
-                cursors=jnp.zeros((1,), jnp.int32),
-                mutable=["cache"])
-            return unfreeze(mut["cache"])
-        return self.family.fresh_cache(self.model, params, self.cfg.slots)
+        self.cache.fresh(self.params)
 
     def _warmup(self) -> None:
-        """Compile every program the serve loop runs.  Each of them takes
-        the cache donated, so each call's result is rebound: the leaves
-        a program was given are deleted once it is enqueued."""
-        if self.cfg.paged:
-            table1 = jnp.full((1, self.cfg.table_width), self._sink,
-                              jnp.int32)
-            for bucket in self.cfg.warmup_buckets:
-                if bucket > self.cfg.max_seq:
-                    continue
-                tok, self._cache = self._paged_prefill_jit(
-                    self.params, self._cache,
-                    jnp.zeros((1, bucket), jnp.int32), table1,
-                    jnp.zeros((1,), jnp.int32),
-                    jnp.ones((1,), jnp.int32))
-                jax.block_until_ready(tok)
-            decode, extra = self._paged_jit, (jnp.asarray(self._tables),
-                                              jnp.asarray(self._cursors))
-        else:
-            for bucket in self.cfg.warmup_buckets:
-                if bucket > self.cfg.max_seq:
-                    continue
-                tok, cache1 = self._prefill_jit(
-                    self.params, jnp.zeros((1, bucket), jnp.int32),
-                    jnp.int32(1))
-                # One shape whatever the bucket: compiled once.
-                self._cache = self._insert_jit(self._cache, cache1,
-                                               np.int32(0))
-                jax.block_until_ready(tok)
-            decode, extra = self._decode_jit, ()
-        args = (self.params, self._cache,
-                jnp.asarray(self._last_tokens[:, None]), *extra)
-        self._note_cache_aliasing(decode.lower(*args).compile())
-        nxt, self._cache = decode(*args)
-        jax.block_until_ready(nxt)
-        self._cache = None             # one copy at a time
-        self._init_cache()             # discard warmup cache writes
-
-    def _note_cache_aliasing(self, decode_program) -> None:
-        """How much of the cache the compiled decode program updates in
-        place: the cache is its only donated argument, so what it
-        aliases from input to output is cache."""
-        leaves = jax.tree_util.tree_flatten_with_path(self._cache)[0]
-        self.stats["cache_bytes"] = sum(leaf.nbytes for _, leaf in leaves)
-        self.stats["state_bytes"] = sum(
-            leaf.nbytes for path, leaf in leaves
-            if path[-1].key in self.family.state_leaves)
-        self.stats["kv_bytes"] = \
-            self.stats["cache_bytes"] - self.stats["state_bytes"]
-        self.stats["cache_aliased_bytes"] = \
-            decode_program.memory_analysis().alias_size_in_bytes
-        logger.info("serving: slot cache %.2f of %.2f GB aliased by the "
-                    "decode program (%d of %d bytes)",
-                    self.stats["cache_aliased_bytes"] / 1e9,
-                    self.stats["cache_bytes"] / 1e9,
-                    self.stats["cache_aliased_bytes"],
-                    self.stats["cache_bytes"])
-
-    @staticmethod
-    def _bucket(n: int) -> int:
-        return max(8, 1 << max(0, (n - 1)).bit_length())
+        self.cache.warm(self.params, self._last_tokens)
 
     # -- per-step halves -------------------------------------------------
     def _assemble(self) -> BatchPlan:
@@ -531,160 +385,43 @@ class ReplicaExecutor:
             slot = next(i for i, s in enumerate(self.slots) if s is None)
             admits += 1
             with parts("admit", rid=a.rid,
-                       bucket=self._prompt_bucket(
-                           len(self._clamped_tokens(a)))):
+                       bucket=prompt_bucket(
+                           self.cfg, len(self._clamped_tokens(a)))):
                 if a.prefill >= 0:
                     self._admit_disaggregated(slot, a, now)
-                elif self.cfg.paged:
-                    self._prefill_slot_paged(slot, a, now)
                 else:
                     self._prefill_slot(slot, a, now)
         return admits
 
-    def _prompt_bucket(self, n: int) -> int:
-        """The compiled prefill shape ``n`` prompt tokens pad to."""
-        return min(self._bucket(n), self.cfg.max_seq)
-
-    # -- dense prefill ---------------------------------------------------
     def _prefill_slot(self, slot: int, a: Assignment, now: float) -> None:
         toks = self._clamped_tokens(a)
-        padded = np.zeros((1, self._prompt_bucket(len(toks))), np.int32)
-        padded[0, :len(toks)] = toks
-        with span("serve.prefill_dispatch"):
-            first, cache1 = self._prefill_jit(
-                self.params, jnp.asarray(padded), jnp.int32(len(toks)))
-        with span("serve.cache_insert"):     # a dispatch: nothing waits
-            self._cache = self._insert_jit(self._cache, cache1,
-                                           np.int32(slot))
-        with span("serve.first_token_fetch"):
-            first = int(first)         # waits for the device
-        self._activate_slot(slot, a, now, first, seq_len=len(toks))
-
-    def _activate_slot(self, slot: int, a: Assignment, now: float,
-                       first: int, blocks: list | None = None,
-                       seq_len: int = 0) -> None:
+        first = self.cache.admit(self.params, slot, toks, a.max_new_tokens)
         self._last_tokens[slot] = first
         self.slots[slot] = _Slot(
             rid=a.rid, remaining=a.max_new_tokens - 1,
             deadline=now + a.deadline_rel_ms / 1e3, assigned_at=now,
             age_ms=a.age_ms, slo_ms=a.slo_ms, generated=[first],
-            blocks=blocks or [], seq_len=seq_len)
+            seq_len=len(toks))
         self.prefilled.add(a.rid)
 
-    # -- paged prefill + prefix cache ------------------------------------
     def _clamped_tokens(self, a: Assignment) -> list[int]:
         # Clamp so prompt + generation always fits the KV cache.
         limit = self.cfg.max_seq - a.max_new_tokens
         return a.tokens[:max(1, limit)]
-
-    def _lookup_prefix(self, toks: list[int]) -> tuple[list, int]:
-        """Walk the prompt's block chain through the prefix cache:
-        returns (hit block ids — refcounts already bumped, tokens
-        covered)."""
-        bt = self.cfg.block_tokens
-        parent = FNV_SEED
-        hits: list[int] = []
-        pos = 0
-        while pos < len(toks):
-            seg = toks[pos:pos + bt]
-            blk = self.pool.lookup(parent, seg)
-            if blk is None:
-                break
-            hits.append(blk)
-            parent = chain_hash(parent, seg)
-            pos += len(seg)
-        return hits, pos
-
-    def _publish_prompt(self, toks: list[int], blocks: list) -> None:
-        """Content-address every prompt block (full blocks and the
-        partial tail) so later identical prefixes hit instead of
-        re-prefilling.  Publishing makes a block immutable — the next
-        write into the tail COWs it (the first divergent write)."""
-        bt = self.cfg.block_tokens
-        parent = FNV_SEED
-        for i in range(0, len(toks), bt):
-            parent = self.pool.publish(blocks[i // bt], parent,
-                                       toks[i:i + bt])
-
-    def _ensure_writable(self, slot_blocks: list, j: int) -> bool:
-        """COW guard before writing into logical block ``j``: a shared
-        or published block gets a private copy (pool ids + tensor rows)
-        and the slot's table repoints.  Returns True when a copy
-        happened."""
-        old = slot_blocks[j]
-        new, copied = self.pool.cow(old)
-        if copied:
-            self._cache = self._copy_block_jit(
-                self._cache, jnp.int32(old), jnp.int32(new))
-            slot_blocks[j] = new
-        return copied
-
-    def _prefill_slot_paged(self, slot: int, a: Assignment,
-                            now: float) -> None:
-        bt = self.cfg.block_tokens
-        toks = self._clamped_tokens(a)
-        hits, pos = self._lookup_prefix(toks)
-        full_hit = pos >= len(toks)
-        if full_hit:
-            # Whole prompt resident: no prefill at all — re-run just the
-            # last prompt token (its K/V rewrite is value-identical;
-            # COW below keeps shared blocks untouched) to get the
-            # next-token logits.
-            pos = len(toks) - 1
-            self.stats["prefill_skipped"] += 1
-        total = -(-(len(toks) + a.max_new_tokens) // bt)
-        fresh = self.pool.alloc(total - len(hits))
-        if fresh is None:
-            # The front end reserves worst-case blocks per admission, so
-            # this is unreachable unless accounting drifted; fail loud.
-            for b in hits:
-                self.pool.deref(b)
-            raise RuntimeError(
-                f"KV pool exhausted admitting rid {a.rid}: "
-                f"{self.pool.free_count()} free of {self.pool.num_blocks}")
-        blocks = hits + fresh
-        j0 = pos // bt
-        self._ensure_writable(blocks, j0)
-        rem = toks[pos:]
-        padded = np.zeros((1, self._prompt_bucket(len(rem))), np.int32)
-        padded[0, :len(rem)] = rem
-        row = np.full(self.cfg.table_width, self._sink, np.int32)
-        row[:total] = blocks
-        with span("serve.prefill_dispatch"):
-            first, self._cache = self._paged_prefill_jit(
-                self.params, self._cache, jnp.asarray(padded),
-                jnp.asarray(row[None]), jnp.asarray([pos], np.int32),
-                jnp.asarray([len(rem)], np.int32))
-        # The paged program wrote the pool rows itself; what is left of
-        # the insert is the host's: publish the blocks, point the slot's
-        # table at them.
-        with span("serve.cache_insert"):
-            self._publish_prompt(toks, blocks)
-            self._tables[slot] = row
-        with span("serve.first_token_fetch"):
-            first = int(first)         # waits for the device
-        self._activate_slot(slot, a, now, first, blocks=blocks,
-                            seq_len=len(toks))
 
     # -- disaggregated prefill/decode ------------------------------------
     def _admit_disaggregated(self, slot: int, a: Assignment,
                              now: float) -> None:
         """Decode-rank admission of a prefill-rank-assigned request: a
         full local prefix hit admits immediately (the stream, when it
-        lands, is discarded); otherwise the slot parks PENDING — it
-        skips decode until the streamed blocks arrive (or the fallback
-        re-prefills locally), so the long prompt never stalls a step."""
-        toks = self._clamped_tokens(a)
-        hits, pos = self._lookup_prefix(toks)
-        if pos >= len(toks):
-            for b in hits:          # _prefill_slot_paged re-looks-up
-                self.pool.deref(b)
-            self._prefill_slot_paged(slot, a, now)
+        lands, is discarded); otherwise the slot parks PENDING: no
+        decode until the streamed blocks arrive (or the fallback
+        re-prefills locally), so a long prompt never stalls a step."""
+        if self.cache.holds_prompt(self._clamped_tokens(a)):
+            self._prefill_slot(slot, a, now)
             if self._kvstream is not None:
                 self._kvstream.discard(a.rid)
             return
-        for b in hits:
-            self.pool.deref(b)
         self.slots[slot] = _Slot(
             rid=a.rid, remaining=a.max_new_tokens,
             deadline=now + a.deadline_rel_ms / 1e3, assigned_at=now,
@@ -693,21 +430,10 @@ class ReplicaExecutor:
         self.prefilled.add(a.rid)
 
     def _prefill_and_stream(self, a: Assignment) -> None:
-        """Prefill-rank half: compute the prompt's KV blocks in the
-        local scratch pool (identity table) and stream them to every
-        rank of the decode replica group."""
-        bt = self.cfg.block_tokens
+        """Prefill-rank half: compute the prompt's KV blocks and stream
+        them to every rank of the decode replica group."""
         toks = self._clamped_tokens(a)
-        nblk = -(-len(toks) // bt)
-        row = np.full(self.cfg.table_width, self._sink, np.int32)
-        row[:nblk] = np.arange(nblk)
-        padded = np.zeros((1, self._prompt_bucket(len(toks))), np.int32)
-        padded[0, :len(toks)] = toks
-        first, self._cache = self._paged_prefill_jit(
-            self.params, self._cache, jnp.asarray(padded),
-            jnp.asarray(row[None]), jnp.zeros((1,), np.int32),
-            jnp.asarray([len(toks)], np.int32))
-        image = self._extract_blocks(nblk)
+        first, image = self.cache.prefill_image(self.params, toks)
         dests = list(range(a.replica * self.group_size,
                            (a.replica + 1) * self.group_size))
         from ..resilience import deadline_scope
@@ -720,7 +446,7 @@ class ReplicaExecutor:
             with deadline_scope(time.monotonic()
                                 + a.deadline_rel_ms / 1e3):
                 self._kvstream.send_image(
-                    a.rid, dests, image.tobytes(), first=int(first),
+                    a.rid, dests, image.tobytes(), first=first,
                     plen=len(toks), cursor=len(toks), shape=image.shape,
                     dtype=str(image.dtype))
         except (ConnectionError, OSError) as exc:
@@ -730,34 +456,6 @@ class ReplicaExecutor:
                            "%s", a.rid, exc)
             return
         self.stats["prefill_streams"] += 1
-
-    def _cache_pool_leaves(self) -> list:
-        """The per-layer key/value pool arrays in a deterministic
-        traversal order (identical on sender and receiver: same model,
-        same cache tree)."""
-        leaves = []
-
-        def walk(node):
-            if not isinstance(node, dict):
-                return
-            for key in sorted(node):
-                if key in ("key_pool", "value_pool"):
-                    leaves.append((key, node))
-                else:
-                    walk(node[key])
-        walk(self._cache)
-        return leaves
-
-    def _extract_blocks(self, nblk: int) -> np.ndarray:
-        """[n_leaves, nblk, bt, H, D]: the prompt's pool rows across
-        every layer, ready to serialize."""
-        return np.stack([np.asarray(node[key][:nblk])
-                         for key, node in self._cache_pool_leaves()])
-
-    def _insert_blocks(self, ids: list, image: np.ndarray) -> None:
-        idx = jnp.asarray(np.asarray(ids, np.int32))
-        for i, (key, node) in enumerate(self._cache_pool_leaves()):
-            node[key] = node[key].at[idx].set(jnp.asarray(image[i]))
 
     def _integrate_prefills(self) -> None:
         """Decode-rank step hook: land fully streamed transfers into
@@ -780,7 +478,7 @@ class ReplicaExecutor:
             if now - s.pending_since > patience:
                 a = s.pending
                 self.slots[i] = None
-                self._prefill_slot_paged(i, a, now)
+                self._prefill_slot(i, a, now)
                 self.stats["prefill_fallbacks"] += 1
                 if self._kvstream is not None:
                     self._kvstream.discard(a.rid)
@@ -790,32 +488,16 @@ class ReplicaExecutor:
                     self._kvstream.discard(rid)
 
     def _land_streamed(self, slot: int, img) -> None:
-        """Insert a streamed prefill into the pool and activate the
-        slot: allocate the sequence's full block run, write the prompt
-        rows, publish them for prefix reuse."""
-        a = self.slots[slot].pending
-        now = time.monotonic()
-        bt = self.cfg.block_tokens
-        toks = self._clamped_tokens(a)
-        total = -(-(len(toks) + a.max_new_tokens) // bt)
-        blocks = self.pool.alloc(total)
-        if blocks is None:
-            raise RuntimeError(
-                f"KV pool exhausted landing streamed rid {a.rid}")
+        """Land a streamed prefill in the pool and activate the slot."""
+        s = self.slots[slot]
         image = np.frombuffer(bytes(img.data),
                               np.dtype(img.dtype)).reshape(img.shape)
-        nblk = image.shape[1]
-        self._insert_blocks(blocks[:nblk], image)
-        self._publish_prompt(toks, blocks)
-        row = np.full(self.cfg.table_width, self._sink, np.int32)
-        row[:total] = blocks
-        self._tables[slot] = row
-        remaining = self.slots[slot].remaining
+        self.cache.land(slot, self._clamped_tokens(s.pending),
+                        s.pending.max_new_tokens, image)
         self._last_tokens[slot] = img.first
         self.slots[slot] = dataclasses.replace(
-            self.slots[slot], remaining=remaining - 1,
-            generated=[img.first], blocks=blocks, seq_len=img.cursor,
-            pending=None, pending_since=0.0)
+            s, remaining=s.remaining - 1, generated=[img.first],
+            seq_len=img.cursor, pending=None, pending_since=0.0)
 
     # -- decode ----------------------------------------------------------
     def _decode_once(self, parts: StepParts) -> tuple[list[int], Any]:
@@ -827,25 +509,8 @@ class ReplicaExecutor:
                       and s.remaining > 0]
             if not active:
                 return active, None
-            if self.cfg.paged:
-                bt = self.cfg.block_tokens
-                for i in active:
-                    s = self.slots[i]
-                    # COW guard: the write position may sit in a
-                    # published tail (the first divergent write of a
-                    # shared prefix).
-                    if self._ensure_writable(s.blocks, s.seq_len // bt):
-                        self._tables[i][s.seq_len // bt] = \
-                            s.blocks[s.seq_len // bt]
-                    self._cursors[i] = s.seq_len
-                nxt, self._cache = self._paged_jit(
-                    self.params, self._cache,
-                    jnp.asarray(self._last_tokens[:, None]),
-                    jnp.asarray(self._tables), jnp.asarray(self._cursors))
-            else:
-                nxt, self._cache = self._decode_jit(
-                    self.params, self._cache,
-                    jnp.asarray(self._last_tokens[:, None]))
+            nxt = self.cache.decode(self.params, self._last_tokens,
+                                    active, self.slots)
         with parts("token_fetch"):
             return active, np.asarray(nxt)     # waits for the device
 
@@ -886,14 +551,9 @@ class ReplicaExecutor:
             self._release_slot(i)
 
     def _release_slot(self, i: int) -> None:
-        s = self.slots[i]
-        if self.cfg.paged and s is not None:
-            for b in s.blocks:
-                self.pool.deref(b)
-            self._tables[i] = self._sink
-            self._cursors[i] = 0
-            if self._kvstream is not None:
-                self._kvstream.discard(s.rid)
+        self.cache.release(i)
+        if self._kvstream is not None:
+            self._kvstream.discard(self.slots[i].rid)
         self.slots[i] = None
 
     def _exchange_completions(self) -> list[dict]:
@@ -1178,7 +838,7 @@ class ReplicaExecutor:
                 return False
             admits = self._apply_plan(plan, parts)
             if not self.is_prefill:
-                if self.cfg.paged and self.prefill_rank_list:
+                if self.prefill_rank_list:
                     self._integrate_prefills()
                 active, nxt = self._decode_once(parts)
                 with parts("slot_update"):
@@ -1338,7 +998,7 @@ class ReplicaExecutor:
                 continue
             a = s.pending
             self.slots[i] = None
-            self._prefill_slot_paged(i, a, now)
+            self._prefill_slot(i, a, now)
             self.stats["prefill_fallbacks"] += 1
 
     def _resync(self) -> None:
@@ -1369,37 +1029,26 @@ class ReplicaExecutor:
             self.stats["lost"] += len(lost)
 
     # -- introspection / teardown ----------------------------------------
-    def inflight_rids(self) -> list[int]:
-        return sorted(s.rid for s in self.slots if s is not None)
-
     def request_stop(self) -> None:
         self._stop_requested = True
 
     def kv_stats(self) -> dict | None:
         """The paged pool's residency/reuse numbers for reports and the
         leak census (None in dense mode)."""
-        if self.pool is None:
-            return None
-        return {"pool_blocks": self.pool.num_blocks,
-                "block_tokens": self.pool.block_tokens,
-                "free": self.pool.free_count(),
-                "active": self.pool.active_count(),
-                "cached": self.pool.cached_count(),
-                "prefix_hits": self.pool._m_hits.value,
-                "prefix_misses": self.pool._m_misses.value,
-                "evictions": self.pool._m_evicted.value,
-                "cow_copies": self.pool._m_cow.value,
-                "max_concurrent_seqs": self.batcher.max_concurrent,
-                "prefill_streams": self.stats["prefill_streams"],
-                "prefill_fallbacks": self.stats["prefill_fallbacks"],
-                "prefill_skipped": self.stats["prefill_skipped"]}
+        kv = self.cache.kv_stats()
+        if kv is not None:
+            kv["max_concurrent_seqs"] = self.batcher.max_concurrent
+            for key in ("prefill_streams", "prefill_fallbacks",
+                        "prefill_skipped"):
+                kv[key] = self.stats[key]
+        return kv
 
     def close(self) -> None:
         """Release the serving resources this executor owns: the
-        kvstream mesh (drain threads + sockets) and the KV block pool
-        (hvdlife HVD702/704 — the pool must not outlive the executor
-        across elastic reinit cycles).  Leaves the part timers' totals
-        and the slow steps in the log."""
+        kvstream mesh (drain threads + sockets) and the slot cache (its
+        KV block pool must not outlive the executor across elastic
+        reinit cycles: hvdlife HVD702/704).  Leaves the part timers'
+        totals and the slow steps in the log."""
         if any(self.stats["steps"].values()):
             logger.info("serving: step parts %s", json.dumps(
                 {key: self.stats[key]
@@ -1411,8 +1060,7 @@ class ReplicaExecutor:
         if self._kvstream is not None:
             self._kvstream.close()
             self._kvstream = None
-        if self.pool is not None:
-            self.pool.close()
+        self.cache.close()
 
 
 # Slow-step records (``stats["slow_steps"]``): a step is slow when its
@@ -1424,16 +1072,11 @@ _MIN_RECENT_STEPS = 8
 _SLOW_STEPS_KEPT = 32
 
 
-def _sample(logits):
-    """Greedy sampling: the arg-max over the vocabulary axis."""
-    with jax.named_scope("hvd.sample"):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
 def _decode_model_cfg(cfg: ServeConfig):
     model_cfg = cfg.model_cfg
     if model_cfg is None:
-        model_cfg = tfm.gpt_tiny(dtype=jnp.float32)
+        from ..models.transformer import gpt_tiny
+        model_cfg = gpt_tiny(dtype=jnp.float32)
     return dataclasses.replace(model_cfg, decode=True,
                                max_seq_len=cfg.max_seq)
 

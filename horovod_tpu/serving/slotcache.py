@@ -1,0 +1,382 @@
+"""The slot cache of one replica: where the keys, values and recurrent
+state of its in-flight requests live on the device, in one of two
+layouts behind one interface.
+
+``ReplicaExecutor`` picks a class once, from ``ServeConfig.paged``, and
+from then on asks only: ``warm`` (compile every program, note how much
+of the cache the decode program updates in place), ``admit`` (prefill a
+prompt into a slot, return its first token), ``decode`` (enqueue one
+step for the slot array), ``release``, ``fresh``, ``kv_stats``,
+``close``, and ``block_capacity`` for the batcher.  ``tree`` is the
+cache itself: every program that writes it takes it donated and its
+result is rebound, so nobody else may hold it.  Both classes reach the
+model through its family (``models/family.py``); the leaves of either
+layout are ``models/kvcache.py``'s.
+
+- :class:`DenseSlotCache`: the slot on axis 0 of every leaf; an
+  admission prefills one row and inserts it as row ``slot``.
+- :class:`PagedSlotCache` (ISSUE 14): blocks from a
+  :class:`~.kvpool.KVBlockPool` addressed through per-slot block tables,
+  so live tokens, not the batch shape, bound concurrency; prefix reuse
+  by content address, copy-on-write, LRU eviction.  It alone offers what
+  disaggregated prefill streams: ``holds_prompt``, ``prefill_image``,
+  ``land``.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..common.logging import logger
+from ..telemetry.spans import span
+from .kvpool import FNV_SEED, KVBlockPool, chain_hash
+
+
+def prompt_bucket(cfg, n: int) -> int:
+    """The compiled prefill shape ``n`` prompt tokens pad to."""
+    return min(max(8, 1 << max(0, (n - 1)).bit_length()), cfg.max_seq)
+
+
+def _padded(cfg, toks: list) -> np.ndarray:
+    padded = np.zeros((1, prompt_bucket(cfg, len(toks))), np.int32)
+    padded[0, :len(toks)] = toks
+    return padded
+
+
+def _sample(logits):
+    """Greedy sampling: the arg-max over the vocabulary axis."""
+    with jax.named_scope("hvd.sample"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+class _SlotCache:
+    """What the layouts share: warm-up and the aliasing counters."""
+    block_capacity = 0         # blocks the batcher reserves from; 0 = none
+
+    def __init__(self, cfg, family, model, stats: dict) -> None:
+        self.cfg, self.family, self.model = cfg, family, model
+        self.stats = stats             # the executor's own
+        # Jitted like every other model call here: un-jitted, each of its
+        # hundreds of small ops compiles and dispatches on its own.
+        self._init_cache_jit = jax.jit(self._init_cache_impl)
+        self.tree = None
+
+    def fresh(self, params) -> None:
+        self.tree = self._init_cache_jit(params)
+
+    def warm(self, params, last_tokens: np.ndarray) -> None:
+        """Compile every program the serve loop runs.  Each of them takes
+        the cache donated, so each call's result is rebound: the leaves
+        a program was given are deleted once it is enqueued."""
+        for bucket in self.cfg.warmup_buckets:
+            if bucket <= self.cfg.max_seq:
+                self._warm_prefill(params, [0] * bucket)
+        decode, args = self._decode_call(params, last_tokens)
+        self._note_cache_aliasing(decode.lower(*args).compile())
+        nxt, self.tree = decode(*args)
+        jax.block_until_ready(nxt)
+        self.tree = None               # one copy at a time
+        self.fresh(params)             # discard warmup cache writes
+
+    def decode(self, params, last_tokens: np.ndarray, active: list,
+               slots: list):
+        """Enqueue one step for the whole slot array; the next tokens
+        stay on the device."""
+        decode, args = self._decode_call(params, last_tokens)
+        nxt, self.tree = decode(*args)
+        return nxt
+
+    def _note_cache_aliasing(self, decode_program) -> None:
+        """How much of the cache the compiled decode program updates in
+        place: the cache is its only donated argument, so what it
+        aliases from input to output is cache."""
+        leaves = jax.tree_util.tree_flatten_with_path(self.tree)[0]
+        stats = self.stats
+        stats["cache_bytes"] = sum(leaf.nbytes for _, leaf in leaves)
+        stats["state_bytes"] = sum(
+            leaf.nbytes for path, leaf in leaves
+            if path[-1].key in self.family.state_leaves)
+        stats["kv_bytes"] = stats["cache_bytes"] - stats["state_bytes"]
+        stats["cache_aliased_bytes"] = \
+            decode_program.memory_analysis().alias_size_in_bytes
+        logger.info("serving: slot cache %.2f of %.2f GB aliased by the "
+                    "decode program (%d of %d bytes)",
+                    stats["cache_aliased_bytes"] / 1e9,
+                    stats["cache_bytes"] / 1e9,
+                    stats["cache_aliased_bytes"], stats["cache_bytes"])
+
+    def release(self, slot: int) -> None:
+        """Nothing to give back: the next admission replaces the row."""
+
+    def kv_stats(self) -> dict | None:
+        return None
+
+    def close(self) -> None:
+        pass
+
+
+class DenseSlotCache(_SlotCache):
+    """The family's dense cache of ``cfg.slots`` rows."""
+
+    def __init__(self, cfg, family, model, stats: dict) -> None:
+        super().__init__(cfg, family, model, stats)
+        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=1)
+        self._prefill_jit = jax.jit(self._prefill_impl)
+        self._insert_jit = jax.jit(self._insert_impl, donate_argnums=0)
+
+    def _decode_impl(self, params, cache, tokens):
+        logits, cache = self.family.decode_step(
+            self.model, {"params": params}, cache, tokens)
+        return _sample(logits[:, -1, :]), cache
+
+    def _prefill_impl(self, params, tokens, n):
+        logits, cache = self.family.prefill(
+            self.model, {"params": params}, tokens, lengths=n)
+        return _sample(logits[0, n - 1, :]), cache
+
+    @staticmethod
+    def _insert_impl(cache, cache1, slot):
+        """Row ``slot`` of every leaf of the slot cache becomes the
+        prefilled request's only row (keys, values, write cursor and a
+        family's recurrent state alike: nothing of the slot's last
+        occupant is left); ``slot`` is traced: one program for all."""
+        return jax.tree_util.tree_map(
+            lambda big, small: jax.lax.dynamic_update_slice_in_dim(
+                big, small, slot, axis=0), cache, cache1)
+
+    def _init_cache_impl(self, params):
+        return self.family.fresh_cache(self.model, params, self.cfg.slots)
+
+    def _warm_prefill(self, params, toks: list) -> None:
+        self.admit(params, 0, toks, 1)   # the insert compiles once
+
+    def _decode_call(self, params, last_tokens):
+        return self._decode_jit, (params, self.tree,
+                                  jnp.asarray(last_tokens[:, None]))
+
+    def admit(self, params, slot: int, toks: list, max_new: int) -> int:
+        with span("serve.prefill_dispatch"):
+            first, cache1 = self._prefill_jit(
+                params, jnp.asarray(_padded(self.cfg, toks)),
+                jnp.int32(len(toks)))
+        with span("serve.cache_insert"):     # a dispatch: nothing waits
+            self.tree = self._insert_jit(self.tree, cache1, np.int32(slot))
+        with span("serve.first_token_fetch"):
+            return int(first)          # waits for the device
+
+
+class PagedSlotCache(_SlotCache):
+    """The block pool (id bookkeeping), the per-slot block tables and
+    cursors (the model's addressing arguments), each slot's block list
+    (physical ids in logical order, each held once by the slot) and the
+    pools themselves (``tree``)."""
+
+    def __init__(self, cfg, family, model, stats: dict) -> None:
+        super().__init__(cfg, family, model, stats)
+        self._sink = self.block_capacity = cfg.resolved_pool_blocks
+        self.pool = KVBlockPool(self._sink, cfg.block_tokens)
+        self._tables = np.full((cfg.slots, cfg.table_width), self._sink,
+                               np.int32)
+        self._cursors = np.zeros(cfg.slots, np.int32)
+        self._blocks: list[list] = [[] for _ in range(cfg.slots)]
+        self._paged_jit = jax.jit(self._paged_impl, donate_argnums=1)
+        self._paged_prefill_jit = jax.jit(self._paged_prefill_impl,
+                                          donate_argnums=1)
+        self._copy_block_jit = jax.jit(family.paged_copy_block,
+                                       donate_argnums=0)
+
+    def _paged_impl(self, params, cache, tokens, tables, cursors):
+        """One paged decode step for the whole slot array: inactive
+        slots' tables point at the pool sink row, so their writes land
+        in garbage space and their outputs are ignored."""
+        logits, cache = self.family.paged_apply(
+            self.model, {"params": params}, cache, tokens, tables,
+            cursors)
+        return _sample(logits[:, -1, :]), cache
+
+    def _paged_prefill_impl(self, params, cache, tokens, table, cursor,
+                            n):
+        """Paged prefill of ONE request (B=1) straight into the shared
+        pool through the slot's block table; ``cursor`` > 0 resumes
+        past prefix-cache hits and ``n`` masks the padded tail."""
+        logits, cache = self.family.paged_apply(
+            self.model, {"params": params}, cache, tokens, table,
+            cursor, lengths=n)
+        return _sample(logits[0, n[0] - 1, :]), cache
+
+    def _init_cache_impl(self, params):
+        """One apply creates the pools; its only write is the sink's."""
+        return self.family.paged_apply(
+            self.model, {"params": params}, {},
+            jnp.zeros((1, 1), jnp.int32), self._row([])[None],
+            jnp.zeros((1,), jnp.int32))[1]
+
+    def _row(self, blocks: list) -> np.ndarray:
+        """A block table row: ``blocks``, then the sink."""
+        row = np.full(self.cfg.table_width, self._sink, np.int32)
+        row[:len(blocks)] = blocks
+        return row
+
+    def _prefill(self, params, toks: list, blocks: list, pos: int):
+        """Enqueue the prefill of ``toks[pos:]`` through ``blocks``."""
+        rem = toks[pos:]
+        first, self.tree = self._paged_prefill_jit(
+            params, self.tree, jnp.asarray(_padded(self.cfg, rem)),
+            jnp.asarray(self._row(blocks)[None]),
+            jnp.asarray([pos], np.int32), jnp.asarray([len(rem)], np.int32))
+        return first
+
+    def _warm_prefill(self, params, toks: list) -> None:
+        jax.block_until_ready(self._prefill(params, toks, [], 0))
+
+    def _decode_call(self, params, last_tokens):
+        return self._paged_jit, (
+            params, self.tree, jnp.asarray(last_tokens[:, None]),
+            jnp.asarray(self._tables), jnp.asarray(self._cursors))
+
+    # -- prefix cache ----------------------------------------------------
+    def _lookup_prefix(self, toks: list) -> tuple[list, int]:
+        """Walk the prompt's block chain through the prefix cache: (hit
+        block ids, their refcounts already bumped; tokens covered)."""
+        bt = self.cfg.block_tokens
+        parent = FNV_SEED
+        hits: list[int] = []
+        pos = 0
+        while pos < len(toks):
+            seg = toks[pos:pos + bt]
+            blk = self.pool.lookup(parent, seg)
+            if blk is None:
+                break
+            hits.append(blk)
+            parent = chain_hash(parent, seg)
+            pos += len(seg)
+        return hits, pos
+
+    def _publish_prompt(self, toks: list, blocks: list) -> None:
+        """Content-address every prompt block (full ones and the partial
+        tail) so later identical prefixes hit.  Publishing makes a block
+        immutable: the next write into the tail copies it first."""
+        bt = self.cfg.block_tokens
+        parent = FNV_SEED
+        for i in range(0, len(toks), bt):
+            parent = self.pool.publish(blocks[i // bt], parent,
+                                       toks[i:i + bt])
+
+    def _ensure_writable(self, slot_blocks: list, j: int) -> bool:
+        """COW guard before writing into logical block ``j``: a shared
+        or published block gets a private copy (pool ids + tensor rows)
+        and the slot's list repoints.  True when a copy happened."""
+        old = slot_blocks[j]
+        new, copied = self.pool.cow(old)
+        if copied:
+            self.tree = self._copy_block_jit(
+                self.tree, jnp.int32(old), jnp.int32(new))
+            slot_blocks[j] = new
+        return copied
+
+    # -- the interface ---------------------------------------------------
+    def admit(self, params, slot: int, toks: list, max_new: int) -> int:
+        bt = self.cfg.block_tokens
+        hits, pos = self._lookup_prefix(toks)
+        if pos >= len(toks):
+            # Whole prompt resident: no prefill, just the last prompt
+            # token again for the next-token logits (its K/V rewrite is
+            # value-identical; COW below keeps shared blocks untouched).
+            pos = len(toks) - 1
+            self.stats["prefill_skipped"] += 1
+        blocks = self._block_run(slot, toks, max_new, hits)
+        self._ensure_writable(blocks, pos // bt)
+        with span("serve.prefill_dispatch"):
+            first = self._prefill(params, toks, blocks, pos)
+        # The program wrote the pool rows itself; the host's part of the
+        # insert: publish the blocks, point the slot's table at them.
+        with span("serve.cache_insert"):
+            self._publish_prompt(toks, blocks)
+            self._point(slot, blocks)
+        with span("serve.first_token_fetch"):
+            return int(first)          # waits for the device
+
+    def _block_run(self, slot: int, toks: list, max_new: int,
+                   hits: list) -> list:
+        """The sequence's full block run: ``hits``, then fresh blocks."""
+        total = -(-(len(toks) + max_new) // self.cfg.block_tokens)
+        fresh = self.pool.alloc(total - len(hits))
+        if fresh is None:
+            # The front end reserves worst-case blocks per admission, so
+            # this is unreachable unless accounting drifted; fail loud.
+            for b in hits:
+                self.pool.deref(b)
+            raise RuntimeError(
+                f"KV pool exhausted admitting into slot {slot}: "
+                f"{self.pool.free_count()} free of {self.pool.num_blocks}")
+        return hits + fresh
+
+    def _point(self, slot: int, blocks: list) -> None:
+        self._blocks[slot] = blocks
+        self._tables[slot] = self._row(blocks)
+
+    def decode(self, params, last_tokens: np.ndarray, active: list,
+               slots: list):
+        bt = self.cfg.block_tokens
+        for i in active:
+            j = slots[i].seq_len // bt
+            # COW guard: the write position may sit in a published tail
+            # (the first divergent write of a shared prefix).
+            if self._ensure_writable(self._blocks[i], j):
+                self._tables[i][j] = self._blocks[i][j]
+            self._cursors[i] = slots[i].seq_len
+        return super().decode(params, last_tokens, active, slots)
+
+    def release(self, slot: int) -> None:
+        for b in self._blocks[slot]:
+            self.pool.deref(b)
+        self._point(slot, [])
+        self._cursors[slot] = 0
+
+    def kv_stats(self) -> dict:
+        """The pool's residency and reuse, for reports and the census."""
+        pool = self.pool
+        return {"pool_blocks": pool.num_blocks,
+                "block_tokens": pool.block_tokens,
+                "free": pool.free_count(), "active": pool.active_count(),
+                "cached": pool.cached_count(),
+                "prefix_hits": pool._m_hits.value,
+                "prefix_misses": pool._m_misses.value,
+                "evictions": pool._m_evicted.value,
+                "cow_copies": pool._m_cow.value}
+
+    def close(self) -> None:
+        """The pool must not outlive the executor (hvdlife HVD702/704)."""
+        self.pool.close()
+
+    # -- disaggregated prefill (serving/kvstream.py) ---------------------
+    def holds_prompt(self, toks: list) -> bool:
+        """Whether the prefix cache covers the whole prompt (no block
+        stays referenced: ``admit`` looks the prompt up again)."""
+        hits, pos = self._lookup_prefix(toks)
+        for b in hits:
+            self.pool.deref(b)
+        return pos >= len(toks)
+
+    def prefill_image(self, params, toks: list) -> tuple[int, np.ndarray]:
+        """Prefill-rank half: the prompt's blocks computed in the local
+        scratch pool (identity table): (first token, the pool rows across
+        every layer [n_leaves, nblk, bt, H, D], ready to serialize)."""
+        nblk = -(-len(toks) // self.cfg.block_tokens)
+        first = self._prefill(params, toks, list(range(nblk)), 0)
+        return int(first), np.stack(
+            [np.asarray(node[key][:nblk])
+             for key, node in self.family.paged_pool_leaves(self.tree)])
+
+    def land(self, slot: int, toks: list, max_new: int,
+             image: np.ndarray) -> None:
+        """A streamed prefill into the pool: allocate the sequence's
+        block run, write the prompt rows, publish them, point the table."""
+        blocks = self._block_run(slot, toks, max_new, [])
+        idx = jnp.asarray(np.asarray(blocks[:image.shape[1]], np.int32))
+        for i, (key, node) in enumerate(
+                self.family.paged_pool_leaves(self.tree)):
+            node[key] = node[key].at[idx].set(jnp.asarray(image[i]))
+        self._publish_prompt(toks, blocks)
+        self._point(slot, blocks)

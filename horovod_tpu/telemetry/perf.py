@@ -19,8 +19,8 @@ directory argument loads every ``*.json`` under it).  The ledger
 - **lost time**: with ``--timeline``, the PR 7 critical-path phases
   attribute straggler time (telemetry/trace.py) into the ledger.
 
-The merged ledger is what ``telemetry.perfcheck`` gates against and
-what bench.py stamps into every BENCH payload (docs/observability.md).
+The merged ledger is what ``telemetry.perfcheck`` gates against
+(docs/observability.md).
 """
 from __future__ import annotations
 

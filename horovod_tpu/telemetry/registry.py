@@ -296,7 +296,7 @@ class MetricsRegistry:
 
     def snapshot(self) -> dict:
         """JSON-able dump of every metric (the HOROVOD_METRICS_FILE
-        payload and the bench.py metrics attachment)."""
+        payload)."""
         metrics = []
         for (name, _), m in self._sorted_metrics():
             entry: dict = {"name": name, "labels": m.labels}

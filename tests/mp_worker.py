@@ -2216,7 +2216,7 @@ def battery_serving_paged(hvd, rank, size):
         max_batch=4, token_budget=64, max_seq=64, slo_ms=120000.0,
         paged=True, block_tokens=8))
     assert ex.num_groups == size
-    assert ex.cfg.slots == 8 and ex.pool is not None
+    assert ex.cfg.slots == 8 and ex.cache.pool is not None
     n_requests = 24
     if rank == 0:
         rng = _random.Random(7)

@@ -28,6 +28,7 @@ import run as harness  # noqa: E402
 from horovod_tpu.models import hybrid  # noqa: E402
 from horovod_tpu.models import transformer as tfm  # noqa: E402
 from horovod_tpu.ops import ssm  # noqa: E402
+from horovod_tpu.serving.slotcache import prompt_bucket  # noqa: E402
 
 CONFIG = "granite-4.0-h-micro.serve"
 F32 = {"dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}
@@ -319,7 +320,8 @@ def test_a_reused_slot_carries_nothing_of_its_last_occupant(
     decode = jax.jit(lambda p, c, t: family.decode_step(
         model, {"params": p}, c, t))
     for prompt, count, served in zip(prompts, new, streams):
-        padded = np.zeros((1, ex._prompt_bucket(len(prompt))), np.int32)
+        padded = np.zeros((1, prompt_bucket(ex.cfg, len(prompt))),
+                          np.int32)
         padded[0, :len(prompt)] = prompt
         logits, cache = prefill(params, jnp.asarray(padded),
                                 jnp.int32(len(prompt)))
@@ -337,9 +339,9 @@ def test_the_hybrid_programs_carry_the_scope_names(toy, solo_world):
     hvd.sample, and nowhere in the programs themselves."""
     ex = executor(model_config(toy))
     try:
-        decode = ex._decode_jit.lower(
-            ex.params, ex._cache, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
-        prefill = ex._prefill_jit.lower(
+        decode = ex.cache._decode_jit.lower(
+            ex.params, ex.cache.tree, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
+        prefill = ex.cache._prefill_jit.lower(
             ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))
         for program, scopes in (
                 (decode, ("hvd.ssm_update", "hvd.ssm_conv")),
@@ -361,7 +363,7 @@ def test_the_decoders_programs_are_what_they_were(solo_world):
     """TransformerLM is the protocol's first implementation: the replica
     lowers the decode and prefill programs that tfm.decode_step and
     tfm.prefill lower when called directly, as before the protocol."""
-    from horovod_tpu.serving.replica import _sample
+    from horovod_tpu.serving.slotcache import _sample
     ex = executor(None, max_batch=2)
     try:
         assert ex.family is tfm.FAMILY
@@ -378,11 +380,12 @@ def test_the_decoders_programs_are_what_they_were(solo_world):
             return _sample(logits[0, n - 1, :]), cache
 
         tokens = jnp.zeros((2, 1), jnp.int32)
-        assert ex._decode_jit.lower(ex.params, ex._cache, tokens).as_text() \
+        assert ex.cache._decode_jit.lower(
+            ex.params, ex.cache.tree, tokens).as_text() \
             == jax.jit(_decode_impl, donate_argnums=1).lower(
-                ex.params, ex._cache, tokens).as_text()
+                ex.params, ex.cache.tree, tokens).as_text()
         prompt = (jnp.zeros((1, 8), jnp.int32), jnp.int32(3))
-        assert ex._prefill_jit.lower(ex.params, *prompt).as_text() \
+        assert ex.cache._prefill_jit.lower(ex.params, *prompt).as_text() \
             == jax.jit(_prefill_impl).lower(ex.params, *prompt).as_text()
         assert ex.stats["state_bytes"] == 0
         assert ex.stats["kv_bytes"] == ex.stats["cache_bytes"] > 0

@@ -769,9 +769,9 @@ def test_decode_program_carries_the_scope_names():
 
     hvd, ex = _toy_executor(requests=0)
     try:
-        lowered = ex._decode_jit.lower(
-            ex.params, ex._cache, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
-        for program in (lowered, ex._prefill_jit.lower(
+        lowered = ex.cache._decode_jit.lower(
+            ex.params, ex.cache.tree, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
+        for program in (lowered, ex.cache._prefill_jit.lower(
                 ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))):
             named = program.as_text(debug_info=True)
             assert "hvd.decode_attend" in named and "hvd.sample" in named
@@ -791,7 +791,7 @@ def _executor(paged: bool, **kw):
 
 def _cache_leaves(ex):
     import jax
-    return jax.tree_util.tree_leaves(ex._cache)
+    return jax.tree_util.tree_leaves(ex.cache.tree)
 
 
 def _submit(ex, prompts, max_new):
@@ -811,6 +811,7 @@ def _reference(ex):
 
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.serving.replica import _decode_model_cfg
+    from horovod_tpu.serving.slotcache import prompt_bucket
 
     # The dense model: the paged replica's own reads the block pool.
     model = tfm.TransformerLM(_decode_model_cfg(ex.cfg))
@@ -819,7 +820,8 @@ def _reference(ex):
     decode = jax.jit(lambda v, c, t: tfm.decode_step(model, v, c, t))
 
     def stream(prompt, max_new):
-        padded = np.zeros((1, ex._prompt_bucket(len(prompt))), np.int32)
+        padded = np.zeros((1, prompt_bucket(ex.cfg, len(prompt))),
+                          np.int32)
         padded[0, :len(prompt)] = prompt
         logits, cache = prefill(variables, jnp.asarray(padded),
                                 jnp.int32(len(prompt)))
@@ -870,11 +872,11 @@ def test_one_insert_program_writes_any_slot_like_the_eager_insert():
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.serving import ReplicaExecutor
+    from horovod_tpu.serving.slotcache import DenseSlotCache
 
     # The insert needs nothing of an executor, so its compiled programs
     # are shared by every executor of the process: count from here.
-    compiled = jax.jit(ReplicaExecutor._insert_impl,
+    compiled = jax.jit(DenseSlotCache._insert_impl,
                        donate_argnums=0)._cache_size
     before = compiled()
     hvd, ex = _executor(False, max_batch=4, max_seq=96)
@@ -888,8 +890,8 @@ def test_one_insert_program_writes_any_slot_like_the_eager_insert():
                 return rng.integers(1, 60, leaf.shape).astype(leaf.dtype)
             return rng.standard_normal(leaf.shape).astype(leaf.dtype)
 
-        big = jax.tree_util.tree_map(noise, ex._cache)
-        _, row = ex._prefill_jit(
+        big = jax.tree_util.tree_map(noise, ex.cache.tree)
+        _, row = ex.cache._prefill_jit(
             ex.params, jnp.asarray(rng.integers(2, 256, (1, 16)), jnp.int32),
             jnp.int32(11))
         assert any(leaf.dtype == np.int32 and (np.asarray(leaf) == 11).all()
@@ -899,7 +901,7 @@ def test_one_insert_program_writes_any_slot_like_the_eager_insert():
                 lambda b, s: np.asarray(jnp.asarray(b).at[slot].set(s[0])),
                 big, row)
             given = jax.tree_util.tree_map(jnp.asarray, big)
-            got = ex._insert_jit(given, row, np.int32(slot))
+            got = ex.cache._insert_jit(given, row, np.int32(slot))
             assert all(leaf.is_deleted()
                        for leaf in jax.tree_util.tree_leaves(given))
             for g, w, b in zip(*map(jax.tree_util.tree_leaves,
@@ -959,7 +961,7 @@ def test_warmup_leaves_a_live_fresh_cache_and_init_cache_works_again(paged):
     hvd, ex = _executor(paged)
     try:
         fresh = jax.tree_util.tree_map(np.asarray,
-                                       ex._init_cache_jit(ex.params))
+                                       ex.cache._init_cache_jit(ex.params))
         for _ in range(2):
             leaves = _cache_leaves(ex)
             assert leaves and not any(leaf.is_deleted() for leaf in leaves)

@@ -1,0 +1,273 @@
+"""The seam of ISSUE 32: one function attends over the serving cache
+(models/kvcache.py) and one module knows each layout of it
+(serving/slotcache.py).
+
+- the shared attention against the formulas it replaced, written out
+  plainly here (a loop over rows and heads, one softmax at a time);
+- ``ReplicaExecutor`` holds a slot cache of one layout and nothing of the
+  other, and reads ``cfg.paged`` in two places;
+- either layout, driven through the interface alone, generates what
+  ``tfm.prefill`` and ``tfm.decode_step`` generate.
+"""
+from __future__ import annotations
+
+import dataclasses
+import inspect
+import math
+import os
+import types
+from typing import Any
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax import linen as nn
+
+from horovod_tpu.models import kvcache
+from horovod_tpu.models import transformer as tfm
+
+B, T, D = 2, 5, 8
+LENGTHS = (5, 3)                 # true prompt lengths of the two rows
+BLOCK, POOL = 4, 6
+TABLES = ((2, 0, 5), (1, 4, 3))  # M = 3 blocks a row: 12 positions
+THETA = 10000.0
+
+
+class _Layer(nn.Module):
+    """An attention layer that is only its cache: ``through(self, q, k,
+    v, ...)`` is the function under test."""
+    through: Any
+
+    @nn.compact
+    def __call__(self, q, k, v, *extra):
+        return self.through(self, q, k, v, *extra)
+
+
+def _plain(q, keys, values, depth, scale):
+    """One query position, written out: ``q`` [H, D] at position
+    ``depth`` over ``keys`` / ``values`` [S, KV, D], of which positions
+    0..depth are visible; query head h reads key-value head h // group."""
+    heads, kv = q.shape[0], keys.shape[1]
+    out = np.zeros(q.shape, np.float64)
+    for h in range(heads):
+        k = keys[:depth + 1, h // (heads // kv)].astype(np.float64)
+        v = values[:depth + 1, h // (heads // kv)].astype(np.float64)
+        scores = scale * (k @ q[h].astype(np.float64))
+        weights = np.exp(scores - scores.max())
+        out[h] = (weights / weights.sum()) @ v
+    return out
+
+
+def _inputs(heads, kv, dtype):
+    rng = np.random.default_rng(32)
+
+    def draw(t, n):
+        return jnp.asarray(rng.standard_normal((B, t, n, D)), dtype)
+    prompt = draw(T, heads), draw(T, kv), draw(T, kv)
+    step = draw(1, heads), draw(1, kv), draw(1, kv)
+    return prompt, step
+
+
+CASES = {
+    # multi-head, rotary positions, 1/sqrt(d): transformer.Attention
+    "multihead_rotary": dict(heads=4, kv=4, scale=1 / math.sqrt(D),
+                             rotary=True, paged=False),
+    # grouped 4-to-1, no positions, a given scale: hybrid.GroupedAttention
+    "grouped_4to1": dict(heads=8, kv=2, scale=0.37, rotary=False,
+                         paged=False),
+    # the paged gather: the same attention over a pool through tables
+    "paged_gather": dict(heads=4, kv=4, scale=1 / math.sqrt(D),
+                         rotary=True, paged=True),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_shared_attention_is_the_formulas_it_replaced(case, dtype):
+    """A padded prompt of 5 positions (true lengths 5 and 3), then one
+    decode step with each row at its own depth: the step's output is the
+    plain softmax over that row's own keys, in the cache's dtype."""
+    heads, kv, scale, rotary, paged = (CASES[case][key] for key in (
+        "heads", "kv", "scale", "rotary", "paged"))
+    rotate = (lambda x, pos: tfm.apply_rope(x, pos, THETA)) if rotary \
+        else None
+    (q0, k0, v0), (q1, k1, v1) = _inputs(heads, kv, dtype)
+    lengths = jnp.asarray(LENGTHS, jnp.int32)
+    if paged:
+        layer = _Layer(lambda m, q, k, v, cursors, n: kvcache.paged_attention(
+            m, q, k, v, jnp.asarray(TABLES), cursors, n, pool_blocks=POOL,
+            block_tokens=BLOCK, dtype=dtype, scale=scale, rotate=rotate))
+        _, mut = layer.apply({}, q0, k0, v0, jnp.zeros((B,), jnp.int32),
+                             lengths, mutable=["cache"])
+        cache = mut["cache"]
+        out, mut = layer.apply({"cache": cache}, q1, k1, v1, lengths, None,
+                               mutable=["cache"])
+    else:
+        layer = _Layer(lambda m, q, k, v: kvcache.cached_attention(
+            m, q, k, v, max_seq_len=12, dtype=dtype, scale=scale,
+            rotate=rotate))
+        _, mut = layer.apply({}, q0, k0, v0, mutable=["cache"])
+        cache = kvcache._with_cache_index(mut["cache"], lengths)
+        out, mut = layer.apply({"cache": cache}, q1, k1, v1,
+                               mutable=["cache"])
+    assert out.shape == (B, 1, heads, D) and out.dtype == jnp.float32
+
+    for b, depth in enumerate(LENGTHS):
+        # The row's keys and values as the cache holds them: the true
+        # prompt, then this step's, rotated at their absolute positions
+        # and rounded to the cache's dtype.
+        keys = jnp.concatenate([k0[b, :depth], k1[b]])[None]
+        values = jnp.concatenate([v0[b, :depth], v1[b]])
+        query = q1[b][None]
+        if rotary:
+            keys = tfm.apply_rope(keys, jnp.arange(depth + 1), THETA)
+            query = tfm.apply_rope(query, jnp.asarray([depth]), THETA)
+        want = _plain(np.asarray(query[0, 0], np.float32),
+                      np.asarray(keys[0], np.float32),
+                      np.asarray(values, np.float32), depth, scale)
+        np.testing.assert_allclose(np.asarray(out[b, 0]), want, atol=2e-5,
+                                   rtol=2e-5)
+    if paged:
+        # Position p of row b lives at pool[TABLES[b][p // BLOCK], p % BLOCK].
+        pool = np.asarray(mut["cache"]["key_pool"], np.float32)
+        for b, depth in enumerate(LENGTHS):
+            stepped = tfm.apply_rope(k1[b][None], jnp.asarray([depth]), THETA)
+            np.testing.assert_array_equal(
+                pool[TABLES[b][depth // BLOCK], depth % BLOCK],
+                np.asarray(stepped[0, 0], np.float32))
+    else:
+        assert (np.asarray(mut["cache"]["cache_index"])
+                == np.asarray(LENGTHS) + 1).all()
+
+
+# --- the executor holds one layout ------------------------------------------
+def _solo_world():
+    import horovod_tpu as hvd
+    hvd.shutdown()
+    for var in ("HOROVOD_RANK", "HOROVOD_SIZE"):
+        os.environ.pop(var, None)
+    hvd.init()
+    return hvd
+
+
+def _serve_cfg(paged: bool):
+    from horovod_tpu.serving import ServeConfig
+    return ServeConfig.from_env(max_batch=2, token_budget=64, max_seq=64,
+                                slo_ms=60000.0, block_tokens=8, paged=paged)
+
+
+_PAGED_ONLY = ("pool", "_tables", "_cursors", "_sink", "_blocks",
+               "_paged_jit", "_paged_prefill_jit", "_copy_block_jit")
+_DENSE_ONLY = ("_decode_jit", "_prefill_jit", "_insert_jit")
+
+
+def test_the_executor_holds_one_layout_and_asks_for_it_twice():
+    from horovod_tpu.serving import ReplicaExecutor, slotcache
+
+    hvd = _solo_world()
+    try:
+        for paged, kind, absent in (
+                (False, slotcache.DenseSlotCache, _PAGED_ONLY),
+                (True, slotcache.PagedSlotCache, _DENSE_ONLY)):
+            ex = ReplicaExecutor(_serve_cfg(paged))
+            try:
+                assert type(ex.cache) is kind
+                assert not any(hasattr(ex.cache, name) for name in absent)
+                assert not any(hasattr(ex, name)
+                               for name in _PAGED_ONLY + _DENSE_ONLY)
+                assert (ex.kv_stats() is not None) == paged
+                assert ex.batcher.block_capacity \
+                    == (ex.cfg.resolved_pool_blocks if paged else 0)
+            finally:
+                ex.close()
+    finally:
+        hvd.shutdown()
+    source = inspect.getsource(ReplicaExecutor)
+    assert source.count("cfg.paged") == 2
+    for method in (ReplicaExecutor.__init__,
+                   ReplicaExecutor._configure_groups):
+        assert inspect.getsource(method).count("cfg.paged") == 1
+    for name in ("self.pool", "_tables", "_cursors", "_sink"):
+        assert name not in source, name
+
+
+# --- either layout through the interface alone ------------------------------
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
+    """Admit, three decode steps, release, admit again into the same
+    slot (beside a second request that keeps decoding): every token is
+    the one ``tfm.prefill`` and ``tfm.decode_step`` give the request on
+    a cache of its own."""
+    from horovod_tpu.serving import slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg, _seeded_params
+
+    cfg = _serve_cfg(paged)
+    dense_cfg = _decode_model_cfg(cfg)
+    dense = tfm.TransformerLM(dense_cfg)
+    params = _seeded_params(dense, 0)
+    if paged:
+        model = tfm.TransformerLM(dataclasses.replace(
+            dense_cfg, paged=True, kv_pool_blocks=cfg.resolved_pool_blocks,
+            kv_block_tokens=cfg.block_tokens))
+        cache = slotcache.PagedSlotCache(cfg, tfm.FAMILY, model,
+                                         stats := {"prefill_skipped": 0})
+    else:
+        cache = slotcache.DenseSlotCache(cfg, tfm.FAMILY, dense, stats := {})
+    slots = [None] * cfg.slots
+    last = np.zeros(cfg.slots, np.int32)
+    got: dict[int, list] = {}
+
+    def admit(slot, rid, prompt):
+        last[slot] = cache.admit(params, slot, prompt, 8)
+        slots[slot] = types.SimpleNamespace(seq_len=len(prompt), rid=rid)
+        got[rid] = [int(last[slot])]
+
+    def step():
+        active = [i for i, s in enumerate(slots) if s is not None]
+        nxt = np.asarray(cache.decode(params, last, active, slots))
+        for i in active:
+            last[i] = nxt[i]
+            slots[i].seq_len += 1
+            got[slots[i].rid].append(int(nxt[i]))
+
+    def reference(prompt, count):
+        padded = np.zeros((1, slotcache.prompt_bucket(cfg, len(prompt))),
+                          np.int32)
+        padded[0, :len(prompt)] = prompt
+        logits, own = tfm.prefill(dense, {"params": params},
+                                  jnp.asarray(padded),
+                                  lengths=jnp.int32(len(prompt)))
+        out = [int(jnp.argmax(logits[0, len(prompt) - 1]))]
+        while len(out) < count:
+            logits, own = tfm.decode_step(
+                dense, {"params": params}, own,
+                jnp.asarray([[out[-1]]], jnp.int32))
+            out.append(int(jnp.argmax(logits[0, -1])))
+        return out
+
+    prompts = {0: [5, 9, 200, 31, 77, 3, 18, 64, 120],
+               1: [44, 45, 46], 2: [5, 9, 200, 31, 77, 3, 18, 64, 120, 7]}
+    try:
+        cache.fresh(params)
+        cache.warm(params, last)
+        assert stats["cache_aliased_bytes"] == stats["cache_bytes"] > 0
+        admit(0, 0, prompts[0])
+        admit(1, 1, prompts[1])
+        for _ in range(3):
+            step()
+        cache.release(0)
+        slots[0] = None
+        admit(0, 2, prompts[2])        # the same slot, a longer prompt
+        for _ in range(3):
+            step()
+        for rid, prompt in prompts.items():
+            assert got[rid] == reference(prompt, len(got[rid])), rid
+        assert [len(got[rid]) for rid in (0, 1, 2)] == [4, 7, 4]
+        if paged:
+            cache.release(0)
+            cache.release(1)
+            assert cache.kv_stats()["active"] == 0        # nothing leaked
+            assert cache.kv_stats()["prefix_hits"] > 0    # request 2's
+    finally:
+        cache.close()

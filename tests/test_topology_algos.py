@@ -32,7 +32,6 @@ tests/test_multiprocess.py / mp_worker.py.
 """
 from __future__ import annotations
 
-import json
 import os
 import sys
 import threading
@@ -493,41 +492,6 @@ def test_no_per_step_thread_spawn_on_new_algos(kv, monkeypatch):
     assert marker["after"] == marker["before"], \
         (f"{marker['after'] - marker['before']} thread(s) spawned during "
          f"tree/rhd/torus collectives: {spawned[marker['before']:]}")
-
-
-# ---------------------------------------------------------------------------
-# Bench satellite: payload topology stamp
-# ---------------------------------------------------------------------------
-def _load_bench():
-    import importlib.util
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        os.pardir, "bench.py")
-    spec = importlib.util.spec_from_file_location("_bench_under_test",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_payload_topology_algo_stamp(monkeypatch, capsys):
-    """EVERY emitted payload carries the declared topology and algo —
-    env-sourced so even failure payloads from processes that never
-    imported the package are stamped."""
-    bench = _load_bench()
-    monkeypatch.setenv("HOROVOD_TOPOLOGY", "torus:2x2")
-    monkeypatch.setenv("HOROVOD_ALGO", "tree")
-    bench._emit({"metric": "m", "value": 1.0})
-    monkeypatch.delenv("HOROVOD_TOPOLOGY")
-    monkeypatch.delenv("HOROVOD_ALGO")
-    bench._emit({"metric": "m", "value": 1.0})
-    # A leg that knows the runtime-selected value wins over the env.
-    bench._emit({"metric": "m", "value": 1.0, "algo": "rhd"})
-    lines = [json.loads(ln) for ln in
-             capsys.readouterr().out.strip().splitlines()]
-    assert (lines[0]["topology"], lines[0]["algo"]) == ("torus:2x2",
-                                                        "tree")
-    assert (lines[1]["topology"], lines[1]["algo"]) == ("flat", "auto")
-    assert lines[2]["algo"] == "rhd"
 
 
 # ---------------------------------------------------------------------------
