@@ -579,6 +579,12 @@ def init(*, rank: int | None = None, size: int | None = None,
             target=_background_loop, daemon=True, name="hvd-background")
         _global.initialized = True
         _global.background_thread.start()
+        # On a TPU: the kernels' toolchain, imported while the program
+        # loads its weights and not at the first trace of a kernel.
+        from .common import prefetch
+        kernels = prefetch.start_kernel_imports()
+        if kernels is not None:
+            _global.resources.append(kernels)
         # Finalize on interpreter exit like the reference (its library
         # destructor shuts Horovod down when the process ends): a script
         # that returns without calling hvd.shutdown() still flushes the

@@ -11,7 +11,10 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
   shared by every row and addressed through block tables, the last row
   a write sink for padded positions (``paged_attention``, ``paged_*``).
 
-Both write this call's keys and values, then ``attend``.  A family's
+Both write this call's keys and values, then ``attend``; a decode step
+on the dense layout (one query position a row) goes through
+``decode_attend``, which on a TPU reads each row's keys and values up to
+its own live length.  A family's
 attention layer (``transformer.Attention``, ``hybrid.GroupedAttention``)
 brings its projections, its positional encoding and its score scale,
 and writes no cache code of its own.
@@ -22,28 +25,9 @@ import jax
 import jax.numpy as jnp
 from flax.core import unfreeze
 
-
-def attend(q: jax.Array, keys: jax.Array, values: jax.Array, positions,
-           scale: float) -> jax.Array:
-    """``softmax(scale * q k^T + causal mask) v`` in float32: ``q`` [B, T,
-    H, D] at absolute ``positions`` [B|1, T] over ``keys`` / ``values``
-    [B, S, KV, D]; query head ``h`` reads key-value head ``h // (H //
-    KV)`` (a group of 1 is plain multi-head).  Key ``s`` is visible at
-    position ``p`` when ``s <= p``: right-padded prefill garbage and
-    unwritten positions sit past every live query."""
-    b, t, h, d = q.shape
-    kv = keys.shape[2]
-    with jax.named_scope("hvd.decode_attend"):
-        mask = jnp.arange(keys.shape[1])[None, None, :] \
-            <= positions[:, :, None]                           # [B|1, T, S]
-        qf = q.astype(jnp.float32).reshape(b, t, kv, h // kv, d)
-        scores = jnp.einsum("bqkgd,bskd->bkgqs", qf,
-                            keys.astype(jnp.float32)) * scale
-        scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
-        out = jnp.einsum("bkgqs,bskd->bqkgd",
-                         jax.nn.softmax(scores, axis=-1),
-                         values.astype(jnp.float32))
-    return out.reshape(b, t, h, d)
+# The one attention over the cache, in its plain form and as a decode
+# step's kernel (ops/decode_attention.py).
+from ..ops.decode_attention import attend_plain as attend, decode_attend
 
 
 def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -70,6 +54,9 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
     cached_k.value = write(cached_k.value, k.astype(dtype), idx)
     cached_v.value = write(cached_v.value, v.astype(dtype), idx)
     index.value = idx + t
+    if t == 1:     # a decode step: each row up to its own length, no further
+        return decode_attend(q, cached_k.value, cached_v.value, idx + 1,
+                             scale)
     return attend(q, cached_k.value, cached_v.value, positions, scale)
 
 

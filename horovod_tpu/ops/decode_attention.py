@@ -1,0 +1,309 @@
+"""Attention over the dense serving cache, in its two forms.
+
+``softmax(scale * q k^T + causal mask) v`` in float32, query head ``h``
+reading key-value head ``h // (H // KV)``:
+
+- ``attend_plain``: any number of query positions, in plain
+  ``jax.numpy``; it reads every cached position and masks the dead ones
+  afterwards.  Prefill, the paged layout and every backend but the TPU
+  run it, and it is the kernel's reference.
+- ``decode_attend``: one query position a slot (a decode step).  On a
+  TPU it is one Pallas kernel, named ``hvd.decode_attend`` the way the
+  flash kernels carry their names (a device trace selects an operation
+  by ``<opcode> <name>`` only), which reads each slot's keys and values
+  in blocks **up to that slot's own live length and no further**: the
+  lengths are a runtime operand (scalar prefetch), the index of a block
+  past a slot's length repeats the one before it, so nothing is
+  fetched for it, and its arithmetic is skipped.  One compiled program
+  serves every mix of lengths.
+
+The kernel takes the cache leaves as they lie, ``[B, S, KV, D]``: merged
+to ``[B, S, KV * D]`` they would be copied on the device every step (the
+tiles of the last two dimensions differ).  A block of positions is read
+as ``[block * KV, D]``, a row a (position, head) pair, and stays bfloat16
+up to the matrix unit.  Scores for all heads come from one product of
+the queries with those rows, of which each query keeps the columns of
+its own key-value head; the softmax runs with (position, head) along the
+lanes; the weights, spread back over the query rows, meet the values in
+one product the other way.  A float32 operand (the softmax weights, a
+float32 query) goes through the matrix unit as three bfloat16 pieces
+that sum to it exactly, the products accumulated in float32: nothing is
+rounded that ``attend_plain`` does not round.  The online softmax across
+blocks is ``ops/flash_attention.py``'s.
+
+``block_positions`` is the one rule for the block's length, and
+``read_positions`` the count of what the compiled path reads for a set
+of lengths: the serving replica's ``attend_read_positions`` counter is
+the latter, so it cannot drift from the kernel.  ``interpret=True`` runs
+the kernel interpreted (the unit tests).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+NEG_INF = -1e30
+_LANE = 128
+# One key block in VMEM.  Keys and values, each double-buffered, are four
+# of them.  Measured on a v5e at the 7B shape (16 slots of 4,096, live
+# lengths 34 to 1,699): blocks of 128 and of 256 positions take the same
+# time where the mean length is 585 (0.331 and 0.335 ms a layer), and the
+# smaller block reads less of the cache past a short context (0.157
+# against 0.184 ms where every slot holds one position); 512 is slower
+# (0.405).  A grid step that finds its block dead costs about 0.3 us.
+_BLOCK_BYTES = 1 << 20
+_VMEM_BYTES = 32 << 20     # those, and the scores and weights of a block
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+# ---------------------------------------------------------------------------
+# The plain form
+# ---------------------------------------------------------------------------
+def attend_plain(q: jax.Array, keys: jax.Array, values: jax.Array,
+                 positions, scale: float) -> jax.Array:
+    """``q`` [B, T, H, D] at absolute ``positions`` [B|1, T] over
+    ``keys`` / ``values`` [B, S, KV, D] (a group of 1 is plain
+    multi-head).  Key ``s`` is visible at position ``p`` when ``s <= p``:
+    right-padded prefill garbage and unwritten positions sit past every
+    live query."""
+    b, t, h, d = q.shape
+    kv = keys.shape[2]
+    with jax.named_scope("hvd.decode_attend"):
+        mask = jnp.arange(keys.shape[1])[None, None, :] \
+            <= positions[:, :, None]                           # [B|1, T, S]
+        qf = q.astype(jnp.float32).reshape(b, t, kv, h // kv, d)
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", qf,
+                            keys.astype(jnp.float32)) * scale
+        scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
+        out = jnp.einsum("bkgqs,bskd->bqkgd",
+                         jax.nn.softmax(scores, axis=-1),
+                         values.astype(jnp.float32))
+    return out.reshape(b, t, h, d)
+
+
+# ---------------------------------------------------------------------------
+# What the kernel reads
+# ---------------------------------------------------------------------------
+def block_positions(max_seq: int, kv_heads: int, head_dim: int,
+                    dtype) -> int:
+    """How many positions one block of the kernel holds for leaves
+    ``[B, max_seq, kv_heads, head_dim]`` of ``dtype``: the largest
+    power of two that divides ``max_seq`` and keeps a key block within
+    ``_BLOCK_BYTES`` (128 positions of the 7B shape).  0, and the plain
+    form runs, where the kernel cannot take the leaves as they lie: it
+    wants bfloat16, a head width of whole lanes (a narrower leaf lies
+    position-minor on the device, ``{1,3,2,0}``, and would be copied
+    every step) and key-value heads that fill whole sublane tiles and
+    divide the lanes."""
+    if jnp.dtype(dtype) != jnp.bfloat16 or head_dim % _LANE \
+            or kv_heads % 16 or _LANE % kv_heads:
+        return 0
+    row = kv_heads * head_dim * 2
+    fit = [n for n in (8 << i for i in range(max_seq.bit_length()))
+           if max_seq % n == 0 and n * row <= _BLOCK_BYTES]
+    return max(fit, default=0)
+
+
+def kernel_block(shape: tuple, dtype, interpret: bool = False) -> int:
+    """The block the compiled decode path reads cache leaves of
+    ``shape`` in, 0 where it runs the plain form: the choice
+    ``decode_attend`` makes, for whoever counts what it reads."""
+    if not (_on_tpu() or interpret):
+        return 0
+    _, max_seq, kv, d = shape
+    return block_positions(max_seq, kv, d, dtype)
+
+
+def read_positions(lengths, max_seq: int, block: int) -> int:
+    """Positions the decode path reads for slots of live ``lengths``:
+    each length rounded up to the kernel's ``block``; with no kernel
+    (``block`` 0), ``max_seq`` a slot."""
+    lengths = np.asarray(lengths, np.int64)
+    if not block:
+        return int(lengths.size) * max_seq
+    return int((-(-np.clip(lengths, 1, max_seq) // block)).sum()) * block
+
+
+# ---------------------------------------------------------------------------
+# The kernel
+# ---------------------------------------------------------------------------
+def _pieces(x: jax.Array) -> list:
+    """``x`` as bfloat16 arrays that sum to it exactly: itself if it is
+    bfloat16, else the three successive roundings of a float32."""
+    if x.dtype == jnp.bfloat16:
+        return [x]
+    rest, out = x.astype(jnp.float32), []
+    for _ in range(3):
+        out.append(rest.astype(jnp.bfloat16))
+        rest = rest - out[-1].astype(jnp.float32)
+    return out
+
+
+def _fold(x: jax.Array, rows: int) -> jax.Array:
+    """The sum of ``x``'s consecutive groups of ``rows`` rows."""
+    return sum(x[i:i + rows] for i in range(0, x.shape[0], rows))
+
+
+def _attend_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
+                   qp_ref, m_ref, l_ref, acc_ref, *,
+                   scale: float, block: int, kv: int, group: int):
+    """One slot, one block of positions.  A block of a leaf is read as
+    ``[block * KV, D]``, a row a (position, key-value head) pair, which
+    is how it lies; query rows are ordered (group member, key-value
+    head).  Scores come out ``[H, block * KV]``, every query against
+    every pair: a query's own are the columns of its key-value head
+    (``mine`` below), and a sum down the rows leaves them ``[group, block
+    * KV]`` with the lane ``position * KV + head``, the layout the
+    softmax runs in.  The weights go back the same way: spread over the
+    rows, masked by ``mine``, one product with the values.  Written
+    with few operations: the kernel is lowered in every process that
+    serves, and its lowering is set-up time."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    j = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+    h = kv * group
+    lanes = block * kv
+    d = k_ref.shape[-1]
+    # [H, 128] and [H, lanes]: whether a lane is of the key-value head
+    # that a query row reads (lane % KV == row % KV; 128 % KV == 0).
+    own = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (h, _LANE), 0),
+                      kv) \
+        == jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (h, _LANE), 1),
+                       kv)
+    mine = jnp.tile(own, (1, lanes // _LANE))
+
+    def spread(x):
+        """``x`` [group, n], a row a group member, to the query rows."""
+        return jnp.repeat(x, kv, axis=0) if group > 1 \
+            else jnp.broadcast_to(x, (h, x.shape[1]))
+
+    def a_head(x):
+        """``x`` [group, 128], lane ``c`` a partial result of head ``c %
+        KV``, to the column [H, 1] of each query row's own head."""
+        return jnp.max(jnp.where(own, spread(x), NEG_INF), axis=1,
+                       keepdims=True)
+
+    def all_lanes(x, op):
+        """``x`` [group, block * KV] reduced by ``op`` over positions:
+        [group, 128], lane ``c`` holding the result of head ``c % KV``."""
+        while x.shape[1] > _LANE:       # halves: few operations to lower
+            half = x.shape[1] // 2
+            x = op(x[:, :half], x[:, half:])
+        shift = kv
+        while shift < _LANE:
+            x = op(x, pltpu.roll(x, shift, axis=1))
+            shift *= 2
+        return x
+
+    @pl.when(j == 0)
+    def _init():
+        qp_ref[...] = jnp.concatenate(_pieces(q_ref[0]), axis=0)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < length)
+    def _accumulate():
+        # Pairs (position, head) of this block that are live: all of
+        # them but in the slot's last live block.  Masking every block
+        # costs nothing the chip shows (0.326 against 0.332 ms a layer
+        # for a second, unmasked copy of this body; a ``cond`` around
+        # the values' mask copies the block: 0.429), and lowers once.
+        live = (length - j * block) * kv
+        k = k_ref[0].reshape(lanes, d)
+        v = v_ref[0].reshape(lanes, d)
+        v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0)
+                      < live, v, jnp.zeros_like(v))        # 0 * NaN is NaN
+        s = jax.lax.dot_general(qp_ref[...], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mine, _fold(s, h), 0.0)                  # [H, lanes]
+        s = jnp.sum(s.reshape(group, kv, lanes), axis=1) * scale
+        s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+                      < live, s, NEG_INF)
+        m_prev = m_ref[...]                                  # [group, 128]
+        m_cur = jnp.maximum(m_prev, all_lanes(s, jnp.maximum))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - jnp.tile(m_cur, (1, lanes // _LANE)))
+        l_ref[...] = l_ref[...] * alpha + all_lanes(p, jnp.add)
+        m_ref[...] = m_cur
+        weights = jnp.where(mine, spread(p), 0.0)
+        pv = jnp.dot(jnp.concatenate(_pieces(weights), axis=0), v,
+                     preferred_element_type=jnp.float32)        # [3H, D]
+        acc_ref[...] = acc_ref[...] * a_head(alpha) + _fold(pv, h)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / a_head(l_ref[...])).astype(o_ref.dtype)
+
+
+def _live_block(slot, j, lens, *, block: int) -> tuple:
+    """The block of a leaf that grid step ``(slot, j)`` holds: past the
+    slot's last live block the index repeats, and nothing is fetched."""
+    return (slot, jnp.minimum(j, (lens[slot] - 1) // block), 0, 0)
+
+
+# Jitted, so that the layers of a model, which call it with the same
+# shapes, share one traced and one lowered kernel: lowering it again
+# for every layer was 1 s of a 7B decode program's 1.4 s here, paid at
+# each of warm-up's two lowerings, and 3.5 s of set-up on the chip.
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def _decode_attend_pallas(q, keys, values, lengths, scale, *, block: int,
+                          interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, h, d = q.shape
+    _, s, kv, _ = keys.shape
+    group = h // kv
+    pieces = 1 if q.dtype == jnp.bfloat16 else 3
+
+    leaf = pl.BlockSpec((1, block, kv, d),
+                        functools.partial(_live_block, block=block))
+    head = pl.BlockSpec((1, h, d), lambda slot, j, lens: (slot, 0, 0))
+    # Query rows by (group member, key-value head): head kv * group + g.
+    rows = q.reshape(b, kv, group, d).swapaxes(1, 2).reshape(b, h, d)
+    out = pl.pallas_call(
+        functools.partial(_attend_kernel, scale=scale, block=block, kv=kv,
+                          group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, s // block),
+            in_specs=[head, leaf, leaf],
+            out_specs=head,
+            scratch_shapes=[
+                pltpu.VMEM((pieces * h, d), jnp.bfloat16),
+                pltpu.VMEM((group, _LANE), jnp.float32),
+                pltpu.VMEM((group, _LANE), jnp.float32),
+                pltpu.VMEM((h, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+        name="hvd.decode_attend",
+    )(lengths, rows, keys, values)
+    return out.reshape(b, group, kv, d).swapaxes(1, 2).reshape(b, 1, h, d)
+
+
+def decode_attend(q: jax.Array, keys: jax.Array, values: jax.Array,
+                  lengths: jax.Array, scale: float, *,
+                  interpret: bool = False) -> jax.Array:
+    """One decode step's attention: ``q`` [B, 1, H, D] over the first
+    ``lengths`` [B] positions of ``keys`` / ``values`` [B, S, KV, D],
+    float32 [B, 1, H, D].  The kernel on a TPU or interpreted, where
+    ``kernel_block`` finds it a block; the plain form elsewhere."""
+    block = kernel_block(keys.shape, keys.dtype, interpret)
+    lengths = jnp.clip(lengths.astype(jnp.int32), 1, keys.shape[1])
+    if not block:
+        return attend_plain(q, keys, values, lengths[:, None] - 1, scale)
+    with jax.named_scope("hvd.decode_attend"):
+        return _decode_attend_pallas(q, keys, values, lengths, scale,
+                                     block=block, interpret=interpret)

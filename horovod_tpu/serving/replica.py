@@ -222,6 +222,11 @@ class ReplicaExecutor:
                       # state_bytes (a family's recurrent state).
                       "cache_bytes": 0, "cache_aliased_bytes": 0,
                       "kv_bytes": 0, "state_bytes": 0,
+                      # Decode attention, summed over decode dispatches:
+                      # the active slots' live contexts, and the positions
+                      # the compiled path reads for them (a layer).
+                      "attend_live_positions": 0,
+                      "attend_read_positions": 0,
                       # Always-on part timers of the serve step
                       # (telemetry/spans.py), by kind of step: "admit"
                       # steps prefilled at least one request here,

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.logging import logger
+from ..ops import decode_attention
 from ..telemetry.spans import span
 from .kvpool import FNV_SEED, KVBlockPool, chain_hash
 
@@ -53,6 +54,10 @@ def _sample(logits):
 class _SlotCache:
     """What the layouts share: warm-up and the aliasing counters."""
     block_capacity = 0         # blocks the batcher reserves from; 0 = none
+    # What a decode step's attention reads of one slot: ``_attend_span``
+    # positions, or with the kernel (ops/decode_attention.py) the slot's
+    # live length rounded up to ``_attend_block``.
+    _attend_block = 0
 
     def __init__(self, cfg, family, model, stats: dict) -> None:
         self.cfg, self.family, self.model = cfg, family, model
@@ -60,6 +65,7 @@ class _SlotCache:
         # Jitted like every other model call here: un-jitted, each of its
         # hundreds of small ops compiles and dispatches on its own.
         self._init_cache_jit = jax.jit(self._init_cache_impl)
+        self._attend_span = cfg.max_seq
         self.tree = None
 
     def fresh(self, params) -> None:
@@ -85,6 +91,17 @@ class _SlotCache:
         stay on the device."""
         decode, args = self._decode_call(params, last_tokens)
         nxt, self.tree = decode(*args)
+        # This step's write is read too: a slot's live context is its
+        # length after it.
+        lengths = np.fromiter((slots[i].seq_len + 1 for i in active),
+                              np.int64, len(active))
+        stats = self.stats
+        stats["attend_live_positions"] = \
+            stats.get("attend_live_positions", 0) + int(lengths.sum())
+        stats["attend_read_positions"] = \
+            stats.get("attend_read_positions", 0) \
+            + decode_attention.read_positions(lengths, self._attend_span,
+                                              self._attend_block)
         return nxt
 
     def _note_cache_aliasing(self, decode_program) -> None:
@@ -148,6 +165,16 @@ class DenseSlotCache(_SlotCache):
     def _init_cache_impl(self, params):
         return self.family.fresh_cache(self.model, params, self.cfg.slots)
 
+    def fresh(self, params) -> None:
+        super().fresh(params)
+        # The block the decode program's attention reads the key and
+        # value leaves in (one shape for all of a family's layers).
+        self._attend_block, = {
+            decode_attention.kernel_block(leaf.shape, leaf.dtype)
+            for path, leaf in
+            jax.tree_util.tree_flatten_with_path(self.tree)[0]
+            if path[-1].key == "cached_key"}
+
     def _warm_prefill(self, params, toks: list) -> None:
         self.admit(params, 0, toks, 1)   # the insert compiles once
 
@@ -175,6 +202,7 @@ class PagedSlotCache(_SlotCache):
     def __init__(self, cfg, family, model, stats: dict) -> None:
         super().__init__(cfg, family, model, stats)
         self._sink = self.block_capacity = cfg.resolved_pool_blocks
+        self._attend_span = cfg.table_width * cfg.block_tokens
         self.pool = KVBlockPool(self._sink, cfg.block_tokens)
         self._tables = np.full((cfg.slots, cfg.table_width), self._sink,
                                np.int32)

@@ -9,6 +9,7 @@ of the children and the in-process compiles.
 from __future__ import annotations
 
 import importlib
+import math
 import os
 import subprocess
 import sys
@@ -195,6 +196,54 @@ def test_ssm_update_compiles_for_v5e_at_published_shapes(v5e):
     assert compiled.as_text().count(MOSAIC) == 1
     assert compiled.memory_analysis().alias_size_in_bytes \
         == slots * heads * p * n * 4
+
+
+def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
+        v5e, monkeypatch):
+    """``lm7b_serve_chat_sat``'s decode program (deepseek-llm-7b's widths,
+    4 layers, 16 slots of 4,096 positions, bfloat16) compiled for the
+    v5e: one hvd.decode_attend custom call a layer, taking the cache's
+    leaves as they lie, so the program still updates the whole cache in
+    place and holds no copy of a leaf among its temporaries."""
+    from horovod_tpu.models import transformer as tfm
+    from horovod_tpu.ops import decode_attention as da
+    from horovod_tpu.serving import ServeConfig, slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg
+
+    monkeypatch.setattr(da, "_on_tpu", lambda: True)   # the target, not the CPU
+    layers, slots, max_seq = 4, 16, 4096
+    cfg = ServeConfig.from_env(
+        max_batch=slots, max_seq=max_seq, token_budget=1040, paged=False,
+        model_cfg=tfm.TransformerConfig(
+            vocab_size=102400, num_layers=layers, num_heads=32, d_model=4096,
+            d_ff=11008, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    model = tfm.TransformerLM(_decode_model_cfg(cfg))
+    cache = slotcache.DenseSlotCache(cfg, tfm.FAMILY, model, {})
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=v5e), tree)
+
+    params = placed(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    tree = placed(jax.eval_shape(cache._init_cache_impl, params))
+    leaf = 2 * slots * max_seq * 32 * 128            # one key leaf, bytes
+    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(tree))
+    assert cache_bytes == layers * (2 * leaf + slots * 4)
+    compiled = cache._decode_jit.lower(
+        params, tree, placed(jnp.zeros((slots, 1), jnp.int32))).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if MOSAIC in line]
+    assert len(calls) == layers
+    assert all("hvd.decode_attend" in call for call in calls)
+    assert all(call.count("bf16[16,4096,32,128]") == 2 for call in calls)
+    memory = compiled.memory_analysis()
+    # (each layer's 64 bytes of write cursors are a 512-byte tile there)
+    assert memory.alias_size_in_bytes == cache_bytes + layers * (512 - 64)
+    assert memory.temp_size_in_bytes < leaf // 4
 
 
 def test_fit_block_follows_the_tpu_tiling_rule():

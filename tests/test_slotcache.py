@@ -271,3 +271,87 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
             assert cache.kv_stats()["prefix_hits"] > 0    # request 2's
     finally:
         cache.close()
+
+
+# --- the decode kernel through the dense layout (ISSUE 33) ------------------
+@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
+def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
+        kernel, monkeypatch):
+    """A decoder wide enough for hvd.decode_attend (16 heads of 128,
+    bfloat16) through ``DenseSlotCache``: with the kernel interpreted
+    (the entry point's platform check patched, its block cut to 16
+    positions so that slots end in different blocks) every token is the
+    one ``tfm.prefill`` and ``tfm.decode_step`` give with the plain
+    form; ``stats`` counts the live positions and what the compiled path
+    reads for them; one decode program either way."""
+    import functools
+
+    from horovod_tpu.ops import decode_attention as da
+    from horovod_tpu.serving import ServeConfig, slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg, _seeded_params
+
+    cfg = ServeConfig.from_env(
+        max_batch=3, token_budget=64, max_seq=64, slo_ms=60000.0,
+        paged=False, warmup_buckets=(8,),
+        model_cfg=tfm.TransformerConfig(
+            vocab_size=64, num_layers=2, num_heads=16, d_model=2048, d_ff=64,
+            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    model = tfm.TransformerLM(_decode_model_cfg(cfg))
+    params = _seeded_params(model, 0)
+    prompts = {0: list(range(3, 20)), 1: [44, 45, 46], 2: [9] * 31}
+    steps, block = 4, 16
+
+    def reference(prompt):
+        padded = np.zeros((1, slotcache.prompt_bucket(cfg, len(prompt))),
+                          np.int32)
+        padded[0, :len(prompt)] = prompt
+        logits, own = tfm.prefill(model, {"params": params},
+                                  jnp.asarray(padded),
+                                  lengths=jnp.int32(len(prompt)))
+        out = [int(jnp.argmax(logits[0, len(prompt) - 1]))]
+        for _ in range(steps):
+            logits, own = tfm.decode_step(
+                model, {"params": params}, own,
+                jnp.asarray([[out[-1]]], jnp.int32))
+            out.append(int(jnp.argmax(logits[0, -1])))
+        return out
+
+    want = {rid: reference(prompt) for rid, prompt in prompts.items()}
+    if kernel:
+        monkeypatch.setattr(da, "_on_tpu", lambda: True)
+        monkeypatch.setattr(da, "_BLOCK_BYTES", block * 16 * 128 * 2)
+        monkeypatch.setattr(kvcache, "decode_attend", functools.partial(
+            da.decode_attend, interpret=True))
+    cache = slotcache.DenseSlotCache(cfg, tfm.FAMILY, model, stats := {})
+    slots = [None] * cfg.slots
+    last = np.zeros(cfg.slots, np.int32)
+    got = {}
+    try:
+        cache.fresh(params)
+        cache.warm(params, last)
+        assert stats["cache_aliased_bytes"] == stats["cache_bytes"] > 0
+        assert cache._attend_block == (block if kernel else 0)
+        for rid, prompt in prompts.items():
+            last[rid] = cache.admit(params, rid, prompt, 8)
+            slots[rid] = types.SimpleNamespace(seq_len=len(prompt))
+            got[rid] = [int(last[rid])]
+        live = read = 0
+        for _ in range(steps):
+            nxt = np.asarray(cache.decode(params, last, [0, 1, 2], slots))
+            for rid in prompts:
+                after = slots[rid].seq_len + 1
+                live += after
+                read += -(-after // block) * block if kernel else cfg.max_seq
+                last[rid] = nxt[rid]
+                slots[rid].seq_len += 1
+                got[rid].append(int(nxt[rid]))
+        assert got == want
+        assert stats["attend_live_positions"] == live
+        assert stats["attend_read_positions"] == read
+        assert read > live
+        # 18..21 and 4..7 positions read 32 and 16 a step in blocks of
+        # 16; 32 positions read 32, then 33..35 read 48.
+        assert not kernel or read == steps * (32 + 16) + 32 + 3 * 48
+        assert cache._decode_jit._cache_size() == 1
+    finally:
+        cache.close()
