@@ -14,7 +14,8 @@ slot cache (``serving/slotcache.py``) inserts a prefilled request as row
 ``slot`` of every leaf, and donates the whole tree to the decode program.
 
 ``models/transformer.py`` (``TransformerLM``) is the first family,
-``models/hybrid.py`` (``HybridLM``) the second.
+``models/hybrid.py`` (``HybridLM``) the second; with routed experts its
+decode step also counts what only the device knows (``decode_counters``).
 """
 from __future__ import annotations
 
@@ -31,13 +32,20 @@ class ModelFamily:
     fresh_cache: Callable[[Any, Any, int], dict]
     # (model, variables, tokens [B, T], lengths) -> (logits, cache of B rows)
     prefill: Callable[..., tuple]
-    # (model, variables, cache, tokens [B, 1]) -> (logits, cache)
+    # (model, variables, cache, tokens [B, 1]) -> (logits, cache); a
+    # dict given as ``sown`` receives what the layers sowed into the
+    # collections it names (models/kvcache.py:_apply)
     decode_step: Callable[..., tuple]
     # (config, context) -> operations of one generated token
     decode_flops: Callable[[Any, float], float]
     # Names of the cache leaves that hold recurrent state (a fixed size a
     # slot, live until replaced) and not keys and values.
     state_leaves: tuple = ()
+    # Names of what a decode step counts on the device (the model sows
+    # each into its "counters" collection, a layer at a time): the sums
+    # over the layers ride back behind the slots' tokens, in the same
+    # fetch, and add up in the executor's ``stats`` under these names.
+    decode_counters: tuple = ()
     # Why the paged cache (serving/kvpool.py) cannot hold this family
     # yet; empty where it can, and then the three below are given
     # (models/kvcache.py says what each takes and returns).

@@ -1,11 +1,14 @@
-"""Hybrid decoder: Mamba-2 layers beside grouped-query attention.
+"""Hybrid decoder: recurrent layers beside grouped-query attention.
 
-The family of IBM's Granite 4.0-H (``model_type`` ``granitemoehybrid``,
-dense: no routed experts).  What sets it apart from
+First the family of IBM's Granite 4.0-H (``model_type``
+``granitemoehybrid``, dense: no routed experts), then that of Upstage's
+Solar Open 2 (``solar_open2``: delta-rule layers, a gated softmax layer
+in four, routed experts).  What sets it apart from
 ``models/transformer.py``, all of it configuration and none of it a
 switch there:
 
-- layer kinds by list (``layer_types``: ``"mamba"`` or ``"attention"``);
+- layer kinds by list (``layer_types``: ``"mamba"``, ``"kda"`` or
+  ``"attention"``);
 - grouped-query attention with **no positional encoding** and a score
   scale that is a published constant (``attention_multiplier``);
 - scaled residuals (``h + residual_multiplier * f(norm(h))``), an
@@ -15,6 +18,16 @@ switch there:
   recurrence (``ops/ssm.py``), ``RMSNorm(y * silu(z))`` with the gate
   before the norm, ``out_proj``.
 
+- the delta-rule mixer (Kimi Delta Attention, ``"kda"``): ``q``, ``k``,
+  ``v`` through a causal depthwise convolution with SiLU, ``q`` and ``k``
+  normalised a head, a decay a key channel and a write strength a head
+  (``ops/kda.py`` has the recurrence), ``RMSNorm`` a head times a
+  sigmoid gate of low rank, ``out_proj``;
+- for Solar Open 2: a head width of its own (``attn_head_dim``) and an
+  elementwise sigmoid gate (``attn_gate``) on the attention layer, the
+  routed expert block of ``models/moe.py`` in place of the MLP where
+  ``num_experts`` is set, an untied head (``tie_embeddings=False``).
+
 RMSNorm and the gated MLP are ``transformer.py``'s.
 
 Serving (``decode=True``): the cache collection keeps the attention
@@ -22,14 +35,16 @@ layers' keys, values and write cursors (``models/kvcache.py``) and gains,
 for each Mamba layer, ``conv_state`` [B, conv - 1, channels] (the last
 inputs of the convolution, in the activations' type) and ``ssm_state``
 (the H state matrices of P x N, **float32**: the recurrence sums over
-thousands of steps; stored as ``ops/ssm.py:state_shape`` lays them out).
-Every leaf has the slot on axis 0.  A call without a cache is a prefill:
+thousands of steps; stored as ``ops/ssm.py:state_shape`` lays them out);
+for each delta-rule layer a ``conv_state`` likewise and ``kda_state``
+[B, H, K, V], float32 too.  Every leaf has the slot on axis 0.  A call
+without a cache is a prefill:
 the chunked scan over the prompt, which with ``lengths`` **stops at the
 true length** (a recurrence cannot be rewound past padding the way a
 write cursor can): padded positions leave the state unchanged, and the
 window holds the last real positions, zeros before the start.  A call
 with a cache is one decode step: the window shifts by one and the state
-is updated once, in place (``hvd.ssm_update``).
+is updated once, in place (``hvd.ssm_update``, ``hvd.kda_update``).
 """
 from __future__ import annotations
 
@@ -41,13 +56,15 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..ops import ssm
+from ..ops import kda, ssm
+from . import moe
 from .family import ModelFamily
 from .kvcache import (attend, cached_attention, decode_step, fresh_cache,
                       prefill)
 from .transformer import MLP, RMSNorm
 
-STATE_LEAVES = ("conv_state", "ssm_state")
+STATE_LEAVES = ("conv_state", "ssm_state", "kda_state")
+KINDS = ("mamba", "kda", "attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,36 +77,56 @@ class HybridConfig:
     num_heads: int = 4
     num_kv_heads: int = 2
     attention_multiplier: float = 0.25       # the score scale itself
+    attn_head_dim: int = 0           # 0: d_model // num_heads
+    attn_gate: bool = False          # out * sigmoid(W_gate x), elementwise
     # Mamba-2 layers: heads x head_dim inner channels, one group
     mamba_heads: int = 4
     mamba_head_dim: int = 16
     mamba_state: int = 16
     mamba_conv: int = 4
     mamba_chunk: int = 8
+    # delta-rule layers: heads x head_dim channels each of q, k and v
+    kda_heads: int = 4
+    kda_head_dim: int = 16
+    kda_conv: int = 4
+    kda_chunk: int = 64
+    # the routed expert block (models/moe.py), where num_experts is set:
+    # the router's width, the experts a token takes, their hidden width,
+    # and which of them this chip holds, (first, count)
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_ff: int = 0
+    experts_held: tuple = ()
+    shared_experts: int = 1
+    norm_topk: bool = True
+    routed_scaling: float = 1.0
     # the residual stream
     residual_multiplier: float = 1.0
     embedding_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    tie_embeddings: bool = True
     rms_norm_eps: float = 1e-5
     max_seq_len: int = 256
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     decode: bool = False
-    ssm_interpret: bool = False       # run hvd.ssm_update interpreted (tests)
+    # run the family's kernels interpreted (tests): hvd.ssm_update,
+    # hvd.kda_update, hvd.moe_experts
+    interpret: bool = False
 
     def __post_init__(self):
-        unknown = set(self.layer_types) - {"mamba", "attention"}
+        unknown = set(self.layer_types) - set(KINDS)
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         if self.num_heads % self.num_kv_heads or \
-                self.d_model % self.num_heads:
+                (not self.attn_head_dim and self.d_model % self.num_heads):
             raise ValueError(
                 f"{self.num_heads} query heads over {self.num_kv_heads} "
                 f"key-value heads at width {self.d_model}")
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+        return self.attn_head_dim or self.d_model // self.num_heads
 
     @property
     def ff_dim(self) -> int:
@@ -105,14 +142,27 @@ class HybridConfig:
         return self.d_inner + 2 * self.mamba_state
 
     @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def kda_rank(self) -> int:
+        """Of the decay's and the output gate's low-rank pairs: a head's
+        width (``kda_use_full_proj: false``)."""
+        return self.kda_head_dim
+
+    @property
     def family(self):
-        """What the serving replica asks of a model (models/family.py)."""
-        return FAMILY
+        """What the serving replica asks of a model (models/family.py);
+        with routed experts its decode step also counts the routing."""
+        return ROUTED_FAMILY if self.num_experts else FAMILY
 
 
 class GroupedAttention(nn.Module):
     """Query head ``h`` reads key-value head ``h // group``; no position
-    term; ``softmax(attention_multiplier * q k^T + causal mask) v``."""
+    term; ``softmax(attention_multiplier * q k^T + causal mask) v``, with
+    ``attn_gate`` times ``sigmoid(W_gate x)`` a channel before ``wo``.
+    Heads are ``cfg.head_dim`` wide, whatever ``d_model`` is."""
     cfg: HybridConfig
 
     @nn.compact
@@ -131,6 +181,10 @@ class GroupedAttention(nn.Module):
         else:
             out = attend(q, k, v, jnp.arange(x.shape[1])[None, :],
                          cfg.attention_multiplier)
+        if cfg.attn_gate:
+            gate = dense(features=(cfg.num_heads, d), name="wg")(x)
+            out = out.astype(jnp.float32) \
+                * jax.nn.sigmoid(gate.astype(jnp.float32))
         out = out.astype(cfg.dtype)
         return dense(features=cfg.d_model, axis=(-2, -1), name="wo")(out)
 
@@ -163,29 +217,11 @@ class Mamba2Mixer(nn.Module):
         cached = cfg.decode and not self.is_initializing()
         stepping = cached and self.has_variable("cache", "ssm_state")
         if cached:
-            window = self.variable("cache", "conv_state", jnp.zeros,
-                                   (b, width - 1, channels), cfg.dtype)
             state = self.variable("cache", "ssm_state", jnp.zeros,
                                   ssm.state_shape(b, h, p, n), jnp.float32)
-        with jax.named_scope("hvd.ssm_conv"):
-            before = window.value if stepping \
-                else jnp.zeros((b, width - 1, channels), xbc.dtype)
-            padded = jnp.concatenate([before, xbc], axis=1)
-            if cached:
-                # The last inputs of the convolution: of the true length
-                # where the prompt is padded, zeros before its start.
-                # (A static ``padded[:, t:]`` for a decode step reads
-                # simpler and cost the v5e 0.34 ms a step: PERF.md, PR 29.)
-                end = jnp.full((b,), t, jnp.int32) if lengths is None \
-                    else jnp.broadcast_to(
-                        jnp.asarray(lengths, jnp.int32), (b,))
-                window.value = jax.vmap(
-                    lambda row, at: jax.lax.dynamic_slice_in_dim(
-                        row, at, width - 1, axis=0))(padded, end)
-            conv = sum(padded[:, i:i + t].astype(jnp.float32)
-                       * conv_w[i].astype(jnp.float32)
-                       for i in range(width)) + conv_b.astype(jnp.float32)
-            xbc = nn.silu(conv).astype(cfg.dtype)
+        xbc = _windowed_conv(self, cfg, xbc, conv_w, conv_b, lengths,
+                             cached=cached, stepping=stepping,
+                             scope="hvd.ssm_conv")
         xs, bs, cs = jnp.split(xbc, [inner, inner + n], axis=-1)
         xs = xs.reshape(b, t, h, p)
 
@@ -194,7 +230,7 @@ class Mamba2Mixer(nn.Module):
                 raise ValueError("a decode step takes one token a slot")
             y, state.value = ssm.ssm_update(
                 state.value, xs[:, 0], dt[:, 0], a, bs[:, 0], cs[:, 0],
-                skip, interpret=cfg.ssm_interpret)
+                skip, interpret=cfg.interpret)
             y = y[:, None]
         else:
             y, final = ssm.ssm_scan(xs, dt, a, bs, cs, skip,
@@ -205,6 +241,116 @@ class Mamba2Mixer(nn.Module):
         y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.rms_norm_eps,
                     name="norm")(y)
         return dense(cfg.d_model, name="out_proj")(y)
+
+
+def _windowed_conv(module, cfg, x, kernel, bias, lengths, *, cached: bool,
+                   stepping: bool, scope: str) -> jax.Array:
+    """The causal depthwise convolution of a recurrent mixer with SiLU,
+    ``x`` [B, T, channels], and the update of ``module``'s window,
+    ``conv_state`` [B, width - 1, channels], where the call runs through
+    the cache: a decode step (``stepping``) reads the window before it."""
+    b, t, channels = x.shape
+    width = kernel.shape[0]
+    if cached:
+        window = module.variable("cache", "conv_state", jnp.zeros,
+                                 (b, width - 1, channels), cfg.dtype)
+    with jax.named_scope(scope):
+        before = window.value if stepping \
+            else jnp.zeros((b, width - 1, channels), x.dtype)
+        padded = jnp.concatenate([before, x], axis=1)
+        if cached:
+            # The last inputs of the convolution: of the true length
+            # where the prompt is padded, zeros before its start.
+            # (A static ``padded[:, t:]`` for a decode step reads
+            # simpler and cost the v5e 0.34 ms a step: PERF.md, PR 29.)
+            end = jnp.full((b,), t, jnp.int32) if lengths is None \
+                else jnp.broadcast_to(
+                    jnp.asarray(lengths, jnp.int32), (b,))
+            window.value = jax.vmap(
+                lambda row, at: jax.lax.dynamic_slice_in_dim(
+                    row, at, width - 1, axis=0))(padded, end)
+        conv = sum(padded[:, i:i + t].astype(jnp.float32)
+                   * kernel[i].astype(jnp.float32)
+                   for i in range(width))
+        if bias is not None:
+            conv = conv + bias.astype(jnp.float32)
+        return nn.silu(conv).astype(cfg.dtype)
+
+
+class KDAMixer(nn.Module):
+    """The delta-rule mixer (Kimi Delta Attention).  ``in_proj`` is the
+    maps of ``x`` side by side: ``q``, ``k``, ``v`` (``heads x head_dim``
+    each), the low-rank halves of the decay and of the output gate
+    (``kda_rank`` each) and the write strength (a head)."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, lengths=None) -> jax.Array:
+        cfg = self.cfg
+        b, t, _ = x.shape
+        h, d, inner, rank = cfg.kda_heads, cfg.kda_head_dim, \
+            cfg.kda_inner, cfg.kda_rank
+        dense = partial(nn.Dense, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype)
+        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                            (cfg.kda_conv, 3 * inner), cfg.param_dtype)
+        a_log = self.param("A_log", _a_log_init, (h,), jnp.float32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (inner,),
+                             jnp.float32)
+
+        proj = dense(3 * inner + 2 * rank + h, name="in_proj")(x)
+        qkv, decay, gate, beta = jnp.split(
+            proj, [3 * inner, 3 * inner + rank, 3 * inner + 2 * rank],
+            axis=-1)
+        cached = cfg.decode and not self.is_initializing()
+        stepping = cached and self.has_variable("cache", "kda_state")
+        if cached:
+            state = self.variable("cache", "kda_state", jnp.zeros,
+                                  (b, h, d, d), jnp.float32)
+        qkv = _windowed_conv(self, cfg, qkv, conv_w, None, lengths,
+                             cached=cached, stepping=stepping,
+                             scope="hvd.kda_conv")
+        q, k, v = (each.reshape(b, t, h, d).astype(jnp.float32)
+                   for each in jnp.split(qkv, 3, axis=-1))
+        q, k = (each * jax.lax.rsqrt(
+            jnp.sum(each * each, axis=-1, keepdims=True) + _L2_EPS)
+            for each in (q, k))
+        q = q * d ** -0.5
+        # The log decay a key channel, at most 0, and the write strength
+        # a head, 0 to 2 (``kda_allow_neg_eigval``), both float32.
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            dense(inner, name="decay_up")(decay).astype(jnp.float32)
+            + dt_bias).reshape(b, t, h, d)
+        beta = _WRITE_SCALE * jax.nn.sigmoid(beta.astype(jnp.float32))
+        # What the recurrence is fed, for a caller that replays a stream
+        # and checks the state it reached (nothing is kept else).
+        for name, fed in (("k", k), ("v", v), ("g", g), ("beta", beta)):
+            self.sow("recurrence", name, fed)
+
+        if stepping:
+            if t != 1:
+                raise ValueError("a decode step takes one token a slot")
+            o, state.value = kda.kda_update(
+                state.value, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                interpret=cfg.interpret)
+            o = o[:, None]
+        else:
+            o, final = kda.kda_scan(q, k, v, g, beta, chunk=cfg.kda_chunk,
+                                    lengths=lengths)
+            if cached:
+                state.value = final
+        o = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.rms_norm_eps,
+                    name="norm")(o)                           # a head
+        gate = dense(inner, name="gate_up")(gate).reshape(b, t, h, d)
+        o = o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return dense(cfg.d_model, name="out_proj")(
+            o.reshape(b, t, inner).astype(cfg.dtype))
+
+
+_L2_EPS = 1e-6      # under the root of a head's norm, as the kernels of
+                    # the published implementation have it
+_WRITE_SCALE = 2.0  # kda_allow_neg_eigval: b on 0 to 2, so that
+                    # I - b k k^T has eigenvalues down to -1
 
 
 def _a_log_init(key, shape, dtype):
@@ -228,13 +374,20 @@ class HybridBlock(nn.Module):
         cfg = self.cfg
         norm = partial(RMSNorm, cfg.dtype, cfg.param_dtype,
                        cfg.rms_norm_eps)
-        mixed = Mamba2Mixer(cfg, name="mamba")(
-            norm(name="mixer_norm")(x), lengths) if self.kind == "mamba" \
-            else GroupedAttention(cfg, name="attn")(
-                norm(name="mixer_norm")(x))
+        mixed = norm(name="mixer_norm")(x)
+        if self.kind == "attention":
+            mixed = GroupedAttention(cfg, name="attn")(mixed)
+        else:
+            mixer = Mamba2Mixer if self.kind == "mamba" else KDAMixer
+            mixed = mixer(cfg, name=self.kind)(mixed, lengths)
         x = x + cfg.residual_multiplier * mixed
-        return x + cfg.residual_multiplier * MLP(cfg, name="mlp")(
-            norm(name="mlp_norm")(x))
+        ffn = MLP(cfg, name="mlp") if not cfg.num_experts \
+            else moe.RoutedExperts(
+                cfg.num_experts, cfg.experts_per_token, cfg.expert_ff,
+                tuple(cfg.experts_held), cfg.shared_experts, cfg.norm_topk,
+                cfg.routed_scaling, cfg.dtype, cfg.param_dtype,
+                cfg.interpret, name="moe")
+        return x + cfg.residual_multiplier * ffn(norm(name="mlp_norm")(x))
 
 
 class HybridLM(nn.Module):
@@ -254,7 +407,10 @@ class HybridLM(nn.Module):
             x = HybridBlock(cfg, kind, name=f"layer_{i}")(x, lengths)
         x = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.rms_norm_eps,
                     name="final_norm")(x)
-        return embed.attend(x) / cfg.logits_scaling          # a tied head
+        logits = embed.attend(x) if cfg.tie_embeddings \
+            else nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                          param_dtype=cfg.param_dtype, name="lm_head")(x)
+        return logits / cfg.logits_scaling
 
 
 # Serving: the entry points of models/family.py are models/kvcache.py's;
@@ -272,3 +428,4 @@ FAMILY = ModelFamily(
                   "convolution window and state matrix are a fixed size a "
                   "slot and live until replaced, and serving/kvpool.py "
                   "holds blocks of keys and values only")
+ROUTED_FAMILY = dataclasses.replace(FAMILY, decode_counters=moe.COUNTERS)

@@ -119,8 +119,33 @@ def _with_cache_index(cache: dict, lengths) -> dict:
     return fix(unfreeze(cache))
 
 
+def _apply(model, variables: dict, tokens, sown, **kw):
+    """``model.apply`` through the cache -> ``(logits, cache)``.
+    ``sown``, where a caller gives one, names further collections and
+    receives what the layers sowed into each during this call
+    (``{"counters": {}}`` comes back as ``{"counters": {layer: ...}}``):
+    what a step counted or chose on the device leaves by this door, and
+    the result stays the pair it was."""
+    logits, mut = model.apply(variables, tokens, **kw,
+                              mutable=["cache", *(sown or ())])
+    for name in sown or ():
+        sown[name] = unfreeze(mut.get(name, {}))
+    return logits, unfreeze(mut["cache"])
+
+
+def summed(sown: dict, names: tuple) -> list:
+    """What the layers sowed under each of ``names`` into one collection,
+    summed over the layers: an int32 [1] a name (none: an empty list,
+    which a concatenation with a step's tokens leaves as they were)."""
+    leaves = jax.tree_util.tree_leaves_with_path(sown)
+    return [jnp.reshape(sum(leaf for path, leaf in leaves
+                            if path[-2].key == name), (1,)).astype(jnp.int32)
+            for name in names]
+
+
 def prefill(model, variables: dict, tokens: jax.Array,
-            lengths=None) -> tuple[jax.Array, dict]:
+            lengths=None, sown: dict | None = None
+            ) -> tuple[jax.Array, dict]:
     """Run the prompt through a ``decode=True`` model and return
     ``(logits [B, T, vocab], cache)``.  ``lengths`` ([B] or scalar) gives
     each row's true length when ``tokens`` is right-padded to a bucket:
@@ -128,10 +153,8 @@ def prefill(model, variables: dict, tokens: jax.Array,
     the pad garbage and the mask hides the rest of it; the model gets it
     too (a recurrence cannot be rewound: a family with recurrent state
     stops there).  Row b's next-token logits are ``logits[b, lengths[b]
-    - 1]``."""
-    logits, mut = model.apply(variables, tokens, lengths=lengths,
-                              mutable=["cache"])
-    cache = unfreeze(mut["cache"])
+    - 1]``.  ``sown``: see ``_apply``."""
+    logits, cache = _apply(model, variables, tokens, sown, lengths=lengths)
     if lengths is not None:
         cache = _with_cache_index(cache, lengths)
     return logits, cache
@@ -148,17 +171,16 @@ def fresh_cache(model, params, slots: int) -> dict:
         lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes))
 
 
-def decode_step(model, variables: dict, cache: dict,
-                tokens: jax.Array) -> tuple[jax.Array, dict]:
+def decode_step(model, variables: dict, cache: dict, tokens: jax.Array,
+                sown: dict | None = None) -> tuple[jax.Array, dict]:
     """One incremental step of a ``decode=True`` model: ``tokens``
     [B, 1] (or [B]) → ``(logits [B, 1, vocab], updated cache)``.  Each
     row advances at its own depth, which is what lets continuous
-    batching admit a fresh prefill into a half-decoded batch."""
+    batching admit a fresh prefill into a half-decoded batch.
+    ``sown``: see ``_apply``."""
     if tokens.ndim == 1:
         tokens = tokens[:, None]
-    logits, mut = model.apply({**variables, "cache": cache}, tokens,
-                              mutable=["cache"])
-    return logits, unfreeze(mut["cache"])
+    return _apply(model, {**variables, "cache": cache}, tokens, sown)
 
 
 def paged_apply(model, variables: dict, cache: dict, tokens: jax.Array,

@@ -227,6 +227,10 @@ class ReplicaExecutor:
                       # the compiled path reads for them (a layer).
                       "attend_live_positions": 0,
                       "attend_read_positions": 0,
+                      # What a family's decode program counts on the
+                      # device (ModelFamily.decode_counters; routed
+                      # experts: models/moe.py:COUNTERS) joins these,
+                      # summed over layers and decode dispatches.
                       # Always-on part timers of the serve step
                       # (telemetry/spans.py), by kind of step: "admit"
                       # steps prefilled at least one request here,
@@ -517,7 +521,7 @@ class ReplicaExecutor:
             nxt = self.cache.decode(self.params, self._last_tokens,
                                     active, self.slots)
         with parts("token_fetch"):
-            return active, np.asarray(nxt)     # waits for the device
+            return active, self.cache.fetch(nxt)   # waits for the device
 
     def _advance_slots(self, active: list[int], nxt) -> None:
         for i in active:

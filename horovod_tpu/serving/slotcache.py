@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.logging import logger
+from ..models.kvcache import summed
 from ..ops import decode_attention
 from ..telemetry.spans import span
 from .kvpool import FNV_SEED, KVBlockPool, chain_hash
@@ -62,6 +63,7 @@ class _SlotCache:
     def __init__(self, cfg, family, model, stats: dict) -> None:
         self.cfg, self.family, self.model = cfg, family, model
         self.stats = stats             # the executor's own
+        stats.update(dict.fromkeys(family.decode_counters, 0))
         # Jitted like every other model call here: un-jitted, each of its
         # hundreds of small ops compiles and dispatches on its own.
         self._init_cache_jit = jax.jit(self._init_cache_impl)
@@ -104,6 +106,17 @@ class _SlotCache:
                                               self._attend_block)
         return nxt
 
+    def fetch(self, nxt) -> np.ndarray:
+        """Wait for a step's tokens, one a slot.  What the decode
+        program counted on the device (the family's ``decode_counters``;
+        none for most) rides behind them in the same array: added up in
+        ``stats`` here."""
+        fetched = np.asarray(nxt)
+        for name, value in zip(self.family.decode_counters,
+                               fetched[self.cfg.slots:]):
+            self.stats[name] += int(value)
+        return fetched[:self.cfg.slots]
+
     def _note_cache_aliasing(self, decode_program) -> None:
         """How much of the cache the compiled decode program updates in
         place: the cache is its only donated argument, so what it
@@ -143,9 +156,12 @@ class DenseSlotCache(_SlotCache):
         self._insert_jit = jax.jit(self._insert_impl, donate_argnums=0)
 
     def _decode_impl(self, params, cache, tokens):
+        sown = {"counters": {}}
         logits, cache = self.family.decode_step(
-            self.model, {"params": params}, cache, tokens)
-        return _sample(logits[:, -1, :]), cache
+            self.model, {"params": params}, cache, tokens, sown=sown)
+        return jnp.concatenate([
+            _sample(logits[:, -1, :]),
+            *summed(sown["counters"], self.family.decode_counters)]), cache
 
     def _prefill_impl(self, params, tokens, n):
         logits, cache = self.family.prefill(

@@ -239,19 +239,35 @@ def transformer_decode_flops(cfg: Any, context_len: float,
 
 def hybrid_decode_flops(cfg: Any, context_len: float) -> float:
     """FLOPs of ONE generated token of a HybridLM config (models/
-    hybrid.py) at context ``context_len``: 2 a matmul weight (the tied
-    head once), the state update's 6*H*P*N a Mamba layer, and scores and
-    values over the context in the attention layers alone."""
-    d, ff = cfg.d_model, cfg.ff_dim
-    mamba = sum(kind == "mamba" for kind in cfg.layer_types)
-    attn = len(cfg.layer_types) - mamba
-    kv_width = cfg.num_kv_heads * cfg.head_dim
+    hybrid.py) at context ``context_len``, as this chip computes it: 2 a
+    matmul weight that the token meets here (the head once; of routed
+    experts the router's whole width, the shared expert, and of the
+    ``experts_per_token`` it takes the share that ``experts_held`` is of
+    the router's width: the rest run on the chips that hold them), the
+    state update's 6*H*P*N a Mamba layer and 8*H*K*V a delta-rule layer,
+    and scores and values over the context in the attention layers alone."""
+    d = cfg.d_model
+    kinds = cfg.layer_types
+    mamba, delta = kinds.count("mamba"), kinds.count("kda")
+    attn = len(kinds) - mamba - delta
+    width, kv_width = cfg.num_heads * cfg.head_dim, \
+        cfg.num_kv_heads * cfg.head_dim
+    if cfg.num_experts:
+        held = cfg.experts_held[1] / cfg.num_experts
+        ffn = d * cfg.num_experts + 3 * d * cfg.expert_ff * (
+            cfg.experts_per_token * held + cfg.shared_experts)
+    else:
+        ffn = 3 * d * cfg.ff_dim
+    inner, rank = cfg.kda_inner, cfg.kda_rank
     weights = (mamba * (d * (2 * cfg.d_inner + 2 * cfg.mamba_state
                              + cfg.mamba_heads) + cfg.d_inner * d)
-               + attn * (2 * d * d + 2 * d * kv_width)
-               + len(cfg.layer_types) * 3 * d * ff + d * cfg.vocab_size)
-    update = 6.0 * cfg.mamba_heads * cfg.mamba_head_dim * cfg.mamba_state
-    return 2.0 * weights + mamba * update + 4.0 * attn * d * context_len
+               + delta * (d * (3 * inner + 2 * rank + cfg.kda_heads)
+                          + 2 * rank * inner + inner * d)
+               + attn * ((2 + cfg.attn_gate) * d * width + 2 * d * kv_width)
+               + len(kinds) * ffn + d * cfg.vocab_size)
+    update = 6.0 * mamba * cfg.mamba_heads * cfg.mamba_head_dim \
+        * cfg.mamba_state + 8.0 * delta * cfg.kda_heads * cfg.kda_head_dim ** 2
+    return 2.0 * weights + update + 4.0 * attn * width * context_len
 
 
 # ---------------------------------------------------------------------------

@@ -198,6 +198,52 @@ def test_ssm_update_compiles_for_v5e_at_published_shapes(v5e):
         == slots * heads * p * n * 4
 
 
+def test_kda_update_compiles_for_v5e_at_published_shapes(v5e):
+    """hvd.kda_update at Solar-Open2-250B's decode shapes (80 slots, 64
+    heads of 128 x 128: a state of [80, 64, 128, 128] float32, blocks of
+    16 heads) lowers through Mosaic, fits the v5e's scoped VMEM, and
+    writes the donated state in place."""
+    from horovod_tpu.ops import kda
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)
+
+    slots, heads, d = 80, 64, 128
+    update = jax.jit(lambda *operands: kda._kda_update_pallas(
+        *operands, block_heads=16, interpret=False), donate_argnums=0)
+    small = shaped(slots, heads, d)
+    compiled = update.lower(shaped(slots, heads, d, d), small, small, small,
+                            small, shaped(slots, heads)).compile()
+    assert compiled.as_text().count(MOSAIC) == 1
+    assert "hvd.kda_update" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == slots * heads * d * d * 4
+
+
+@pytest.mark.parametrize("tokens", [80, 512])
+def test_moe_experts_compiles_for_v5e_at_published_shapes(v5e, tokens):
+    """hvd.moe_experts at Solar-Open2-250B's shapes (40 experts held of
+    4096 x 1280, top-8 of 320; a decode step of 80 tokens in tiles of 16
+    rows, a prefill of 512 in tiles of 32) lowers through Mosaic with
+    three blocks of weights held twice over in the v5e's VMEM."""
+    from horovod_tpu.models import moe
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    d, ff, held = 4096, 1280, 40
+    tile = moe.tile_rows(tokens, 8, 320)
+    rows = -(-(tokens * 8 + held * (tile - 1)) // tile) * tile
+    products = jax.jit(lambda *operands: moe._experts_pallas(
+        *operands, tile=tile, block=moe.hidden_block(ff), interpret=False))
+    compiled = products.lower(
+        shaped((rows, d)), shaped((rows // tile,), jnp.int32),
+        shaped((1,), jnp.int32), shaped((held, d, ff)),
+        shaped((held, d, ff)), shaped((held, ff, d))).compile()
+    assert compiled.as_text().count(MOSAIC) == 1
+    assert "hvd.moe_experts" in compiled.as_text()
+
+
 def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
         v5e, monkeypatch):
     """``lm7b_serve_chat_sat``'s decode program (deepseek-llm-7b's widths,

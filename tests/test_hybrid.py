@@ -101,7 +101,7 @@ def test_prefill_of_a_padded_bucket_then_decode_through_the_cache(
     recurrence runs into the padding)."""
     steps = 6
     config = model_config(toy, decode=True, max_seq_len=32,
-                          ssm_interpret=True)
+                          interpret=True)
     family = config.family
     model = family.build(config)
     tokens = tokens_of(100 + n, 1, n + steps)
