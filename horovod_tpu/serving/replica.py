@@ -19,6 +19,19 @@ Execution model (ISSUE 9 tentpole, extended by ISSUE 14):
   slots and advances every in-flight slot by one greedy token per step
   (continuous batching, not run-to-completion), through the model's
   family (models/family.py).
+- **One decode step of lookahead** (ISSUE 35): a step enqueues decode
+  program k+1 and only then waits for the tokens of program k, so the
+  device has its next program queued when one ends and everything the
+  host does in a step runs under the device's step.  Program k+1 reads
+  its input tokens from program k's result on the device; what a
+  dispatch needs of a slot (its length, how many tokens it has been
+  issued) advances at dispatch, what only the device knows (the tokens,
+  the end-of-sequence test) at fetch.  So a token is visible one
+  ``_serve_step`` after the one that enqueued its program, and a request
+  is complete only once every row issued for it has been fetched.  A
+  step that admits settles the step in flight first, and so does
+  whatever reads or replaces the cache, the parameters or the world from
+  outside a step (``_settle``).
 - **The slot cache** is an object of ``serving/slotcache.py``, chosen
   once from ``HOROVOD_SERVE_PAGED``: dense per-slot arrays, or paged
   blocks with prefix reuse (ISSUE 14).  The executor knows neither
@@ -62,7 +75,7 @@ import numpy as np
 from ..common import config
 from ..common.exceptions import RanksFailedError
 from ..common.logging import logger
-from ..telemetry.spans import StepParts
+from ..telemetry.spans import StepParts, span
 from .admission import AdmissionController
 from .batcher import Assignment, BatchPlan, ContinuousBatcher
 from .queue import RequestQueue
@@ -151,7 +164,8 @@ class _Slot:
     age_ms: float                      # ingress age when assigned
     slo_ms: float
     generated: list[int]
-    seq_len: int = 0                   # the sequence's write cursor
+    seq_len: int = 0                   # the write cursor, at dispatch
+    in_flight: int = 0                 # decode rows enqueued, not fetched
     # Disaggregated mode: the original assignment while the streamed
     # prefill is still in flight (slot skips decode until it lands or
     # the fallback re-prefills locally), and when it went pending.
@@ -200,6 +214,13 @@ class ReplicaExecutor:
 
         self.slots: list[_Slot | None] = [None] * self.cfg.slots
         self._last_tokens = np.zeros(self.cfg.slots, np.int32)
+        # Where _last_tokens holds a slot's newest token and the last
+        # decode result on the device does not: the slots admitted or
+        # landed since the last dispatch, and all before the first.
+        self._token_on_host = np.ones(self.cfg.slots, bool)
+        # The decode step enqueued and not fetched yet: (its result on
+        # the device, the (index, slot) pairs it was enqueued for).
+        self._in_flight: tuple | None = None
         self.completed: dict[int, dict] = {}
         self.prefilled: set[int] = set()
         # Completions not yet acknowledged by a successful exchange: a
@@ -227,6 +248,10 @@ class ReplicaExecutor:
                       # the compiled path reads for them (a layer).
                       "attend_live_positions": 0,
                       "attend_read_positions": 0,
+                      # Decode programs enqueued, and those of them
+                      # enqueued while the one before was still unfetched
+                      # (the device had its next program when one ended).
+                      "decode_dispatches": 0, "decode_overlapped": 0,
                       # What a family's decode program counts on the
                       # device (ModelFamily.decode_counters; routed
                       # experts: models/moe.py:COUNTERS) joins these,
@@ -391,6 +416,7 @@ class ReplicaExecutor:
                 continue
             if a.replica != self.group:
                 continue
+            self._settle(parts)        # a no-op after the first
             slot = next(i for i, s in enumerate(self.slots) if s is None)
             admits += 1
             with parts("admit", rid=a.rid,
@@ -406,6 +432,7 @@ class ReplicaExecutor:
         toks = self._clamped_tokens(a)
         first = self.cache.admit(self.params, slot, toks, a.max_new_tokens)
         self._last_tokens[slot] = first
+        self._token_on_host[slot] = True
         self.slots[slot] = _Slot(
             rid=a.rid, remaining=a.max_new_tokens - 1,
             deadline=now + a.deadline_rel_ms / 1e3, assigned_at=now,
@@ -504,42 +531,87 @@ class ReplicaExecutor:
         self.cache.land(slot, self._clamped_tokens(s.pending),
                         s.pending.max_new_tokens, image)
         self._last_tokens[slot] = img.first
+        self._token_on_host[slot] = True
         self.slots[slot] = dataclasses.replace(
             s, remaining=s.remaining - 1, generated=[img.first],
             seq_len=img.cursor, pending=None, pending_since=0.0)
 
     # -- decode ----------------------------------------------------------
-    def _decode_once(self, parts: StepParts) -> tuple[list[int], Any]:
-        """Enqueue one decode step for the active slots and fetch its
-        tokens: (active slots, the slot array's next tokens)."""
+    def _decode_once(self, parts: StepParts) -> tuple[list, Any]:
+        """Enqueue the next decode step, then fetch the one before it,
+        which the device has had queued since the last call: (the
+        (index, slot) pairs that one was enqueued for, the slot array's
+        tokens)."""
         with parts("decode_dispatch"):
-            active = [i for i, s in enumerate(self.slots)
-                      if s is not None and s.pending is None
-                      and s.remaining > 0]
-            if not active:
-                return active, None
-            nxt = self.cache.decode(self.params, self._last_tokens,
-                                    active, self.slots)
-        with parts("token_fetch"):
-            return active, self.cache.fetch(nxt)   # waits for the device
+            ahead = self._enqueue_decode()
+        fetched = self._fetch_in_flight(parts)
+        self._in_flight = ahead
+        return fetched
 
-    def _advance_slots(self, active: list[int], nxt) -> None:
-        for i in active:
-            s = self.slots[i]
+    def _enqueue_decode(self) -> tuple | None:
+        """Enqueue one decode step for the slots that still have a token
+        to be issued, whatever is in flight, and advance what a dispatch
+        knows of them: (the result on the device, the (index, slot)
+        pairs), or None where no slot wants one."""
+        issued = [(i, s) for i, s in enumerate(self.slots)
+                  if s is not None and s.pending is None
+                  and s.remaining > s.in_flight]
+        if not issued:
+            return None
+        active = [i for i, _ in issued]
+        result = self.cache.decode(self.params, self._last_tokens,
+                                   self._token_on_host, active, self.slots)
+        self.stats["decode_dispatches"] += 1
+        self.stats["decode_overlapped"] += self._in_flight is not None
+        for _, s in issued:
+            s.in_flight += 1
+            s.seq_len += 1
+        # The newest token of these slots is the device's from here on.
+        self._token_on_host[:] = True
+        self._token_on_host[active] = False
+        return result, issued
+
+    def _fetch_in_flight(self, parts: StepParts | None = None
+                         ) -> tuple[list, Any]:
+        """Wait for the decode step in flight: (the (index, slot) pairs
+        it was enqueued for, the slot array's tokens); no pairs where
+        none is."""
+        if self._in_flight is None:
+            return [], None
+        (result, issued), self._in_flight = self._in_flight, None
+        with parts("token_fetch") if parts else span("serve.token_fetch"):
+            return issued, self.cache.fetch(result)   # waits for the device
+
+    def _advance_slots(self, issued: list, nxt) -> None:
+        """What only the device knew of a fetched step.  A row is dropped
+        where its slot no longer holds the occupant it was enqueued for,
+        or that occupant wants no more: the row enqueued behind an end
+        token, before the host had seen it."""
+        for i, s in issued:
+            s.in_flight -= 1
+            if self.slots[i] is not s or s.remaining <= 0:
+                continue
             tok = int(nxt[i])
             s.generated.append(tok)
             s.remaining -= 1
-            s.seq_len += 1
             self._last_tokens[i] = tok
             if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
                 s.remaining = 0
+
+    def _settle(self, parts: StepParts | None = None) -> None:
+        """Leave nothing in flight: fetch the decode step that is, and
+        advance its slots.  Before an admission, and before anything
+        outside a step reads or replaces the cache, the parameters or
+        the world."""
+        self._advance_slots(*self._fetch_in_flight(parts))
 
     def _collect_completions(self) -> None:
         now = time.monotonic()
         stale = self._fleet_staleness_steps()
         for i, s in enumerate(self.slots):
-            if s is None or s.pending is not None or s.remaining > 0:
-                continue
+            if s is None or s.pending is not None or s.remaining > 0 \
+                    or s.in_flight:
+                continue               # its last row is still in flight
             rec = {"rid": s.rid, "replica": self.group,
                    "latency_ms": s.age_ms + (now - s.assigned_at) * 1e3,
                    "tokens": len(s.generated),
@@ -621,6 +693,7 @@ class ReplicaExecutor:
         static mode, so the bulk image IS the joiner's entry state."""
         import jax
 
+        self._settle()
         return {"params": jax.tree_util.tree_map(np.asarray,
                                                  self.params)}
 
@@ -776,6 +849,7 @@ class ReplicaExecutor:
         (step, gen, resident rids), adopts the maxima, and rebuilds the
         batcher with the new replica group present but empty.  Nothing
         in flight is touched: incumbents' KV caches are process-local."""
+        self._settle()
         old_size = self.size
         self.rank, self.size = new_rank, new_size
         self.front = 0
@@ -844,14 +918,15 @@ class ReplicaExecutor:
                 plan = self._exchange_plan(plan)
             self._step += 1
             if plan.stop:
+                self._settle(parts)
                 return False
             admits = self._apply_plan(plan, parts)
             if not self.is_prefill:
                 if self.prefill_rank_list:
                     self._integrate_prefills()
-                active, nxt = self._decode_once(parts)
+                issued, nxt = self._decode_once(parts)
                 with parts("slot_update"):
-                    self._advance_slots(active, nxt)
+                    self._advance_slots(issued, nxt)
                     for s in self.slots:
                         if s is not None and s.pending is None:
                             decoded += 1
@@ -1022,6 +1097,7 @@ class ReplicaExecutor:
           are counted lost.  Nothing on a surviving replica is ever
           dropped, so the zero-failed-on-survivors invariant holds.
         """
+        self._settle()     # the step in flight at the failed exchange
         rids = sorted(s.rid for s in self.slots if s is not None)
         rids += [rec["rid"] for rec in self._unreported]
         mine = {"step": self._step,
@@ -1058,6 +1134,7 @@ class ReplicaExecutor:
         KV block pool must not outlive the executor across elastic
         reinit cycles: hvdlife HVD702/704).  Leaves the part timers'
         totals and the slow steps in the log."""
+        self._settle()
         if any(self.stats["steps"].values()):
             logger.info("serving: step parts %s", json.dumps(
                 {key: self.stats[key]

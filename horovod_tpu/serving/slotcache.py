@@ -6,12 +6,16 @@ layouts behind one interface.
 from then on asks only: ``warm`` (compile every program, note how much
 of the cache the decode program updates in place), ``admit`` (prefill a
 prompt into a slot, return its first token), ``decode`` (enqueue one
-step for the slot array), ``release``, ``fresh``, ``kv_stats``,
+step for the slot array; its result stays on the device), ``fetch``
+(wait for a step's result), ``release``, ``fresh``, ``kv_stats``,
 ``close``, and ``block_capacity`` for the batcher.  ``tree`` is the
 cache itself: every program that writes it takes it donated and its
-result is rebound, so nobody else may hold it.  Both classes reach the
-model through its family (``models/family.py``); the leaves of either
-layout are ``models/kvcache.py``'s.
+result is rebound, so nobody else may hold it.  ``result`` is the last
+decode step's result, on the device: the next step reads a slot's input
+token from it wherever the host has none newer, so a step can be
+enqueued before the one before it has been fetched.  Both classes reach
+the model through its family (``models/family.py``); the leaves of
+either layout are ``models/kvcache.py``'s.
 
 - :class:`DenseSlotCache`: the slot on axis 0 of every leaf; an
   admission prefills one row and inserts it as row ``slot``.
@@ -69,6 +73,10 @@ class _SlotCache:
         self._init_cache_jit = jax.jit(self._init_cache_impl)
         self._attend_span = cfg.max_seq
         self.tree = None
+        # The last decode step's result (a token a slot, then the
+        # family's counters), fetched or not.
+        self.result = jnp.zeros(
+            cfg.slots + len(family.decode_counters), jnp.int32)
 
     def fresh(self, params) -> None:
         self.tree = self._init_cache_jit(params)
@@ -80,19 +88,23 @@ class _SlotCache:
         for bucket in self.cfg.warmup_buckets:
             if bucket <= self.cfg.max_seq:
                 self._warm_prefill(params, [0] * bucket)
-        decode, args = self._decode_call(params, last_tokens)
+        decode, args = self._decode_call(
+            params, last_tokens, np.ones(self.cfg.slots, bool))
         self._note_cache_aliasing(decode.lower(*args).compile())
-        nxt, self.tree = decode(*args)
-        jax.block_until_ready(nxt)
+        self.result, self.tree = decode(*args)
+        jax.block_until_ready(self.result)
         self.tree = None               # one copy at a time
         self.fresh(params)             # discard warmup cache writes
 
-    def decode(self, params, last_tokens: np.ndarray, active: list,
-               slots: list):
-        """Enqueue one step for the whole slot array; the next tokens
-        stay on the device."""
-        decode, args = self._decode_call(params, last_tokens)
-        nxt, self.tree = decode(*args)
+    def decode(self, params, last_tokens: np.ndarray,
+               from_host: np.ndarray, active: list, slots: list):
+        """Enqueue one step for the whole slot array; its result stays
+        on the device.  A slot's input token is ``last_tokens``' where
+        ``from_host`` says so (it was admitted since the last step, or
+        there was none) and the last step's own result elsewhere, which
+        nobody need have fetched yet."""
+        decode, args = self._decode_call(params, last_tokens, from_host)
+        self.result, self.tree = decode(*args)
         # This step's write is read too: a slot's live context is its
         # length after it.
         lengths = np.fromiter((slots[i].seq_len + 1 for i in active),
@@ -104,18 +116,24 @@ class _SlotCache:
             stats.get("attend_read_positions", 0) \
             + decode_attention.read_positions(lengths, self._attend_span,
                                               self._attend_block)
-        return nxt
+        return self.result
 
-    def fetch(self, nxt) -> np.ndarray:
+    def fetch(self, result) -> np.ndarray:
         """Wait for a step's tokens, one a slot.  What the decode
         program counted on the device (the family's ``decode_counters``;
         none for most) rides behind them in the same array: added up in
         ``stats`` here."""
-        fetched = np.asarray(nxt)
+        fetched = np.asarray(result)
         for name, value in zip(self.family.decode_counters,
                                fetched[self.cfg.slots:]):
             self.stats[name] += int(value)
         return fetched[:self.cfg.slots]
+
+    def _input_tokens(self, result, last_tokens, from_host):
+        """Inside the decode program: each slot's input token, [slots,
+        1], the host's where ``from_host`` and else the last step's."""
+        return jnp.where(from_host, last_tokens,
+                         result[:self.cfg.slots])[:, None]
 
     def _note_cache_aliasing(self, decode_program) -> None:
         """How much of the cache the compiled decode program updates in
@@ -155,10 +173,11 @@ class DenseSlotCache(_SlotCache):
         self._prefill_jit = jax.jit(self._prefill_impl)
         self._insert_jit = jax.jit(self._insert_impl, donate_argnums=0)
 
-    def _decode_impl(self, params, cache, tokens):
+    def _decode_impl(self, params, cache, result, last_tokens, from_host):
         sown = {"counters": {}}
         logits, cache = self.family.decode_step(
-            self.model, {"params": params}, cache, tokens, sown=sown)
+            self.model, {"params": params}, cache,
+            self._input_tokens(result, last_tokens, from_host), sown=sown)
         return jnp.concatenate([
             _sample(logits[:, -1, :]),
             *summed(sown["counters"], self.family.decode_counters)]), cache
@@ -194,9 +213,10 @@ class DenseSlotCache(_SlotCache):
     def _warm_prefill(self, params, toks: list) -> None:
         self.admit(params, 0, toks, 1)   # the insert compiles once
 
-    def _decode_call(self, params, last_tokens):
-        return self._decode_jit, (params, self.tree,
-                                  jnp.asarray(last_tokens[:, None]))
+    def _decode_call(self, params, last_tokens, from_host):
+        # Copies: the host's arrays change while the step is in flight.
+        return self._decode_jit, (params, self.tree, self.result,
+                                  last_tokens.copy(), from_host.copy())
 
     def admit(self, params, slot: int, toks: list, max_new: int) -> int:
         with span("serve.prefill_dispatch"):
@@ -230,12 +250,14 @@ class PagedSlotCache(_SlotCache):
         self._copy_block_jit = jax.jit(family.paged_copy_block,
                                        donate_argnums=0)
 
-    def _paged_impl(self, params, cache, tokens, tables, cursors):
+    def _paged_impl(self, params, cache, result, last_tokens, from_host,
+                    tables, cursors):
         """One paged decode step for the whole slot array: inactive
         slots' tables point at the pool sink row, so their writes land
         in garbage space and their outputs are ignored."""
         logits, cache = self.family.paged_apply(
-            self.model, {"params": params}, cache, tokens, tables,
+            self.model, {"params": params}, cache,
+            self._input_tokens(result, last_tokens, from_host), tables,
             cursors)
         return _sample(logits[:, -1, :]), cache
 
@@ -274,10 +296,11 @@ class PagedSlotCache(_SlotCache):
     def _warm_prefill(self, params, toks: list) -> None:
         jax.block_until_ready(self._prefill(params, toks, [], 0))
 
-    def _decode_call(self, params, last_tokens):
+    def _decode_call(self, params, last_tokens, from_host):
+        # Copies: the host's arrays change while the step is in flight.
         return self._paged_jit, (
-            params, self.tree, jnp.asarray(last_tokens[:, None]),
-            jnp.asarray(self._tables), jnp.asarray(self._cursors))
+            params, self.tree, self.result, last_tokens.copy(),
+            from_host.copy(), self._tables.copy(), self._cursors.copy())
 
     # -- prefix cache ----------------------------------------------------
     def _lookup_prefix(self, toks: list) -> tuple[list, int]:
@@ -360,8 +383,8 @@ class PagedSlotCache(_SlotCache):
         self._blocks[slot] = blocks
         self._tables[slot] = self._row(blocks)
 
-    def decode(self, params, last_tokens: np.ndarray, active: list,
-               slots: list):
+    def decode(self, params, last_tokens: np.ndarray,
+               from_host: np.ndarray, active: list, slots: list):
         bt = self.cfg.block_tokens
         for i in active:
             j = slots[i].seq_len // bt
@@ -370,7 +393,7 @@ class PagedSlotCache(_SlotCache):
             if self._ensure_writable(self._blocks[i], j):
                 self._tables[i][j] = self._blocks[i][j]
             self._cursors[i] = slots[i].seq_len
-        return super().decode(params, last_tokens, active, slots)
+        return super().decode(params, last_tokens, from_host, active, slots)
 
     def release(self, slot: int) -> None:
         for b in self._blocks[slot]:

@@ -279,8 +279,11 @@ def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
     cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(tree))
     assert cache_bytes == layers * (2 * leaf + slots * 4)
+    # The last step's result, the host's tokens and where they win.
     compiled = cache._decode_jit.lower(
-        params, tree, placed(jnp.zeros((slots, 1), jnp.int32))).compile()
+        params, tree, *placed((jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, bool)))).compile()
     calls = [line for line in compiled.as_text().splitlines()
              if MOSAIC in line]
     assert len(calls) == layers

@@ -339,8 +339,9 @@ def test_the_hybrid_programs_carry_the_scope_names(toy, solo_world):
     hvd.sample, and nowhere in the programs themselves."""
     ex = executor(model_config(toy))
     try:
-        decode = ex.cache._decode_jit.lower(
-            ex.params, ex.cache.tree, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
+        decode, args = ex.cache._decode_call(
+            ex.params, ex._last_tokens, ex._token_on_host)
+        decode = decode.lower(*args)
         prefill = ex.cache._prefill_jit.lower(
             ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))
         for program, scopes in (
@@ -369,7 +370,10 @@ def test_the_decoders_programs_are_what_they_were(solo_world):
         assert ex.family is tfm.FAMILY
         model = ex.model
 
-        def _decode_impl(params, cache, tokens):
+        def _decode_impl(params, cache, result, last_tokens, from_host):
+            # The host's token where it has one newer than the last
+            # step's result on the device (ISSUE 35).
+            tokens = jnp.where(from_host, last_tokens, result[:2])[:, None]
             logits, cache = tfm.decode_step(model, {"params": params},
                                             cache, tokens)
             return _sample(logits[:, -1, :]), cache
@@ -379,11 +383,12 @@ def test_the_decoders_programs_are_what_they_were(solo_world):
                                         lengths=n)
             return _sample(logits[0, n - 1, :]), cache
 
-        tokens = jnp.zeros((2, 1), jnp.int32)
-        assert ex.cache._decode_jit.lower(
-            ex.params, ex.cache.tree, tokens).as_text() \
+        decode, args = ex.cache._decode_call(
+            ex.params, ex._last_tokens, ex._token_on_host)
+        assert decode is ex.cache._decode_jit
+        assert decode.lower(*args).as_text() \
             == jax.jit(_decode_impl, donate_argnums=1).lower(
-                ex.params, ex.cache.tree, tokens).as_text()
+                *args).as_text()
         prompt = (jnp.zeros((1, 8), jnp.int32), jnp.int32(3))
         assert ex.cache._prefill_jit.lower(ex.params, *prompt).as_text() \
             == jax.jit(_prefill_impl).lower(ex.params, *prompt).as_text()

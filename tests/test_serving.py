@@ -623,8 +623,15 @@ def test_step_parts_sum_to_every_step_and_feed_the_counters(monkeypatch):
             total = seconds.pop("total")
             assert total > 0 and seconds["other"] >= 0
             assert sum(seconds.values()) == pytest.approx(total, abs=1e-9)
-            assert set(STEP_PARTS) <= set(seconds)
+            assert set(STEP_PARTS) - {"token_fetch"} <= set(seconds)
             assert ("admit" in seconds) == bool(admits)
+        # A step fetches the decode step enqueued by the one before it
+        # (ISSUE 35): the first step finds none in flight, nor does the
+        # step that admits the third request into a drained replica.
+        unfetched = [i for i, (seconds, _) in enumerate(seen)
+                     if "token_fetch" not in seconds]
+        assert len(unfetched) == 2 and unfetched[0] == 0
+        assert all(seen[i][1] for i in unfetched)
         kinds = ex.stats["steps"]
         assert kinds["admit"] == sum(bool(a) for _, a in seen) >= 2
         assert kinds["decode"] == len(seen) - kinds["admit"]
@@ -635,7 +642,7 @@ def test_step_parts_sum_to_every_step_and_feed_the_counters(monkeypatch):
         hist = {m["labels"]["part"]: m["count"]
                 for m in reg.snapshot()["metrics"]
                 if m["name"] == "horovod_serve_step_part_ms"}
-        assert hist["token_fetch"] == hist["other"] == len(seen)
+        assert hist["token_fetch"] + 2 == hist["other"] == len(seen)
         assert hist["admit"] == kinds["admit"]
         assert "total" not in hist
     finally:
@@ -684,7 +691,9 @@ def test_serve_spans_nest_in_a_profiler_session(tmp_path):
 
     for part in STEP_PARTS:
         found = by_name["hvd.serve." + part]
-        assert [holders(steps, f) for f in found] == [[i] for i in range(5)]
+        # The first step has no step in flight to fetch (ISSUE 35).
+        assert [holders(steps, f) for f in found] \
+            == [[i] for i in range(part == "token_fetch", 5)]
     admits = by_name["hvd.serve.admit"]
     assert [holders(steps, a) for a in admits] == [[0], [0]]
     assert sorted(a[2]["rid"] for a in admits) == [0, 1]
@@ -769,8 +778,9 @@ def test_decode_program_carries_the_scope_names():
 
     hvd, ex = _toy_executor(requests=0)
     try:
-        lowered = ex.cache._decode_jit.lower(
-            ex.params, ex.cache.tree, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
+        decode, args = ex.cache._decode_call(
+            ex.params, ex._last_tokens, ex._token_on_host)
+        lowered = decode.lower(*args)
         for program in (lowered, ex.cache._prefill_jit.lower(
                 ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))):
             named = program.as_text(debug_info=True)
@@ -802,22 +812,22 @@ def _submit(ex, prompts, max_new):
 
 def _reference(ex):
     """What the replica has to generate for a request, as a function of
-    (prompt, max_new): a loop over tfm.prefill and tfm.decode_step on a
-    cache of the request's own, nothing donated, the prompt padded to
-    the executor's bucket."""
+    (prompt, max_new): a plain greedy loop over the family's own prefill
+    and decode_step on a dense cache of the request's own, nothing
+    donated, the prompt padded to the executor's bucket."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.models import transformer as tfm
     from horovod_tpu.serving.replica import _decode_model_cfg
     from horovod_tpu.serving.slotcache import prompt_bucket
 
+    family = ex.family
     # The dense model: the paged replica's own reads the block pool.
-    model = tfm.TransformerLM(_decode_model_cfg(ex.cfg))
+    model = family.build(_decode_model_cfg(ex.cfg))
     variables = {"params": ex.params}
-    prefill = jax.jit(lambda v, t, n: tfm.prefill(model, v, t, lengths=n))
-    decode = jax.jit(lambda v, c, t: tfm.decode_step(model, v, c, t))
+    prefill = jax.jit(lambda v, t, n: family.prefill(model, v, t, lengths=n))
+    decode = jax.jit(lambda v, c, t: family.decode_step(model, v, c, t))
 
     def stream(prompt, max_new):
         padded = np.zeros((1, prompt_bucket(ex.cfg, len(prompt))),
@@ -852,9 +862,13 @@ def test_every_program_that_writes_the_cache_deletes_the_one_it_got(paged):
         while ex.batcher.inflight_count() or ex.queue.depth():
             before = _cache_leaves(ex)
             admits = ex.stats["steps"]["admit"]
+            programs = ex.stats["decode_dispatches"]
             assert ex._serve_step()
             kinds.append(ex.stats["steps"]["admit"] > admits)
-            assert all(leaf.is_deleted() for leaf in before), kinds
+            # (The last step of a request enqueues nothing: it fetches
+            # the row enqueued a step earlier, ISSUE 35.)
+            if kinds[-1] or ex.stats["decode_dispatches"] > programs:
+                assert all(leaf.is_deleted() for leaf in before), kinds
             assert not any(leaf.is_deleted() for leaf in _cache_leaves(ex))
         assert True in kinds and False in kinds     # both kinds of step
         if paged:
@@ -1243,5 +1257,275 @@ def test_a_stop_plan_ends_a_lone_replicas_loop(monkeypatch):
         ex.serve_loop(stop_when=lambda: True)
         assert ex.stats["served"] == 1 and ex.batcher.inflight == {}
     finally:
+        ex.close()
+        hvd.shutdown()
+
+
+# --- one decode step of lookahead (ISSUE 35) --------------------------------
+def _hybrid_toy():
+    """The hybrid configuration at the rehearsal sizes of its own file
+    (Mamba, attention, Mamba; hidden 64), in float32, and the benchmark's
+    seeded weights for it (under flax's own a token's row of the tied
+    matrix wins every arg-max): (model configuration, parameters)."""
+    import types
+
+    chip = os.path.join(REPO, "benchmarks", "chip")
+    if chip not in sys.path:
+        sys.path.insert(0, chip)
+    import granite_reference
+    import run as harness
+
+    from horovod_tpu.models import hybrid
+    cfg = harness.load_json(harness.HERE, "configs",
+                            "granite-4.0-h-micro.serve.json")
+    cfg = harness.merged(cfg, cfg["rehearsal"])
+    cfg["model"] = {**cfg["model"], "args": {
+        "dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}}
+    return hybrid.HybridConfig(**harness.build_args(cfg)), \
+        granite_reference.weights(types.SimpleNamespace(
+            config=cfg, seed=35, resolve=harness.resolve))
+
+
+_LOOKAHEAD = {"dense-transformer": dict(paged=False),
+              "paged-transformer": dict(paged=True, paged_slots=3),
+              "dense-hybrid": dict(paged=False, hybrid=True)}
+
+
+def _lookahead_executor(kind: str, **kw):
+    """Three slots of the layout and the family ``kind`` names."""
+    from horovod_tpu.serving import ReplicaExecutor, ServeConfig
+
+    layout = dict(_LOOKAHEAD[kind])
+    model_cfg, params = _hybrid_toy() if layout.pop("hybrid", False) \
+        else (None, None)
+    hvd = _solo_world()
+    return hvd, ReplicaExecutor(ServeConfig(**{**dict(
+        model_cfg=model_cfg, max_batch=3, token_budget=64, max_seq=64,
+        slo_ms=60000.0, block_tokens=8, warmup_buckets=(8, 16)),
+        **layout, **kw}), params=params)
+
+
+def _lookahead_requests():
+    """Nine requests of unequal lengths over three slots, in three waves:
+    the later ones are admitted into a batch that is decoding, into
+    slots their predecessors left."""
+    import random
+
+    rng = random.Random(35)
+    lengths = (5, 2, 9, 13, 3, 16, 7, 1, 11)
+    new = (6, 3, 9, 2, 12, 4, 7, 5, 1)
+    prompts = [[rng.randrange(2, 256) for _ in range(n)] for n in lengths]
+    return list(zip(prompts, new))
+
+
+def _drive(ex, waves, each_step=lambda: None) -> dict:
+    """Submit a wave, run three steps, submit the next; then drain.
+    ``each_step`` runs after every step.  rid -> (prompt, max_new)."""
+    asked = {}
+    for wave in waves:
+        for prompt, new in wave:
+            ex.stats["offered"] += 1
+            rid = ex.queue.submit(list(prompt), new)
+            assert rid is not None
+            asked[rid] = (prompt, new)
+        for _ in range(3):
+            assert ex._serve_step()
+            each_step()
+    for _ in range(200):
+        if not (ex.batcher.inflight_count() or ex.queue.depth()):
+            break
+        assert ex._serve_step()
+        each_step()
+    assert ex.stats["served"] == len(asked)
+    return asked
+
+
+@pytest.mark.parametrize("kind", list(_LOOKAHEAD))
+def test_the_lookahead_serves_what_a_plain_greedy_loop_serves(kind):
+    """Admissions into a decoding batch, unequal output lengths, slots
+    used again: request by request the replica serves exactly the tokens
+    of a one-request greedy loop over the family's own decode_step, and
+    nearly every decode program was enqueued with the one before it
+    still unfetched."""
+    hvd, ex = _lookahead_executor(kind)
+    try:
+        requests = _lookahead_requests()
+        asked = _drive(ex, [requests[:4], requests[4:6], requests[6:]])
+        greedy = _reference(ex)
+        for rid, (prompt, new) in asked.items():
+            assert ex.completed[rid]["generated"] == greedy(prompt, new), rid
+            assert ex.completed[rid]["tokens"] == new
+        stats = ex.stats
+        assert stats["decode_dispatches"] > stats["decode_overlapped"] \
+            >= stats["decode_dispatches"] - stats["steps"]["admit"] > 0
+        assert ex._in_flight is None and ex.slots == [None] * 3
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+@pytest.mark.parametrize("kind", list(_LOOKAHEAD))
+def test_a_stream_ends_at_its_end_token_and_the_row_behind_it_is_dropped(
+        kind):
+    """With ``eos_id`` set to a token that greedy decoding emits in
+    mid-stream, the program enqueued behind it has a row for the slot
+    already: that row's token appears nowhere (not in ``generated``, not
+    in the completion's count), the stream ends at its end token, a
+    request is complete only once nothing of it is in flight, and the
+    slot's next occupant is served its own tokens."""
+    hvd, plain = _lookahead_executor(kind)
+    try:
+        greedy = _reference(plain)
+        requests = _lookahead_requests()
+        streams = [greedy(prompt, new) for prompt, new in requests]
+    finally:
+        plain.close()
+        hvd.shutdown()
+    # An end token that cuts a stream short, with tokens still to come.
+    eos = next(stream[j] for stream in streams if len(stream) > 4
+               for j in (2,) if stream[j] not in stream[1:j])
+
+    def until_eos(stream):
+        stop = [j for j in range(1, len(stream)) if stream[j] == eos]
+        return stream[:stop[0] + 1] if stop else stream
+
+    want = [until_eos(stream) for stream in streams]
+    assert any(len(w) < len(s) for w, s in zip(want, streams))
+    hvd, ex = _lookahead_executor(kind, eos_id=eos)
+    try:
+        early = []
+
+        def each_step():
+            for s in ex.slots:
+                if s is not None:
+                    assert s.rid not in ex.completed
+                    early.append(s.remaining == 0 and s.in_flight)
+            for rec in ex.completed.values():
+                assert rec["tokens"] == len(rec["generated"])
+
+        asked = _drive(ex, [requests[:4], requests[4:6], requests[6:]],
+                       each_step)
+        # Some stopped slot did wait for the row enqueued behind its end
+        # token, and was not complete while it waited.
+        assert any(early)
+        for rid, stream in zip(sorted(asked), want):
+            assert ex.completed[rid]["generated"] == stream, rid
+        assert sum(rec["tokens"] for rec in ex.completed.values()) \
+            == sum(map(len, want))
+        assert ex._in_flight is None and ex.slots == [None] * 3
+        if ex.cfg.paged:
+            assert ex.kv_stats()["active"] == 0      # nothing leaked
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+class _Recorded:
+    """The cache's ``decode`` and ``fetch`` with their order kept: each
+    decode gets a number, and a fetch names the decode it waits for."""
+
+    def __init__(self, ex):
+        self.calls, self._ids = [], {}
+        self._kept = []               # alive, so that no id comes twice
+        decode, fetch = ex.cache.decode, ex.cache.fetch
+
+        def recorded_decode(*args):
+            result = decode(*args)
+            self._kept.append(result)
+            self._ids[id(result)] = len(self._ids)
+            self.calls.append(("decode", self._ids[id(result)]))
+            return result
+
+        def recorded_fetch(result):
+            self.calls.append(("fetch", self._ids[id(result)]))
+            return fetch(result)
+
+        ex.cache.decode, ex.cache.fetch = recorded_decode, recorded_fetch
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_a_step_enqueues_the_next_decode_before_it_fetches_the_last(paged):
+    """In a step that admits nothing the dispatch of step k+1 precedes
+    the fetch of step k; a step that admits fetches first and starts the
+    chain again; a request's last step has only a fetch left; the two
+    counters count what happened."""
+    hvd, ex = _executor(paged, max_batch=3)
+    try:
+        seen = _Recorded(ex)
+
+        def step():
+            del seen.calls[:]
+            assert ex._serve_step()
+            return seen.calls
+
+        _submit(ex, [[5, 9, 200], [31, 77, 3, 18]], 6)
+        assert step() == [("decode", 0)]                  # admits both
+        assert step() == [("decode", 1), ("fetch", 0)]
+        assert step() == [("decode", 2), ("fetch", 1)]
+        _submit(ex, [[64, 120]], 3)
+        assert step() == [("fetch", 2), ("decode", 3)]    # admits the third
+        assert step() == [("decode", 4), ("fetch", 3)]
+        assert sorted(len(s.generated) for s in ex.slots if s is not None) \
+            == [2, 5, 5]
+        assert ex.completed == {}      # each one's last row is in flight
+        assert step() == [("fetch", 4)]
+        assert sorted(ex.completed) == [0, 1, 2]
+        assert [ex.completed[rid]["tokens"] for rid in range(3)] == [6, 6, 3]
+        assert (ex.stats["decode_dispatches"],
+                ex.stats["decode_overlapped"]) == (5, 3)
+        assert ex.stats["steps"] == {"admit": 2, "decode": 4}
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+def _settled_by_state_tree(ex):
+    assert set(ex.state_tree()) == {"params"}
+
+
+def _settled_by_close(ex):
+    ex.close()
+
+
+def _settled_by_a_stop(ex):
+    from horovod_tpu.serving.batcher import BatchPlan
+    ex._exchange_plan = lambda plan: BatchPlan(step=ex._step, stop=True)
+    assert ex._serve_step() is False
+
+
+def _settled_by_a_world_change(ex):
+    ex.hvd = _TwoRankHvd()             # answers the resync's gather
+    ex._resync()
+    assert ex.hvd.calls == [("allgather_object", "serve.resync.g0")]
+
+
+def _settled_by_a_grow(ex):
+    ex.hvd = _TwoRankHvd()
+    ex._grow_resync(7, 0, 2)
+    assert ex.size == 2
+
+
+@pytest.mark.parametrize("settle", [
+    _settled_by_state_tree, _settled_by_close, _settled_by_a_stop,
+    _settled_by_a_world_change, _settled_by_a_grow],
+    ids=["state_tree", "close", "stop", "resync", "grow_resync"])
+def test_what_leaves_the_step_leaves_nothing_in_flight(settle):
+    """Whatever reads or replaces the cache, the parameters or the world
+    from outside a step first fetches the decode step in flight and
+    advances its slots: nothing is leaked and no token is lost."""
+    hvd, ex = _toy_executor(requests=2, max_new=8)
+    try:
+        for _ in range(3):
+            assert ex._serve_step()
+        assert ex._in_flight is not None
+        assert [(len(s.generated), s.in_flight) for s in ex.slots] \
+            == [(3, 1), (3, 1)]
+        settle(ex)
+        assert ex._in_flight is None
+        assert [(len(s.generated), s.in_flight, s.remaining)
+                for s in ex.slots] == [(4, 0, 4), (4, 0, 4)]
+        assert list(ex._last_tokens) == [s.generated[-1] for s in ex.slots]
+    finally:
+        ex.hvd = hvd
         ex.close()
         hvd.shutdown()

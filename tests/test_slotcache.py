@@ -198,7 +198,9 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
     """Admit, three decode steps, release, admit again into the same
     slot (beside a second request that keeps decoding): every token is
     the one ``tfm.prefill`` and ``tfm.decode_step`` give the request on
-    a cache of its own."""
+    a cache of its own.  A step is enqueued before the one before it is
+    fetched (ISSUE 35): a slot's input token is the host's after an
+    admission and the last step's result, on the device, otherwise."""
     from horovod_tpu.serving import slotcache
     from horovod_tpu.serving.replica import _decode_model_cfg, _seeded_params
 
@@ -216,20 +218,33 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
         cache = slotcache.DenseSlotCache(cfg, tfm.FAMILY, dense, stats := {})
     slots = [None] * cfg.slots
     last = np.zeros(cfg.slots, np.int32)
+    from_host = np.ones(cfg.slots, bool)
     got: dict[int, list] = {}
+    flying: list = []                 # (result, the rids it decodes for)
 
     def admit(slot, rid, prompt):
         last[slot] = cache.admit(params, slot, prompt, 8)
+        from_host[slot] = True
         slots[slot] = types.SimpleNamespace(seq_len=len(prompt), rid=rid)
         got[rid] = [int(last[slot])]
 
+    def fetch():
+        result, rids = flying.pop()
+        nxt = cache.fetch(result)
+        for i, rid in rids:
+            got[rid].append(int(nxt[i]))
+
     def step():
+        """Enqueue the next step, then fetch the one before it."""
         active = [i for i, s in enumerate(slots) if s is not None]
-        nxt = np.asarray(cache.decode(params, last, active, slots))
+        result = cache.decode(params, last, from_host, active, slots)
+        from_host[:] = False
+        last[:] = -1                  # the host's copy is not read again
         for i in active:
-            last[i] = nxt[i]
             slots[i].seq_len += 1
-            got[slots[i].rid].append(int(nxt[i]))
+        if flying:
+            fetch()
+        flying.append((result, [(i, slots[i].rid) for i in active]))
 
     def reference(prompt, count):
         padded = np.zeros((1, slotcache.prompt_bucket(cfg, len(prompt))),
@@ -256,11 +271,13 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
         admit(1, 1, prompts[1])
         for _ in range(3):
             step()
+        fetch()                        # an admission settles what flies
         cache.release(0)
         slots[0] = None
         admit(0, 2, prompts[2])        # the same slot, a longer prompt
         for _ in range(3):
             step()
+        fetch()
         for rid, prompt in prompts.items():
             assert got[rid] == reference(prompt, len(got[rid])), rid
         assert [len(got[rid]) for rid in (0, 1, 2)] == [4, 7, 4]
@@ -336,13 +353,16 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
             slots[rid] = types.SimpleNamespace(seq_len=len(prompt))
             got[rid] = [int(last[rid])]
         live = read = 0
-        for _ in range(steps):
-            nxt = np.asarray(cache.decode(params, last, [0, 1, 2], slots))
+        for step in range(steps):
+            # The host's tokens first, then the last result's own.
+            nxt = cache.fetch(cache.decode(
+                params, last, np.full(cfg.slots, step == 0), [0, 1, 2],
+                slots))
             for rid in prompts:
                 after = slots[rid].seq_len + 1
                 live += after
                 read += -(-after // block) * block if kernel else cfg.max_seq
-                last[rid] = nxt[rid]
+                last[rid] = -1
                 slots[rid].seq_len += 1
                 got[rid].append(int(nxt[rid]))
         assert got == want

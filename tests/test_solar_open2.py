@@ -685,8 +685,9 @@ def test_the_replica_serves_the_references_best_and_counts_the_routing(
 def test_the_programs_carry_the_scope_and_kernel_names(toy, solo_world):
     ex = executor(model_config(toy))
     try:
-        decode = ex.cache._decode_jit.lower(
-            ex.params, ex.cache.tree, jnp.zeros((ex.cfg.slots, 1), jnp.int32))
+        decode, args = ex.cache._decode_call(
+            ex.params, ex._last_tokens, ex._token_on_host)
+        decode = decode.lower(*args)
         prefill = ex.cache._prefill_jit.lower(
             ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))
         for program, scopes in (
