@@ -7,8 +7,8 @@ in four, routed experts).  What sets it apart from
 ``models/transformer.py``, all of it configuration and none of it a
 switch there:
 
-- layer kinds by list (``layer_types``: ``"mamba"``, ``"kda"`` or
-  ``"attention"``);
+- layer kinds by list (``layer_types``: ``"mamba"``, ``"kda"``,
+  ``"attention"`` or ``"window"``);
 - grouped-query attention with **no positional encoding** and a score
   scale that is a published constant (``attention_multiplier``);
 - scaled residuals (``h + residual_multiplier * f(norm(h))``), an
@@ -28,10 +28,20 @@ switch there:
   routed expert block of ``models/moe.py`` in place of the MLP where
   ``num_experts`` is set, an untied head (``tie_embeddings=False``).
 
-RMSNorm and the gated MLP are ``transformer.py``'s.
+- for Xiaomi's MiMo-V2 (``mimo_v2``): a second kind of softmax layer,
+  ``"window"`` (the last ``window`` keys, a learned sink a query head in
+  the softmax, key-value heads and a rotary base of its own), keys and
+  queries wider than values (``attn_value_dim``), rotary positions on
+  the first ``attn_rotary_dim`` channels of a head, values scaled
+  (``attn_value_scale``), a dense MLP in the layers ``dense_layers``
+  lists and the expert block elsewhere, the router's correction bias
+  (``router_bias``) and no shared expert.
+
+RMSNorm, the gated MLP and ``apply_rope`` are ``transformer.py``'s.
 
 Serving (``decode=True``): the cache collection keeps the attention
-layers' keys, values and write cursors (``models/kvcache.py``) and gains,
+layers' keys, values and write cursors (``models/kvcache.py``; a window
+layer's are rings of ``window`` positions) and gains,
 for each Mamba layer, ``conv_state`` [B, conv - 1, channels] (the last
 inputs of the convolution, in the activations' type) and ``ssm_state``
 (the H state matrices of P x N, **float32**: the recurrence sums over
@@ -61,10 +71,10 @@ from . import moe
 from .family import ModelFamily
 from .kvcache import (attend, cached_attention, decode_step, fresh_cache,
                       prefill)
-from .transformer import MLP, RMSNorm
+from .transformer import MLP, RMSNorm, apply_rope
 
 STATE_LEAVES = ("conv_state", "ssm_state", "kda_state")
-KINDS = ("mamba", "kda", "attention")
+KINDS = ("mamba", "kda", "attention", "window")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +89,18 @@ class HybridConfig:
     attention_multiplier: float = 0.25       # the score scale itself
     attn_head_dim: int = 0           # 0: d_model // num_heads
     attn_gate: bool = False          # out * sigmoid(W_gate x), elementwise
+    attn_value_dim: int = 0          # 0: as wide as the keys
+    attn_value_scale: float = 1.0    # sum_j p_ij (scale * v_j)
+    attn_rotary_dim: int = 0         # channels of a head that rotate; 0: none
+    rope_theta: float = 10000.0
+    attn_sink: bool = False          # a learned column of the softmax
+    # "window" layers: the last ``window`` keys, the query's own among
+    # them; their own key-value heads (0: num_kv_heads), rotary base (0:
+    # rope_theta) and sink
+    window: int = 0
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_sink: bool = False
     # Mamba-2 layers: heads x head_dim inner channels, one group
     mamba_heads: int = 4
     mamba_head_dim: int = 16
@@ -100,6 +122,8 @@ class HybridConfig:
     shared_experts: int = 1
     norm_topk: bool = True
     routed_scaling: float = 1.0
+    router_bias: bool = False        # a correction bias in the choice
+    dense_layers: tuple = ()         # layers that keep the MLP of d_ff
     # the residual stream
     residual_multiplier: float = 1.0
     embedding_multiplier: float = 1.0
@@ -119,14 +143,24 @@ class HybridConfig:
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
         if self.num_heads % self.num_kv_heads or \
+                self.num_heads % (self.window_kv_heads or 1) or \
                 (not self.attn_head_dim and self.d_model % self.num_heads):
             raise ValueError(
                 f"{self.num_heads} query heads over {self.num_kv_heads} "
                 f"key-value heads at width {self.d_model}")
+        if "window" in self.layer_types and self.window < 1:
+            raise ValueError("a \"window\" layer needs ``window``")
+        if self.attn_rotary_dim % 2 or self.attn_rotary_dim > self.head_dim:
+            raise ValueError(f"{self.attn_rotary_dim} rotary channels of "
+                             f"{self.head_dim}")
 
     @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.d_model // self.num_heads
+
+    @property
+    def value_dim(self) -> int:
+        return self.attn_value_dim or self.head_dim
 
     @property
     def ff_dim(self) -> int:
@@ -158,29 +192,67 @@ class HybridConfig:
         return ROUTED_FAMILY if self.num_experts else FAMILY
 
 
+def _rotate(x: jax.Array, positions: jax.Array, *, width: int,
+            theta: float) -> jax.Array:
+    """Rotary positions on the first ``width`` channels of every head of
+    ``x`` [B, T, H, D], pairs ``(i, i + width / 2)`` as ``apply_rope``
+    pairs them; the other channels as they are."""
+    if width == x.shape[-1]:
+        return apply_rope(x, positions, theta)
+    return jnp.concatenate([apply_rope(x[..., :width], positions, theta),
+                            x[..., width:]], axis=-1)
+
+
 class GroupedAttention(nn.Module):
-    """Query head ``h`` reads key-value head ``h // group``; no position
-    term; ``softmax(attention_multiplier * q k^T + causal mask) v``, with
+    """Query head ``h`` reads key-value head ``h // group``;
+    ``softmax(attention_multiplier * q k^T + causal mask) v``, with
     ``attn_gate`` times ``sigmoid(W_gate x)`` a channel before ``wo``.
-    Heads are ``cfg.head_dim`` wide, whatever ``d_model`` is."""
+    Heads are ``cfg.head_dim`` wide, whatever ``d_model`` is, their
+    values ``cfg.value_dim``; no position term unless
+    ``attn_rotary_dim``.  ``windowed`` is the ``"window"`` kind: the
+    last ``cfg.window`` keys, and the window's own key-value heads,
+    rotary base and sink."""
     cfg: HybridConfig
+    windowed: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, lengths=None) -> jax.Array:
         cfg = self.cfg
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype)
-        kv, d = cfg.num_kv_heads, cfg.head_dim
+        d, wide = cfg.head_dim, cfg.value_dim
+        kv, theta, has_sink, window = cfg.num_kv_heads, cfg.rope_theta, \
+            cfg.attn_sink, 0
+        if self.windowed:
+            kv, theta, has_sink, window = (
+                cfg.window_kv_heads or kv, cfg.window_rope_theta or theta,
+                cfg.window_sink, cfg.window)
         q = dense(features=(cfg.num_heads, d), name="wq")(x)
         k = dense(features=(kv, d), name="wk")(x)
-        v = dense(features=(kv, d), name="wv")(x)
+        v = dense(features=(kv, wide), name="wv")(x)
+        sink = self.param("sink", nn.initializers.normal(1.0),
+                          (cfg.num_heads,), jnp.float32) if has_sink else None
+        rotate = partial(_rotate, width=cfg.attn_rotary_dim, theta=theta) \
+            if cfg.attn_rotary_dim else None
+        # What the softmax is fed (before positions), for a caller that
+        # replays a stream and checks what came out (nothing is kept
+        # else).
+        for name, fed in (("q", q), ("k", k), ("v", v)):
+            self.sow("attention", name, fed)
         if cfg.decode and not self.is_initializing():
             out = cached_attention(
                 self, q, k, v, max_seq_len=cfg.max_seq_len,
-                dtype=cfg.dtype, scale=cfg.attention_multiplier)
+                dtype=cfg.dtype, scale=cfg.attention_multiplier,
+                rotate=rotate, window=window, sink=sink, lengths=lengths)
         else:
-            out = attend(q, k, v, jnp.arange(x.shape[1])[None, :],
-                         cfg.attention_multiplier)
+            positions = jnp.arange(x.shape[1])[None, :]
+            if rotate is not None:
+                q, k = rotate(q, positions), rotate(k, positions)
+            out = attend(q, k, v, positions, cfg.attention_multiplier, sink,
+                         window)
+        if cfg.attn_value_scale != 1.0:    # on the sum: the same function
+            out = out * cfg.attn_value_scale
+        self.sow("attention", "out", out)
         if cfg.attn_gate:
             gate = dense(features=(cfg.num_heads, d), name="wg")(x)
             out = out.astype(jnp.float32) \
@@ -368,6 +440,7 @@ def _dt_bias_init(key, shape, dtype):
 class HybridBlock(nn.Module):
     cfg: HybridConfig
     kind: str
+    dense: bool = False     # the MLP of d_ff, whatever num_experts says
 
     @nn.compact
     def __call__(self, x: jax.Array, lengths=None) -> jax.Array:
@@ -375,18 +448,19 @@ class HybridBlock(nn.Module):
         norm = partial(RMSNorm, cfg.dtype, cfg.param_dtype,
                        cfg.rms_norm_eps)
         mixed = norm(name="mixer_norm")(x)
-        if self.kind == "attention":
-            mixed = GroupedAttention(cfg, name="attn")(mixed)
+        if self.kind in ("attention", "window"):
+            mixed = GroupedAttention(cfg, self.kind == "window",
+                                     name="attn")(mixed, lengths)
         else:
             mixer = Mamba2Mixer if self.kind == "mamba" else KDAMixer
             mixed = mixer(cfg, name=self.kind)(mixed, lengths)
         x = x + cfg.residual_multiplier * mixed
-        ffn = MLP(cfg, name="mlp") if not cfg.num_experts \
+        ffn = MLP(cfg, name="mlp") if self.dense or not cfg.num_experts \
             else moe.RoutedExperts(
                 cfg.num_experts, cfg.experts_per_token, cfg.expert_ff,
                 tuple(cfg.experts_held), cfg.shared_experts, cfg.norm_topk,
                 cfg.routed_scaling, cfg.dtype, cfg.param_dtype,
-                cfg.interpret, name="moe")
+                cfg.interpret, bias=cfg.router_bias, name="moe")
         return x + cfg.residual_multiplier * ffn(norm(name="mlp_norm")(x))
 
 
@@ -404,7 +478,8 @@ class HybridLM(nn.Module):
                          param_dtype=cfg.param_dtype, name="embed")
         x = embed(tokens) * cfg.embedding_multiplier
         for i, kind in enumerate(cfg.layer_types):
-            x = HybridBlock(cfg, kind, name=f"layer_{i}")(x, lengths)
+            x = HybridBlock(cfg, kind, i in cfg.dense_layers,
+                            name=f"layer_{i}")(x, lengths)
         x = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.rms_norm_eps,
                     name="final_norm")(x)
         logits = embed.attend(x) if cfg.tie_embeddings \

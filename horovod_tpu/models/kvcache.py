@@ -6,7 +6,11 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
 
 - dense: ``cached_key`` / ``cached_value`` [B, max_seq_len, KV, D] and
   ``cache_index`` [B], each row's write cursor (``cached_attention``,
-  ``prefill``, ``decode_step``);
+  ``prefill``, ``decode_step``); values may be narrower than keys; a
+  window layer keeps rings of ``window`` rows instead, ``ring_key`` /
+  ``ring_value``; fewer than 8 bfloat16 key-value heads lie side by side
+  in the lanes, [B, max_seq_len, KV * D]
+  (``ops/decode_attention.py:lanes_layout``);
 - paged: ``key_pool`` / ``value_pool`` [blocks + 1, block_tokens, KV, D]
   shared by every row and addressed through block tables, the last row
   a write sink for padded positions (``paged_attention``, ``paged_*``).
@@ -14,7 +18,9 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
 Both write this call's keys and values, then ``attend``; a decode step
 on the dense layout (one query position a row) goes through
 ``decode_attend``, which on a TPU reads each row's keys and values up to
-its own live length.  A family's
+its own live length; a long prompt, and any prompt of a window layer,
+attends over its own keys and values in blocks (``attend_blocked``).
+A family's
 attention layer (``transformer.Attention``, ``hybrid.GroupedAttention``)
 brings its projections, its positional encoding and its score scale,
 and writes no cache code of its own.
@@ -25,39 +31,97 @@ import jax
 import jax.numpy as jnp
 from flax.core import unfreeze
 
-# The one attention over the cache, in its plain form and as a decode
-# step's kernel (ops/decode_attention.py).
-from ..ops.decode_attention import attend_plain as attend, decode_attend
+# The one attention over the cache, in its plain form, as a decode
+# step's kernel and in blocks over a whole prompt
+# (ops/decode_attention.py).
+from ..ops import decode_attention
+from ..ops.decode_attention import (attend_blocked, attend_plain as attend,
+                                    decode_attend)
+
+# A prefill whose float32 scores over its row of the cache would pass
+# this many bytes goes in blocks over its own keys instead (a prompt of
+# 8,192 tokens in a cache of 12,288 positions, 64 heads: 25.8 GB); under
+# it the prefill programs are the ones PRs up to 35 compiled.
+PLAIN_PREFILL_BYTES = 1 << 30
 
 
 def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
                      max_seq_len: int, dtype, scale: float,
-                     rotate=None) -> jax.Array:
+                     rotate=None, window: int = 0, sink=None,
+                     lengths=None) -> jax.Array:
     """Incremental attention over ``module``'s dense cache: write this
     call's K/V at each row's own depth, attend over the cached prefix.
     ``rotate(x, positions)`` is the family's positional encoding, if it
-    has one; positions are absolute, so the math is the full forward's."""
+    has one; positions are absolute, so the math is the full forward's.
+    ``v`` may be narrower than ``k``; ``sink`` [H] is a column of the
+    softmax with no value (ops/decode_attention.py).
+
+    With a ``window`` the leaves are rings, ``ring_key`` / ``ring_value``
+    [B, window, KV, D]: position ``p`` lives at row ``p mod window``
+    (rotary positions are in the keys before they are written, so the
+    ring's order does not matter).  A decode step writes row ``index mod
+    window`` and attends over the ``min(index + 1, window)`` rows that
+    are live; a prompt (a call that starts its rows: a ring takes a
+    whole prompt or one token) attends over its own keys and values in
+    blocks, then the ring takes the last ``window`` positions before
+    ``lengths``, the true length of a right-padded row, never the
+    padding."""
     b, t, kv, d = k.shape
-    shape = (b, max_seq_len, kv, d)
-    cached_k = module.variable("cache", "cached_key", jnp.zeros, shape,
-                               dtype)
-    cached_v = module.variable("cache", "cached_value", jnp.zeros, shape,
-                               dtype)
+    h, dv = q.shape[2], v.shape[-1]
+    rows = window or max_seq_len
+    names = ("ring_key", "ring_value") if window \
+        else ("cached_key", "cached_value")
+    starts = not module.has_variable("cache", names[0])
+    lanes = not window and decode_attention.lanes_layout(kv, d, dv, dtype)
+
+    def leaf(name, wide):
+        shape = (b, rows, kv * wide) if lanes else (b, rows, kv, wide)
+        return module.variable("cache", name, jnp.zeros, shape, dtype)
+
+    cached_k, cached_v = leaf(names[0], d), leaf(names[1], dv)
     index = module.variable("cache", "cache_index",
                             lambda: jnp.zeros((b,), jnp.int32))
     idx = index.value                                       # [B]
     positions = idx[:, None] + jnp.arange(t)[None, :]       # [B, T]
     if rotate is not None:
         q, k = rotate(q, positions), rotate(k, positions)
-    write = jax.vmap(lambda cache, new, i:
-                     jax.lax.dynamic_update_slice(cache, new, (i, 0, 0)))
-    cached_k.value = write(cached_k.value, k.astype(dtype), idx)
-    cached_v.value = write(cached_v.value, v.astype(dtype), idx)
     index.value = idx + t
+    k, v = k.astype(dtype), v.astype(dtype)
+    write = jax.vmap(lambda cache, new, i: jax.lax.dynamic_update_slice(
+        cache, new, (i,) + (0,) * (cache.ndim - 1)))
+    if lanes:
+        k, v = k.reshape(b, t, kv * d), v.reshape(b, t, kv * dv)
+    if t == 1 or not window:
+        at = idx % window if window else idx
+        cached_k.value = write(cached_k.value, k, at)
+        cached_v.value = write(cached_v.value, v, at)
     if t == 1:     # a decode step: each row up to its own length, no further
-        return decode_attend(q, cached_k.value, cached_v.value, idx + 1,
-                             scale)
-    return attend(q, cached_k.value, cached_v.value, positions, scale)
+        return decode_attend(
+            q, cached_k.value, cached_v.value,
+            jnp.minimum(idx + 1, window) if window else idx + 1, scale,
+            sink,
+            scope="hvd.window_attend" if window else "hvd.decode_attend")
+    if window:
+        if not starts:
+            raise ValueError("a window layer's ring takes a whole prompt "
+                             "or one token")
+        # Ring row r: the last position before the true length that
+        # lies at r, and nothing where the prompt is shorter than that.
+        end = jnp.full((b,), t, jnp.int32) if lengths is None \
+            else jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (b,))
+        last = end[:, None] - 1
+        held = last - (last - jnp.arange(window)[None, :]) % window
+        for cache, new in ((cached_k, k), (cached_v, v)):
+            cache.value = jnp.where(
+                (held >= 0)[:, :, None, None], jnp.take_along_axis(
+                    new, jnp.clip(held, 0, t - 1)[:, :, None, None], axis=1),
+                jnp.zeros((), dtype))
+    elif not (starts and 4 * h * t * max_seq_len > PLAIN_PREFILL_BYTES):
+        return attend(q, cached_k.value, cached_v.value, positions, scale,
+                      sink)
+    if lanes:
+        k, v = k.reshape(b, t, kv, d), v.reshape(b, t, kv, dv)
+    return attend_blocked(q, k, v, scale, window=window, sink=sink)
 
 
 def paged_attention(module, q: jax.Array, k: jax.Array, v: jax.Array,

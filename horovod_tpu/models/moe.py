@@ -170,7 +170,7 @@ def _on_tpu() -> bool:
 
 
 def route(scores: jax.Array, per_token: int, held: tuple, *,
-          norm: bool = True, scaling: float = 1.0):
+          norm: bool = True, scaling: float = 1.0, bias=None):
     """``scores`` [N, E] float32 (the router's, over every expert of the
     model) -> the ``per_token`` largest a token: ``(weights [N, k]
     float32, local [N, k], here [N, k])``: each chosen expert's weight
@@ -178,9 +178,14 @@ def route(scores: jax.Array, per_token: int, held: tuple, *,
     ``scaling``), its index among the experts held (``first`` ..
     ``first + count - 1`` of the model's) and whether it is held at all.
     A token whose experts all live elsewhere gets nothing here; none is
-    dropped for want of room."""
+    dropped for want of room.  ``bias`` [E] float32, a router's
+    correction bias, enters the choice (the largest of ``scores +
+    bias``) and not the weights."""
     first, count = held
-    top, chosen = lax.top_k(scores, per_token)
+    top, chosen = lax.top_k(scores if bias is None else scores + bias,
+                            per_token)
+    if bias is not None:
+        top = jnp.take_along_axis(scores, chosen, axis=-1)
     if norm:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     local = chosen - first
@@ -326,8 +331,9 @@ class RoutedExperts(nn.Module):
     expert-parallel deployment runs it.  Input [B, T, D] -> [B, T, D].
 
     ``s = sigmoid(W_r x)`` over all ``num_experts`` of the model, the
-    ``per_token`` largest, weights ``s_e / sum of the chosen``
-    (``norm_topk``) times ``scaling``; ``y = sum_e w_e E_e(x) +
+    ``per_token`` largest (of ``s + c`` with a correction ``bias``
+    ``c``, which the weights leave out), weights ``s_e / sum of the
+    chosen`` (``norm_topk``) times ``scaling``; ``y = sum_e w_e E_e(x) +
     E_shared(x)`` with ``E(x) = W_down (silu(W_gate x) * W_up x)`` at
     width ``d_ff`` (the shared expert ``shared`` times as wide).  The
     sum runs over the chosen experts among ``held = (first, count)``,
@@ -351,6 +357,7 @@ class RoutedExperts(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     interpret: bool = False          # run hvd.moe_experts interpreted
+    bias: bool = False               # a correction bias, float32 [E]
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -369,6 +376,9 @@ class RoutedExperts(nn.Module):
                         self.param_dtype)
         down = self.param("experts_down", init, (count, self.d_ff, d),
                           self.param_dtype)
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (self.num_experts,), jnp.float32) \
+            if self.bias else None
         dense = partial(nn.Dense, use_bias=False, dtype=self.dtype,
                         param_dtype=self.param_dtype)
         shared = 0.0
@@ -386,7 +396,7 @@ class RoutedExperts(nn.Module):
                 precision=lax.Precision.HIGHEST))
             weights, local, here = route(
                 scores, self.per_token, self.held, norm=self.norm_topk,
-                scaling=self.scaling)
+                scaling=self.scaling, bias=bias)
             tile = tile_rows(b * t, self.per_token, self.num_experts)
             row_token, at, tile_expert, tiles, sizes = group_rows(
                 local, here, count, tile)
@@ -394,9 +404,12 @@ class RoutedExperts(nn.Module):
         for name, value in zip(COUNTERS, (
                 here.size, jnp.sum(here), jnp.sum(sizes > 0), count)):
             self.sow("counters", name, jnp.asarray(value, jnp.int32))
-        # Which experts of the model each token took, [N, k]: for a
+        # Which experts of the model each token took, [N, k], its scores
+        # over all of them and the weights it gave the chosen: for a
         # caller that replays a stream and asks (nothing is kept else).
         self.sow("routing", "chosen", local + first)
+        self.sow("routing", "scores", scores)
+        self.sow("routing", "weights", weights)
         gate, up, down = (w.astype(self.dtype) for w in (gate, up, down))
         if _on_tpu() or self.interpret:
             routed = _experts_pallas(

@@ -1,7 +1,10 @@
-"""Attention over the dense serving cache, in its two forms.
+"""Attention over the dense serving cache, in its forms.
 
 ``softmax(scale * q k^T + causal mask) v`` in float32, query head ``h``
-reading key-value head ``h // (H // KV)``:
+reading key-value head ``h // (H // KV)``; values may be narrower than
+keys; with a ``sink`` [H] a learned scalar a query head takes part in
+the softmax as one more column that carries no value; with a ``window``
+key ``j`` is visible at ``i`` when ``i - window < j <= i``:
 
 - ``attend_plain``: any number of query positions, in plain
   ``jax.numpy``; it reads every cached position and masks the dead ones
@@ -16,9 +19,25 @@ reading key-value head ``h // (H // KV)``:
   past a slot's length repeats the one before it, so nothing is
   fetched for it, and its arithmetic is skipped.  One compiled program
   serves every mix of lengths.
+- ``attend_blocked``: a whole prompt over its own keys and values, in
+  blocks of query positions with the online softmax across key blocks
+  (plain ``jax.numpy`` under the scope ``hvd.prefill_attend``): a query
+  block visits the key blocks up to its own, a window layer's only the
+  last ``ceil(window / block) + 1`` of them, so a prefill holds ``block
+  x block`` scores a head at a time and not ``T x max_seq``.
 
-The kernel takes the cache leaves as they lie, ``[B, S, KV, D]``: merged
-to ``[B, S, KV * D]`` they would be copied on the device every step (the
+Two layouts of the leaves, by ``lanes_layout``.  Heads that fill tiles
+of sublanes lie ``[B, S, KV, D]``.  Fewer than 8 key-value heads would
+leave half of every tile empty (or lie position-minor), so their leaves
+keep the heads side by side in the lanes, ``[B, S, KV * D]`` keys and
+``[B, S, KV * Dv]`` values, nothing padded: there the kernel multiplies
+every query, laid at its own head's lanes of a zero row, with the whole
+key row (the matrix unit loads the same key tiles either way), runs the
+softmax a query head a sublane, and meets each head's values, an
+aligned slice of lanes, in one product a head.
+
+The first kernel takes the cache leaves as they lie, ``[B, S, KV, D]``:
+merged to ``[B, S, KV * D]`` they would be copied on the device every step (the
 tiles of the last two dimensions differ).  A block of positions is read
 as ``[block * KV, D]``, a row a (position, head) pair, and stays bfloat16
 up to the matrix unit.  Scores for all heads come from one product of
@@ -65,44 +84,157 @@ def _on_tpu() -> bool:
 # ---------------------------------------------------------------------------
 # The plain form
 # ---------------------------------------------------------------------------
+def _by_head(q, keys, values):
+    """The leaves as ``[B, S, KV, D]`` and ``[B, S, KV, Dv]``, whichever
+    way they lie (``lanes_layout``)."""
+    if keys.ndim == 4:
+        return keys, values
+    b, s, width = keys.shape
+    kv = width // q.shape[-1]
+    return keys.reshape(b, s, kv, -1), values.reshape(b, s, kv, -1)
+
+
 def attend_plain(q: jax.Array, keys: jax.Array, values: jax.Array,
-                 positions, scale: float) -> jax.Array:
+                 positions, scale: float, sink=None, window: int = 0,
+                 scope: str = "hvd.decode_attend") -> jax.Array:
     """``q`` [B, T, H, D] at absolute ``positions`` [B|1, T] over
-    ``keys`` / ``values`` [B, S, KV, D] (a group of 1 is plain
-    multi-head).  Key ``s`` is visible at position ``p`` when ``s <= p``:
-    right-padded prefill garbage and unwritten positions sit past every
-    live query."""
+    ``keys`` [B, S, KV, D] / ``values`` [B, S, KV, Dv] (a group of 1 is
+    plain multi-head) -> float32 [B, T, H, Dv].  Key ``s`` is visible at
+    position ``p`` when ``s <= p`` (and ``p - s < window``, where there
+    is one): right-padded prefill garbage and unwritten positions sit
+    past every live query.  ``sink`` [H]: one more column of the softmax
+    a query head, with no value."""
+    keys, values = _by_head(q, keys, values)
     b, t, h, d = q.shape
     kv = keys.shape[2]
-    with jax.named_scope("hvd.decode_attend"):
-        mask = jnp.arange(keys.shape[1])[None, None, :] \
-            <= positions[:, :, None]                           # [B|1, T, S]
+    with jax.named_scope(scope):
+        at = jnp.arange(keys.shape[1])[None, None, :]
+        mask = at <= positions[:, :, None]                     # [B|1, T, S]
+        if window:
+            mask &= positions[:, :, None] - at < window
         qf = q.astype(jnp.float32).reshape(b, t, kv, h // kv, d)
         scores = jnp.einsum("bqkgd,bskd->bkgqs", qf,
                             keys.astype(jnp.float32)) * scale
         scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
-        out = jnp.einsum("bkgqs,bskd->bqkgd",
-                         jax.nn.softmax(scores, axis=-1),
+        if sink is None:
+            weights = jax.nn.softmax(scores, axis=-1)
+        else:
+            column = jnp.broadcast_to(
+                sink.astype(jnp.float32).reshape(1, kv, h // kv, 1, 1),
+                (*scores.shape[:-1], 1))
+            weights = jax.nn.softmax(
+                jnp.concatenate([scores, column], axis=-1),
+                axis=-1)[..., :-1]
+        out = jnp.einsum("bkgqs,bskd->bqkgd", weights,
                          values.astype(jnp.float32))
-    return out.reshape(b, t, h, d)
+    return out.reshape(b, t, h, values.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# A whole prompt, in blocks
+# ---------------------------------------------------------------------------
+PREFILL_BLOCK = 512     # query and key positions of one block
+
+
+def attend_blocked(q: jax.Array, k: jax.Array, v: jax.Array, scale: float,
+                   *, window: int = 0, sink=None,
+                   block: int = PREFILL_BLOCK) -> jax.Array:
+    """``q`` [B, T, H, D] at positions 0 to T - 1 over this call's own
+    ``k`` [B, T, KV, D] and ``v`` [B, T, KV, Dv] -> float32 [B, T, H,
+    Dv], what ``attend_plain`` gives for them, holding one block of
+    queries against one block of keys at a time."""
+    b, t, h, d = q.shape
+    kv, dv = k.shape[2], v.shape[-1]
+    group = h // kv
+    block = min(block, t)
+    pad = -t % block
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for x in (q, k, v))
+    blocks = (t + pad) // block
+    behind = -(-window // block) if window else blocks
+    q = q.reshape(b, blocks, block, kv, group, d)
+    k = k.reshape(b, blocks, block, kv, d)
+    v = v.reshape(b, blocks, block, kv, dv)
+    if sink is None:
+        first = jnp.full((b, kv, group, block), NEG_INF, jnp.float32)
+    else:
+        first = jnp.broadcast_to(sink.astype(jnp.float32).reshape(
+            1, kv, group, 1), (b, kv, group, block))
+    at = jnp.arange(block)
+
+    def one(i):
+        """Query block ``i`` against the key blocks it can see."""
+        mine = jax.lax.dynamic_index_in_dim(q, i, 1, keepdims=False)
+        q_at = i * block + at[:, None]
+
+        def step(j, carry):
+            m, total, acc = carry
+            keys = jax.lax.dynamic_index_in_dim(k, j, 1, keepdims=False)
+            vals = jax.lax.dynamic_index_in_dim(v, j, 1, keepdims=False)
+            k_at = j * block + at[None, :]
+            seen = k_at <= q_at
+            if window:
+                seen &= q_at - k_at < window
+            s = jnp.einsum("bqkgd,bskd->bkgqs", mine, keys,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen, s, NEG_INF)
+            # A row with nothing visible yet weighs its block evenly;
+            # its own block comes last and wipes that (alpha = 0).
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bkgqs,bskd->bkgqd", p.astype(vals.dtype), vals,
+                preferred_element_type=jnp.float32)
+            return m_new, total * alpha + jnp.sum(p, axis=-1), acc
+
+        _, total, acc = jax.lax.fori_loop(
+            jnp.maximum(i - behind, 0), i + 1, step,
+            (first, jnp.where(first > NEG_INF / 2, 1.0, 0.0),
+             jnp.zeros((b, kv, group, block, dv), jnp.float32)))
+        return jnp.transpose(acc / total[..., None], (0, 3, 1, 2, 4))
+
+    with jax.named_scope("hvd.prefill_attend"):
+        out = jax.lax.map(one, jnp.arange(blocks))   # [blocks, B, block, ...]
+    return jnp.moveaxis(out, 0, 1).reshape(b, blocks * block, h, dv)[:, :t]
 
 
 # ---------------------------------------------------------------------------
 # What the kernel reads
 # ---------------------------------------------------------------------------
+def lanes_layout(kv_heads: int, head_dim: int, value_dim: int,
+                 dtype) -> bool:
+    """Whether leaves of these heads keep them side by side in the
+    lanes, ``[B, S, KV * D]`` and ``[B, S, KV * Dv]``: bfloat16 heads
+    too few to fill a tile of 8 sublanes (``[B, S, KV, D]`` would be
+    padded to twice its bytes there, or lie position-minor and be
+    copied every step) whose rows come to whole lanes.  The 8 heads of
+    the granite and Solar cells fill a tile and stay as they were."""
+    return jnp.dtype(dtype) == jnp.bfloat16 and kv_heads < 8 \
+        and not (kv_heads * head_dim) % _LANE \
+        and not (kv_heads * value_dim) % _LANE
+
+
 def block_positions(max_seq: int, kv_heads: int, head_dim: int,
-                    dtype) -> int:
+                    dtype, value_dim: int = 0) -> int:
     """How many positions one block of the kernel holds for leaves
     ``[B, max_seq, kv_heads, head_dim]`` of ``dtype``: the largest
     power of two that divides ``max_seq`` and keeps a key block within
-    ``_BLOCK_BYTES`` (128 positions of the 7B shape).  0, and the plain
-    form runs, where the kernel cannot take the leaves as they lie: it
-    wants bfloat16, a head width of whole lanes (a narrower leaf lies
+    ``_BLOCK_BYTES`` (128 positions of the 7B shape, 512 of MiMo's four
+    heads of 192).  0, and the plain form runs, where the kernel cannot
+    take the leaves as they lie: it wants bfloat16 and either
+    ``lanes_layout`` or, heads in the sublanes, values as wide as the
+    keys, a head width of whole lanes (a narrower leaf lies
     position-minor on the device, ``{1,3,2,0}``, and would be copied
     every step) and key-value heads that fill whole sublane tiles and
     divide the lanes."""
-    if jnp.dtype(dtype) != jnp.bfloat16 or head_dim % _LANE \
-            or kv_heads % 16 or _LANE % kv_heads:
+    value_dim = value_dim or head_dim
+    if jnp.dtype(dtype) != jnp.bfloat16:
+        return 0
+    if not lanes_layout(kv_heads, head_dim, value_dim, dtype) and (
+            head_dim % _LANE or value_dim != head_dim or kv_heads % 16
+            or _LANE % kv_heads):
         return 0
     row = kv_heads * head_dim * 2
     fit = [n for n in (8 << i for i in range(max_seq.bit_length()))
@@ -110,14 +242,21 @@ def block_positions(max_seq: int, kv_heads: int, head_dim: int,
     return max(fit, default=0)
 
 
-def kernel_block(shape: tuple, dtype, interpret: bool = False) -> int:
-    """The block the compiled decode path reads cache leaves of
-    ``shape`` in, 0 where it runs the plain form: the choice
-    ``decode_attend`` makes, for whoever counts what it reads."""
+def kernel_block(shape: tuple, dtype, interpret: bool = False,
+                 values: tuple | None = None) -> int:
+    """The block the compiled decode path reads key leaves of ``shape``
+    (and value leaves of ``values``, where they differ) in, 0 where it
+    runs the plain form: the choice ``decode_attend`` makes, for whoever
+    counts what it reads.  A leaf with the heads in its lanes, ``[B, S,
+    W]``, counts as one head of ``W``."""
     if not (_on_tpu() or interpret):
         return 0
-    _, max_seq, kv, d = shape
-    return block_positions(max_seq, kv, d, dtype)
+    _, max_seq, *heads = shape
+    kv, d = heads if len(heads) == 2 else (1, *heads)
+    wide = (values or shape)[-1]
+    if len(heads) == 2 and lanes_layout(kv, d, wide, dtype):
+        return 0                   # such heads belong in the lanes
+    return block_positions(max_seq, kv, d, dtype, wide)
 
 
 def read_positions(lengths, max_seq: int, block: int) -> int:
@@ -293,17 +432,132 @@ def _decode_attend_pallas(q, keys, values, lengths, scale, *, block: int,
     return out.reshape(b, group, kv, d).swapaxes(1, 2).reshape(b, 1, h, d)
 
 
+def _lanes_kernel(len_ref, q_ref, sink_ref, k_ref, v_ref, o_ref,
+                  m_ref, l_ref, acc_ref, *, scale: float, block: int,
+                  kv: int, group: int):
+    """One slot, one block of positions of leaves with the heads in the
+    lanes: ``k_ref`` [block, KV * D], ``v_ref`` [block, KV * Dv].  A
+    query row holds its head's query at that head's lanes and zeros
+    elsewhere, so one product with the key rows gives every head's
+    scores, ``[H, block]``, a query head a sublane and a position a
+    lane; the softmax runs there; each key-value head's weights meet
+    its own lanes of the values.  The running maximum starts at the
+    head's sink and the sum at 1 where there is one (its column has no
+    value), at ``NEG_INF`` and 0 where not."""
+    from jax.experimental import pallas as pl
+
+    j = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+    h = kv * group
+    dv = v_ref.shape[-1] // kv
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = sink_ref[...]
+        l_ref[...] = jnp.where(sink_ref[...] > NEG_INF / 2, 1.0, 0.0)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block < length)
+    def _accumulate():
+        live = length - j * block
+        v = v_ref[0]
+        v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+                      < live, v, jnp.zeros_like(v))        # 0 * NaN is NaN
+        s = jax.lax.dot_general(q_ref[0], k_ref[0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = _fold(s, h) * scale                              # [H, block]
+        s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+                      < live, s, NEG_INF)
+        m_prev = m_ref[...]                                  # [H, 128]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur[:, :1])
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = m_cur
+        weights = _pieces(p)
+        for head in range(kv):
+            rows = slice(head * group, (head + 1) * group)
+            pv = jnp.dot(
+                jnp.concatenate([w[rows] for w in weights], axis=0),
+                v[:, head * dv:(head + 1) * dv],
+                preferred_element_type=jnp.float32)      # [3 group, Dv]
+            acc_ref[rows] = acc_ref[rows] * alpha[rows, :1] \
+                + _fold(pv, group)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def _decode_attend_lanes(q, keys, values, lengths, sink, scale, *,
+                         block: int, interpret: bool):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, _, h, d = q.shape
+    _, s, width = keys.shape
+    kv = width // d
+    group, dv = h // kv, values.shape[-1] // kv
+    # Each query at its own head's lanes of a row of KV * D, in exact
+    # bfloat16 pieces, the pieces one under the other.
+    own = jnp.arange(kv)[:, None, None, None] == jnp.arange(kv)[None, None, :,
+                                                                None]
+    rows = jnp.concatenate([
+        jnp.where(own, piece.reshape(b, kv, group, 1, d), 0)
+        .reshape(b, h, width) for piece in _pieces(q)], axis=1)
+    start = jnp.full((h,), NEG_INF, jnp.float32) if sink is None \
+        else sink.astype(jnp.float32)
+    leaf = lambda w: pl.BlockSpec(                           # noqa: E731
+        (1, block, w), lambda slot, j, lens: _live_block(
+            slot, j, lens, block=block)[:3])
+    out = pl.pallas_call(
+        functools.partial(_lanes_kernel, scale=scale, block=block, kv=kv,
+                          group=group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, s // block),
+            in_specs=[pl.BlockSpec((1, rows.shape[1], width),
+                                   lambda slot, j, lens: (slot, 0, 0)),
+                      pl.BlockSpec((h, _LANE), lambda slot, j, lens: (0, 0)),
+                      leaf(width), leaf(values.shape[-1])],
+            out_specs=pl.BlockSpec((1, h, dv),
+                                   lambda slot, j, lens: (slot, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((h, _LANE), jnp.float32),
+                            pltpu.VMEM((h, _LANE), jnp.float32),
+                            pltpu.VMEM((h, dv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+        name="hvd.decode_attend",
+    )(lengths, rows, jnp.broadcast_to(start[:, None], (h, _LANE)), keys,
+      values)
+    return out.reshape(b, 1, h, dv)
+
+
 def decode_attend(q: jax.Array, keys: jax.Array, values: jax.Array,
-                  lengths: jax.Array, scale: float, *,
-                  interpret: bool = False) -> jax.Array:
+                  lengths: jax.Array, scale: float, sink=None, *,
+                  interpret: bool = False,
+                  scope: str = "hvd.decode_attend") -> jax.Array:
     """One decode step's attention: ``q`` [B, 1, H, D] over the first
-    ``lengths`` [B] positions of ``keys`` / ``values`` [B, S, KV, D],
-    float32 [B, 1, H, D].  The kernel on a TPU or interpreted, where
-    ``kernel_block`` finds it a block; the plain form elsewhere."""
-    block = kernel_block(keys.shape, keys.dtype, interpret)
+    ``lengths`` [B] positions of ``keys`` / ``values`` (``[B, S, KV,
+    D]`` or, ``lanes_layout``, ``[B, S, KV * D]``), float32 [B, 1, H,
+    Dv].  The kernel on a TPU or interpreted, where ``kernel_block``
+    finds it a block; the plain form, under ``scope``, elsewhere (and
+    for heads in the sublanes with a ``sink``, which that kernel does
+    not take: the rings of a window layer, few positions)."""
+    block = kernel_block(keys.shape, keys.dtype, interpret, values.shape)
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, keys.shape[1])
-    if not block:
-        return attend_plain(q, keys, values, lengths[:, None] - 1, scale)
+    if not block or (sink is not None and keys.ndim == 4):
+        return attend_plain(q, keys, values, lengths[:, None] - 1, scale,
+                            sink, scope=scope)
     with jax.named_scope("hvd.decode_attend"):
+        if keys.ndim == 3:
+            return _decode_attend_lanes(q, keys, values, lengths, sink,
+                                        scale, block=block,
+                                        interpret=interpret)
         return _decode_attend_pallas(q, keys, values, lengths, scale,
                                      block=block, interpret=interpret)

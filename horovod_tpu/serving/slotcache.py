@@ -59,10 +59,6 @@ def _sample(logits):
 class _SlotCache:
     """What the layouts share: warm-up and the aliasing counters."""
     block_capacity = 0         # blocks the batcher reserves from; 0 = none
-    # What a decode step's attention reads of one slot: ``_attend_span``
-    # positions, or with the kernel (ops/decode_attention.py) the slot's
-    # live length rounded up to ``_attend_block``.
-    _attend_block = 0
 
     def __init__(self, cfg, family, model, stats: dict) -> None:
         self.cfg, self.family, self.model = cfg, family, model
@@ -71,7 +67,12 @@ class _SlotCache:
         # Jitted like every other model call here: un-jitted, each of its
         # hundreds of small ops compiles and dispatches on its own.
         self._init_cache_jit = jax.jit(self._init_cache_impl)
-        self._attend_span = cfg.max_seq
+        # What a decode step's attention reads of one slot, a kind of
+        # attention layer: (layers, span, block): ``span`` positions at
+        # most (a window layer's ring holds no more), all of them or,
+        # with the kernel (ops/decode_attention.py), the slot's live
+        # length rounded up to ``block``.
+        self._attend_kinds = [(1, cfg.max_seq, 0)]
         self.tree = None
         # The last decode step's result (a token a slot, then the
         # family's counters), fetched or not.
@@ -109,13 +110,20 @@ class _SlotCache:
         # length after it.
         lengths = np.fromiter((slots[i].seq_len + 1 for i in active),
                               np.int64, len(active))
+        # A layer's worth, the mean over the attention layers: a window
+        # layer's live context ends at its ring's span.
+        live = read = 0
+        for layers, span, block in self._attend_kinds:
+            within = np.minimum(lengths, span)
+            live += layers * int(within.sum())
+            read += layers * decode_attention.read_positions(within, span,
+                                                             block)
+        layers = sum(kind[0] for kind in self._attend_kinds)
         stats = self.stats
         stats["attend_live_positions"] = \
-            stats.get("attend_live_positions", 0) + int(lengths.sum())
+            stats.get("attend_live_positions", 0) + live // layers
         stats["attend_read_positions"] = \
-            stats.get("attend_read_positions", 0) \
-            + decode_attention.read_positions(lengths, self._attend_span,
-                                              self._attend_block)
+            stats.get("attend_read_positions", 0) + read // layers
         return self.result
 
     def fetch(self, result) -> np.ndarray:
@@ -146,6 +154,10 @@ class _SlotCache:
             leaf.nbytes for path, leaf in leaves
             if path[-1].key in self.family.state_leaves)
         stats["kv_bytes"] = stats["cache_bytes"] - stats["state_bytes"]
+        # Of those, the rings of the window layers.
+        stats["window_bytes"] = sum(
+            leaf.nbytes for path, leaf in leaves
+            if path[-1].key in ("ring_key", "ring_value"))
         stats["cache_aliased_bytes"] = \
             decode_program.memory_analysis().alias_size_in_bytes
         logger.info("serving: slot cache %.2f of %.2f GB aliased by the "
@@ -202,13 +214,20 @@ class DenseSlotCache(_SlotCache):
 
     def fresh(self, params) -> None:
         super().fresh(params)
-        # The block the decode program's attention reads the key and
-        # value leaves in (one shape for all of a family's layers).
-        self._attend_block, = {
-            decode_attention.kernel_block(leaf.shape, leaf.dtype)
-            for path, leaf in
-            jax.tree_util.tree_flatten_with_path(self.tree)[0]
-            if path[-1].key == "cached_key"}
+        # The span and the block the decode program's attention reads
+        # each layer's key and value leaves in, by kind of layer.
+        kinds: dict = {}
+        leaves = {tuple(k.key for k in path): leaf for path, leaf in
+                  jax.tree_util.tree_flatten_with_path(self.tree)[0]}
+        for path, keys in leaves.items():
+            if path[-1] in ("cached_key", "ring_key"):
+                values = leaves[(*path[:-1],
+                                 path[-1].replace("key", "value"))]
+                kind = (keys.shape[1], decode_attention.kernel_block(
+                    keys.shape, keys.dtype, values=values.shape))
+                kinds[kind] = kinds.get(kind, 0) + 1
+        self._attend_kinds = [(layers, span, block)
+                              for (span, block), layers in kinds.items()]
 
     def _warm_prefill(self, params, toks: list) -> None:
         self.admit(params, 0, toks, 1)   # the insert compiles once
@@ -238,7 +257,7 @@ class PagedSlotCache(_SlotCache):
     def __init__(self, cfg, family, model, stats: dict) -> None:
         super().__init__(cfg, family, model, stats)
         self._sink = self.block_capacity = cfg.resolved_pool_blocks
-        self._attend_span = cfg.table_width * cfg.block_tokens
+        self._attend_kinds = [(1, cfg.table_width * cfg.block_tokens, 0)]
         self.pool = KVBlockPool(self._sink, cfg.block_tokens)
         self._tables = np.full((cfg.slots, cfg.table_width), self._sink,
                                np.int32)

@@ -243,31 +243,43 @@ def hybrid_decode_flops(cfg: Any, context_len: float) -> float:
     matmul weight that the token meets here (the head once; of routed
     experts the router's whole width, the shared expert, and of the
     ``experts_per_token`` it takes the share that ``experts_held`` is of
-    the router's width: the rest run on the chips that hold them), the
-    state update's 6*H*P*N a Mamba layer and 8*H*K*V a delta-rule layer,
-    and scores and values over the context in the attention layers alone."""
+    the router's width: the rest run on the chips that hold them; the
+    MLP of ``d_ff`` in the layers ``dense_layers`` lists), the state
+    update's 6*H*P*N a Mamba layer and 8*H*K*V a delta-rule layer, and
+    scores (over the keys' width) and values (over theirs) over the
+    context in the attention layers, over its last ``window`` positions
+    in the window layers."""
     d = cfg.d_model
     kinds = cfg.layer_types
-    mamba, delta = kinds.count("mamba"), kinds.count("kda")
-    attn = len(kinds) - mamba - delta
-    width, kv_width = cfg.num_heads * cfg.head_dim, \
-        cfg.num_kv_heads * cfg.head_dim
+    mamba, delta, window = (kinds.count(kind)
+                            for kind in ("mamba", "kda", "window"))
+    attn = len(kinds) - mamba - delta - window
+    width, wide = cfg.num_heads * cfg.head_dim, cfg.num_heads * cfg.value_dim
+
+    def projections(kv_heads: int) -> int:
+        return (1 + cfg.attn_gate) * d * width + wide * d \
+            + d * kv_heads * (cfg.head_dim + cfg.value_dim)
+
+    dense = len(cfg.dense_layers) if cfg.num_experts else len(kinds)
+    ffn = dense * 3 * d * cfg.ff_dim
     if cfg.num_experts:
         held = cfg.experts_held[1] / cfg.num_experts
-        ffn = d * cfg.num_experts + 3 * d * cfg.expert_ff * (
-            cfg.experts_per_token * held + cfg.shared_experts)
-    else:
-        ffn = 3 * d * cfg.ff_dim
+        ffn += (len(kinds) - dense) * (
+            d * cfg.num_experts + 3 * d * cfg.expert_ff * (
+                cfg.experts_per_token * held + cfg.shared_experts))
     inner, rank = cfg.kda_inner, cfg.kda_rank
     weights = (mamba * (d * (2 * cfg.d_inner + 2 * cfg.mamba_state
                              + cfg.mamba_heads) + cfg.d_inner * d)
                + delta * (d * (3 * inner + 2 * rank + cfg.kda_heads)
                           + 2 * rank * inner + inner * d)
-               + attn * ((2 + cfg.attn_gate) * d * width + 2 * d * kv_width)
-               + len(kinds) * ffn + d * cfg.vocab_size)
+               + attn * projections(cfg.num_kv_heads)
+               + window * projections(cfg.window_kv_heads
+                                      or cfg.num_kv_heads)
+               + ffn + d * cfg.vocab_size)
     update = 6.0 * mamba * cfg.mamba_heads * cfg.mamba_head_dim \
         * cfg.mamba_state + 8.0 * delta * cfg.kda_heads * cfg.kda_head_dim ** 2
-    return 2.0 * weights + update + 4.0 * attn * width * context_len
+    seen = attn * context_len + window * min(context_len, cfg.window)
+    return 2.0 * weights + update + 2.0 * (width + wide) * seen
 
 
 # ---------------------------------------------------------------------------

@@ -295,6 +295,78 @@ def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
     assert memory.temp_size_in_bytes < leaf // 4
 
 
+def test_the_mimo_programs_fit_a_v5e_and_attend_through_the_kernel(
+        v5e, monkeypatch):
+    """``mimov25_serve_mixlen_sat``'s programs (MiMo-V2.5's widths, 7
+    layers, 64 slots, bfloat16) compiled for the v5e.  The decode
+    program: one hvd.decode_attend custom call a global layer, taking
+    the two leaves with their 4 heads in the lanes as they lie (768 and
+    512 wide, 12,288 positions), one hvd.moe_experts a layer that has
+    experts, the five rings through the plain form; it updates the whole
+    cache in place.  The prefill of 8,192 tokens attends in blocks: its
+    temporaries fit beside the weights and the cache."""
+    from horovod_tpu.models import hybrid, moe
+    from horovod_tpu.ops import decode_attention as da
+    from horovod_tpu.serving import ServeConfig, slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks", "chip"))
+    import run as harness
+    for module in (da, moe):                # the target, not the CPU
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    file = harness.load_json(harness.HERE, "configs", "MiMo-V2.5.serve.json")
+    serve = {**file["serve"],
+             "warmup_buckets": tuple(file["serve"]["warmup_buckets"])}
+    cfg = ServeConfig(model_cfg=hybrid.HybridConfig(
+        **harness.build_args(file)), **serve)
+    slots, max_seq = cfg.slots, cfg.max_seq
+    assert (slots, max_seq) == (64, 12288)
+    model = hybrid.HybridLM(_decode_model_cfg(cfg))
+    cache = slotcache.DenseSlotCache(cfg, cfg.model_cfg.family, model, {})
+
+    def placed(tree):
+        return jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=v5e), tree)
+
+    def nbytes(tree):
+        return sum(math.prod(x.shape) * x.dtype.itemsize
+                   for x in jax.tree_util.tree_leaves(tree))
+
+    params = placed(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    tree = placed(jax.eval_shape(cache._init_cache_impl, params))
+    rings = 5 * slots * 128 * 8 * (192 + 128) * 2
+    whole = 2 * slots * max_seq * 4 * (192 + 128) * 2
+    assert nbytes(tree) == rings + whole + 7 * slots * 4
+    assert 6.85e9 < nbytes(params) < 6.87e9
+    assert tree["layer_0"]["attn"]["cached_key"].shape \
+        == (slots, max_seq, 4 * 192)
+    assert tree["layer_1"]["attn"]["ring_value"].shape \
+        == (slots, 128, 8, 128)
+    compiled = cache._decode_jit.lower(
+        params, tree, *placed((jnp.zeros(slots + 4, jnp.int32),
+                               jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, bool)))).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if MOSAIC in line]
+    attend = [call for call in calls if "hvd.decode_attend" in call]
+    assert len(attend) == 2 and len(calls) == 2 + 6
+    assert sum("hvd.moe_experts" in call for call in calls) == 6
+    assert all("bf16[64,12288,768]" in call and "bf16[64,12288,512]" in call
+               for call in attend)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes(tree)
+    assert memory.temp_size_in_bytes < 0.1e9
+    prefill = cache._prefill_jit.lower(
+        params, placed(jnp.zeros((1, 8192), jnp.int32)),
+        placed(jnp.zeros((), jnp.int32))).compile().memory_analysis()
+    # 16 GiB less what the runtime keeps: 15.75 GB usable.
+    assert nbytes(params) + nbytes(tree) + prefill.temp_size_in_bytes \
+        + prefill.output_size_in_bytes < 14.5e9
+
+
 def test_fit_block_follows_the_tpu_tiling_rule():
     assert fa._fit_block(2048, 1024) == 1024
     assert fa._fit_block(2000, 128) == 80       # not 125

@@ -1,0 +1,738 @@
+"""MiMo-V2.5 on the hybrid decoder: window layers with a sink and a ring
+in the cache beside global layers whose few key-value heads lie in the
+lanes, keys wider than values, rotary positions on part of a head, a
+router with a correction bias; the model through the slot cache and the
+replica, all against the benchmark's plain float32 reference
+(benchmarks/chip/mimo_v2_reference.py) on its seeded weights, comparing
+logits.  Toy widths: the rehearsal sizes of the configuration's own
+file (a window of 8, keys 24 and values 16 wide, 8 rotary channels)."""
+from __future__ import annotations
+
+import copy
+import dataclasses
+import os
+import random
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for path in (os.path.join(REPO, "benchmarks", "chip"), REPO):
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+import mimo_v2_counts  # noqa: E402
+import mimo_v2_reference as ref  # noqa: E402
+import run as harness  # noqa: E402
+
+from horovod_tpu.models import hybrid, kvcache, moe  # noqa: E402
+from horovod_tpu.ops import decode_attention as da  # noqa: E402
+from horovod_tpu.serving import slotcache  # noqa: E402
+
+CONFIG = "MiMo-V2.5.serve"
+CELL = "mimov25_serve_mixlen_sat"
+F32 = {"dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}
+WINDOW = 8
+
+
+def load(name: str = CONFIG) -> dict:
+    return harness.load_json(harness.HERE, "configs", name + ".json")
+
+
+def toy_config() -> dict:
+    """The configuration's file at its rehearsal sizes (hidden 64; 4 query
+    heads over 1 key-value head in the two global layers and over 2 in
+    the five window layers of 8 positions; keys 24, values 16, 8 rotary
+    channels; a dense MLP of 128 in layer 0, then 16 experts of width
+    32, top-2, experts 4 to 7 held; vocabulary 256), in float32 so that
+    the program and the reference differ by rounding alone."""
+    cfg = load()
+    cfg = harness.merged(cfg, cfg["rehearsal"])
+    cfg["model"] = {**cfg["model"], "args": {**cfg["model"]["args"], **F32}}
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def toy() -> dict:
+    return toy_config()
+
+
+def seeded(cfg: dict, seed: int = 36, held=None) -> dict:
+    return ref.weights(types.SimpleNamespace(
+        config=cfg, seed=seed, resolve=harness.resolve), held)
+
+
+@pytest.fixture(scope="module")
+def params(toy):
+    return seeded(toy)
+
+
+def model_config(cfg: dict, **overrides) -> hybrid.HybridConfig:
+    return hybrid.HybridConfig(**{**harness.build_args(cfg), **overrides})
+
+
+def reference_logits(params, tokens, cfg):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda p, t: ref.logits(p, t, cfg))(
+            params, jnp.asarray(tokens))
+
+
+def tokens_of(seed: int, *shape) -> jax.Array:
+    return jax.random.randint(jax.random.key(seed), shape, 2, 256)
+
+
+# ------------------------------------------------- the attention's three forms
+def operands(seed: int, b: int, t: int, s: int, h: int, kv: int, dk: int,
+             dv: int, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(keys[0], (b, t, h, dk), dtype),
+            jax.random.normal(keys[1], (b, s, kv, dk), dtype),
+            jax.random.normal(keys[2], (b, s, kv, dv), dtype),
+            jax.random.normal(keys[3], (h,), jnp.float32))
+
+
+def softmax_by_hand(q, k, v, positions, scale, sink=None, window=0):
+    """One query head at a time, the sink an explicit exponential in the
+    denominator."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    b, t, h, _ = q.shape
+    group = h // k.shape[2]
+    out = np.zeros((b, t, h, v.shape[-1]))
+    for row in range(b):
+        for i in range(t):
+            p = int(positions[row, i])
+            lo = max(0, p - window + 1) if window else 0
+            for head in range(h):
+                s = k[row, lo:p + 1, head // group] @ q[row, i, head] * scale
+                top = max(s.max(), sink[head]) if sink is not None \
+                    else s.max()
+                e = np.exp(s - top)
+                den = e.sum() + (np.exp(sink[head] - top)
+                                 if sink is not None else 0.0)
+                out[row, i, head] = e @ v[row, lo:p + 1, head // group] / den
+    return out
+
+
+@pytest.mark.parametrize("window", [0, 5])
+@pytest.mark.parametrize("sinks", [False, True])
+def test_the_plain_form_takes_a_sink_a_window_and_narrower_values(window,
+                                                                  sinks):
+    q, k, v, sink = operands(1, 2, 6, 16, 4, 2, 24, 16)
+    sink = np.asarray(sink) if sinks else None
+    positions = jnp.asarray([[3, 4, 5, 6, 7, 8], [9, 10, 11, 12, 13, 14]])
+    got = da.attend_plain(q, k, v, positions, 0.2,
+                          None if sink is None else jnp.asarray(sink), window)
+    assert got.shape == (2, 6, 4, 16) and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, softmax_by_hand(
+        q, k, v, np.asarray(positions), 0.2, sink, window), atol=2e-6)
+
+
+@pytest.mark.parametrize("t, block", [(5, 8), (8, 8), (19, 4), (32, 8),
+                                      (37, 16)])
+@pytest.mark.parametrize("window, sinks", [(0, False), (0, True), (3, True),
+                                           (8, True), (11, False)])
+def test_the_blocked_prefill_is_the_plain_form_over_its_own_keys(
+        t, block, window, sinks):
+    """Whole blocks and a ragged last one, windows shorter than a block,
+    as long, and longer: a row that sees nothing of an earlier block is
+    wiped by its own."""
+    q, k, v, sink = operands(t, 2, t, t, 4, 2, 24, 16)
+    sink = sink if sinks else None
+    got = da.attend_blocked(q, k, v, 0.2, window=window, sink=sink,
+                            block=block)
+    want = da.attend_plain(q, k, v, jnp.arange(t)[None, :], 0.2, sink,
+                           window)
+    assert got.shape == want.shape == (2, t, 4, 16)
+    np.testing.assert_allclose(got, want, atol=3e-6, rtol=3e-6)
+
+
+LENGTHS = {"ragged": (1, 7, 8, 9, 33, 64), "one": (1,) * 6,
+           "full": (64,) * 6}
+
+
+@pytest.mark.parametrize("lengths", sorted(LENGTHS))
+@pytest.mark.parametrize("sinks", [False, True])
+@pytest.mark.parametrize("dk, dv, dtype", [(24, 16, jnp.bfloat16),
+                                           (24, 16, jnp.float32),
+                                           (192, 128, jnp.bfloat16)])
+def test_the_lanes_kernel_interpreted_agrees_with_the_plain_form(
+        dk, dv, dtype, sinks, lengths):
+    """hvd.decode_attend on leaves with the heads in the lanes, 4
+    key-value heads of 16 query heads each, at the toy widths and at
+    MiMo's: ragged lengths, a slot of one position, full slots; dead
+    positions hold NaN and must not reach the result."""
+    heads, kv, s, block = 64, 4, 64, 16
+    lens = np.asarray(LENGTHS[lengths], np.int32)
+    q, k, v, sink = operands(7, len(lens), 1, s, heads, kv, dk, dv, dtype)
+    sink = sink if sinks else None
+    dead = np.arange(s)[None, :, None, None] >= lens[:, None, None, None]
+    merged = [jnp.where(dead, jnp.nan, x).reshape(len(lens), s, -1)
+              for x in (k, v)]
+    got = da._decode_attend_lanes(q, *merged, jnp.asarray(lens), sink, 0.11,
+                                  block=block, interpret=True)
+    want = da.attend_plain(q, k, v, jnp.asarray(lens)[:, None] - 1, 0.11,
+                           sink)
+    assert got.shape == (len(lens), 1, heads, dv)
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+
+
+def test_the_entry_point_reads_lanes_leaves_in_the_block_the_rule_gives(
+        monkeypatch):
+    """MiMo's global leaves, [B, S, 4 x 192] and [B, S, 4 x 128] in
+    bfloat16: ``decode_attend`` interpreted goes through the lanes kernel
+    in ``block_positions``' block, with the sink; elsewhere the plain
+    form reads the same leaves; the rule leaves the other families'
+    leaves where they were."""
+    assert da.lanes_layout(4, 192, 128, jnp.bfloat16)
+    assert not da.lanes_layout(8, 128, 128, jnp.bfloat16)     # Solar
+    assert not da.lanes_layout(8, 64, 64, jnp.bfloat16)       # granite
+    assert not da.lanes_layout(4, 192, 128, jnp.float32)
+    assert not da.lanes_layout(1, 24, 16, jnp.bfloat16)       # the toy
+    assert da.block_positions(12288, 4, 192, jnp.bfloat16, 128) == 512
+    assert da.kernel_block((64, 12288, 768), jnp.bfloat16, True,
+                           (64, 12288, 512)) == 512
+    # Such heads in the sublanes would be padded: no kernel for them.
+    assert da.kernel_block((64, 12288, 4, 192), jnp.bfloat16, True,
+                           (64, 12288, 4, 128)) == 0
+    assert da.kernel_block((64, 128, 8, 192), jnp.bfloat16, True,
+                           (64, 128, 8, 128)) == 0            # the rings
+    lens = jnp.asarray([5, 40, 64], jnp.int32)
+    q, k, v, sink = operands(3, 3, 1, 64, 64, 4, 192, 128, jnp.bfloat16)
+    monkeypatch.setattr(da, "_BLOCK_BYTES", 16 * 4 * 192 * 2)
+    calls = []
+    real = da._decode_attend_lanes
+    monkeypatch.setattr(da, "_decode_attend_lanes",
+                        lambda *a, **kw: calls.append(kw) or real(*a, **kw))
+    merged = (k.reshape(3, 64, -1), v.reshape(3, 64, -1))
+    got = da.decode_attend(q, *merged, lens, 0.07, sink, interpret=True)
+    assert calls == [{"block": 16, "interpret": True}]
+    want = da.attend_plain(q, k, v, lens[:, None] - 1, 0.07, sink)
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+    plain = da.decode_attend(q, *merged, lens, 0.07, sink)       # a CPU
+    assert len(calls) == 1
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(want))
+
+
+def pallas_calls(jaxpr) -> list:
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(pallas_calls(sub))
+    return found
+
+
+def test_the_lanes_kernel_carries_the_decode_kernels_name():
+    q, k, v, _ = operands(3, 2, 1, 32, 64, 4, 192, 128, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda *a: da._decode_attend_lanes(
+        *a, None, 0.1, block=16, interpret=True))(
+        q, k.reshape(2, 32, -1), v.reshape(2, 32, -1),
+        jnp.asarray([3, 30], jnp.int32))
+    call, = pallas_calls(jaxpr.jaxpr)
+    assert "hvd.decode_attend" in str(call.params["name"]) \
+        or "hvd.decode_attend" in str(call.params.get("name_and_src_info"))
+
+
+# ------------------------------------------------------------------ the ring
+def a_window_layer(toy, **overrides):
+    cfg = model_config(toy, **overrides)
+    return cfg, hybrid.GroupedAttention(cfg, windowed=True)
+
+
+@pytest.mark.parametrize("n, bucket", [(3, 8), (8, 8), (9, 16), (21, 32),
+                                       (32, 32)])
+def test_a_window_layers_ring_is_a_full_leaf_under_a_window_mask(n, bucket,
+                                                                 toy):
+    """One window layer, two ways: through the cache, a right-padded
+    prompt of ``n`` in ``bucket`` and then 3 x 8 + 5 decode steps, the
+    ring going round three times and more; and over the whole sequence
+    at once, every key kept and the window a mask.  The ring never holds
+    the padding."""
+    steps = 3 * WINDOW + 5
+    cfg, plain = a_window_layer(toy)
+    _, cached = a_window_layer(toy, decode=True, max_seq_len=128)
+    x = jax.random.normal(jax.random.key(n), (1, n + steps, 64))
+    own = plain.init(jax.random.key(1), x)["params"]
+    own = {**own, "sink": jax.random.normal(jax.random.key(2), (4,))}
+    want = plain.apply({"params": own}, x)
+    padded = jnp.zeros((1, bucket, 64)).at[:, :n].set(x[:, :n]) \
+        .at[:, n:].set(7.0)                  # padding that would be seen
+    got, mut = cached.apply({"params": own}, padded, jnp.int32(n),
+                            mutable=["cache"])
+    cache = kvcache._with_cache_index(mut["cache"], n)
+    assert cache["ring_key"].shape == (1, WINDOW, 2, 24)
+    assert cache["ring_value"].shape == (1, WINDOW, 2, 16)
+    np.testing.assert_allclose(got[:, :n], want[:, :n], atol=3e-6)
+    for at in range(n, n + steps):
+        out, mut = cached.apply({"params": own, "cache": cache},
+                                x[:, at:at + 1], mutable=["cache"])
+        cache = mut["cache"]
+        np.testing.assert_allclose(out[:, 0], want[:, at], atol=3e-6)
+    assert int(cache["cache_index"][0]) == n + steps
+    with pytest.raises(ValueError, match="whole prompt or one token"):
+        cached.apply({"params": own, "cache": cache}, x[:, :2],
+                     mutable=["cache"])
+
+
+def test_lanes_leaves_and_long_prompts_through_the_cache(monkeypatch):
+    """What the toy widths never take: bfloat16 leaves of 2 key-value
+    heads whose rows are whole lanes lie ``[B, S, KV x D]`` (keys 128
+    and values 64 wide), and a prefill whose scores would pass
+    ``PLAIN_PREFILL_BYTES`` attends in blocks over its own keys; both
+    against the same layer without a cache."""
+    cfg = hybrid.HybridConfig(
+        d_model=64, num_heads=4, num_kv_heads=2, attn_head_dim=128,
+        attn_value_dim=64, attn_rotary_dim=32, attn_value_scale=0.5,
+        attention_multiplier=0.09, layer_types=("attention",),
+        dtype=jnp.bfloat16)
+    plain = hybrid.GroupedAttention(cfg)
+    cached = hybrid.GroupedAttention(dataclasses.replace(
+        cfg, decode=True, max_seq_len=32))
+    x = jax.random.normal(jax.random.key(0), (2, 20, 64), jnp.bfloat16)
+    own = plain.init(jax.random.key(1), x)["params"]
+    want = plain.apply({"params": own}, x).astype(jnp.float32)
+    monkeypatch.setattr(kvcache, "PLAIN_PREFILL_BYTES", 1024)
+    blocked = []
+    real = kvcache.attend_blocked
+    monkeypatch.setattr(kvcache, "attend_blocked", lambda *a, **kw:
+                        blocked.append(kw) or real(*a, **{**kw, "block": 8}))
+    got, mut = cached.apply({"params": own}, x[:, :16], mutable=["cache"])
+    assert len(blocked) == 1
+    cache = mut["cache"]
+    assert cache["cached_key"].shape == (2, 32, 2 * 128)
+    assert cache["cached_value"].shape == (2, 32, 2 * 64)
+    rows = [got.astype(jnp.float32)]
+    for at in range(16, 20):
+        out, mut = cached.apply({"params": own, "cache": cache},
+                                x[:, at:at + 1], mutable=["cache"])
+        cache = mut["cache"]
+        rows.append(out.astype(jnp.float32))
+    np.testing.assert_allclose(jnp.concatenate(rows, 1), want, atol=0.03)
+
+
+# ---------------------------------------------------------------- the router
+def test_the_correction_bias_enters_the_choice_and_not_the_weights():
+    scores = jnp.asarray([[0.50, 0.49, 0.30, 0.10],
+                          [0.20, 0.60, 0.59, 0.58]])
+    bias = jnp.asarray([0.0, -0.2, 0.0, 0.25])
+    weights, local, here = moe.route(scores, 2, (0, 4), bias=bias)
+    # Token 0: 0.50 and 0.10 + 0.25 (0.49 - 0.2 is out); token 1: 0.58 +
+    # 0.25 and 0.59 (0.60 - 0.2 is out).
+    assert sorted(np.asarray(local[0])) == [0, 3]
+    assert sorted(np.asarray(local[1])) == [2, 3]
+    picked = np.take_along_axis(np.asarray(scores), np.asarray(local), -1)
+    np.testing.assert_allclose(weights, picked / picked.sum(-1,
+                                                            keepdims=True),
+                               rtol=1e-6)
+    plain, local0, _ = moe.route(scores, 2, (0, 4))
+    assert sorted(np.asarray(local0[0])) == [0, 1] and bool(here.all())
+    np.testing.assert_allclose(np.asarray(plain).sum(-1), 1.0, rtol=1e-6)
+
+
+def routed(held, **kw):
+    return moe.RoutedExperts(num_experts=16, per_token=2, d_ff=32,
+                             held=held, shared=0, bias=True,
+                             dtype=jnp.float32, param_dtype=jnp.float32, **kw)
+
+
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
+    """The guide's section 4, with the bias in the choice and no shared
+    expert: the toy's 16 experts over 4 chips, 4 held each (the cell's
+    256 over 16).  The partial results of all the shares add up to what
+    the reference gives the layer with every expert in one place;
+    through the program's layer and the reference's."""
+    cfg = {**toy, "experts_held": [0, 16], "n_routed_experts": 16}
+    whole = seeded(cfg)["layer_1"]
+    x = jax.random.normal(jax.random.key(3), (2, 11, 64))
+    with jax.default_matmul_precision("highest"):
+        uncut = ref.feed_forward(
+            {"mlp_norm": {"scale": jnp.ones(64)}, "moe": whole["moe"]},
+            x, cfg) - x
+        normed = x * jax.lax.rsqrt(
+            jnp.mean(x * x, -1, keepdims=True) + cfg["layernorm_epsilon"])
+        total_program = total_reference = 0.0
+        for first in (0, 4, 8, 12):
+            held = (first, 4)
+            mine = seeded({**toy, "experts_held": list(held)})["layer_1"]
+            for name in ("experts_gate", "experts_up", "experts_down"):
+                np.testing.assert_array_equal(
+                    mine["moe"][name], whole["moe"][name][first:first + 4])
+            np.testing.assert_array_equal(mine["moe"]["router_bias"],
+                                          whole["moe"]["router_bias"])
+            total_program = total_program + routed(held).apply(
+                {"params": mine["moe"]}, normed)
+            total_reference = total_reference + ref.experts_share(
+                mine["moe"], normed.reshape(-1, 64), toy, held
+            ).reshape(x.shape)
+    assert float(jnp.max(jnp.abs(whole["moe"]["router_bias"]))) > 0.01
+    np.testing.assert_allclose(total_reference, uncut, atol=5e-6)
+    np.testing.assert_allclose(total_program, uncut, atol=5e-6)
+
+
+# ------------------------------------------------------------------ the model
+def test_the_seeded_weights_have_the_models_own_tree(toy, params):
+    model = hybrid.HybridLM(model_config(toy))
+    own = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    assert jax.tree_util.tree_structure(own) \
+        == jax.tree_util.tree_structure(params)
+    assert [(leaf.shape, leaf.dtype) for leaf in
+            jax.tree_util.tree_leaves(own)] \
+        == [(leaf.shape, leaf.dtype) for leaf in
+            jax.tree_util.tree_leaves(params)]
+    assert toy["layer_types"] == ["attention"] + ["window"] * 5 \
+        + ["attention"]
+    assert "mlp" in params["layer_0"] and "moe" not in params["layer_0"]
+    assert "sink" in params["layer_1"]["attn"] \
+        and "sink" not in params["layer_6"]["attn"]
+    assert params["layer_1"]["attn"]["wk"]["kernel"].shape == (64, 2, 24)
+    assert params["layer_6"]["attn"]["wk"]["kernel"].shape == (64, 1, 24)
+    assert params["layer_6"]["attn"]["wv"]["kernel"].shape == (64, 1, 16)
+    assert "shared_gate" not in params["layer_3"]["moe"]
+
+
+@pytest.mark.parametrize("length", [1, 3, 8, 9, 23, 41])
+def test_the_whole_forward_pass_agrees_with_the_reference(length, toy,
+                                                          params):
+    model = hybrid.HybridLM(model_config(toy))
+    tokens = tokens_of(length, 2, length)
+    got = jax.jit(model.apply)({"params": params}, tokens)
+    want = reference_logits(params, tokens, toy)
+    assert got.shape == want.shape == (2, length, 256)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("n, bucket", [(1, 8), (5, 8), (8, 8), (13, 16),
+                                       (29, 32), (50, 64)])
+def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
+        n, bucket, toy, params):
+    """A prompt of ``n`` (shorter than the window of 8, as long, longer)
+    right-padded to ``bucket`` with ``lengths = n``, inserted as row 2
+    of a ``DenseSlotCache`` of 3 rows whose last occupant was another
+    stream, then 30 tokens decoded there, the rings going round three
+    times and more: every row of logits against the reference's full
+    forward pass."""
+    steps = 30
+    config = model_config(toy, decode=True, max_seq_len=128)
+    family = config.family
+    model = family.build(config)
+    serve = types.SimpleNamespace(slots=3, max_seq=128, warmup_buckets=())
+    cache = slotcache.DenseSlotCache(serve, family, model, {})
+    cache.fresh(params)
+    stale = tokens_of(99, 1, 64)
+    _, old = jax.jit(lambda p, t: family.prefill(
+        model, {"params": p}, t, lengths=jnp.int32(60)))(params, stale)
+    cache.tree = cache._insert_jit(cache.tree, old, np.int32(2))
+    tokens = tokens_of(n, 1, n + steps)
+    want = reference_logits(params, tokens, toy)[0]
+    padded = jnp.ones((1, bucket), jnp.int32).at[:, :n].set(tokens[:, :n])
+    logits, row = jax.jit(lambda p, t: family.prefill(
+        model, {"params": p}, t, lengths=jnp.int32(n)))(params, padded)
+    np.testing.assert_allclose(logits[0, n - 1], want[n - 1], atol=2e-5)
+    cache.tree = cache._insert_jit(cache.tree, row, np.int32(2))
+    step = jax.jit(lambda p, c, t: family.decode_step(
+        model, {"params": p}, c, t))
+    for at in range(n, n + steps):
+        fed = jnp.zeros((3, 1), jnp.int32).at[2, 0].set(tokens[0, at])
+        logits, cache.tree = step(params, cache.tree, fed)
+        np.testing.assert_allclose(logits[2, 0], want[at], atol=2e-5)
+    assert [kind for kind in sorted(cache._attend_kinds)] \
+        == [(2, 128, 0), (5, WINDOW, 0)]
+
+
+# -------------------------------------------------------------- the counts
+def test_the_counts_at_the_published_widths():
+    """ISSUE 36's arithmetic, from the configuration's own file."""
+    cfg = load()
+    counts = mimo_v2_counts
+    assert counts.layers(cfg) == (2, 5, 6)
+    assert counts.attention_params(cfg, 8) == 94_371_840
+    assert counts.attention_params(cfg, 4) == 89_128_960
+    weights = counts.dense_params(cfg) + 6 * 16 * 25_165_824 \
+        + 19072 * 4096
+    assert abs(weights * 2 - 6.86e9) < 0.01e9           # the file on the chip
+    full = [4050] * 64
+    attend = counts.decode_attend_bytes_per_step(cfg, full)
+    assert attend == 2 * (64 * 4051 * 4 * 320 * 2
+                          + 64 * 64 * (192 * 2 + 128 * 4))
+    assert 1.32e9 < attend < 1.34e9
+    assert counts.moe_held_expert_bytes_per_step(cfg, full) \
+        == 6 * 16 * 25_165_824 * 2
+    assert counts.moe_routed_row_bytes_per_step(cfg, full) \
+        == 6 * 64 * 8 * 4096 * 6
+    total = counts.decode_bytes_per_step(cfg, full)
+    assert 7.4e9 < total < 7.8e9
+    # A window layer reads its last 128 positions, a global layer all.
+    short, long = (counts.decode_bytes_per_step(cfg, [c] * 64)
+                   for c in (100, 1100))
+    assert long - short == 64 * (1000 * 2 * 4 * 320 * 2
+                                 + 28 * 5 * 8 * 320 * 2)
+    import tracing
+    reader = harness.load_json(harness.HERE, "layer_metrics",
+                               "kernels.decode_attend_roofline.json")
+    facts = {"counters": {"decode_attend_bytes_per_step": 819e6},
+             "peaks": {"hbm_bytes_per_s": 819e9},
+             "metrics": {"kernels.decode_attend_device_ms_per_step": 2.0}}
+    assert tracing.evaluate(reader["reader"], facts) == pytest.approx(50.0)
+    facts["metrics"] = {}                  # the plain form: no such kernel
+    assert tracing.evaluate(reader["reader"], facts) is None
+    share = harness.load_json(harness.HERE, "layer_metrics",
+                              "replica.window_cache_share.json")
+    facts["counters"] = {"stats.window_bytes": 5, "stats.cache_bytes": 100}
+    assert tracing.evaluate(share["reader"], facts) == pytest.approx(5.0)
+    del facts["counters"]["stats.window_bytes"]          # the parent
+    assert tracing.evaluate(share["reader"], facts) is None
+    # The program's own count of one generated token is this chip's.
+    from horovod_tpu.telemetry import perfmodel
+    config = hybrid.HybridConfig(**harness.build_args(cfg))
+    assert abs(64 * perfmodel.hybrid_decode_flops(config, 4050)
+               / counts.decode_flops_per_step(cfg, full) - 1.0) < 1e-6
+    assert perfmodel.hybrid_decode_flops(config, 50) \
+        < perfmodel.hybrid_decode_flops(config, 128) \
+        < perfmodel.hybrid_decode_flops(config, 129)
+    assert perfmodel.hybrid_decode_flops(config, 1129) \
+        - perfmodel.hybrid_decode_flops(config, 129) \
+        == 2.0 * 64 * 320 * 2 * 1000
+
+
+def test_the_traffic_tables_are_the_laws_quantiles():
+    def quantiles(low, high, points):
+        return [round(low * (high / low) ** ((i + 0.5) / points))
+                for i in range(points)]
+    traffic = harness.load_json(harness.HERE, "traffic", "mixlen_sat.json")
+    table = traffic["requests"]
+    assert sorted(p for p, _ in table) == quantiles(512, 8192, 64)
+    assert sorted(o for _, o in table) == quantiles(1024, 4096, 64)
+    prompts, outputs = quantiles(512, 8192, 64), quantiles(1024, 4096, 64)
+    rng = random.Random(36)
+    rng.shuffle(prompts)
+    rng.shuffle(outputs)
+    assert table == [list(pair) for pair in zip(prompts, outputs)]
+    cfg = load()
+    buckets = cfg["serve"]["warmup_buckets"]
+    assert sorted({max(8, 1 << (p - 1).bit_length()) for p, _ in table}) \
+        == buckets == [1024, 2048, 4096, 8192]
+    assert max(p + o for p, o in table) <= cfg["serve"]["max_seq"]
+    assert cfg["serve"]["token_budget"] >= max(buckets) + 64
+
+
+# ------------------------------------------------------------- the replica
+@pytest.fixture
+def solo_world():
+    import horovod_tpu as hvd
+    hvd.shutdown()
+    for var in ("HOROVOD_RANK", "HOROVOD_SIZE"):
+        os.environ.pop(var, None)
+    hvd.init()
+    yield hvd
+    hvd.shutdown()
+
+
+def executor(model_cfg, params=None, **kw):
+    from horovod_tpu.serving import ReplicaExecutor, ServeConfig
+    return ReplicaExecutor(ServeConfig(**{**dict(
+        model_cfg=model_cfg, max_batch=3, token_budget=64, max_seq=64,
+        slo_ms=60000.0, warmup_buckets=(8, 16, 32)), **kw}), params=params)
+
+
+def serve(ex, prompts, max_new) -> list[list[int]]:
+    for prompt, new in zip(prompts, max_new):
+        ex.stats["offered"] += 1
+        assert ex.queue.submit(list(prompt), new) is not None
+    ex.serve_loop(stop_when=lambda: True)
+    assert ex.stats["served"] == len(prompts)
+    return [ex.completed[rid]["generated"] for rid in sorted(ex.completed)]
+
+
+def test_the_replica_serves_the_references_best_and_counts_by_layer_kind(
+        toy, params, solo_world):
+    """Seven requests over three slots on the normal path, prompts
+    shorter and longer than the window, streams that leave it far
+    behind: every served token is the reference's best (float32); the
+    rings are 5 of the cache's 7 layers and counted as ``window_bytes``;
+    the attend counters take ``min(length, 8)`` in a window layer."""
+    rng = random.Random(36)
+    prompts = [[rng.randrange(2, 256) for _ in range(n)]
+               for n in (1, 3, 8, 9, 17, 26, 30)]
+    new = [12, 30, 7, 25, 5, 21, 9]
+    ex = executor(model_config(toy), params)
+    try:
+        assert ex.family is hybrid.ROUTED_FAMILY
+        stats = ex.stats
+        assert stats["state_bytes"] == 0
+        assert stats["kv_bytes"] == stats["cache_bytes"] \
+            == stats["cache_aliased_bytes"]
+        ring = 3 * WINDOW * 2 * (24 + 16) * 4
+        whole = 3 * 64 * 1 * (24 + 16) * 4
+        assert stats["window_bytes"] == 5 * ring
+        assert stats["cache_bytes"] == 5 * ring + 2 * whole + 7 * 3 * 4
+        streams = serve(ex, prompts, new)
+    finally:
+        ex.close()
+    assert [len(s) for s in streams] == new
+    for prompt, served in zip(prompts, streams):
+        logits = reference_logits(params, [prompt + served], toy)[0]
+        at = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
+        assert float(jnp.max(jnp.max(logits[at], -1)
+                             - logits[at, np.asarray(served)])) <= 1e-5
+    # A layer's worth: (2 global x length + 5 window x min(length, 8)) / 7,
+    # and the plain form reads 64 positions there and 8 here.
+    assert 0 < stats["attend_live_positions"] < stats["attend_read_positions"]
+    dispatched = stats["attend_read_positions"] / ((2 * 64 + 5 * 8) / 7)
+    assert dispatched == pytest.approx(round(dispatched), abs=0.2)
+    assert stats["moe_expert_slots"] % (6 * 4) == 0
+
+
+def test_the_programs_carry_the_scope_and_kernel_names(toy, solo_world):
+    ex = executor(model_config(toy))
+    try:
+        decode, args = ex.cache._decode_call(
+            ex.params, ex._last_tokens, ex._token_on_host)
+        decode = decode.lower(*args)
+        prefill = ex.cache._prefill_jit.lower(
+            ex.params, jnp.zeros((1, 16), jnp.int32), jnp.int32(11))
+        for program, scopes in (
+                (decode, ("hvd.window_attend", "hvd.decode_attend")),
+                (prefill, ("hvd.prefill_attend", "hvd.decode_attend"))):
+            named = program.as_text(debug_info=True)
+            for scope in (*scopes, "hvd.moe_route", "hvd.sample"):
+                assert scope in named, scope
+    finally:
+        ex.close()
+
+
+# --------------------------------------------- the benchmark's own comparison
+def check_control(monkeypatch, capsys, seed: int) -> dict:
+    load_json = harness.load_json
+
+    def patched(*parts):
+        data = copy.deepcopy(load_json(*parts))
+        for over in ({"served_check": {"requests": 64}},
+                     {"trace_steps": 300}):
+            if set(over) <= set(data):
+                data["rehearsal"] = harness.merged(data["rehearsal"], over)
+        return data
+
+    monkeypatch.setattr(harness, "load_json", patched)
+    code = harness.main(["--workload", CELL, "--seed", str(seed),
+                         "--trace", "1", "--rehearse-cpu", "--check",
+                         "control"])
+    line, = [ln for ln in capsys.readouterr().out.splitlines()
+             if " check {" in ln]
+    return {"code": code, **harness.json.loads(line[line.index("{"):])}
+
+
+@pytest.mark.parametrize("seed", [3, 2147483659])
+def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
+                                                         capsys):
+    """``--check control`` of the new cell at its rehearsal sizes (a
+    ``ReplicaExecutor`` rehearsal whose ``correct`` is true): the served
+    tokens, their replay, the attention over what the program fed its
+    own and the router's rule stay inside the toy limits, and the
+    reference computed in int8 does not, by the logits' limits (what the
+    program fed its own is not the control's to round)."""
+    seen = check_control(monkeypatch, capsys, seed)
+    assert seen["code"] == 0 and seen["ok"] and not seen["problems"]
+    assert seen["served_tokens"] > 200
+    assert all(seen[key] <= limit for key, limit in seen["limits"].items())
+    over = {key for key, limit in seen["limits"].items()
+            if seen["control_" + key] > limit}
+    assert "replay_err" in over and len(over) >= 2, seen
+    assert not over & {"attend_gap", "route_gap"}
+
+
+def without_an_expert(monkeypatch):
+    route = moe.route
+
+    def without_the_first(scores, per_token, held, **kw):
+        weights, local, here = route(scores, per_token, held, **kw)
+        return weights, local, here & (local != 0)
+    monkeypatch.setattr(moe, "route", without_the_first)
+
+
+def bias_in_the_weights(monkeypatch):
+    route = moe.route
+
+    def biased(scores, per_token, held, *, bias=None, **kw):
+        return route(scores + bias, per_token, held, **kw)
+    monkeypatch.setattr(moe, "route", biased)
+
+
+def ring_keeps_the_padding(monkeypatch):
+    cached = kvcache.cached_attention
+    monkeypatch.setattr(
+        hybrid, "cached_attention", lambda *a, lengths=None, **kw:
+        cached(*a, **kw, lengths=None if kw.get("window") else lengths))
+
+
+def configured(**over):
+    """A fault that is a wrong argument of the model's configuration."""
+    def plant(monkeypatch):
+        build = harness.build_args
+        monkeypatch.setattr(harness, "build_args",
+                            lambda config: {**build(config), **over})
+    return plant
+
+
+def faults(window: int, head_dim: int) -> dict:
+    """name -> (how it is planted, the numbers it must push over their
+    limits), for a model of this window and head width: the tests plant
+    them at the toy size, a chip script at the cell's."""
+    return {
+        "window_one_longer": (configured(window=window + 1), {"attend_gap"}),
+        "window_one_shorter": (configured(window=window - 1),
+                               {"attend_gap"}),
+        "sink_left_out": (configured(window_sink=False), {"attend_gap"}),
+        "value_scale_left_out": (configured(attn_value_scale=1.0),
+                                 {"attend_gap"}),
+        "rotary_bases_swapped": (configured(rope_theta=10000.0,
+                                            window_rope_theta=10000000.0),
+                                 {"attend_gap"}),
+        "rotary_on_every_channel": (configured(attn_rotary_dim=head_dim),
+                                    {"attend_gap"}),
+        "bias_in_the_weights": (bias_in_the_weights, {"route_gap"}),
+        "an_expert_left_out": (without_an_expert, set()),
+        "ring_keeps_the_padding": (ring_keeps_the_padding, {"attend_gap"}),
+    }
+
+
+FAULTS = faults(WINDOW, 24)
+ALONE = ("replay_err", "attend_gap", "route_gap")
+
+
+def program_against_reference(cfg: dict, params) -> dict:
+    """``served_gap`` on one stream of 21 prompt tokens (in the widest
+    bucket, so right-padded) and 40 more: the three numbers that do not
+    ask who chose the tokens."""
+    tokens = np.zeros((1, 256), np.int32)
+    tokens[0, :61] = np.asarray(tokens_of(5, 61))
+    seen = ref.served_gap(cfg)(params, tokens, np.int32(21), np.int32(61))
+    return {key: float(seen[key]) for key in ALONE}
+
+
+def test_the_sound_program_reads_rounding_alone(toy, params):
+    seen = program_against_reference(toy, params)
+    limits = toy["served_check"]["limits"]
+    assert all(seen[key] <= limits[key] / 10 for key in ALONE), seen
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_planted_fault_comes_out_over_a_toy_limit(fault, toy, params,
+                                                    monkeypatch):
+    """The program broken underneath, nine ways, each over at least one
+    of the cell's toy limits and over the one that is there to catch it
+    (``attend_gap`` holds the attention's arithmetic apart from the
+    logits, ``route_gap`` the router's rule; with random weights the
+    logits alone hardly see an attention layer)."""
+    plant, must = FAULTS[fault]
+    plant(monkeypatch)
+    seen = program_against_reference(toy, params)
+    limits = toy["served_check"]["limits"]
+    over = {key for key in ALONE if seen[key] > limits[key]}
+    assert over and must <= over, (seen, limits)
+    assert "replay_err" in over, seen         # float32: the logits see it too

@@ -347,7 +347,8 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
         cache.fresh(params)
         cache.warm(params, last)
         assert stats["cache_aliased_bytes"] == stats["cache_bytes"] > 0
-        assert cache._attend_block == (block if kernel else 0)
+        assert [kind[1:] for kind in cache._attend_kinds] \
+            == [(cfg.max_seq, block if kernel else 0)]
         for rid, prompt in prompts.items():
             last[rid] = cache.admit(params, rid, prompt, 8)
             slots[rid] = types.SimpleNamespace(seq_len=len(prompt))
