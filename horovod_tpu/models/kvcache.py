@@ -8,8 +8,9 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
   ``cache_index`` [B], each row's write cursor (``cached_attention``,
   ``prefill``, ``decode_step``); values may be narrower than keys; a
   window layer keeps rings of ``window`` rows instead, ``ring_key`` /
-  ``ring_value``; fewer than 8 bfloat16 key-value heads lie side by side
-  in the lanes, [B, max_seq_len, KV * D]
+  ``ring_value``; fewer than 16 bfloat16 key-value heads whose value
+  heads are whole lanes lie side by side in the lanes, [B, max_seq_len,
+  KV * D], caches and rings alike
   (``ops/decode_attention.py:lanes_layout``);
 - paged: ``key_pool`` / ``value_pool`` [blocks + 1, block_tokens, KV, D]
   shared by every row and addressed through block tables, the last row
@@ -57,7 +58,8 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
     softmax with no value (ops/decode_attention.py).
 
     With a ``window`` the leaves are rings, ``ring_key`` / ``ring_value``
-    [B, window, KV, D]: position ``p`` lives at row ``p mod window``
+    [B, window, KV, D] (or, ``lanes_layout``, [B, window, KV * D]):
+    position ``p`` lives at row ``p mod window``
     (rotary positions are in the keys before they are written, so the
     ring's order does not matter).  A decode step writes row ``index mod
     window`` and attends over the ``min(index + 1, window)`` rows that
@@ -72,7 +74,7 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
     names = ("ring_key", "ring_value") if window \
         else ("cached_key", "cached_value")
     starts = not module.has_variable("cache", names[0])
-    lanes = not window and decode_attention.lanes_layout(kv, d, dv, dtype)
+    lanes = decode_attention.lanes_layout(kv, d, dv, dtype)
 
     def leaf(name, wide):
         shape = (b, rows, kv * wide) if lanes else (b, rows, kv, wide)
@@ -111,10 +113,11 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
             else jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (b,))
         last = end[:, None] - 1
         held = last - (last - jnp.arange(window)[None, :]) % window
+        ax = (..., *(None,) * (k.ndim - 2))     # over a row, however it lies
         for cache, new in ((cached_k, k), (cached_v, v)):
             cache.value = jnp.where(
-                (held >= 0)[:, :, None, None], jnp.take_along_axis(
-                    new, jnp.clip(held, 0, t - 1)[:, :, None, None], axis=1),
+                (held >= 0)[ax], jnp.take_along_axis(
+                    new, jnp.clip(held, 0, t - 1)[ax], axis=1),
                 jnp.zeros((), dtype))
     elif not (starts and 4 * h * t * max_seq_len > PLAIN_PREFILL_BYTES):
         return attend(q, cached_k.value, cached_v.value, positions, scale,

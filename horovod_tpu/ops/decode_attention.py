@@ -27,14 +27,20 @@ key ``j`` is visible at ``i`` when ``i - window < j <= i``:
   x block`` scores a head at a time and not ``T x max_seq``.
 
 Two layouts of the leaves, by ``lanes_layout``.  Heads that fill tiles
-of sublanes lie ``[B, S, KV, D]``.  Fewer than 8 key-value heads would
-leave half of every tile empty (or lie position-minor), so their leaves
-keep the heads side by side in the lanes, ``[B, S, KV * D]`` keys and
-``[B, S, KV * Dv]`` values, nothing padded: there the kernel multiplies
-every query, laid at its own head's lanes of a zero row, with the whole
-key row (the matrix unit loads the same key tiles either way), runs the
-softmax a query head a sublane, and meets each head's values, an
-aligned slice of lanes, in one product a head.
+of sublanes lie ``[B, S, KV, D]``: a tile of bfloat16 holds 16 rows, so
+16 key-value heads or more (the 7B's 32).  Fewer than 16 would leave
+part of every tile empty (or lie position-minor), so their leaves keep
+the heads side by side in the lanes, ``[B, S, KV * D]`` keys and ``[B,
+S, KV * Dv]`` values, nothing padded, where the rows come to whole
+lanes and each value head is whole lanes (MiMo's 4 heads of 192 and
+128, the 8 of its rings, Solar's 8 of 128; granite's 8 of 64 are not,
+and keep the plain form): there the kernel multiplies every query, laid
+at its own head's lanes of a zero row, with the whole key row (the
+matrix unit loads the same key tiles either way), runs the softmax a
+query head a sublane, and meets each head's values, an aligned slice of
+lanes, in one product a head.  A window layer's ring goes through the
+same kernel under its own name, ``hvd.window_attend``: a device trace
+tells the rings' calls from the caches' by name alone.
 
 The first kernel takes the cache leaves as they lie, ``[B, S, KV, D]``:
 merged to ``[B, S, KV * D]`` they would be copied on the device every step (the
@@ -207,13 +213,15 @@ def lanes_layout(kv_heads: int, head_dim: int, value_dim: int,
                  dtype) -> bool:
     """Whether leaves of these heads keep them side by side in the
     lanes, ``[B, S, KV * D]`` and ``[B, S, KV * Dv]``: bfloat16 heads
-    too few to fill a tile of 8 sublanes (``[B, S, KV, D]`` would be
-    padded to twice its bytes there, or lie position-minor and be
-    copied every step) whose rows come to whole lanes.  The 8 heads of
-    the granite and Solar cells fill a tile and stay as they were."""
-    return jnp.dtype(dtype) == jnp.bfloat16 and kv_heads < 8 \
+    too few to fill a sublane tile of 16 rows (``[B, S, KV, D]`` would
+    be padded there, or lie position-minor and be copied every step)
+    whose key rows come to whole lanes and whose value heads are each
+    whole lanes, the slices the kernel meets them in.  Solar's 8 heads
+    of 128 and the 8 of MiMo's rings are such; granite's 8 of 64 are
+    not, and stay ``[B, S, KV, D]`` under the plain form."""
+    return jnp.dtype(dtype) == jnp.bfloat16 and kv_heads < 16 \
         and not (kv_heads * head_dim) % _LANE \
-        and not (kv_heads * value_dim) % _LANE
+        and not value_dim % _LANE
 
 
 def block_positions(max_seq: int, kv_heads: int, head_dim: int,
@@ -222,7 +230,8 @@ def block_positions(max_seq: int, kv_heads: int, head_dim: int,
     ``[B, max_seq, kv_heads, head_dim]`` of ``dtype``: the largest
     power of two that divides ``max_seq`` and keeps a key block within
     ``_BLOCK_BYTES`` (128 positions of the 7B shape, 512 of MiMo's four
-    heads of 192).  0, and the plain form runs, where the kernel cannot
+    heads of 192 and of Solar's eight of 128, a ring's 128 whole).  0,
+    and the plain form runs, where the kernel cannot
     take the leaves as they lie: it wants bfloat16 and either
     ``lanes_layout`` or, heads in the sublanes, values as wide as the
     keys, a head width of whole lanes (a narrower leaf lies
@@ -490,9 +499,11 @@ def _lanes_kernel(len_ref, q_ref, sink_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "block", "interpret", "name"))
 def _decode_attend_lanes(q, keys, values, lengths, sink, scale, *,
-                         block: int, interpret: bool):
+                         block: int, interpret: bool,
+                         name: str = "hvd.decode_attend"):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -532,7 +543,7 @@ def _decode_attend_lanes(q, keys, values, lengths, sink, scale, *,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
-        name="hvd.decode_attend",
+        name=name,
     )(lengths, rows, jnp.broadcast_to(start[:, None], (h, _LANE)), keys,
       values)
     return out.reshape(b, 1, h, dv)
@@ -546,18 +557,19 @@ def decode_attend(q: jax.Array, keys: jax.Array, values: jax.Array,
     ``lengths`` [B] positions of ``keys`` / ``values`` (``[B, S, KV,
     D]`` or, ``lanes_layout``, ``[B, S, KV * D]``), float32 [B, 1, H,
     Dv].  The kernel on a TPU or interpreted, where ``kernel_block``
-    finds it a block; the plain form, under ``scope``, elsewhere (and
-    for heads in the sublanes with a ``sink``, which that kernel does
-    not take: the rings of a window layer, few positions)."""
+    finds it a block; the plain form elsewhere (and for heads in the
+    sublanes with a ``sink``, which that kernel does not take).  Either
+    way under ``scope``, which is also the lanes kernel's name: a window
+    layer's ring is ``hvd.window_attend``."""
     block = kernel_block(keys.shape, keys.dtype, interpret, values.shape)
     lengths = jnp.clip(lengths.astype(jnp.int32), 1, keys.shape[1])
     if not block or (sink is not None and keys.ndim == 4):
         return attend_plain(q, keys, values, lengths[:, None] - 1, scale,
                             sink, scope=scope)
-    with jax.named_scope("hvd.decode_attend"):
+    with jax.named_scope(scope):
         if keys.ndim == 3:
             return _decode_attend_lanes(q, keys, values, lengths, sink,
                                         scale, block=block,
-                                        interpret=interpret)
+                                        interpret=interpret, name=scope)
         return _decode_attend_pallas(q, keys, values, lengths, scale,
                                      block=block, interpret=interpret)

@@ -8,6 +8,7 @@ of the children and the in-process compiles.
 """
 from __future__ import annotations
 
+import functools
 import importlib
 import math
 import os
@@ -244,6 +245,18 @@ def test_moe_experts_compiles_for_v5e_at_published_shapes(v5e, tokens):
     assert "hvd.moe_experts" in compiled.as_text()
 
 
+def _placed(tree, sharding):
+    """``tree``'s shapes on the described device."""
+    return jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=sharding), tree)
+
+
+def _nbytes(tree) -> int:
+    return sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(tree))
+
+
 def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
         v5e, monkeypatch):
     """``lm7b_serve_chat_sat``'s decode program (deepseek-llm-7b's widths,
@@ -266,18 +279,13 @@ def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
     model = tfm.TransformerLM(_decode_model_cfg(cfg))
     cache = slotcache.DenseSlotCache(cfg, tfm.FAMILY, model, {})
 
-    def placed(tree):
-        return jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                              sharding=v5e), tree)
-
+    placed = functools.partial(_placed, sharding=v5e)
     params = placed(jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 8), jnp.int32))["params"]))
     tree = placed(jax.eval_shape(cache._init_cache_impl, params))
     leaf = 2 * slots * max_seq * 32 * 128            # one key leaf, bytes
-    cache_bytes = sum(math.prod(x.shape) * x.dtype.itemsize
-                      for x in jax.tree_util.tree_leaves(tree))
+    cache_bytes = _nbytes(tree)
     assert cache_bytes == layers * (2 * leaf + slots * 4)
     # The last step's result, the host's tokens and where they win.
     compiled = cache._decode_jit.lower(
@@ -301,9 +309,11 @@ def test_the_mimo_programs_fit_a_v5e_and_attend_through_the_kernel(
     layers, 64 slots, bfloat16) compiled for the v5e.  The decode
     program: one hvd.decode_attend custom call a global layer, taking
     the two leaves with their 4 heads in the lanes as they lie (768 and
-    512 wide, 12,288 positions), one hvd.moe_experts a layer that has
-    experts, the five rings through the plain form; it updates the whole
-    cache in place.  The prefill of 8,192 tokens attends in blocks: its
+    512 wide, 12,288 positions), one hvd.window_attend a window layer
+    over its ring's 8 heads in the lanes (1,536 and 1,024 wide, 128
+    positions: the same kernel under the rings' own name), one
+    hvd.moe_experts a layer that has experts; it updates the whole cache
+    in place.  The prefill of 8,192 tokens attends in blocks: its
     temporaries fit beside the weights and the cache."""
     from horovod_tpu.models import hybrid, moe
     from horovod_tpu.ops import decode_attention as da
@@ -324,27 +334,21 @@ def test_the_mimo_programs_fit_a_v5e_and_attend_through_the_kernel(
     model = hybrid.HybridLM(_decode_model_cfg(cfg))
     cache = slotcache.DenseSlotCache(cfg, cfg.model_cfg.family, model, {})
 
-    def placed(tree):
-        return jax.tree_util.tree_map(
-            lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
-                                              sharding=v5e), tree)
-
-    def nbytes(tree):
-        return sum(math.prod(x.shape) * x.dtype.itemsize
-                   for x in jax.tree_util.tree_leaves(tree))
-
+    placed = functools.partial(_placed, sharding=v5e)
     params = placed(jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 8), jnp.int32))["params"]))
     tree = placed(jax.eval_shape(cache._init_cache_impl, params))
     rings = 5 * slots * 128 * 8 * (192 + 128) * 2
     whole = 2 * slots * max_seq * 4 * (192 + 128) * 2
-    assert nbytes(tree) == rings + whole + 7 * slots * 4
-    assert 6.85e9 < nbytes(params) < 6.87e9
+    assert _nbytes(tree) == rings + whole + 7 * slots * 4
+    assert 6.85e9 < _nbytes(params) < 6.87e9
     assert tree["layer_0"]["attn"]["cached_key"].shape \
         == (slots, max_seq, 4 * 192)
+    assert tree["layer_1"]["attn"]["ring_key"].shape \
+        == (slots, 128, 8 * 192)
     assert tree["layer_1"]["attn"]["ring_value"].shape \
-        == (slots, 128, 8, 128)
+        == (slots, 128, 8 * 128)
     compiled = cache._decode_jit.lower(
         params, tree, *placed((jnp.zeros(slots + 4, jnp.int32),
                                jnp.zeros(slots, jnp.int32),
@@ -352,19 +356,81 @@ def test_the_mimo_programs_fit_a_v5e_and_attend_through_the_kernel(
     calls = [line for line in compiled.as_text().splitlines()
              if MOSAIC in line]
     attend = [call for call in calls if "hvd.decode_attend" in call]
-    assert len(attend) == 2 and len(calls) == 2 + 6
+    rings = [call for call in calls if "hvd.window_attend" in call]
+    assert len(attend) == 2 and len(rings) == 5 and len(calls) == 2 + 5 + 6
     assert sum("hvd.moe_experts" in call for call in calls) == 6
     assert all("bf16[64,12288,768]" in call and "bf16[64,12288,512]" in call
                for call in attend)
+    assert all("bf16[64,128,1536]" in call and "bf16[64,128,1024]" in call
+               for call in rings)
     memory = compiled.memory_analysis()
-    assert memory.alias_size_in_bytes >= nbytes(tree)
+    assert memory.alias_size_in_bytes >= _nbytes(tree)
     assert memory.temp_size_in_bytes < 0.1e9
     prefill = cache._prefill_jit.lower(
         params, placed(jnp.zeros((1, 8192), jnp.int32)),
         placed(jnp.zeros((), jnp.int32))).compile().memory_analysis()
     # 16 GiB less what the runtime keeps: 15.75 GB usable.
-    assert nbytes(params) + nbytes(tree) + prefill.temp_size_in_bytes \
+    assert _nbytes(params) + _nbytes(tree) + prefill.temp_size_in_bytes \
         + prefill.output_size_in_bytes < 14.5e9
+
+
+def test_the_solar_decode_program_attends_through_the_kernel_on_v5e(
+        v5e, monkeypatch):
+    """``solaropen2_serve_reason_sat``'s decode program (Solar-Open2's
+    widths, one period, 80 slots of 4,608 positions, bfloat16) compiled
+    for the v5e: the attention layer's 8 key-value heads of 128 lie in
+    the lanes, ``[80, 4608, 1024]``, and one hvd.decode_attend custom
+    call takes the two leaves as they lie, beside three hvd.kda_update
+    and four hvd.moe_experts; the program updates the whole cache in
+    place and holds no copy of a leaf among its temporaries."""
+    from horovod_tpu.models import hybrid, moe
+    from horovod_tpu.ops import decode_attention as da
+    from horovod_tpu.ops import kda
+    from horovod_tpu.serving import ServeConfig, slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks", "chip"))
+    import run as harness
+    for module in (da, moe, kda):           # the target, not the CPU
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    file = harness.load_json(harness.HERE, "configs",
+                             "Solar-Open2-250B.serve.json")
+    serve = {**file["serve"],
+             "warmup_buckets": tuple(file["serve"]["warmup_buckets"])}
+    cfg = ServeConfig(model_cfg=hybrid.HybridConfig(
+        **harness.build_args(file)), **serve)
+    slots, max_seq = cfg.slots, cfg.max_seq
+    assert (slots, max_seq) == (80, 4608)
+    model = hybrid.HybridLM(_decode_model_cfg(cfg))
+    cache = slotcache.DenseSlotCache(cfg, cfg.model_cfg.family, model, {})
+
+    placed = functools.partial(_placed, sharding=v5e)
+    params = placed(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    tree = placed(jax.eval_shape(cache._init_cache_impl, params))
+    leaf = slots * max_seq * 8 * 128 * 2             # one key leaf, bytes
+    attn = tree["layer_0"]["attn"]
+    assert attn["cached_key"].shape == attn["cached_value"].shape \
+        == (slots, max_seq, 8 * 128)
+    cache_bytes = _nbytes(tree)
+    assert cache_bytes == 2_551_972_160              # as the parent's
+    assert da.kernel_block(attn["cached_key"].shape, jnp.bfloat16,
+                           values=attn["cached_value"].shape) == 512
+    compiled = cache._decode_jit.lower(
+        params, tree, *placed((jnp.zeros(slots + 4, jnp.int32),
+                               jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, bool)))).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if MOSAIC in line]
+    attend, = [call for call in calls if "hvd.decode_attend" in call]
+    assert attend.count("bf16[80,4608,1024]") == 2
+    assert sum("hvd.kda_update" in call for call in calls) == 3
+    assert sum("hvd.moe_experts" in call for call in calls) == 4
+    assert len(calls) == 1 + 3 + 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert memory.temp_size_in_bytes < leaf // 4
 
 
 def test_fit_block_follows_the_tpu_tiling_rule():

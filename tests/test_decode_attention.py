@@ -6,7 +6,11 @@ that reads each slot's keys and values up to its own live length.
   grouped one (group 4 over 16 key-value heads of 128), bfloat16 leaves;
 - positions past a slot's length hold NaN and must not reach the output;
 - ``read_positions`` counts the blocks the kernel's index map visits;
-- which leaves the kernel takes, and the block it reads them in.
+- which leaves the kernel takes, and the block it reads them in;
+- the lanes kernel at 8 key-value heads (ISSUE 37): Solar's cache (8
+  heads of 128 under 64 query heads) and MiMo's ring (keys 192, values
+  128, a sink, one block a slot), the block the entry point really takes
+  against ``read_positions``, and the custom call's two names.
 """
 from __future__ import annotations
 
@@ -127,21 +131,30 @@ def test_read_positions_counts_the_blocks_the_index_map_visits(lengths,
     assert da.read_positions([], S, block) == 0
 
 
-@pytest.mark.parametrize("shape, dtype, block", [
-    ((16, 4096, 32, 128), jnp.bfloat16, 128),   # deepseek-llm-7b: 1 MB
-    ((8, 8192, 16, 128), jnp.bfloat16, 256),
-    ((32, 2560, 8, 64), jnp.bfloat16, 0),       # granite: lies position-minor
-    ((16, 4096, 8, 128), jnp.bfloat16, 0),      # half a sublane tile of heads
-    ((16, 4096, 32, 128), jnp.float32, 0),
-    ((2, 64, 4, 16), jnp.bfloat16, 0),          # the test-sized decoder
-], ids=["lm7b", "kv16", "granite", "kv8", "float32", "tiny"])
-def test_the_block_follows_the_leaves_shape(shape, dtype, block):
-    assert da.block_positions(*shape[1:], dtype) == block
+@pytest.mark.parametrize("shape, dtype, rule, block", [
+    ((16, 4096, 32, 128), jnp.bfloat16, 128, 128),  # deepseek-llm-7b: 1 MB
+    ((8, 8192, 16, 128), jnp.bfloat16, 256, 256),
+    ((32, 2560, 8, 64), jnp.bfloat16, 0, 0),     # granite: lies position-minor
+    # Half a sublane tile of heads: they belong in the lanes (Solar), and
+    # are read there in the rule's block; a ring of MiMo's whole.
+    ((16, 4096, 8, 128), jnp.bfloat16, 512, 0),
+    ((16, 4096, 8 * 128), jnp.bfloat16, 512, 512),
+    ((64, 128, 8 * 192), jnp.bfloat16, 128, 128),
+    ((16, 4096, 32, 128), jnp.float32, 0, 0),
+    ((2, 64, 4, 16), jnp.bfloat16, 0, 0),           # the test-sized decoder
+], ids=["lm7b", "kv16", "granite", "kv8", "kv8_lanes", "ring_lanes",
+        "float32", "tiny"])
+def test_the_block_follows_the_leaves_shape(shape, dtype, rule, block):
+    """``rule``: ``block_positions`` for these heads; ``block``: what the
+    compiled path reads a leaf of this very shape in."""
+    _, max_seq, *heads = shape
+    kv, d = heads if len(heads) == 2 else (1, *heads)
+    assert da.block_positions(max_seq, kv, d, dtype) == rule
     assert da.kernel_block(shape, dtype, interpret=True) == block
     assert da.kernel_block(shape, dtype) == 0                 # a CPU
     if block:
-        assert shape[1] % block == 0
-        assert block * shape[2] * shape[3] * 2 <= da._BLOCK_BYTES
+        assert max_seq % block == 0
+        assert block * kv * d * 2 <= da._BLOCK_BYTES
 
 
 def test_a_narrow_head_keeps_the_plain_form():
@@ -182,3 +195,121 @@ def test_the_kernel_carries_its_name_and_takes_the_leaves_as_they_lie():
     assert "pallas_call" not in str(jax.make_jaxpr(
         lambda *a: da.decode_attend(*a, 0.1))(q, keys, values, lens))
     assert kvcache.attend is da.attend_plain
+
+
+# --- 8 key-value heads in the lanes (ISSUE 37) ------------------------------
+# Solar's attention layer: 64 query heads over 8 heads of 128; MiMo's
+# ring: keys 192 and values 128 wide, a sink, the window one block.
+KV8 = {"solar": dict(heads=64, kv=8, dk=128, dv=128, s=64, block=16,
+                     sink=False, scope="hvd.decode_attend"),
+       "ring": dict(heads=64, kv=8, dk=192, dv=128, s=16, block=16,
+                    sink=True, scope="hvd.window_attend")}
+# 1, mid-block, a whole block, full (a ring at and under its window)
+KV8_LENGTHS = {"solar": (1, 7, 16, 41, 64), "ring": (1, 7, 15, 16, 16)}
+
+
+def _lanes_operands(shape, qdtype=jnp.bfloat16):
+    """q, the leaves ``[B, S, KV * D]`` with NaN past each length, the
+    lengths, the sink, and the clean leaves ``[B, S, KV, D]``."""
+    spec = KV8[shape]
+    lens = np.asarray(KV8_LENGTHS[shape], np.int32)
+    rng = np.random.default_rng(37)
+    b, s, kv = len(lens), spec["s"], spec["kv"]
+    q = jnp.asarray(rng.standard_normal((b, 1, spec["heads"], spec["dk"])),
+                    qdtype)
+    keys = jnp.asarray(rng.standard_normal((b, s, kv, spec["dk"])),
+                       jnp.bfloat16)
+    values = jnp.asarray(rng.standard_normal((b, s, kv, spec["dv"])),
+                         jnp.bfloat16)
+    sink = jnp.asarray(rng.standard_normal(spec["heads"]), jnp.float32) \
+        if spec["sink"] else None
+    dead = np.arange(s)[None, :, None, None] >= lens[:, None, None, None]
+    merged = [jnp.where(dead, jnp.nan, x).reshape(b, s, -1)
+              for x in (keys, values)]
+    return q, merged, jnp.asarray(lens), sink, (keys, values)
+
+
+@pytest.mark.parametrize("qdtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16_q", "f32_q"])
+@pytest.mark.parametrize("shape", sorted(KV8))
+def test_the_lanes_kernel_serves_eight_heads(shape, qdtype):
+    """Interpreted, against ``attend_plain`` over the same heads: every
+    output to float32 rounding, the NaN past each length in none."""
+    spec = KV8[shape]
+    q, merged, lens, sink, clean = _lanes_operands(shape, qdtype)
+    got = da._decode_attend_lanes(q, *merged, lens, sink, 0.09,
+                                  block=spec["block"], interpret=True)
+    want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.09, sink)
+    assert got.shape == want.shape == (len(lens), 1, 64, spec["dv"])
+    assert got.dtype == jnp.float32
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+
+
+@pytest.mark.parametrize("shape", sorted(KV8))
+def test_read_positions_is_the_block_the_entry_point_takes(shape,
+                                                           monkeypatch):
+    """``decode_attend`` interpreted hands the lanes kernel the block
+    ``kernel_block`` names for the very leaves (what
+    ``serving/slotcache.py`` counts with), under the layer kind's name;
+    walking the kernel's index map in that block visits the positions
+    ``read_positions`` counts."""
+    spec = KV8[shape]
+    q, merged, lens, sink, clean = _lanes_operands(shape)
+    assert da.lanes_layout(spec["kv"], spec["dk"], spec["dv"], jnp.bfloat16)
+    monkeypatch.setattr(da, "_BLOCK_BYTES",
+                        spec["block"] * spec["kv"] * spec["dk"] * 2)
+    block = da.kernel_block(merged[0].shape, jnp.bfloat16, True,
+                            merged[1].shape)
+    assert block == spec["block"]
+    assert da.kernel_block(clean[0].shape, jnp.bfloat16, True,
+                           clean[1].shape) == 0   # not where they belong
+    calls = []
+    real = da._decode_attend_lanes
+    monkeypatch.setattr(da, "_decode_attend_lanes",
+                        lambda *a, **kw: calls.append(kw) or real(*a, **kw))
+    got = da.decode_attend(q, *merged, lens, 0.09, sink, interpret=True,
+                           scope=spec["scope"])
+    assert calls == [{"block": block, "interpret": True,
+                      "name": spec["scope"]}]
+    want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.09, sink)
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+    host = np.asarray(lens)
+    visited = {tuple(int(i) for i in da._live_block(slot, j, host,
+                                                    block=block))
+               for slot in range(len(host))
+               for j in range(spec["s"] // block)}
+    assert da.read_positions(host, spec["s"], block) == len(visited) * block
+    # The plain form over the same leaves reads every slot whole.
+    assert da.read_positions(host, spec["s"], 0) == len(host) * spec["s"]
+
+
+def pallas_calls(jaxpr) -> list:
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(pallas_calls(sub))
+    return found
+
+
+@pytest.mark.parametrize("shape", sorted(KV8))
+def test_a_cache_and_a_ring_carry_their_own_kernel_names(shape):
+    """One pallas_call: ``hvd.decode_attend`` over a cache,
+    ``hvd.window_attend`` over a ring (a device trace selects an
+    operation by its name alone, and the two are counted apart); the
+    leaves go in as they lie, the lengths as the scalar prefetch."""
+    spec = KV8[shape]
+    q, merged, lens, sink, _ = _lanes_operands(shape)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, n: da.decode_attend(
+        q, k, v, n, 0.1, sink, interpret=True, scope=spec["scope"]))(
+        q, *merged, lens)
+    call, = pallas_calls(jaxpr.jaxpr)
+    other = {"hvd.decode_attend", "hvd.window_attend"} - {spec["scope"]}
+    named = str(call.params["name"]) \
+        + str(call.params.get("name_and_src_info"))
+    assert spec["scope"] in named and other.pop() not in named
+    assert call.params["grid_mapping"].num_index_operands == 1
+    assert [v.aval.shape for v in call.invars[-2:]] \
+        == [x.shape for x in merged]

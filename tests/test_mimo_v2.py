@@ -32,6 +32,7 @@ import run as harness  # noqa: E402
 from horovod_tpu.models import hybrid, kvcache, moe  # noqa: E402
 from horovod_tpu.ops import decode_attention as da  # noqa: E402
 from horovod_tpu.serving import slotcache  # noqa: E402
+from test_decode_attention import pallas_calls  # noqa: E402
 
 CONFIG = "MiMo-V2.5.serve"
 CELL = "mimov25_serve_mixlen_sat"
@@ -188,7 +189,9 @@ def test_the_entry_point_reads_lanes_leaves_in_the_block_the_rule_gives(
     form reads the same leaves; the rule leaves the other families'
     leaves where they were."""
     assert da.lanes_layout(4, 192, 128, jnp.bfloat16)
-    assert not da.lanes_layout(8, 128, 128, jnp.bfloat16)     # Solar
+    assert da.lanes_layout(8, 128, 128, jnp.bfloat16)         # Solar
+    assert da.lanes_layout(8, 192, 128, jnp.bfloat16)         # the rings
+    assert not da.lanes_layout(16, 128, 128, jnp.bfloat16)    # a whole tile
     assert not da.lanes_layout(8, 64, 64, jnp.bfloat16)       # granite
     assert not da.lanes_layout(4, 192, 128, jnp.float32)
     assert not da.lanes_layout(1, 24, 16, jnp.bfloat16)       # the toy
@@ -199,7 +202,9 @@ def test_the_entry_point_reads_lanes_leaves_in_the_block_the_rule_gives(
     assert da.kernel_block((64, 12288, 4, 192), jnp.bfloat16, True,
                            (64, 12288, 4, 128)) == 0
     assert da.kernel_block((64, 128, 8, 192), jnp.bfloat16, True,
-                           (64, 128, 8, 128)) == 0            # the rings
+                           (64, 128, 8, 128)) == 0
+    assert da.kernel_block((64, 128, 8 * 192), jnp.bfloat16, True,
+                           (64, 128, 8 * 128)) == 128         # the rings
     lens = jnp.asarray([5, 40, 64], jnp.int32)
     q, k, v, sink = operands(3, 3, 1, 64, 64, 4, 192, 128, jnp.bfloat16)
     monkeypatch.setattr(da, "_BLOCK_BYTES", 16 * 4 * 192 * 2)
@@ -209,22 +214,13 @@ def test_the_entry_point_reads_lanes_leaves_in_the_block_the_rule_gives(
                         lambda *a, **kw: calls.append(kw) or real(*a, **kw))
     merged = (k.reshape(3, 64, -1), v.reshape(3, 64, -1))
     got = da.decode_attend(q, *merged, lens, 0.07, sink, interpret=True)
-    assert calls == [{"block": 16, "interpret": True}]
+    assert calls == [{"block": 16, "interpret": True,
+                      "name": "hvd.decode_attend"}]
     want = da.attend_plain(q, k, v, lens[:, None] - 1, 0.07, sink)
     np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
     plain = da.decode_attend(q, *merged, lens, 0.07, sink)       # a CPU
     assert len(calls) == 1
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(want))
-
-
-def pallas_calls(jaxpr) -> list:
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append(eqn)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found.extend(pallas_calls(sub))
-    return found
 
 
 def test_the_lanes_kernel_carries_the_decode_kernels_name():
@@ -281,13 +277,13 @@ def test_a_window_layers_ring_is_a_full_leaf_under_a_window_mask(n, bucket,
 
 def test_lanes_leaves_and_long_prompts_through_the_cache(monkeypatch):
     """What the toy widths never take: bfloat16 leaves of 2 key-value
-    heads whose rows are whole lanes lie ``[B, S, KV x D]`` (keys 128
-    and values 64 wide), and a prefill whose scores would pass
+    heads whose value heads are whole lanes lie ``[B, S, KV x D]`` (keys
+    256 and values 128 wide), and a prefill whose scores would pass
     ``PLAIN_PREFILL_BYTES`` attends in blocks over its own keys; both
     against the same layer without a cache."""
     cfg = hybrid.HybridConfig(
-        d_model=64, num_heads=4, num_kv_heads=2, attn_head_dim=128,
-        attn_value_dim=64, attn_rotary_dim=32, attn_value_scale=0.5,
+        d_model=64, num_heads=4, num_kv_heads=2, attn_head_dim=256,
+        attn_value_dim=128, attn_rotary_dim=32, attn_value_scale=0.5,
         attention_multiplier=0.09, layer_types=("attention",),
         dtype=jnp.bfloat16)
     plain = hybrid.GroupedAttention(cfg)
@@ -304,8 +300,8 @@ def test_lanes_leaves_and_long_prompts_through_the_cache(monkeypatch):
     got, mut = cached.apply({"params": own}, x[:, :16], mutable=["cache"])
     assert len(blocked) == 1
     cache = mut["cache"]
-    assert cache["cached_key"].shape == (2, 32, 2 * 128)
-    assert cache["cached_value"].shape == (2, 32, 2 * 64)
+    assert cache["cached_key"].shape == (2, 32, 2 * 256)
+    assert cache["cached_value"].shape == (2, 32, 2 * 128)
     rows = [got.astype(jnp.float32)]
     for at in range(16, 20):
         out, mut = cached.apply({"params": own, "cache": cache},
@@ -313,6 +309,59 @@ def test_lanes_leaves_and_long_prompts_through_the_cache(monkeypatch):
         cache = mut["cache"]
         rows.append(out.astype(jnp.float32))
     np.testing.assert_allclose(jnp.concatenate(rows, 1), want, atol=0.03)
+
+
+@pytest.mark.parametrize("n, bucket", [(5, 8), (8, 8), (13, 16), (27, 32)])
+def test_a_ring_in_the_lanes_through_the_kernel(n, bucket, monkeypatch):
+    """A window layer of 8 bfloat16 key-value heads (keys 192 and values
+    128 wide, a sink, a window of 8): its rings lie ``[B, 8, 8 x D]``.
+    A right-padded prompt shorter than the window, as long and longer
+    fills them in rows of the merged width; then 2 x 8 + 3 decode steps,
+    past two wraps, each through the lanes kernel (interpreted) under
+    the ring's own name with ``lengths = min(index + 1, window)``;
+    against the same layer over the whole sequence, the window a mask."""
+    import functools
+
+    steps = 2 * WINDOW + 3
+    cfg = hybrid.HybridConfig(
+        d_model=64, num_heads=16, num_kv_heads=4, window_kv_heads=8,
+        attn_head_dim=192, attn_value_dim=128, attn_rotary_dim=64,
+        attention_multiplier=0.07, window=WINDOW, window_sink=True,
+        layer_types=("window",), dtype=jnp.bfloat16)
+    plain = hybrid.GroupedAttention(cfg, windowed=True)
+    cached = hybrid.GroupedAttention(dataclasses.replace(
+        cfg, decode=True, max_seq_len=64), windowed=True)
+    x = jax.random.normal(jax.random.key(n), (2, n + steps, 64),
+                          jnp.bfloat16)
+    own = plain.init(jax.random.key(1), x)["params"]
+    own = {**own, "sink": jax.random.normal(jax.random.key(2), (16,))}
+    want = plain.apply({"params": own}, x).astype(jnp.float32)
+    calls = []
+    real = da._decode_attend_lanes
+    monkeypatch.setattr(da, "_decode_attend_lanes",
+                        lambda *a, **kw: calls.append((a[3], kw))
+                        or real(*a, **kw))
+    monkeypatch.setattr(kvcache, "decode_attend", functools.partial(
+        da.decode_attend, interpret=True))
+    padded = jnp.full((2, bucket, 64), 7.0, jnp.bfloat16) \
+        .at[:, :n].set(x[:, :n])             # padding that would be seen
+    got, mut = cached.apply({"params": own}, padded, jnp.int32(n),
+                            mutable=["cache"])
+    cache = kvcache._with_cache_index(mut["cache"], n)
+    assert cache["ring_key"].shape == (2, WINDOW, 8 * 192)
+    assert cache["ring_value"].shape == (2, WINDOW, 8 * 128)
+    rows = [got[:, :n].astype(jnp.float32)]
+    for at in range(n, n + steps):
+        out, mut = cached.apply({"params": own, "cache": cache},
+                                x[:, at:at + 1], mutable=["cache"])
+        cache = mut["cache"]
+        rows.append(out.astype(jnp.float32))
+    np.testing.assert_allclose(jnp.concatenate(rows, 1), want, atol=0.03)
+    assert len(calls) == steps
+    assert all(kw == {"block": WINDOW, "interpret": True,
+                      "name": "hvd.window_attend"} for _, kw in calls)
+    assert [int(lens[0]) for lens, _ in calls] \
+        == [min(at + 1, WINDOW) for at in range(n, n + steps)]
 
 
 # ---------------------------------------------------------------- the router

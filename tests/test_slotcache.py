@@ -290,44 +290,71 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
         cache.close()
 
 
-# --- the decode kernel through the dense layout (ISSUE 33) ------------------
+# --- the decode kernels through the dense layout (ISSUEs 33 and 37) ----------
+def _heads16():
+    """A decoder wide enough for the sublane kernel: 16 heads of 128."""
+    return tfm.TransformerConfig(
+        vocab_size=64, num_layers=2, num_heads=16, d_model=2048, d_ff=64,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+
+
+def _kv8_ring():
+    """The Solar cell's attention shape (8 key-value heads of 128, under
+    16 query heads here) beside a window layer of 8 heads with a sink:
+    both lie in the lanes, ``[slots, max_seq, 1024]`` and ``[slots,
+    window, 1024]``."""
+    from horovod_tpu.models import hybrid
+    return hybrid.HybridConfig(
+        vocab_size=64, d_model=64, d_ff=64, num_heads=16, num_kv_heads=8,
+        attn_head_dim=128, attention_multiplier=0.09, window=16,
+        window_sink=True, layer_types=("attention", "window"),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+
+
+# decoder -> (its configuration, key-value heads, layers a span)
+DECODERS = {"heads16": (_heads16, 16, {64: 2}),
+            "kv8_ring": (_kv8_ring, 8, {64: 1, 16: 1})}
+
+
 @pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
+@pytest.mark.parametrize("decoder", sorted(DECODERS))
 def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
-        kernel, monkeypatch):
-    """A decoder wide enough for hvd.decode_attend (16 heads of 128,
-    bfloat16) through ``DenseSlotCache``: with the kernel interpreted
-    (the entry point's platform check patched, its block cut to 16
-    positions so that slots end in different blocks) every token is the
-    one ``tfm.prefill`` and ``tfm.decode_step`` give with the plain
-    form; ``stats`` counts the live positions and what the compiled path
-    reads for them; one decode program either way."""
+        decoder, kernel, monkeypatch):
+    """A bfloat16 decoder wide enough for hvd.decode_attend through
+    ``DenseSlotCache``: with the kernel interpreted (the entry point's
+    platform check patched, a cache's block cut to 16 positions so that
+    slots end in different blocks, a ring of 16 one block) every token
+    is the one the family's own ``prefill`` and ``decode_step`` give
+    with the plain form, past a wrap of the ring; ``stats`` counts the
+    live positions and what the compiled path reads for them in the
+    blocks it really takes, a layer's worth (the mean over the layers);
+    one decode program either way."""
     import functools
 
     from horovod_tpu.ops import decode_attention as da
     from horovod_tpu.serving import ServeConfig, slotcache
     from horovod_tpu.serving.replica import _decode_model_cfg, _seeded_params
 
+    config, kv, spans = DECODERS[decoder]
     cfg = ServeConfig.from_env(
         max_batch=3, token_budget=64, max_seq=64, slo_ms=60000.0,
-        paged=False, warmup_buckets=(8,),
-        model_cfg=tfm.TransformerConfig(
-            vocab_size=64, num_layers=2, num_heads=16, d_model=2048, d_ff=64,
-            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
-    model = tfm.TransformerLM(_decode_model_cfg(cfg))
+        paged=False, warmup_buckets=(8,), model_cfg=config())
+    family = cfg.model_cfg.family
+    model = family.build(_decode_model_cfg(cfg))
     params = _seeded_params(model, 0)
     prompts = {0: list(range(3, 20)), 1: [44, 45, 46], 2: [9] * 31}
-    steps, block = 4, 16
+    steps, block, layers = 4, 16, sum(spans.values())
 
     def reference(prompt):
         padded = np.zeros((1, slotcache.prompt_bucket(cfg, len(prompt))),
                           np.int32)
         padded[0, :len(prompt)] = prompt
-        logits, own = tfm.prefill(model, {"params": params},
-                                  jnp.asarray(padded),
-                                  lengths=jnp.int32(len(prompt)))
+        logits, own = family.prefill(model, {"params": params},
+                                     jnp.asarray(padded),
+                                     lengths=jnp.int32(len(prompt)))
         out = [int(jnp.argmax(logits[0, len(prompt) - 1]))]
         for _ in range(steps):
-            logits, own = tfm.decode_step(
+            logits, own = family.decode_step(
                 model, {"params": params}, own,
                 jnp.asarray([[out[-1]]], jnp.int32))
             out.append(int(jnp.argmax(logits[0, -1])))
@@ -336,10 +363,10 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
     want = {rid: reference(prompt) for rid, prompt in prompts.items()}
     if kernel:
         monkeypatch.setattr(da, "_on_tpu", lambda: True)
-        monkeypatch.setattr(da, "_BLOCK_BYTES", block * 16 * 128 * 2)
+        monkeypatch.setattr(da, "_BLOCK_BYTES", block * kv * 128 * 2)
         monkeypatch.setattr(kvcache, "decode_attend", functools.partial(
             da.decode_attend, interpret=True))
-    cache = slotcache.DenseSlotCache(cfg, tfm.FAMILY, model, stats := {})
+    cache = slotcache.DenseSlotCache(cfg, family, model, stats := {})
     slots = [None] * cfg.slots
     last = np.zeros(cfg.slots, np.int32)
     got = {}
@@ -347,8 +374,13 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
         cache.fresh(params)
         cache.warm(params, last)
         assert stats["cache_aliased_bytes"] == stats["cache_bytes"] > 0
-        assert [kind[1:] for kind in cache._attend_kinds] \
-            == [(cfg.max_seq, block if kernel else 0)]
+        assert sorted(cache._attend_kinds) == sorted(
+            (n, span, block if kernel else 0) for span, n in spans.items())
+        if decoder == "kv8_ring":
+            attn = cache.tree["layer_0"]["attn"], cache.tree["layer_1"]["attn"]
+            assert attn[0]["cached_key"].shape == (3, 64, 8 * 128)
+            assert attn[1]["ring_value"].shape == (3, 16, 8 * 128)
+            assert stats["window_bytes"] == 2 * 3 * 16 * 1024 * 2
         for rid, prompt in prompts.items():
             last[rid] = cache.admit(params, rid, prompt, 8)
             slots[rid] = types.SimpleNamespace(seq_len=len(prompt))
@@ -359,10 +391,12 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
             nxt = cache.fetch(cache.decode(
                 params, last, np.full(cfg.slots, step == 0), [0, 1, 2],
                 slots))
+            within = [(n, min(slots[rid].seq_len + 1, span), span)
+                      for rid in prompts for span, n in spans.items()]
+            live += sum(n * held for n, held, _ in within) // layers
+            read += sum(n * (-(-held // block) * block if kernel else span)
+                        for n, held, span in within) // layers
             for rid in prompts:
-                after = slots[rid].seq_len + 1
-                live += after
-                read += -(-after // block) * block if kernel else cfg.max_seq
                 last[rid] = -1
                 slots[rid].seq_len += 1
                 got[rid].append(int(nxt[rid]))
@@ -372,7 +406,8 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
         assert read > live
         # 18..21 and 4..7 positions read 32 and 16 a step in blocks of
         # 16; 32 positions read 32, then 33..35 read 48.
-        assert not kernel or read == steps * (32 + 16) + 32 + 3 * 48
+        assert not (kernel and decoder == "heads16") \
+            or read == steps * (32 + 16) + 32 + 3 * 48
         assert cache._decode_jit._cache_size() == 1
     finally:
         cache.close()
