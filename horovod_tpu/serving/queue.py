@@ -24,6 +24,7 @@ from collections import deque
 from dataclasses import field
 
 from ..common import config
+from ..telemetry.spans import mark
 
 
 @dataclasses.dataclass
@@ -97,7 +98,10 @@ class RequestQueue:
                 max_new_tokens=int(max_new_tokens), arrival=now,
                 deadline=now + slo / 1e3, slo_ms=slo))
             self._m_depth.set(len(self._items))
-            return rid
+        # The first of a request's three marks in a trace (enqueue, admit,
+        # complete: one rid), on the submitting thread.
+        mark("serve.enqueue", rid=rid)
+        return rid
 
     def close(self) -> None:
         """No further submissions; queued requests still drain."""

@@ -63,6 +63,7 @@ import dataclasses
 import gc
 import json
 import os
+import resource
 import statistics
 import threading
 import time
@@ -75,11 +76,11 @@ import numpy as np
 from ..common import config
 from ..common.exceptions import RanksFailedError
 from ..common.logging import logger
-from ..telemetry.spans import StepParts, span
+from ..telemetry.spans import StepParts, mark, span
 from .admission import AdmissionController
 from .batcher import Assignment, BatchPlan, ContinuousBatcher
 from .queue import RequestQueue
-from .slotcache import DenseSlotCache, PagedSlotCache, prompt_bucket
+from .slotcache import DenseSlotCache, PagedSlotCache
 
 
 @dataclasses.dataclass
@@ -162,6 +163,7 @@ class _Slot:
     deadline: float                    # absolute local monotonic
     assigned_at: float
     age_ms: float                      # ingress age when assigned
+    step: int                          # the serve step that admitted it
     slo_ms: float
     generated: list[int]
     seq_len: int = 0                   # the write cursor, at dispatch
@@ -239,10 +241,9 @@ class ReplicaExecutor:
                       "prefill_skipped": 0, "weight_swaps": [],
                       # The slot cache, and how much of it the compiled
                       # decode program updates in place (set by warm-up);
-                      # cache_bytes = kv_bytes (keys, values, cursors) +
-                      # state_bytes (a family's recurrent state).
+                      # state_bytes of it are a family's recurrent state.
                       "cache_bytes": 0, "cache_aliased_bytes": 0,
-                      "kv_bytes": 0, "state_bytes": 0,
+                      "state_bytes": 0,
                       # Decode attention, summed over decode dispatches:
                       # the active slots' live contexts, and the positions
                       # the compiled path reads for them (a layer).
@@ -265,6 +266,21 @@ class ReplicaExecutor:
                       # them that stayed in the process (world size 1).
                       "exchanges": 0, "local_exchanges": 0,
                       "step_parts_s": {"admit": {}, "decode": {}},
+                      # An admission, counted where it happens, every
+                      # one since the executor was built (its warm-up is
+                      # none): requests given a slot, and the host
+                      # seconds inside hvd.serve.admit, which every
+                      # running stream waits.
+                      "admissions": 0, "admit_s": 0.0,
+                      # By the slot cache's admit: prompt tokens computed
+                      # anew (a prefix hit's are not) and the positions
+                      # the prefill program ran over (their bucket).
+                      "prefill_prompt_tokens": 0,
+                      "prefill_bucket_positions": 0,
+                      # positions an admission prefilled (0: parked for
+                      # a streamed prefill) -> [admissions, prompt tokens
+                      # computed, admit_s], for close()'s table.
+                      "prefill_by_bucket": {},
                       "slow_steps": [], "slow_steps_total": 0}
         # Host seconds of the last steps of each kind: a step slower
         # than _SLOW_FACTOR times their median leaves a record.
@@ -306,6 +322,9 @@ class ReplicaExecutor:
         self._kvstream = None
         self._init_cache()
         self._warmup()
+        # Warm-up prefilled each bucket through cache.admit: no admission.
+        self.stats.update(prefill_prompt_tokens=0,
+                          prefill_bucket_positions=0)
         if self.prefill_rank_list:
             self._rebuild_kvstream()
 
@@ -419,13 +438,35 @@ class ReplicaExecutor:
             self._settle(parts)        # a no-op after the first
             slot = next(i for i, s in enumerate(self.slots) if s is None)
             admits += 1
-            with parts("admit", rid=a.rid,
-                       bucket=prompt_bucket(
-                           self.cfg, len(self._clamped_tokens(a)))):
+            # Every stream that is decoding waits for this admission.
+            running = sum(s is not None and s.pending is None
+                          for s in self.slots)
+            # The plan formed at the head of this step (the ranks enter a
+            # step together: the completion exchange ends the one before).
+            waited = a.age_ms / 1e3 + parts.elapsed()
+            stats = self.stats
+            before = (stats["prefill_prompt_tokens"],
+                      stats["prefill_bucket_positions"])
+            admit = parts("admit", rid=a.rid, slot=slot, running=running,
+                          queue_wait_ms=round(waited * 1e3, 3))
+            with admit as annotation:
                 if a.prefill >= 0:
                     self._admit_disaggregated(slot, a, now)
                 else:
                     self._prefill_slot(slot, a, now)
+                # What the cache's admit ran: on the paged layout the
+                # prefix cache's hits are neither computed nor padded.
+                tokens = stats["prefill_prompt_tokens"] - before[0]
+                positions = stats["prefill_bucket_positions"] - before[1]
+                annotation.set_metadata(bucket=positions,
+                                        prompt_tokens=tokens)
+            stats["admissions"] += 1
+            stats["admit_s"] += admit.seconds
+            by_bucket = stats["prefill_by_bucket"].setdefault(
+                positions, [0, 0, 0.0])
+            by_bucket[0] += 1
+            by_bucket[1] += tokens
+            by_bucket[2] += admit.seconds
         return admits
 
     def _prefill_slot(self, slot: int, a: Assignment, now: float) -> None:
@@ -436,8 +477,8 @@ class ReplicaExecutor:
         self.slots[slot] = _Slot(
             rid=a.rid, remaining=a.max_new_tokens - 1,
             deadline=now + a.deadline_rel_ms / 1e3, assigned_at=now,
-            age_ms=a.age_ms, slo_ms=a.slo_ms, generated=[first],
-            seq_len=len(toks))
+            age_ms=a.age_ms, step=self._step, slo_ms=a.slo_ms,
+            generated=[first], seq_len=len(toks))
         self.prefilled.add(a.rid)
 
     def _clamped_tokens(self, a: Assignment) -> list[int]:
@@ -461,8 +502,8 @@ class ReplicaExecutor:
         self.slots[slot] = _Slot(
             rid=a.rid, remaining=a.max_new_tokens,
             deadline=now + a.deadline_rel_ms / 1e3, assigned_at=now,
-            age_ms=a.age_ms, slo_ms=a.slo_ms, generated=[],
-            pending=a, pending_since=now)
+            age_ms=a.age_ms, step=self._step, slo_ms=a.slo_ms,
+            generated=[], pending=a, pending_since=now)
         self.prefilled.add(a.rid)
 
     def _prefill_and_stream(self, a: Assignment) -> None:
@@ -625,6 +666,11 @@ class ReplicaExecutor:
             # The local record also keeps the token stream itself (the
             # answer); only counts ride the completions allgather.
             self.completed[s.rid] = {**rec, "generated": list(s.generated)}
+            # The last of a request's three marks in a trace (enqueue,
+            # admit, complete: one rid); ``steps`` from the one that
+            # admitted it to this one, both counted.
+            mark("serve.complete", rid=s.rid, tokens=rec["tokens"],
+                 steps=self._step - s.step + 1)
             if self.group_leader:
                 # Every group member frees slots identically; only the
                 # leader reports, so completions appear exactly once.
@@ -905,7 +951,8 @@ class ReplicaExecutor:
 
     # -- the loop --------------------------------------------------------
     def _serve_step(self) -> bool:
-        gc2 = gc.get_stats()[2]["collections"]
+        before = (gc.get_stats()[2]["collections"],
+                  resource.getrusage(resource.RUSAGE_THREAD))
         step = self._step
         admits = decoded = ctx_sum = 0
         parts = StepParts("serve", step=step)
@@ -948,15 +995,17 @@ class ReplicaExecutor:
                     self._fleet_gauge(self)
         finally:
             seconds = parts.close(admits=admits, decoded=decoded)
-        self._note_step_parts(step, seconds, admits, gc2)
+        self._note_step_parts(step, seconds, admits, before)
         return True
 
     def _note_step_parts(self, step: int, seconds: dict, admits: int,
-                         gc2_before: int) -> None:
+                         before: tuple) -> None:
         """Fold one finished step's part timers into the always-on
         counters, and keep the record of a step that was slow for its
         kind: more than ``_SLOW_FACTOR`` times the median of the last
-        ``_RECENT_STEPS`` such steps (the threshold is the data's)."""
+        ``_RECENT_STEPS`` such steps (the threshold is the data's).
+        ``before`` is what the step sampled as it began: the collector's
+        generation-2 count and this thread's ``getrusage``."""
         kind = "admit" if admits else "decode"
         total = seconds["total"]
         self.stats["steps"][kind] += 1
@@ -980,13 +1029,24 @@ class ReplicaExecutor:
             return
         parts_ms = {part: round(s * 1e3, 3) for part, s in seconds.items()
                     if part != "total"}
+        gc2_before, usage0 = before
+        usage = resource.getrusage(resource.RUSAGE_THREAD)
         record = {
             "step": step, "kind": kind, "wall_time": time.time(),
             "total_ms": round(total * 1e3, 3), "parts_ms": parts_ms,
             "slowest": max(parts_ms, key=parts_ms.get), "admits": admits,
             # Did a full (generation-2) collection of Python's garbage
             # collector run inside the step?
-            "gc2": gc.get_stats()[2]["collections"] > gc2_before}
+            "gc2": gc.get_stats()[2]["collections"] > gc2_before,
+            # Was the serving thread running?  Its user plus system time
+            # inside the step (the chip's host counts it in ticks of 10
+            # ms, and time.thread_time() no finer) and how often the
+            # kernel took the core from it: next to no CPU in a long step
+            # names a thread that waited or was descheduled, the step's
+            # worth the program.
+            "cpu_ms": round((usage.ru_utime - usage0.ru_utime
+                             + usage.ru_stime - usage0.ru_stime) * 1e3, 3),
+            "nivcsw": usage.ru_nivcsw - usage0.ru_nivcsw}
         kept = self.stats["slow_steps"]
         if len(kept) >= _SLOW_STEPS_KEPT:
             del kept[0]                # the newest are kept
@@ -1139,7 +1199,13 @@ class ReplicaExecutor:
             logger.info("serving: step parts %s", json.dumps(
                 {key: self.stats[key]
                  for key in ("steps", "step_parts_s", "slow_steps_total",
-                             "slow_steps")}))
+                             "slow_steps", "admissions", "admit_s",
+                             "prefill_prompt_tokens",
+                             "prefill_bucket_positions")}))
+        if self.stats["prefill_by_bucket"]:
+            from ..telemetry.report import admission_table
+            logger.info("serving: admissions by bucket\n%s",
+                        admission_table(self.stats["prefill_by_bucket"]))
         if self._fleet_puller is not None:
             self._fleet_puller.close()
             self._fleet_puller = None

@@ -52,8 +52,7 @@ def _padded(cfg, toks: list) -> np.ndarray:
 
 def _sample(logits):
     """Greedy sampling: the arg-max over the vocabulary axis."""
-    with jax.named_scope("hvd.sample"):
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 class _SlotCache:
@@ -81,6 +80,15 @@ class _SlotCache:
 
     def fresh(self, params) -> None:
         self.tree = self._init_cache_jit(params)
+
+    def _count_prefill(self, tokens: int, positions: int) -> None:
+        """One prefill: the prompt tokens it computes anew and the
+        positions its program runs over (their bucket)."""
+        stats = self.stats
+        stats["prefill_prompt_tokens"] = \
+            stats.get("prefill_prompt_tokens", 0) + tokens
+        stats["prefill_bucket_positions"] = \
+            stats.get("prefill_bucket_positions", 0) + positions
 
     def warm(self, params, last_tokens: np.ndarray) -> None:
         """Compile every program the serve loop runs.  Each of them takes
@@ -153,8 +161,7 @@ class _SlotCache:
         stats["state_bytes"] = sum(
             leaf.nbytes for path, leaf in leaves
             if path[-1].key in self.family.state_leaves)
-        stats["kv_bytes"] = stats["cache_bytes"] - stats["state_bytes"]
-        # Of those, the rings of the window layers.
+        # Of the rest (keys, values, cursors), the window layers' rings.
         stats["window_bytes"] = sum(
             leaf.nbytes for path, leaf in leaves
             if path[-1].key in ("ring_key", "ring_value"))
@@ -238,10 +245,11 @@ class DenseSlotCache(_SlotCache):
                                   last_tokens.copy(), from_host.copy())
 
     def admit(self, params, slot: int, toks: list, max_new: int) -> int:
+        padded = _padded(self.cfg, toks)
+        self._count_prefill(len(toks), padded.shape[1])
         with span("serve.prefill_dispatch"):
             first, cache1 = self._prefill_jit(
-                params, jnp.asarray(_padded(self.cfg, toks)),
-                jnp.int32(len(toks)))
+                params, jnp.asarray(padded), jnp.int32(len(toks)))
         with span("serve.cache_insert"):     # a dispatch: nothing waits
             self.tree = self._insert_jit(self.tree, cache1, np.int32(slot))
         with span("serve.first_token_fetch"):
@@ -365,6 +373,7 @@ class PagedSlotCache(_SlotCache):
     def admit(self, params, slot: int, toks: list, max_new: int) -> int:
         bt = self.cfg.block_tokens
         hits, pos = self._lookup_prefix(toks)
+        new = len(toks) - pos          # what the prefix cache lacks
         if pos >= len(toks):
             # Whole prompt resident: no prefill, just the last prompt
             # token again for the next-token logits (its K/V rewrite is
@@ -373,6 +382,7 @@ class PagedSlotCache(_SlotCache):
             self.stats["prefill_skipped"] += 1
         blocks = self._block_run(slot, toks, max_new, hits)
         self._ensure_writable(blocks, pos // bt)
+        self._count_prefill(new, prompt_bucket(self.cfg, len(toks) - pos))
         with span("serve.prefill_dispatch"):
             first = self._prefill(params, toks, blocks, pos)
         # The program wrote the pool rows itself; the host's part of the

@@ -5,6 +5,7 @@ CLI::
 
     python -m horovod_tpu.telemetry.report DUMP_OR_TIMELINE.json [...]
     python -m horovod_tpu.telemetry.report SESSION.xplane.pb
+    python -m horovod_tpu.telemetry.report SESSION.xplane.pb --request RID
 
 Accepts every artifact the runtime produces and answers "where did the
 milliseconds go" as a per-activity table:
@@ -17,7 +18,11 @@ milliseconds go" as a per-activity table:
 - a **profiler session** (``hvd.start_profiler``'s ``.xplane.pb``): the
   program's ``hvd.*`` spans (telemetry/spans.py) by the step they lie
   in, each with its count, median, tail, and self time (its duration
-  less what its children on the same thread cover), and, where the
+  less what its children on the same thread cover); a serving
+  session's admissions by prompt bucket and its five slowest requests to
+  a first token, or with ``--request RID`` that one request's queue
+  wait, admission by part, decode steps and tokens (its ``enqueue``,
+  ``admit`` and ``complete`` marks carry one ``rid``); and, where the
   session has device planes, each device's idle gaps between ``XLA Ops``
   by the innermost ``hvd.*`` span the host was in.
 
@@ -198,9 +203,153 @@ def _group(span: dict) -> str:
         else f"{root['name']} [admits {'> 0' if admits else '= 0'}]"
 
 
-def summarize_xplane(path: str, top: int = 10) -> str:
-    """The ``hvd.*`` spans of one profiler session and, where it traced
-    a device, the device's idle gaps by the span the host was in."""
+def admission_table(by_bucket: dict) -> str:
+    """Admissions by the positions their prefill ran over: ``{bucket:
+    [count, prompt tokens computed, seconds]}`` (``executor.stats
+    ["prefill_by_bucket"]``, or a session's ``hvd.serve.admit`` spans)
+    as rows of count, mean ms, ms per 1,000 prompt tokens and the share
+    of the prefilled positions that was padding.  Bucket 0 prefilled
+    nothing here: parked for a prefill that another rank streams."""
+    rows = []
+    for bucket, (count, tokens, seconds) in sorted(by_bucket.items()):
+        rows.append([
+            str(bucket), str(count), f"{seconds / count * 1e3:.3f}",
+            f"{seconds / tokens * 1e6:.3f}" if tokens else "-",
+            f"{100 * (1 - tokens / (count * bucket)):.2f}" if bucket
+            else "-"])
+    return _fmt_table(rows, ["bucket", "count", "mean_ms", "ms_per_ktoken",
+                             "padding_%"])
+
+
+# A request's zero-length marks: where it entered the queue and where
+# its last token was collected.  With hvd.serve.admit they carry its rid.
+_MARKS = ("hvd.serve.enqueue", "hvd.serve.complete")
+_ADMIT = "hvd.serve.admit"
+
+
+def _requests(spans: list[dict]) -> dict[int, dict]:
+    """rid -> the spans of one request that the session holds (``spans``
+    by start): its ``enqueue`` and ``complete`` marks, its ``admit`` with
+    the spans inside it and the settle before it, and the serve steps
+    that began after the one that admitted it."""
+    found: dict[int, dict] = {}
+    for span in spans:
+        if span["name"] in _MARKS + (_ADMIT,) and "rid" in span["stats"]:
+            found.setdefault(int(span["stats"]["rid"]), {})[
+                span["name"].rsplit(".", 1)[1]] = span
+    steps = [s for s in spans if s["name"] == "hvd.serve.step"]
+    session_end = max(s["end"] for s in spans)
+    for req in found.values():
+        admit, done = req.get("admit"), req.get("complete")
+        # Only its enqueue mark: it had no slot yet when the session ended.
+        req["queued"] = admit is None and done is None
+        lo = admit["end"] if admit else spans[0]["start"]
+        hi = done["start"] if done else session_end
+        req["steps"] = [] if req["queued"] else [
+            s for s in steps if lo <= s["start"] <= hi]
+        req["until"] = hi
+        if admit:
+            req["inside"] = [s for s in spans if s is not admit
+                             and admit["start"] <= s["start"]
+                             and s["end"] <= admit["end"]]
+            # The first admission of a step waits for the decode step in
+            # flight before it begins: that step's only token_fetch.
+            before = [s for s in spans if s["root"] is admit["root"]
+                      and s["end"] <= admit["start"]]
+            req["settle"] = [
+                s for s in before if s["name"] == "hvd.serve.token_fetch"
+                and not any(o["name"] == _ADMIT for o in before)]
+    return found
+
+
+def _ms(ns: float) -> str:
+    return f"{ns / 1e6:.3f}"
+
+
+def request_report(found: dict[int, dict], rid: int) -> str:
+    """One request by phase: queue wait, admission by part, decode
+    steps and tokens, first to last token."""
+    req = found.get(rid)
+    if req is None:
+        held = f"rids {min(found)} to {max(found)}" if found else "none"
+        return (f"request {rid}: no hvd.serve.enqueue, .admit or .complete "
+                f"of it in the session (it holds {held})")
+    admit, done = req.get("admit"), req.get("complete")
+    rows = []
+    if admit:
+        stats = admit["stats"]
+        seen = f"{_ms(admit['start'] - req['enqueue']['start'])} " \
+            "from its enqueue mark" if "enqueue" in req \
+            else "enqueued before the session opened"
+        rows.append(["queue wait", str(stats.get("queue_wait_ms", "-")),
+                     f"by the admission's own count; {seen}"])
+        for s in req["settle"]:
+            rows.append(["  settle", _ms(s["end"] - s["start"]),
+                         "the decode step in flight, before the admission"])
+        rows.append(["admission", _ms(admit["end"] - admit["start"]),
+                     ", ".join(f"{key} {stats[key]}" for key in (
+                         "bucket", "prompt_tokens", "slot", "running")
+                         if key in stats)])
+        rows += [["  " + s["name"].rsplit(".", 1)[1],
+                  _ms(s["end"] - s["start"]), ""] for s in req["inside"]]
+    else:
+        rows.append(["admission", "-", "still queued at the session's end"
+                     if req["queued"] else "before the session opened"])
+    steps = req["steps"]
+    stalls = [s for s in steps if s["stats"].get("admits")]
+    ms = [(s["end"] - s["start"]) / 1e6 for s in steps]
+    rows.append([
+        "decode steps", str(len(steps)),
+        f"median {statistics.median(ms):.3f} ms; {len(stalls)} of them "
+        f"admitted another request and took "
+        f"{sum((s['end'] - s['start']) / 1e6 for s in stalls):.3f} ms"
+        if steps else "none in the session"])
+    if done:
+        rows.append(["tokens", str(done["stats"]["tokens"]),
+                     f"in {done['stats']['steps']} serve steps, the "
+                     "admitting and the completing one counted"])
+    elif not req["queued"]:
+        rows.append(["tokens", "-", "still decoding at the session's end"])
+    if admit:
+        span_ns = req["until"] - admit["end"]
+        rows.append(["first to last token" if done
+                     else "first token to the session's end",
+                     _ms(span_ns), f"{span_ns / 1e6 / len(steps):.3f} ms "
+                     "a step" if steps else ""])
+    return f"request {rid}\n" + _fmt_table(rows, ["phase", "ms_or_count",
+                                                  "what"])
+
+
+def slowest_requests(found: dict[int, dict], top: int = 5) -> str:
+    """The requests admitted inside the session that waited longest for
+    their first token (queue wait plus admission)."""
+    def first_token_ms(req):
+        admit = req["admit"]
+        return float(admit["stats"].get("queue_wait_ms", 0.0)) \
+            + (admit["end"] - admit["start"]) / 1e6
+    admitted = {rid: req for rid, req in found.items() if "admit" in req}
+    rows = []
+    for rid, req in sorted(admitted.items(),
+                           key=lambda kv: -first_token_ms(kv[1]))[:top]:
+        stats, done = req["admit"]["stats"], req.get("complete")
+        rows.append([
+            str(rid), str(stats.get("bucket", "-")),
+            str(stats.get("prompt_tokens", "-")),
+            str(stats.get("queue_wait_ms", "-")),
+            _ms(req["admit"]["end"] - req["admit"]["start"]),
+            str(stats.get("running", "-")), str(len(req["steps"])),
+            str(done["stats"]["tokens"]) if done else "-"])
+    return (f"slowest requests to a first token ({len(admitted)} admitted "
+            "in the session; --request RID for one)\n" + _fmt_table(
+                rows, ["rid", "bucket", "prompt_tokens", "queue_wait_ms",
+                       "admit_ms", "running", "steps_seen", "tokens"]))
+
+
+def summarize_xplane(path: str, top: int = 10,
+                     request: int | None = None) -> str:
+    """The ``hvd.*`` spans of one profiler session, its admissions and
+    requests (``request``: that one alone) and, where it traced a
+    device, the device's idle gaps by the span the host was in."""
     from jax.profiler import ProfileData
 
     spans: list[dict] = []
@@ -219,10 +368,14 @@ def summarize_xplane(path: str, top: int = 10) -> str:
     if not spans:
         return "(no hvd.* spans in the session)"
     spans.sort(key=lambda s: s["start"])
+    requests = _requests(spans)
+    if request is not None:
+        return request_report(requests, request)
     groups: dict[str, dict[str, list]] = {}
     for span in spans:
-        groups.setdefault(_group(span), {}).setdefault(
-            span["name"], []).append(span)
+        if span["name"] not in _MARKS:
+            groups.setdefault(_group(span), {}).setdefault(
+                span["name"], []).append(span)
     parts = []
     for group, names in sorted(groups.items()):
         rows = []
@@ -237,6 +390,18 @@ def summarize_xplane(path: str, top: int = 10) -> str:
         parts.append(f"spans in {group}\n" + _fmt_table(
             rows, ["span", "count", "p50_ms", "tail_ms", "tail_at", "max_ms",
                    "self_p50_ms", "total_ms"]))
+    by_bucket: dict = {}
+    for span in spans:
+        if span["name"] == _ADMIT and "bucket" in span["stats"]:
+            row = by_bucket.setdefault(int(span["stats"]["bucket"]),
+                                       [0, 0, 0.0])
+            row[0] += 1
+            row[1] += int(span["stats"].get("prompt_tokens", 0))
+            row[2] += (span["end"] - span["start"]) / 1e9
+    if by_bucket:
+        parts.append("admissions by bucket\n" + admission_table(by_bucket))
+    if any("admit" in req for req in requests.values()):
+        parts.append(slowest_requests(requests))
     parts += [f"{device}: " + idle_gaps(ops, spans, top)
               for device, ops in sorted(devices.items())]
     return "\n\n".join(parts)
@@ -276,9 +441,10 @@ def idle_gaps(ops: list[tuple], spans: list[dict], top: int = 10) -> str:
                 ["longest idle gaps", "ms"]))
 
 
-def summarize_file(path: str) -> str:
+def summarize_file(path: str, request: int | None = None) -> str:
     if path.endswith(".pb"):
-        return f"== {path} (profiler session) ==\n{summarize_xplane(path)}\n"
+        return (f"== {path} (profiler session) ==\n"
+                f"{summarize_xplane(path, request=request)}\n")
     payload = json.loads(Path(path).read_text())
     if isinstance(payload, list):
         body = summarize_timeline(payload)
@@ -299,11 +465,15 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("paths", nargs="+",
                         help="metrics dump(s), timeline file(s) and/or "
                              "profiler session(s)")
+    parser.add_argument("--request", type=int, metavar="RID",
+                        help="of a profiler session, one request alone: "
+                             "its queue wait, admission by part, decode "
+                             "steps and tokens")
     args = parser.parse_args(argv)
     rc = 0
     for path in args.paths:
         try:
-            sys.stdout.write(summarize_file(path) + "\n")
+            sys.stdout.write(summarize_file(path, args.request) + "\n")
         except (OSError, ValueError) as exc:
             sys.stderr.write(f"report: cannot summarize {path}: {exc}\n")
             rc = 1

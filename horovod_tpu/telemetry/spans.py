@@ -8,8 +8,13 @@ operations (``python -m horovod_tpu.telemetry.report x.xplane.pb``).
 With no session open an enter and exit find tracing off and cost well
 under a microsecond.
 
+``mark(name, **args)`` is a span of no length: where something happened
+to a request (it entered the queue, its last token was collected).
+
 ``timed(slots, key, name)`` is a span that also adds its seconds to
-``slots[key]``, whether or not anybody traces (``Trainer.stats``).
+``slots[key]``, whether or not anybody traces (``Trainer.stats``, the
+replica's admission counters), and keeps them as ``.seconds`` for a
+caller that splits them further (an admission's, by bucket).
 
 ``StepParts`` is the recorder one step owns.  It is the step's span
 (``hvd.<family>.step``) and its only clock: ``with parts("token_fetch"):``
@@ -35,14 +40,22 @@ def span(name: str, **args):
     return jax.profiler.TraceAnnotation(PREFIX + name, **args)
 
 
+def mark(name: str, **args) -> None:
+    """``hvd.<name>`` as an event of no length that carries ``args``."""
+    with span(name, **args):
+        pass
+
+
 class timed:
     """``span(name, **args)`` that also adds its ``time.perf_counter()``
-    seconds to ``slots[key]``, traced or not."""
-    __slots__ = ("_slots", "_key", "_span", "_t0")
+    seconds to ``slots[key]``, traced or not; ``seconds`` is its own
+    time once it has exited."""
+    __slots__ = ("_slots", "_key", "_span", "_t0", "seconds")
 
     def __init__(self, slots: dict, key: str, name: str, **args) -> None:
         self._slots, self._key = slots, key
         self._span = span(name, **args)
+        self.seconds = 0.0
 
     def __enter__(self):
         self._span.__enter__()
@@ -50,7 +63,7 @@ class timed:
         return self._span
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
+        self.seconds = dt = time.perf_counter() - self._t0
         self._slots[self._key] = self._slots.get(self._key, 0.0) + dt
         return self._span.__exit__(*exc)
 

@@ -285,8 +285,9 @@ def test_requests_of_different_lengths_share_the_slot_array(
     try:
         assert ex.family is hybrid.FAMILY
         stats = ex.stats
-        assert stats["state_bytes"] + stats["kv_bytes"] \
-            == stats["cache_bytes"] == stats["cache_aliased_bytes"]
+        assert stats["state_bytes"] < stats["cache_bytes"] \
+            == stats["cache_aliased_bytes"]
+        assert "kv_bytes" not in stats     # cache_bytes less state_bytes
         mamba = 3 * (8 * 16 * 16 * 4 + 3 * (128 + 32) * 4)
         assert stats["state_bytes"] == 2 * mamba
         streams = serve(ex, prompts, new)
@@ -335,8 +336,9 @@ def test_a_reused_slot_carries_nothing_of_its_last_occupant(
 
 def test_the_hybrid_programs_carry_the_scope_names(toy, solo_world):
     """hvd.ssm_update and hvd.ssm_conv are in the decode program's debug
-    info, hvd.ssm_scan in the prefill's, beside hvd.decode_attend and
-    hvd.sample, and nowhere in the programs themselves."""
+    info, hvd.ssm_scan in the prefill's, beside hvd.decode_attend, and
+    nowhere in the programs themselves; hvd.sample, which reached no
+    device event, is gone."""
     ex = executor(model_config(toy))
     try:
         decode, args = ex.cache._decode_call(
@@ -348,8 +350,9 @@ def test_the_hybrid_programs_carry_the_scope_names(toy, solo_world):
                 (decode, ("hvd.ssm_update", "hvd.ssm_conv")),
                 (prefill, ("hvd.ssm_scan", "hvd.ssm_conv"))):
             named = program.as_text(debug_info=True)
-            for scope in (*scopes, "hvd.decode_attend", "hvd.sample"):
+            for scope in (*scopes, "hvd.decode_attend"):
                 assert scope in named, scope
+            assert "hvd.sample" not in named
             assert "hvd." not in program.as_text()
     finally:
         ex.close()
@@ -393,7 +396,7 @@ def test_the_decoders_programs_are_what_they_were(solo_world):
         assert ex.cache._prefill_jit.lower(ex.params, *prompt).as_text() \
             == jax.jit(_prefill_impl).lower(ex.params, *prompt).as_text()
         assert ex.stats["state_bytes"] == 0
-        assert ex.stats["kv_bytes"] == ex.stats["cache_bytes"] > 0
+        assert ex.stats["cache_bytes"] > 0 and "kv_bytes" not in ex.stats
     finally:
         ex.close()
 

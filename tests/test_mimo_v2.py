@@ -614,8 +614,8 @@ def test_the_replica_serves_the_references_best_and_counts_by_layer_kind(
         assert ex.family is hybrid.ROUTED_FAMILY
         stats = ex.stats
         assert stats["state_bytes"] == 0
-        assert stats["kv_bytes"] == stats["cache_bytes"] \
-            == stats["cache_aliased_bytes"]
+        assert stats["cache_bytes"] == stats["cache_aliased_bytes"]
+        assert "kv_bytes" not in stats     # cache_bytes less state_bytes
         ring = 3 * WINDOW * 2 * (24 + 16) * 4
         whole = 3 * 64 * 1 * (24 + 16) * 4
         assert stats["window_bytes"] == 5 * ring
@@ -649,8 +649,9 @@ def test_the_programs_carry_the_scope_and_kernel_names(toy, solo_world):
                 (decode, ("hvd.window_attend", "hvd.decode_attend")),
                 (prefill, ("hvd.prefill_attend", "hvd.decode_attend"))):
             named = program.as_text(debug_info=True)
-            for scope in (*scopes, "hvd.moe_route", "hvd.sample"):
+            for scope in (*scopes, "hvd.moe_route"):
                 assert scope in named, scope
+            assert "hvd.sample" not in named   # it reached no device event
     finally:
         ex.close()
 

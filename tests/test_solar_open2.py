@@ -659,8 +659,9 @@ def test_the_replica_serves_the_references_best_and_counts_the_routing(
     try:
         assert ex.family is hybrid.ROUTED_FAMILY
         stats = ex.stats
-        assert stats["state_bytes"] + stats["kv_bytes"] \
-            == stats["cache_bytes"] == stats["cache_aliased_bytes"]
+        assert stats["state_bytes"] < stats["cache_bytes"] \
+            == stats["cache_aliased_bytes"]
+        assert "kv_bytes" not in stats     # cache_bytes less state_bytes
         a_layer = 4 * 16 * 16 * 4 + 3 * (3 * 64) * 4
         assert stats["state_bytes"] == 3 * 3 * a_layer
         # Warm-up's step is not fetched by the serve loop: not counted.
@@ -694,8 +695,9 @@ def test_the_programs_carry_the_scope_and_kernel_names(toy, solo_world):
                 (decode, ("hvd.kda_update", "hvd.kda_conv")),
                 (prefill, ("hvd.kda_scan", "hvd.kda_conv"))):
             named = program.as_text(debug_info=True)
-            for scope in (*scopes, "hvd.moe_route", "hvd.sample"):
+            for scope in (*scopes, "hvd.moe_route"):
                 assert scope in named, scope
+            assert "hvd.sample" not in named   # it reached no device event
         with pytest.raises(ValueError, match="recurrent state"):
             executor(model_config(toy), paged=True)
     finally:
