@@ -16,11 +16,17 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
   shared by every row and addressed through block tables, the last row
   a write sink for padded positions (``paged_attention``, ``paged_*``).
 
-Both write this call's keys and values, then ``attend``; a decode step
-on the dense layout (one query position a row) goes through
-``decode_attend``, which on a TPU reads each row's keys and values up to
-its own live length; a long prompt, and any prompt of a window layer,
-attends over its own keys and values in blocks (``attend_blocked``).
+Both write this call's keys and values, then ``attend``, but for a
+decode step on the dense layout (one query position a row): that goes
+through ``decode_attend``, which is handed the step's row and where it
+belongs and returns the leaves with it written.  On a TPU, where a
+kernel takes the leaves (``ops/decode_attention.py:kernel_writes``: the
+7B's, MiMo's caches and rings, Solar's), the kernel reads each row's
+keys and values up to its own live length and writes the new row in
+place itself; elsewhere ``write_rows`` writes it, a serial loop over the
+rows, and the plain form attends.  A long prompt, and any prompt of a
+window layer, attends over its own keys and values in blocks
+(``attend_blocked``).
 A family's
 attention layer (``transformer.Attention``, ``hybrid.GroupedAttention``)
 brings its projections, its positional encoding and its score scale,
@@ -37,7 +43,7 @@ from flax.core import unfreeze
 # (ops/decode_attention.py).
 from ..ops import decode_attention
 from ..ops.decode_attention import (attend_blocked, attend_plain as attend,
-                                    decode_attend)
+                                    decode_attend, write_rows)
 
 # A prefill whose float32 scores over its row of the cache would pass
 # this many bytes goes in blocks over its own keys instead (a prompt of
@@ -51,7 +57,9 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
                      rotate=None, window: int = 0, sink=None,
                      lengths=None) -> jax.Array:
     """Incremental attention over ``module``'s dense cache: write this
-    call's K/V at each row's own depth, attend over the cached prefix.
+    call's K/V at each row's own depth, attend over the cached prefix
+    (a decode step: ``decode_attend`` does both, in one kernel where it
+    has one).
     ``rotate(x, positions)`` is the family's positional encoding, if it
     has one; positions are absolute, so the math is the full forward's.
     ``v`` may be narrower than ``k``; ``sink`` [H] is a column of the
@@ -89,20 +97,15 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
         q, k = rotate(q, positions), rotate(k, positions)
     index.value = idx + t
     k, v = k.astype(dtype), v.astype(dtype)
-    write = jax.vmap(lambda cache, new, i: jax.lax.dynamic_update_slice(
-        cache, new, (i,) + (0,) * (cache.ndim - 1)))
     if lanes:
         k, v = k.reshape(b, t, kv * d), v.reshape(b, t, kv * dv)
-    if t == 1 or not window:
-        at = idx % window if window else idx
-        cached_k.value = write(cached_k.value, k, at)
-        cached_v.value = write(cached_v.value, v, at)
     if t == 1:     # a decode step: each row up to its own length, no further
-        return decode_attend(
-            q, cached_k.value, cached_v.value,
-            jnp.minimum(idx + 1, window) if window else idx + 1, scale,
-            sink,
+        out, cached_k.value, cached_v.value = decode_attend(
+            q, cached_k.value, cached_v.value, k, v,
+            jnp.minimum(idx + 1, window) if window else idx + 1,
+            idx % window if window else idx, scale, sink,
             scope="hvd.window_attend" if window else "hvd.decode_attend")
+        return out
     if window:
         if not starts:
             raise ValueError("a window layer's ring takes a whole prompt "
@@ -119,9 +122,12 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
                 (held >= 0)[ax], jnp.take_along_axis(
                     new, jnp.clip(held, 0, t - 1)[ax], axis=1),
                 jnp.zeros((), dtype))
-    elif not (starts and 4 * h * t * max_seq_len > PLAIN_PREFILL_BYTES):
-        return attend(q, cached_k.value, cached_v.value, positions, scale,
-                      sink)
+    else:
+        cached_k.value = write_rows(cached_k.value, k, idx)
+        cached_v.value = write_rows(cached_v.value, v, idx)
+        if not (starts and 4 * h * t * max_seq_len > PLAIN_PREFILL_BYTES):
+            return attend(q, cached_k.value, cached_v.value, positions,
+                          scale, sink)
     if lanes:
         k, v = k.reshape(b, t, kv, d), v.reshape(b, t, kv, dv)
     return attend_blocked(q, k, v, scale, window=window, sink=sink)

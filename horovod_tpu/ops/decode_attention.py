@@ -10,10 +10,11 @@ key ``j`` is visible at ``i`` when ``i - window < j <= i``:
   ``jax.numpy``; it reads every cached position and masks the dead ones
   afterwards.  Prefill, the paged layout and every backend but the TPU
   run it, and it is the kernel's reference.
-- ``decode_attend``: one query position a slot (a decode step).  On a
-  TPU it is one Pallas kernel, named ``hvd.decode_attend`` the way the
-  flash kernels carry their names (a device trace selects an operation
-  by ``<opcode> <name>`` only), which reads each slot's keys and values
+- ``decode_attend``: one query position a slot (a decode step), and
+  the step's own key and value row, which it writes.  On a TPU it is
+  one Pallas kernel, named ``hvd.decode_attend`` the way the flash
+  kernels carry their names (a device trace selects an operation by
+  ``<opcode> <name>`` only), which reads each slot's keys and values
   in blocks **up to that slot's own live length and no further**: the
   lengths are a runtime operand (scalar prefetch), the index of a block
   past a slot's length repeats the one before it, so nothing is
@@ -55,6 +56,25 @@ float32 query) goes through the matrix unit as three bfloat16 pieces
 that sum to it exactly, the products accumulated in float32: nothing is
 rounded that ``attend_plain`` does not round.  The online softmax across
 blocks is ``ops/flash_attention.py``'s.
+
+**Who writes a decode step's row** (ISSUE 39).  Written outside the
+kernel, a row a slot at the slot's own depth (``write_rows``) compiles
+to a ``while`` loop over the slots, one loop a leaf, 3 to 4 us an
+iteration whatever the row's bytes: 23% of the MiMo cell's device step.
+So where a kernel attends, the kernel writes: it takes the new row and
+each slot's position as operands (``at``, a second scalar prefetch),
+lays the row into the block that holds that position **in VMEM**, before
+the scores (always a live block; nothing relies on a write to HBM being
+seen by the same call's reads), and returns the leaves aliased to its
+inputs, of which it writes back only the smallest aligned piece that
+holds the row: the one position ``[1, 1, KV, D]`` where a position is a
+major dimension, a tile of 16 positions ``[1, 16, KV * D]``, filled
+from the block in VMEM, where the heads lie in the lanes.  The rule is
+``kernel_writes``, the very condition under which ``decode_attend``
+takes a kernel: elsewhere (every backend but the TPU, granite's 8 heads
+of 64, float32 leaves, a sink on heads in the sublanes) the path is
+``write_rows``, then ``attend_plain``.  A prefill writes with
+``write_rows`` always: one row, once a request.
 
 ``block_positions`` is the one rule for the block's length, and
 ``read_positions`` the count of what the compiled path reads for a set
@@ -278,6 +298,26 @@ def read_positions(lengths, max_seq: int, block: int) -> int:
     return int((-(-np.clip(lengths, 1, max_seq) // block)).sum()) * block
 
 
+def kernel_writes(keys: tuple, dtype, values: tuple | None = None,
+                  sink: bool = False, interpret: bool = False) -> bool:
+    """Whether a decode step over key leaves of shape ``keys`` (value
+    leaves of ``values``; a layer with a ``sink`` or none) goes through
+    a kernel, which then writes the step's row itself: where
+    ``kernel_block`` finds a block, but for heads in the sublanes with a
+    sink, which that kernel does not take.  The one rule: ``decode_attend``
+    follows it, and whoever counts the layers asks it."""
+    return bool(kernel_block(keys, dtype, interpret, values)) \
+        and not (sink and len(keys) == 4)
+
+
+# A row a slot at the slot's own depth.  XLA compiles it to a ``while``
+# loop over the slots, 3 to 4 us an iteration whatever a row's bytes: a
+# prefill writes so (one row, once a request), and a decode step where
+# no kernel writes for it.
+write_rows = jax.vmap(lambda leaf, new, at: jax.lax.dynamic_update_slice(
+    leaf, new, (at,) + (0,) * (leaf.ndim - 1)))
+
+
 # ---------------------------------------------------------------------------
 # The kernel
 # ---------------------------------------------------------------------------
@@ -298,8 +338,8 @@ def _fold(x: jax.Array, rows: int) -> jax.Array:
     return sum(x[i:i + rows] for i in range(0, x.shape[0], rows))
 
 
-def _attend_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
-                   qp_ref, m_ref, l_ref, acc_ref, *,
+def _attend_kernel(len_ref, at_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref,
+                   o_ref, ko_ref, vo_ref, qp_ref, m_ref, l_ref, acc_ref, *,
                    scale: float, block: int, kv: int, group: int):
     """One slot, one block of positions.  A block of a leaf is read as
     ``[block * KV, D]``, a row a (position, key-value head) pair, which
@@ -309,14 +349,20 @@ def _attend_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     (``mine`` below), and a sum down the rows leaves them ``[group, block
     * KV]`` with the lane ``position * KV + head``, the layout the
     softmax runs in.  The weights go back the same way: spread over the
-    rows, masked by ``mine``, one product with the values.  Written
-    with few operations: the kernel is lowered in every process that
-    serves, and its lowering is set-up time."""
+    rows, masked by ``mine``, one product with the values.  The step's
+    own row (``nk_ref``, ``nv_ref``: [1, KV, D]) belongs at position
+    ``at_ref[slot]``: the block that holds it, always a live one, takes
+    it in VMEM before the scores (the leaf in HBM has it only once the
+    call is over), and ``ko_ref`` / ``vo_ref``, that one position of
+    the leaves, carry it out.  Written with few operations: the kernel
+    is lowered in every process that serves, and its lowering is set-up
+    time."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     j = pl.program_id(1)
     length = len_ref[pl.program_id(0)]
+    row = at_ref[pl.program_id(0)] - j * block     # in this block, if 0..
     h = kv * group
     lanes = block * kv
     d = k_ref.shape[-1]
@@ -354,9 +400,21 @@ def _attend_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j == 0)
     def _init():
         qp_ref[...] = jnp.concatenate(_pieces(q_ref[0]), axis=0)
+        ko_ref[0] = nk_ref[...]
+        vo_ref[0] = nv_ref[...]
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((row >= 0) & (row < block))
+    def _take():
+        # The step's row, into the block as it lies in VMEM: an input
+        # block's buffer is the kernel's to write (nothing copies it
+        # back).  A select over the whole block reads the same time on
+        # the chip (1.040 against 1.044 ms for the 7B cell's four
+        # layers) and lowers more operations.
+        k_ref[0, pl.ds(row, 1)] = nk_ref[...]
+        v_ref[0, pl.ds(row, 1)] = nv_ref[...]
 
     @pl.when(j * block < length)
     def _accumulate():
@@ -392,7 +450,7 @@ def _attend_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / a_head(l_ref[...])).astype(o_ref.dtype)
 
 
-def _live_block(slot, j, lens, *, block: int) -> tuple:
+def _live_block(slot, j, lens, *_, block: int) -> tuple:
     """The block of a leaf that grid step ``(slot, j)`` holds: past the
     slot's last live block the index repeats, and nothing is fetched."""
     return (slot, jnp.minimum(j, (lens[slot] - 1) // block), 0, 0)
@@ -403,8 +461,8 @@ def _live_block(slot, j, lens, *, block: int) -> tuple:
 # for every layer was 1 s of a 7B decode program's 1.4 s here, paid at
 # each of warm-up's two lowerings, and 3.5 s of set-up on the chip.
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
-def _decode_attend_pallas(q, keys, values, lengths, scale, *, block: int,
-                          interpret: bool):
+def _decode_attend_pallas(q, keys, values, new_k, new_v, lengths, at, scale,
+                          *, block: int, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -415,35 +473,44 @@ def _decode_attend_pallas(q, keys, values, lengths, scale, *, block: int,
 
     leaf = pl.BlockSpec((1, block, kv, d),
                         functools.partial(_live_block, block=block))
-    head = pl.BlockSpec((1, h, d), lambda slot, j, lens: (slot, 0, 0))
+    head = pl.BlockSpec((1, h, d), lambda slot, j, *_: (slot, 0, 0))
+    new = pl.BlockSpec((1, kv, d), lambda slot, j, *_: (slot, 0, 0))
+    # A position is a major dimension here: the step's row is a block.
+    row = pl.BlockSpec((1, 1, kv, d),
+                       lambda slot, j, lens, at: (slot, at[slot], 0, 0))
     # Query rows by (group member, key-value head): head kv * group + g.
     rows = q.reshape(b, kv, group, d).swapaxes(1, 2).reshape(b, h, d)
-    out = pl.pallas_call(
+    out, keys, values = pl.pallas_call(
         functools.partial(_attend_kernel, scale=scale, block=block, kv=kv,
                           group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, s // block),
-            in_specs=[head, leaf, leaf],
-            out_specs=head,
+            in_specs=[head, new, new, leaf, leaf],
+            out_specs=[head, row, row],
             scratch_shapes=[
                 pltpu.VMEM((pieces * h, d), jnp.bfloat16),
                 pltpu.VMEM((group, _LANE), jnp.float32),
                 pltpu.VMEM((group, _LANE), jnp.float32),
                 pltpu.VMEM((h, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+                   jax.ShapeDtypeStruct(keys.shape, keys.dtype),
+                   jax.ShapeDtypeStruct(values.shape, values.dtype)],
+        input_output_aliases={5: 1, 6: 2},          # the leaves, in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
         name="hvd.decode_attend",
-    )(lengths, rows, keys, values)
-    return out.reshape(b, group, kv, d).swapaxes(1, 2).reshape(b, 1, h, d)
+    )(lengths, at, rows, new_k.reshape(b, kv, d), new_v.reshape(b, kv, d),
+      keys, values)
+    return out.reshape(b, group, kv, d).swapaxes(1, 2).reshape(b, 1, h, d), \
+        keys, values
 
 
-def _lanes_kernel(len_ref, q_ref, sink_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, scale: float, block: int,
-                  kv: int, group: int):
+def _lanes_kernel(len_ref, at_ref, q_ref, sink_ref, nk_ref, nv_ref, k_ref,
+                  v_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref, *,
+                  scale: float, block: int, tile: int, kv: int, group: int):
     """One slot, one block of positions of leaves with the heads in the
     lanes: ``k_ref`` [block, KV * D], ``v_ref`` [block, KV * Dv].  A
     query row holds its head's query at that head's lanes and zeros
@@ -452,11 +519,17 @@ def _lanes_kernel(len_ref, q_ref, sink_ref, k_ref, v_ref, o_ref,
     lane; the softmax runs there; each key-value head's weights meet
     its own lanes of the values.  The running maximum starts at the
     head's sink and the sum at 1 where there is one (its column has no
-    value), at ``NEG_INF`` and 0 where not."""
+    value), at ``NEG_INF`` and 0 where not.  The step's own row
+    (``nk_ref``, ``nv_ref``: [1, width]) belongs at position
+    ``at_ref[slot]``: the block that holds it, always a live one, takes
+    it in VMEM before the scores (the leaf in HBM has it only once the
+    call is over), and its aligned ``tile`` of positions goes out
+    through ``ko_ref`` / ``vo_ref`` with the row in it."""
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
     length = len_ref[pl.program_id(0)]
+    row = at_ref[pl.program_id(0)] - j * block     # in this block, if 0..
     h = kv * group
     dv = v_ref.shape[-1] // kv
 
@@ -465,6 +538,18 @@ def _lanes_kernel(len_ref, q_ref, sink_ref, k_ref, v_ref, o_ref,
         m_ref[...] = sink_ref[...]
         l_ref[...] = jnp.where(sink_ref[...] > NEG_INF / 2, 1.0, 0.0)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((row >= 0) & (row < block))
+    def _take():
+        # The step's row, into its tile of the block in VMEM (an input
+        # block's buffer is the kernel's to write), and the tile out.
+        rows = pl.ds(pl.multiple_of(row // tile * tile, tile), tile)
+        new = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) \
+            == row % tile
+        for leaf, fresh, out in ((k_ref, nk_ref, ko_ref),
+                                 (v_ref, nv_ref, vo_ref)):
+            leaf[0, rows] = jnp.where(new, fresh[0], leaf[0, rows])
+            out[0] = leaf[0, rows]
 
     @pl.when(j * block < length)
     def _accumulate():
@@ -499,10 +584,20 @@ def _lanes_kernel(len_ref, q_ref, sink_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
 
+# What the lanes kernel writes a slot and a leaf: the smallest aligned
+# run of positions that holds the step's row, a tile of bfloat16 rows
+# (Pallas writes an output block back whole, so it is filled from the
+# block in VMEM: 49 KB of MiMo's 1,536 lanes, where the block is 786).
+# What the write costs on the chip is not these bytes but the five more
+# blocked operands' bookkeeping, 0.07 to 0.12 us a grid step, dead
+# steps too (PERF.md, PR 39).
+_WRITE_TILE = 16
+
+
 @functools.partial(jax.jit,
                    static_argnames=("scale", "block", "interpret", "name"))
-def _decode_attend_lanes(q, keys, values, lengths, sink, scale, *,
-                         block: int, interpret: bool,
+def _decode_attend_lanes(q, keys, values, new_k, new_v, lengths, at, sink,
+                         scale, *, block: int, interpret: bool,
                          name: str = "hvd.decode_attend"):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -511,6 +606,7 @@ def _decode_attend_lanes(q, keys, values, lengths, sink, scale, *,
     _, s, width = keys.shape
     kv = width // d
     group, dv = h // kv, values.shape[-1] // kv
+    tile = min(_WRITE_TILE, block)
     # Each query at its own head's lanes of a row of KV * D, in exact
     # bfloat16 pieces, the pieces one under the other.
     own = jnp.arange(kv)[:, None, None, None] == jnp.arange(kv)[None, None, :,
@@ -520,56 +616,75 @@ def _decode_attend_lanes(q, keys, values, lengths, sink, scale, *,
         .reshape(b, h, width) for piece in _pieces(q)], axis=1)
     start = jnp.full((h,), NEG_INF, jnp.float32) if sink is None \
         else sink.astype(jnp.float32)
+    a_slot = lambda n, w: pl.BlockSpec(                      # noqa: E731
+        (1, n, w), lambda slot, j, *_: (slot, 0, 0))
     leaf = lambda w: pl.BlockSpec(                           # noqa: E731
-        (1, block, w), lambda slot, j, lens: _live_block(
-            slot, j, lens, block=block)[:3])
-    out = pl.pallas_call(
-        functools.partial(_lanes_kernel, scale=scale, block=block, kv=kv,
-                          group=group),
+        (1, block, w), lambda *at: _live_block(*at, block=block)[:3])
+    written = lambda w: pl.BlockSpec(                        # noqa: E731
+        (1, tile, w), lambda slot, j, lens, at: (slot, at[slot] // tile, 0))
+    out, keys, values = pl.pallas_call(
+        functools.partial(_lanes_kernel, scale=scale, block=block, tile=tile,
+                          kv=kv, group=group),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, s // block),
-            in_specs=[pl.BlockSpec((1, rows.shape[1], width),
-                                   lambda slot, j, lens: (slot, 0, 0)),
-                      pl.BlockSpec((h, _LANE), lambda slot, j, lens: (0, 0)),
+            in_specs=[a_slot(rows.shape[1], width),
+                      pl.BlockSpec((h, _LANE), lambda slot, j, *_: (0, 0)),
+                      a_slot(1, width), a_slot(1, values.shape[-1]),
                       leaf(width), leaf(values.shape[-1])],
-            out_specs=pl.BlockSpec((1, h, dv),
-                                   lambda slot, j, lens: (slot, 0, 0)),
+            out_specs=[a_slot(h, dv), written(width),
+                       written(values.shape[-1])],
             scratch_shapes=[pltpu.VMEM((h, _LANE), jnp.float32),
                             pltpu.VMEM((h, _LANE), jnp.float32),
                             pltpu.VMEM((h, dv), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(keys.shape, keys.dtype),
+                   jax.ShapeDtypeStruct(values.shape, values.dtype)],
+        input_output_aliases={6: 1, 7: 2},          # the leaves, in place
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
         name=name,
-    )(lengths, rows, jnp.broadcast_to(start[:, None], (h, _LANE)), keys,
-      values)
-    return out.reshape(b, 1, h, dv)
+    )(lengths, at, rows, jnp.broadcast_to(start[:, None], (h, _LANE)),
+      new_k, new_v, keys, values)
+    return out.reshape(b, 1, h, dv), keys, values
 
 
 def decode_attend(q: jax.Array, keys: jax.Array, values: jax.Array,
-                  lengths: jax.Array, scale: float, sink=None, *,
+                  new_k: jax.Array, new_v: jax.Array, lengths: jax.Array,
+                  at: jax.Array, scale: float, sink=None, *,
                   interpret: bool = False,
-                  scope: str = "hvd.decode_attend") -> jax.Array:
-    """One decode step's attention: ``q`` [B, 1, H, D] over the first
-    ``lengths`` [B] positions of ``keys`` / ``values`` (``[B, S, KV,
-    D]`` or, ``lanes_layout``, ``[B, S, KV * D]``), float32 [B, 1, H,
-    Dv].  The kernel on a TPU or interpreted, where ``kernel_block``
-    finds it a block; the plain form elsewhere (and for heads in the
-    sublanes with a ``sink``, which that kernel does not take).  Either
-    way under ``scope``, which is also the lanes kernel's name: a window
-    layer's ring is ``hvd.window_attend``."""
-    block = kernel_block(keys.shape, keys.dtype, interpret, values.shape)
-    lengths = jnp.clip(lengths.astype(jnp.int32), 1, keys.shape[1])
-    if not block or (sink is not None and keys.ndim == 4):
+                  scope: str = "hvd.decode_attend") -> tuple:
+    """One decode step over the leaves ``keys`` / ``values`` (``[B, S,
+    KV, D]`` or, ``lanes_layout``, ``[B, S, KV * D]``): the step's row
+    ``new_k`` / ``new_v`` (``[B, 1, ...]``, laid and typed as the
+    leaves) is written at position ``at`` [B] (clamped to the last, as
+    ``dynamic_update_slice`` clamps), and ``q`` [B, 1, H, D] attends
+    over the first ``lengths`` [B] positions, the new row among them
+    (``at < lengths``: a cache's ``lengths - 1``, anywhere in a ring
+    past its first lap) -> ``(float32 [B, 1, H, Dv], keys, values)``.
+    Where ``kernel_writes`` (a TPU or interpreted, leaves the kernel
+    finds a block for), one kernel does both and a donated leaf is
+    updated in place; elsewhere ``write_rows``, then the plain form.
+    Either way under ``scope``, which is also the lanes kernel's name: a
+    window layer's ring is ``hvd.window_attend``."""
+    rows = keys.shape[1]
+    lengths = jnp.clip(lengths.astype(jnp.int32), 1, rows)
+    if not kernel_writes(keys.shape, keys.dtype, values.shape,
+                         sink is not None, interpret):
+        keys, values = write_rows(keys, new_k, at), \
+            write_rows(values, new_v, at)
         return attend_plain(q, keys, values, lengths[:, None] - 1, scale,
-                            sink, scope=scope)
+                            sink, scope=scope), keys, values
+    block = kernel_block(keys.shape, keys.dtype, interpret, values.shape)
+    at = jnp.clip(at.astype(jnp.int32), 0, rows - 1)
     with jax.named_scope(scope):
         if keys.ndim == 3:
-            return _decode_attend_lanes(q, keys, values, lengths, sink,
-                                        scale, block=block,
-                                        interpret=interpret, name=scope)
-        return _decode_attend_pallas(q, keys, values, lengths, scale,
-                                     block=block, interpret=interpret)
+            return _decode_attend_lanes(q, keys, values, new_k, new_v,
+                                        lengths, at, sink, scale,
+                                        block=block, interpret=interpret,
+                                        name=scope)
+        return _decode_attend_pallas(q, keys, values, new_k, new_v, lengths,
+                                     at, scale, block=block,
+                                     interpret=interpret)

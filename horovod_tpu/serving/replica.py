@@ -249,6 +249,12 @@ class ReplicaExecutor:
                       # the compiled path reads for them (a layer).
                       "attend_live_positions": 0,
                       "attend_read_positions": 0,
+                      # The dense cache's attention layers, and those of
+                      # them whose decode kernel writes the step's key
+                      # and value row itself (ops/decode_attention.py:
+                      # kernel_writes); the others write it in a serial
+                      # loop over the slots.
+                      "attend_layers": 0, "attend_write_fused_layers": 0,
                       # Decode programs enqueued, and those of them
                       # enqueued while the one before was still unfetched
                       # (the device had its next program when one ended).

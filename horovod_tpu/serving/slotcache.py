@@ -222,8 +222,11 @@ class DenseSlotCache(_SlotCache):
     def fresh(self, params) -> None:
         super().fresh(params)
         # The span and the block the decode program's attention reads
-        # each layer's key and value leaves in, by kind of layer.
+        # each layer's key and value leaves in, by kind of layer; and
+        # the layers whose kernel writes the step's row itself (a
+        # layer's sink lies beside its leaves, in the parameters).
         kinds: dict = {}
+        fused = 0
         leaves = {tuple(k.key for k in path): leaf for path, leaf in
                   jax.tree_util.tree_flatten_with_path(self.tree)[0]}
         for path, keys in leaves.items():
@@ -233,8 +236,15 @@ class DenseSlotCache(_SlotCache):
                 kind = (keys.shape[1], decode_attention.kernel_block(
                     keys.shape, keys.dtype, values=values.shape))
                 kinds[kind] = kinds.get(kind, 0) + 1
+                layer = params
+                for key in path[:-1]:
+                    layer = layer.get(key, {})
+                fused += decode_attention.kernel_writes(
+                    keys.shape, keys.dtype, values.shape, "sink" in layer)
         self._attend_kinds = [(layers, span, block)
                               for (span, block), layers in kinds.items()]
+        self.stats["attend_layers"] = sum(kinds.values())
+        self.stats["attend_write_fused_layers"] = fused
 
     def _warm_prefill(self, params, toks: list) -> None:
         self.admit(params, 0, toks, 1)   # the insert compiles once

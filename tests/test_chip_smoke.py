@@ -252,6 +252,14 @@ def _placed(tree, sharding):
                                           sharding=sharding), tree)
 
 
+def _loops_over(text: str, *leaves: str) -> int:
+    """``while`` loops of a compiled program that carry one of ``leaves``
+    (a shape as HLO prints it): a serial write into such a leaf, a slot
+    an iteration."""
+    return sum(" while(" in line and any(leaf in line for leaf in leaves)
+               for line in text.splitlines())
+
+
 def _nbytes(tree) -> int:
     return sum(math.prod(x.shape) * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(tree))
@@ -262,8 +270,10 @@ def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
     """``lm7b_serve_chat_sat``'s decode program (deepseek-llm-7b's widths,
     4 layers, 16 slots of 4,096 positions, bfloat16) compiled for the
     v5e: one hvd.decode_attend custom call a layer, taking the cache's
-    leaves as they lie, so the program still updates the whole cache in
-    place and holds no copy of a leaf among its temporaries."""
+    leaves as they lie and returning them with the step's row written
+    (ISSUE 39: no ``while`` loop over the slots is left in the program),
+    so the program still updates the whole cache in place and holds no
+    copy of a leaf among its temporaries."""
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.ops import decode_attention as da
     from horovod_tpu.serving import ServeConfig, slotcache
@@ -292,11 +302,13 @@ def test_the_7b_decode_program_attends_through_the_kernel_on_v5e(
         params, tree, *placed((jnp.zeros(slots, jnp.int32),
                                jnp.zeros(slots, jnp.int32),
                                jnp.zeros(slots, bool)))).compile()
-    calls = [line for line in compiled.as_text().splitlines()
-             if MOSAIC in line]
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if MOSAIC in line]
     assert len(calls) == layers
     assert all("hvd.decode_attend" in call for call in calls)
-    assert all(call.count("bf16[16,4096,32,128]") == 2 for call in calls)
+    # Two operands, and two results that alias them.
+    assert all(call.count("bf16[16,4096,32,128]") == 4 for call in calls)
+    assert " while(" not in text
     memory = compiled.memory_analysis()
     # (each layer's 64 bytes of write cursors are a 512-byte tile there)
     assert memory.alias_size_in_bytes == cache_bytes + layers * (512 - 64)
@@ -353,11 +365,14 @@ def test_the_mimo_programs_fit_a_v5e_and_attend_through_the_kernel(
         params, tree, *placed((jnp.zeros(slots + 4, jnp.int32),
                                jnp.zeros(slots, jnp.int32),
                                jnp.zeros(slots, bool)))).compile()
-    calls = [line for line in compiled.as_text().splitlines()
-             if MOSAIC in line]
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if MOSAIC in line]
     attend = [call for call in calls if "hvd.decode_attend" in call]
     rings = [call for call in calls if "hvd.window_attend" in call]
     assert len(attend) == 2 and len(rings) == 5 and len(calls) == 2 + 5 + 6
+    # The kernels write the step's row: none of the fourteen leaves is
+    # written in a loop over the slots (ISSUE 39).
+    assert not _loops_over(text, "bf16[64,12288,", "bf16[64,128,")
     assert sum("hvd.moe_experts" in call for call in calls) == 6
     assert all("bf16[64,12288,768]" in call and "bf16[64,12288,512]" in call
                for call in attend)
@@ -421,10 +436,15 @@ def test_the_solar_decode_program_attends_through_the_kernel_on_v5e(
         params, tree, *placed((jnp.zeros(slots + 4, jnp.int32),
                                jnp.zeros(slots, jnp.int32),
                                jnp.zeros(slots, bool)))).compile()
-    calls = [line for line in compiled.as_text().splitlines()
-             if MOSAIC in line]
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if MOSAIC in line]
     attend, = [call for call in calls if "hvd.decode_attend" in call]
-    assert attend.count("bf16[80,4608,1024]") == 2
+    # Two operands, and two results that alias them: the kernel writes
+    # the step's row, and the loops over the slots that are left write
+    # the three KDA layers' convolution windows (ISSUE 39).
+    assert attend.count("bf16[80,4608,1024]") == 4
+    assert not _loops_over(text, "bf16[80,4608,")
+    assert _loops_over(text, "bf16[80,4,24576]") == 3
     assert sum("hvd.kda_update" in call for call in calls) == 3
     assert sum("hvd.moe_experts" in call for call in calls) == 4
     assert len(calls) == 1 + 3 + 4

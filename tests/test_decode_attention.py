@@ -10,7 +10,12 @@ that reads each slot's keys and values up to its own live length.
 - the lanes kernel at 8 key-value heads (ISSUE 37): Solar's cache (8
   heads of 128 under 64 query heads) and MiMo's ring (keys 192, values
   128, a sink, one block a slot), the block the entry point really takes
-  against ``read_positions``, and the custom call's two names.
+  against ``read_positions``, and the custom call's two names;
+- the step's own row (ISSUE 39): the kernels take it beside the leaves,
+  attend as if it were written and write it in place, to the bit what
+  ``write_rows`` writes, on caches and on rings past their first lap,
+  at a cache's last row, behind a float32 query and a sink; and which
+  leaves ``kernel_writes`` says so for.
 """
 from __future__ import annotations
 
@@ -32,18 +37,44 @@ LENGTHS = {"one_edge_past_full": (1, BLOCK, BLOCK + 1, S),
            "all_full": (S, S)}
 
 
+def _bits(x) -> np.ndarray:
+    """A bfloat16 array's bits: NaN equals NaN there."""
+    return np.asarray(x).view(np.uint16)
+
+
+def _step(leaves, lengths):
+    """A decode step whose row is each slot's last live one: ``(new_k,
+    new_v, lengths, at)`` as the kernels take them, the rows taken out
+    of the clean ``leaves``."""
+    at = jnp.asarray(lengths, jnp.int32) - 1
+    return (*(jnp.take_along_axis(
+        x, at.reshape(-1, *(1,) * (x.ndim - 1)), axis=1) for x in leaves),
+        at + 1, at)
+
+
 def _operands(heads, kv, lengths, qdtype=jnp.bfloat16):
-    """Random q, leaves and lengths; dead positions of the leaves NaN
-    (the clean leaves last)."""
+    """Random q, leaves, the step (``_step``) and the clean leaves: the
+    leaves handed to the kernel hold NaN at every dead position **and at
+    the step's own row**, which only the step carries."""
     rng = np.random.default_rng(33)
     b = len(lengths)
     q = jnp.asarray(rng.standard_normal((b, 1, heads, D)), qdtype)
     keys, values = (jnp.asarray(rng.standard_normal((b, S, kv, D)),
                                 jnp.bfloat16) for _ in range(2))
-    lengths = jnp.asarray(lengths, jnp.int32)
-    dead = jnp.arange(S)[None, :, None, None] >= lengths[:, None, None, None]
+    step = _step((keys, values), lengths)
+    dead = jnp.arange(S)[None, :, None, None] >= step[3][:, None, None, None]
     return q, jnp.where(dead, jnp.nan, keys), \
-        jnp.where(dead, jnp.nan, values), lengths, (keys, values)
+        jnp.where(dead, jnp.nan, values), step, (keys, values)
+
+
+def _written(got, leaves, step):
+    """``got``: what a kernel returned.  Its leaves are the handed ones
+    with the step's row written, to the bit; its output comes back."""
+    out, *new = got
+    for mine, leaf, row in zip(new, leaves, step[:2]):
+        np.testing.assert_array_equal(
+            _bits(mine), _bits(da.write_rows(leaf, row, step[3])))
+    return out
 
 
 @pytest.mark.parametrize("lengths", sorted(LENGTHS))
@@ -53,9 +84,11 @@ def test_the_kernel_is_the_plain_form_up_to_each_slots_length(shape,
     """Interpreted, in blocks of 16: every output is ``attend``'s to
     float32 rounding, and the NaN past each length reaches none."""
     heads, kv = SHAPES[shape]
-    q, keys, values, lens, clean = _operands(heads, kv, LENGTHS[lengths])
-    got = da._decode_attend_pallas(q, keys, values, lens, 0.11,
-                                   block=BLOCK, interpret=True)
+    q, keys, values, step, clean = _operands(heads, kv, LENGTHS[lengths])
+    lens = step[2]
+    got = _written(da._decode_attend_pallas(
+        q, keys, values, *step, 0.11, block=BLOCK, interpret=True),
+        (keys, values), step)
     want = kvcache.attend(q, *clean, lens[:, None] - 1, 0.11)
     assert got.shape == want.shape == (len(lens), 1, heads, D)
     assert got.dtype == jnp.float32
@@ -65,10 +98,11 @@ def test_the_kernel_is_the_plain_form_up_to_each_slots_length(shape,
 
 
 def test_a_float32_query_goes_through_in_three_exact_pieces():
-    q, keys, values, lens, clean = _operands(32, 32, (5, S), jnp.float32)
-    got = da._decode_attend_pallas(q, keys, values, lens, 0.09,
-                                   block=BLOCK, interpret=True)
-    want = kvcache.attend(q, *clean, lens[:, None] - 1, 0.09)
+    q, keys, values, step, clean = _operands(32, 32, (5, S), jnp.float32)
+    got = _written(da._decode_attend_pallas(
+        q, keys, values, *step, 0.09, block=BLOCK, interpret=True),
+        (keys, values), step)
+    want = kvcache.attend(q, *clean, step[3][:, None], 0.09)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-6, rtol=3e-6)
     x = jnp.asarray(np.random.default_rng(1).standard_normal(64),
@@ -88,7 +122,8 @@ def test_the_entry_point_takes_the_kernel_where_it_finds_a_block(
     idle slot's cursor keeps counting) are clipped to it."""
     heads, kv = SHAPES["heads32_group1"]
     monkeypatch.setattr(da, "_BLOCK_BYTES", BLOCK * kv * D * 2)
-    q, keys, values, lens, clean = _operands(heads, kv, LENGTHS[lengths])
+    q, keys, values, step, clean = _operands(heads, kv, LENGTHS[lengths])
+    lens = step[2]
     assert da.kernel_block(keys.shape, keys.dtype, interpret=True) == BLOCK
     assert da.kernel_block(keys.shape, keys.dtype) == 0       # a CPU
     want = kvcache.attend(q, *clean, lens[:, None] - 1, 0.11)
@@ -96,15 +131,23 @@ def test_the_entry_point_takes_the_kernel_where_it_finds_a_block(
     real = da._decode_attend_pallas
     monkeypatch.setattr(da, "_decode_attend_pallas",
                         lambda *a, **kw: calls.append(kw) or real(*a, **kw))
-    got = da.decode_attend(q, keys, values, lens, 0.11, interpret=True)
+    got = _written(da.decode_attend(q, keys, values, *step, 0.11,
+                                    interpret=True), (keys, values), step)
     assert calls == [{"block": BLOCK, "interpret": True}]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-6, rtol=3e-6)
-    plain = da.decode_attend(q, *clean, lens, 0.11)
+    # (the plain form reads the dead positions too: no NaN there, and
+    # zeros where the step's row goes)
+    blank = [da.write_rows(x, jnp.zeros_like(row), step[3])
+             for x, row in zip(clean, step)]
+    plain = _written(da.decode_attend(q, *blank, *step, 0.11), blank, step)
     assert len(calls) == 1
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(want))
-    over = da.decode_attend(q, *clean, jnp.full_like(lens, S + 7), 0.11,
-                            interpret=True)
+    # An idle slot's cursor keeps counting: the row is the cache's last.
+    idle = (*(x[:, -1:] for x in clean), jnp.full_like(lens, S + 8),
+            jnp.full_like(lens, S + 7))
+    over = _written(da.decode_attend(q, *clean, *idle, 0.11,
+                                     interpret=True), clean, idle)
     full = kvcache.attend(q, *clean, jnp.full((len(lens), 1), S - 1), 0.11)
     np.testing.assert_allclose(np.asarray(over), np.asarray(full),
                                atol=3e-6, rtol=3e-6)
@@ -164,11 +207,14 @@ def test_a_narrow_head_keeps_the_plain_form():
     q = jnp.asarray(rng.standard_normal((3, 1, 32, 64)), jnp.bfloat16)
     keys, values = (jnp.asarray(rng.standard_normal((3, 48, 8, 64)),
                                 jnp.bfloat16) for _ in range(2))
-    lens = jnp.asarray([1, 17, 48], jnp.int32)
-    want = kvcache.attend(q, keys, values, lens[:, None] - 1, 1 / 64)
+    step = _step((keys, values), [1, 17, 48])
+    want = kvcache.attend(q, keys, values, step[3][:, None], 1 / 64)
     for interpret in (False, True):
-        got = da.decode_attend(q, keys, values, lens, 1 / 64,
-                               interpret=interpret)
+        assert not da.kernel_writes(keys.shape, keys.dtype,
+                                    interpret=interpret)
+        got = _written(da.decode_attend(q, keys, values, *step, 1 / 64,
+                                        interpret=interpret),
+                       (keys, values), step)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -176,11 +222,13 @@ def test_the_kernel_carries_its_name_and_takes_the_leaves_as_they_lie():
     """One pallas_call named hvd.decode_attend whose key and value
     operands are the leaves themselves, four-dimensional, as the program
     was handed them (merged to [B, S, KV * D] they would be copied on
-    the device every step); the lengths are its scalar prefetch."""
+    the device every step), and come back as its second and third
+    results, aliased: the step's row is written in place; the lengths
+    and the rows' positions are its scalar prefetch."""
     heads, kv = SHAPES["heads32_group1"]
-    q, keys, values, lens, _ = _operands(heads, kv, (1, S))
+    q, keys, values, step, _ = _operands(heads, kv, (1, S))
     jaxpr = jax.make_jaxpr(lambda *a: da.decode_attend(
-        *a, 0.1, interpret=True))(q, keys, values, lens)
+        *a, 0.1, interpret=True))(q, keys, values, *step)
     # (the kernel's wrapper is jitted: one trace for all of a model's layers)
     inner, = [eqn for eqn in jaxpr.jaxpr.eqns if eqn.primitive.name == "jit"
               and eqn.params["name"] == "_decode_attend_pallas"]
@@ -188,12 +236,16 @@ def test_the_kernel_carries_its_name_and_takes_the_leaves_as_they_lie():
     inner = inner.params["jaxpr"].jaxpr
     call, = [eqn for eqn in inner.eqns if eqn.primitive.name == "pallas_call"]
     assert call.params["name"] == "hvd.decode_attend"
-    assert call.params["grid_mapping"].num_index_operands == 1
-    lengths, _, k_in, v_in = call.invars
-    assert lengths.aval.shape == (2,) and lengths.aval.dtype == jnp.int32
+    assert call.params["grid_mapping"].num_index_operands == 2
+    lengths, at, _, new_k, new_v, k_in, v_in = call.invars
+    assert lengths.aval.shape == at.aval.shape == (2,)
+    assert lengths.aval.dtype == at.aval.dtype == jnp.int32
+    assert new_k.aval.shape == new_v.aval.shape == (2, kv, D)
     assert k_in is inner.invars[1] and v_in is inner.invars[2]
+    assert call.params["input_output_aliases"] == ((5, 1), (6, 2))
+    assert [v.aval.shape for v in call.outvars[1:]] == [keys.shape] * 2
     assert "pallas_call" not in str(jax.make_jaxpr(
-        lambda *a: da.decode_attend(*a, 0.1))(q, keys, values, lens))
+        lambda *a: da.decode_attend(*a, 0.1))(q, keys, values, *step))
     assert kvcache.attend is da.attend_plain
 
 
@@ -209,8 +261,9 @@ KV8_LENGTHS = {"solar": (1, 7, 16, 41, 64), "ring": (1, 7, 15, 16, 16)}
 
 
 def _lanes_operands(shape, qdtype=jnp.bfloat16):
-    """q, the leaves ``[B, S, KV * D]`` with NaN past each length, the
-    lengths, the sink, and the clean leaves ``[B, S, KV, D]``."""
+    """q, the leaves ``[B, S, KV * D]`` with NaN past each length **and
+    at the step's own row**, the step (``_step``, its rows merged as the
+    leaves are), the sink, and the clean leaves ``[B, S, KV, D]``."""
     spec = KV8[shape]
     lens = np.asarray(KV8_LENGTHS[shape], np.int32)
     rng = np.random.default_rng(37)
@@ -223,10 +276,12 @@ def _lanes_operands(shape, qdtype=jnp.bfloat16):
                          jnp.bfloat16)
     sink = jnp.asarray(rng.standard_normal(spec["heads"]), jnp.float32) \
         if spec["sink"] else None
-    dead = np.arange(s)[None, :, None, None] >= lens[:, None, None, None]
+    new_k, new_v, *where = _step((keys, values), lens)
+    dead = np.arange(s)[None, :, None, None] >= lens[:, None, None, None] - 1
     merged = [jnp.where(dead, jnp.nan, x).reshape(b, s, -1)
               for x in (keys, values)]
-    return q, merged, jnp.asarray(lens), sink, (keys, values)
+    step = (new_k.reshape(b, 1, -1), new_v.reshape(b, 1, -1), *where)
+    return q, merged, step, sink, (keys, values)
 
 
 @pytest.mark.parametrize("qdtype", [jnp.bfloat16, jnp.float32],
@@ -236,9 +291,11 @@ def test_the_lanes_kernel_serves_eight_heads(shape, qdtype):
     """Interpreted, against ``attend_plain`` over the same heads: every
     output to float32 rounding, the NaN past each length in none."""
     spec = KV8[shape]
-    q, merged, lens, sink, clean = _lanes_operands(shape, qdtype)
-    got = da._decode_attend_lanes(q, *merged, lens, sink, 0.09,
-                                  block=spec["block"], interpret=True)
+    q, merged, step, sink, clean = _lanes_operands(shape, qdtype)
+    lens = step[2]
+    got = _written(da._decode_attend_lanes(
+        q, *merged, *step, sink, 0.09, block=spec["block"], interpret=True),
+        merged, step)
     want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.09, sink)
     assert got.shape == want.shape == (len(lens), 1, 64, spec["dv"])
     assert got.dtype == jnp.float32
@@ -255,7 +312,8 @@ def test_read_positions_is_the_block_the_entry_point_takes(shape,
     walking the kernel's index map in that block visits the positions
     ``read_positions`` counts."""
     spec = KV8[shape]
-    q, merged, lens, sink, clean = _lanes_operands(shape)
+    q, merged, step, sink, clean = _lanes_operands(shape)
+    lens = step[2]
     assert da.lanes_layout(spec["kv"], spec["dk"], spec["dv"], jnp.bfloat16)
     monkeypatch.setattr(da, "_BLOCK_BYTES",
                         spec["block"] * spec["kv"] * spec["dk"] * 2)
@@ -268,8 +326,9 @@ def test_read_positions_is_the_block_the_entry_point_takes(shape,
     real = da._decode_attend_lanes
     monkeypatch.setattr(da, "_decode_attend_lanes",
                         lambda *a, **kw: calls.append(kw) or real(*a, **kw))
-    got = da.decode_attend(q, *merged, lens, 0.09, sink, interpret=True,
-                           scope=spec["scope"])
+    got = _written(da.decode_attend(q, *merged, *step, 0.09, sink,
+                                    interpret=True, scope=spec["scope"]),
+                   merged, step)
     assert calls == [{"block": block, "interpret": True,
                       "name": spec["scope"]}]
     want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.09, sink)
@@ -299,17 +358,135 @@ def test_a_cache_and_a_ring_carry_their_own_kernel_names(shape):
     """One pallas_call: ``hvd.decode_attend`` over a cache,
     ``hvd.window_attend`` over a ring (a device trace selects an
     operation by its name alone, and the two are counted apart); the
-    leaves go in as they lie, the lengths as the scalar prefetch."""
+    leaves go in as they lie and come back aliased, the lengths and the
+    rows' positions as the scalar prefetch."""
     spec = KV8[shape]
-    q, merged, lens, sink, _ = _lanes_operands(shape)
-    jaxpr = jax.make_jaxpr(lambda q, k, v, n: da.decode_attend(
-        q, k, v, n, 0.1, sink, interpret=True, scope=spec["scope"]))(
-        q, *merged, lens)
+    q, merged, step, sink, _ = _lanes_operands(shape)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, *step: da.decode_attend(
+        q, k, v, *step, 0.1, sink, interpret=True, scope=spec["scope"]))(
+        q, *merged, *step)
     call, = pallas_calls(jaxpr.jaxpr)
     other = {"hvd.decode_attend", "hvd.window_attend"} - {spec["scope"]}
     named = str(call.params["name"]) \
         + str(call.params.get("name_and_src_info"))
     assert spec["scope"] in named and other.pop() not in named
-    assert call.params["grid_mapping"].num_index_operands == 1
+    assert call.params["grid_mapping"].num_index_operands == 2
     assert [v.aval.shape for v in call.invars[-2:]] \
+        == [v.aval.shape for v in call.outvars[1:]] \
         == [x.shape for x in merged]
+    assert call.params["input_output_aliases"] == ((6, 1), (7, 2))
+
+
+# --- the step's own row, written by the kernel (ISSUE 39) -------------------
+# The shapes above and a ring as long as MiMo's: heads, key-value heads,
+# key and value widths, positions, block; ``window``: the leaves are a
+# ring; ``lanes``: the heads lie side by side.
+WRITES = {
+    **{name: dict(heads=heads, kv=kv, dk=D, dv=D, s=S, block=BLOCK,
+                  sink=False, window=0, lanes=False)
+       for name, (heads, kv) in SHAPES.items()},
+    "solar": {**KV8["solar"], "window": 0, "lanes": True},
+    "ring": {**KV8["ring"], "window": 16, "lanes": True},
+    "ring128": dict(heads=64, kv=8, dk=192, dv=128, s=128, block=128,
+                    sink=True, window=128, lanes=True,
+                    scope="hvd.window_attend"),
+}
+# Write cursors a slot: the file's lengths less one; a slot at its last
+# row and two past it (an idle slot's cursor keeps counting: the clamp).
+CURSORS = {**{name: tuple(n - 1 for n in lens)
+              for name, lens in LENGTHS.items()},
+           "last_row_and_past": (S - 1, S, S + 7)}
+
+
+@pytest.mark.parametrize("qdtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16_q", "f32_q"])
+@pytest.mark.parametrize("cursors", sorted(CURSORS))
+@pytest.mark.parametrize("shape", sorted(WRITES))
+def test_the_kernel_writes_the_steps_row_and_attends_over_it(
+        shape, cursors, qdtype, monkeypatch):
+    """Interpreted.  The leaves hold NaN where the step's row belongs
+    and past every live position; the kernel is handed the row and
+    where it goes.  Its output is that of ``write_rows``, then
+    ``attend_plain``; the leaves it returns are ``write_rows``' to the
+    bit, every other row as it was.  A cache takes cursor ``i`` at row
+    ``min(i, S - 1)`` and reads ``i + 1`` positions; a ring scales the
+    cursors to its span and sends slot ``n`` round ``n`` more laps: row
+    ``i % window`` of ``min(i + 1, window)`` live ones, the first lap
+    and past it side by side."""
+    spec = WRITES[shape]
+    s, kv, window = spec["s"], spec["kv"], spec["window"]
+    idx = np.asarray(CURSORS[cursors], np.int32)
+    if window:
+        idx = idx * s // S + window * np.arange(len(idx), dtype=np.int32)
+    b = len(idx)
+    at = idx % window if window else idx
+    lengths = np.minimum(idx + 1, window or s)
+    rng = np.random.default_rng(39)
+    q = jnp.asarray(rng.standard_normal((b, 1, spec["heads"], spec["dk"])),
+                    qdtype)
+    sink = jnp.asarray(rng.standard_normal(spec["heads"]), jnp.float32) \
+        if spec["sink"] else None
+    dead = np.arange(s)[None, :] >= lengths[:, None]
+    dead |= np.arange(s)[None, :] == np.minimum(at, s - 1)[:, None]
+    leaves, rows = [], []
+    for wide in (spec["dk"], spec["dv"]):
+        leaf = jnp.where(dead[:, :, None, None], jnp.nan, jnp.asarray(
+            rng.standard_normal((b, s, kv, wide)), jnp.bfloat16))
+        row = jnp.asarray(rng.standard_normal((b, 1, kv, wide)),
+                          jnp.bfloat16)
+        if spec["lanes"]:
+            leaf, row = leaf.reshape(b, s, -1), row.reshape(b, 1, -1)
+        leaves.append(leaf)
+        rows.append(row)
+    monkeypatch.setattr(da, "_BLOCK_BYTES",
+                        spec["block"] * kv * spec["dk"] * 2)
+    assert da.kernel_writes(leaves[0].shape, jnp.bfloat16, leaves[1].shape,
+                            spec["sink"], interpret=True)
+    got, *new = da.decode_attend(
+        q, *leaves, *rows, jnp.asarray(idx + 1 if not window else lengths),
+        jnp.asarray(at), 0.1, sink, interpret=True,
+        scope=spec.get("scope", "hvd.decode_attend"))
+    written = [da.write_rows(leaf, row, jnp.asarray(at))
+               for leaf, row in zip(leaves, rows)]
+    want = da.attend_plain(
+        q, *(jnp.nan_to_num(x) for x in written),   # it reads the dead too
+        jnp.asarray(lengths)[:, None] - 1, 0.1, sink)
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+    for mine, leaf, theirs in zip(new, leaves, written):
+        np.testing.assert_array_equal(_bits(mine), _bits(theirs))
+        elsewhere = ~(np.arange(s)[None, :]
+                      == np.minimum(at, s - 1)[:, None])
+        np.testing.assert_array_equal(_bits(mine)[elsewhere],
+                                      _bits(leaf)[elsewhere])
+        assert not np.isnan(np.asarray(mine, np.float32)[~elsewhere]).any()
+
+
+@pytest.mark.parametrize("keys, values, dtype, sink, on_tpu, want", [
+    ((16, 4096, 32, 128), None, jnp.bfloat16, False, True, True),
+    ((16, 4096, 32, 128), None, jnp.bfloat16, False, False, False),
+    ((16, 4096, 32, 128), None, jnp.bfloat16, True, True, False),
+    ((16, 4096, 32, 128), None, jnp.float32, False, True, False),
+    ((32, 2560, 8, 64), None, jnp.bfloat16, False, True, False),
+    ((80, 4608, 8, 128), None, jnp.bfloat16, False, True, False),
+    ((80, 4608, 1024), None, jnp.bfloat16, False, True, True),
+    ((64, 12288, 768), (64, 12288, 512), jnp.bfloat16, False, True, True),
+    ((64, 128, 1536), (64, 128, 1024), jnp.bfloat16, True, True, True),
+    ((64, 128, 1536), (64, 128, 1024), jnp.bfloat16, True, False, False),
+], ids=["lm7b", "lm7b_off_the_tpu", "sublanes_with_a_sink", "float32",
+        "granite", "kv8_not_in_the_lanes", "solar", "mimo_global",
+        "mimo_ring_with_its_sink", "mimo_ring_off_the_tpu"])
+def test_the_kernel_writes_wherever_a_kernel_attends(
+        keys, values, dtype, sink, on_tpu, want, monkeypatch):
+    """``kernel_writes`` is ``decode_attend``'s own choice of path: yes
+    where ``kernel_block`` finds a block, but for a sink on heads in the
+    sublanes; no for granite's 8 heads of 64, float32 leaves, heads that
+    belong in the lanes and do not lie there, and off the TPU."""
+    monkeypatch.setattr(da, "_on_tpu", lambda: on_tpu)
+    assert da.kernel_writes(keys, dtype, values, sink) is want
+    block = da.kernel_block(keys, dtype, values=values)
+    assert want == bool(block and not (sink and len(keys) == 4))
+    # Interpreted, the backend does not matter (both shapes tried off
+    # the TPU are the kernel's).
+    assert da.kernel_writes(keys, dtype, values, sink, interpret=True) \
+        is (want or not on_tpu)

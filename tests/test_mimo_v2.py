@@ -155,6 +155,16 @@ LENGTHS = {"ragged": (1, 7, 8, 9, 33, 64), "one": (1,) * 6,
            "full": (64,) * 6}
 
 
+def last_row_step(k, v, lens):
+    """A decode step whose row is each slot's last live one, as the
+    lanes kernel takes it (ISSUE 39: the kernel writes it): ``(new_k,
+    new_v, lengths, at)``, the rows out of ``k`` and ``v`` [B, S, KV, D]
+    and merged."""
+    at = jnp.asarray(lens, jnp.int32) - 1
+    return (*(jnp.take_along_axis(x, at[:, None, None, None], axis=1)
+              .reshape(len(at), 1, -1) for x in (k, v)), at + 1, at)
+
+
 @pytest.mark.parametrize("lengths", sorted(LENGTHS))
 @pytest.mark.parametrize("sinks", [False, True])
 @pytest.mark.parametrize("dk, dv, dtype", [(24, 16, jnp.bfloat16),
@@ -170,15 +180,22 @@ def test_the_lanes_kernel_interpreted_agrees_with_the_plain_form(
     lens = np.asarray(LENGTHS[lengths], np.int32)
     q, k, v, sink = operands(7, len(lens), 1, s, heads, kv, dk, dv, dtype)
     sink = sink if sinks else None
-    dead = np.arange(s)[None, :, None, None] >= lens[:, None, None, None]
+    # (and so does the step's own row, which the kernel is handed)
+    dead = np.arange(s)[None, :, None, None] \
+        >= lens[:, None, None, None] - 1
     merged = [jnp.where(dead, jnp.nan, x).reshape(len(lens), s, -1)
               for x in (k, v)]
-    got = da._decode_attend_lanes(q, *merged, jnp.asarray(lens), sink, 0.11,
-                                  block=block, interpret=True)
+    step = last_row_step(k, v, lens)
+    got, *written = da._decode_attend_lanes(
+        q, *merged, *step, sink, 0.11, block=block, interpret=True)
     want = da.attend_plain(q, k, v, jnp.asarray(lens)[:, None] - 1, 0.11,
                            sink)
     assert got.shape == (len(lens), 1, heads, dv)
     np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+    for mine, leaf, row in zip(written, merged, step):
+        np.testing.assert_array_equal(                    # NaN equals NaN
+            np.asarray(mine, np.float32),
+            np.asarray(da.write_rows(leaf, row, step[3]), np.float32))
 
 
 def test_the_entry_point_reads_lanes_leaves_in_the_block_the_rule_gives(
@@ -212,15 +229,23 @@ def test_the_entry_point_reads_lanes_leaves_in_the_block_the_rule_gives(
     real = da._decode_attend_lanes
     monkeypatch.setattr(da, "_decode_attend_lanes",
                         lambda *a, **kw: calls.append(kw) or real(*a, **kw))
-    merged = (k.reshape(3, 64, -1), v.reshape(3, 64, -1))
-    got = da.decode_attend(q, *merged, lens, 0.07, sink, interpret=True)
+    step = last_row_step(k, v, lens)
+    # Zeros where the step's row goes: it is the kernel's to write.
+    merged = [da.write_rows(x.reshape(3, 64, -1), jnp.zeros_like(row),
+                            step[3]) for x, row in zip((k, v), step)]
+    got, *written = da.decode_attend(q, *merged, *step, 0.07, sink,
+                                     interpret=True)
     assert calls == [{"block": 16, "interpret": True,
                       "name": "hvd.decode_attend"}]
     want = da.attend_plain(q, k, v, lens[:, None] - 1, 0.07, sink)
     np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
-    plain = da.decode_attend(q, *merged, lens, 0.07, sink)       # a CPU
+    plain, *plainly = da.decode_attend(q, *merged, *step, 0.07,
+                                       sink)                    # a CPU
     assert len(calls) == 1
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(want))
+    for mine, theirs, whole in zip(written, plainly, (k, v)):
+        np.testing.assert_array_equal(mine, theirs)
+        np.testing.assert_array_equal(mine, whole.reshape(3, 64, -1))
 
 
 def test_the_lanes_kernel_carries_the_decode_kernels_name():
@@ -228,7 +253,7 @@ def test_the_lanes_kernel_carries_the_decode_kernels_name():
     jaxpr = jax.make_jaxpr(lambda *a: da._decode_attend_lanes(
         *a, None, 0.1, block=16, interpret=True))(
         q, k.reshape(2, 32, -1), v.reshape(2, 32, -1),
-        jnp.asarray([3, 30], jnp.int32))
+        *last_row_step(k, v, [3, 30]))
     call, = pallas_calls(jaxpr.jaxpr)
     assert "hvd.decode_attend" in str(call.params["name"]) \
         or "hvd.decode_attend" in str(call.params.get("name_and_src_info"))
@@ -339,7 +364,7 @@ def test_a_ring_in_the_lanes_through_the_kernel(n, bucket, monkeypatch):
     calls = []
     real = da._decode_attend_lanes
     monkeypatch.setattr(da, "_decode_attend_lanes",
-                        lambda *a, **kw: calls.append((a[3], kw))
+                        lambda *a, **kw: calls.append((a[5], kw))
                         or real(*a, **kw))
     monkeypatch.setattr(kvcache, "decode_attend", functools.partial(
         da.decode_attend, interpret=True))

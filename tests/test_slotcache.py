@@ -267,6 +267,9 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
         cache.fresh(params)
         cache.warm(params, last)
         assert stats["cache_aliased_bytes"] == stats["cache_bytes"] > 0
+        if not paged:      # no kernel on a CPU: write_rows' loops write
+            assert stats["attend_layers"] == dense_cfg.num_layers
+            assert stats["attend_write_fused_layers"] == 0
         admit(0, 0, prompts[0])
         admit(1, 1, prompts[1])
         for _ in range(3):
@@ -327,8 +330,11 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
     is the one the family's own ``prefill`` and ``decode_step`` give
     with the plain form, past a wrap of the ring; ``stats`` counts the
     live positions and what the compiled path reads for them in the
-    blocks it really takes, a layer's worth (the mean over the layers);
-    one decode program either way."""
+    blocks it really takes, a layer's worth (the mean over the layers),
+    and the layers whose kernel writes the step's row itself (ISSUE 39:
+    all of them with the kernel, none on the CPU path); a slot released
+    and admitted again serves its new request's tokens; one decode
+    program either way."""
     import functools
 
     from horovod_tpu.ops import decode_attention as da
@@ -345,7 +351,7 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
     prompts = {0: list(range(3, 20)), 1: [44, 45, 46], 2: [9] * 31}
     steps, block, layers = 4, 16, sum(spans.values())
 
-    def reference(prompt):
+    def reference(prompt, steps=steps):
         padded = np.zeros((1, slotcache.prompt_bucket(cfg, len(prompt))),
                           np.int32)
         padded[0, :len(prompt)] = prompt
@@ -408,6 +414,32 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
         # 16; 32 positions read 32, then 33..35 read 48.
         assert not (kernel and decoder == "heads16") \
             or read == steps * (32 + 16) + 32 + 3 * 48
+        # ISSUE 39: where a kernel attends it writes the step's row
+        # itself, in every layer here; the plain form's layers write it
+        # in a loop over the slots.
+        assert stats["attend_layers"] == layers
+        assert stats["attend_write_fused_layers"] == sum(
+            n for n, span, block in cache._attend_kinds if block) \
+            == (layers if kernel else 0)
+        # A slot released and taken again: its new occupant's rows land
+        # on the last one's, the other two slots decoding beside it.
+        cache.release(1)
+        again = [7, 8, 9, 10, 11]
+        last[1] = cache.admit(params, 1, again, 8)
+        slots[1].seq_len = len(again)
+        after = [int(last[1])]
+        for step in range(3):
+            nxt = cache.fetch(cache.decode(
+                params, last, np.arange(cfg.slots) == (1 if step == 0
+                                                       else -1),
+                [0, 1, 2], slots))
+            for rid in prompts:
+                last[rid] = -1
+                slots[rid].seq_len += 1
+                got[rid].append(int(nxt[rid]))
+            after.append(got[1].pop())
+        assert after == reference(again)[:4]
+        assert got[0] == want[0] + reference(prompts[0], 7)[-3:]
         assert cache._decode_jit._cache_size() == 1
     finally:
         cache.close()
