@@ -37,6 +37,14 @@ switch there:
   lists and the expert block elsewhere, the router's correction bias
   (``router_bias``) and no shared expert.
 
+- for SK Telecom's A.X-K1 (``axk1``, DeepSeek-V3's layer): a fifth kind,
+  ``"latent"``, multi-head latent attention (``LatentAttention``): a
+  query through a rank of ``q_lora_rank`` and a norm, one latent of
+  ``kv_lora_rank`` channels with a norm and one rotary key of
+  ``qk_rope_head_dim`` channels that every head shares, YaRN rotary
+  frequencies and score scale (``rope_scaling``), and in the router
+  expert groups (``n_group``, ``topk_group``).
+
 RMSNorm, the gated MLP and ``apply_rope`` are ``transformer.py``'s.
 
 Serving (``decode=True``): the cache collection keeps the attention
@@ -59,22 +67,24 @@ is updated once, in place (``hvd.ssm_update``, ``hvd.kda_update``).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
-from ..ops import kda, ssm
+from ..ops import kda, mla, ssm
 from . import moe
 from .family import ModelFamily
 from .kvcache import (attend, cached_attention, decode_step, fresh_cache,
-                      prefill)
+                      latent_attention, prefill)
 from .transformer import MLP, RMSNorm, apply_rope
 
 STATE_LEAVES = ("conv_state", "ssm_state", "kda_state")
-KINDS = ("mamba", "kda", "attention", "window")
+KINDS = ("mamba", "kda", "attention", "window", "latent")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +111,15 @@ class HybridConfig:
     window_kv_heads: int = 0
     window_rope_theta: float = 0.0
     window_sink: bool = False
+    # "latent" layers (multi-head latent attention): the query's and the
+    # latent's ranks, a head's channels without and with rotary
+    # positions (its values are attn_value_dim wide), and YaRN's
+    # settings as a checkpoint's rope_scaling gives them (None: plain)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    rope_scaling: Any = None
     # Mamba-2 layers: heads x head_dim inner channels, one group
     mamba_heads: int = 4
     mamba_head_dim: int = 16
@@ -123,6 +142,8 @@ class HybridConfig:
     norm_topk: bool = True
     routed_scaling: float = 1.0
     router_bias: bool = False        # a correction bias in the choice
+    n_group: int = 1                 # the router's expert groups, and how
+    topk_group: int = 1              # many of them a token chooses among
     dense_layers: tuple = ()         # layers that keep the MLP of d_ff
     # the residual stream
     residual_multiplier: float = 1.0
@@ -139,6 +160,9 @@ class HybridConfig:
     interpret: bool = False
 
     def __post_init__(self):
+        if isinstance(self.rope_scaling, dict):      # hashable, as a field
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
         unknown = set(self.layer_types) - set(KINDS)
         if unknown:
             raise ValueError(f"unknown layer types {sorted(unknown)}")
@@ -156,6 +180,8 @@ class HybridConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.attn_head_dim or self.d_model // self.num_heads
 
     @property
@@ -192,15 +218,53 @@ class HybridConfig:
         return ROUTED_FAMILY if self.num_experts else FAMILY
 
 
+def yarn_frequencies(width: int, theta: float, scaling) -> np.ndarray:
+    """The inverse frequencies of ``width`` rotary channels under YaRN
+    (DeepSeek-V3's ``yarn_find_correction_range``): pair ``i``'s plain
+    ``theta^(-2i / width)`` below ``low``, divided by ``factor`` above
+    ``high``, a linear ramp between, where ``low`` and ``high`` are the
+    pairs that turn ``beta_fast`` and ``beta_slow`` times over the
+    original context (10 and 23 of A.X-K1's 32 pairs)."""
+    yarn = dict(scaling)
+    factor, original = yarn["factor"], \
+        yarn["original_max_position_embeddings"]
+
+    def pair(turns):
+        return width * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair(yarn["beta_fast"])), 0)
+    high = min(math.ceil(pair(yarn["beta_slow"])), width - 1)
+    plain = theta ** -(np.arange(0, width, 2, dtype=np.float64) / width)
+    ramp = np.clip((np.arange(width // 2) - low)
+                   / ((high - low) or 0.001), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def latent_scale(cfg: "HybridConfig") -> float:
+    """A latent layer's score scale: ``(nope + rope)^-1/2``, times YaRN's
+    ``m^2`` with ``m = 0.1 mscale_all_dim ln(factor) + 1`` where the
+    context is stretched (A.X-K1: ``192^-1/2 x 1.34657^2 = 0.130861``)."""
+    scale = cfg.head_dim ** -0.5
+    yarn = dict(cfg.rope_scaling or ())
+    if yarn.get("mscale_all_dim") and yarn["factor"] > 1:
+        scale *= (0.1 * yarn["mscale_all_dim"] * math.log(yarn["factor"])
+                  + 1.0) ** 2
+    return scale
+
+
 def _rotate(x: jax.Array, positions: jax.Array, *, width: int,
-            theta: float) -> jax.Array:
+            theta: float, scaling=None) -> jax.Array:
     """Rotary positions on the first ``width`` channels of every head of
     ``x`` [B, T, H, D], pairs ``(i, i + width / 2)`` as ``apply_rope``
-    pairs them; the other channels as they are."""
+    pairs them; the other channels as they are.  ``scaling``: YaRN's
+    frequencies (its factor on cos and sin, ``mscale`` over
+    ``mscale_all_dim``'s, is 1 where the two agree: A.X-K1's)."""
+    freqs = jnp.asarray(yarn_frequencies(width, theta, scaling)) \
+        if scaling else None
     if width == x.shape[-1]:
-        return apply_rope(x, positions, theta)
-    return jnp.concatenate([apply_rope(x[..., :width], positions, theta),
-                            x[..., width:]], axis=-1)
+        return apply_rope(x, positions, theta, freqs)
+    return jnp.concatenate([apply_rope(x[..., :width], positions, theta,
+                                       freqs), x[..., width:]], axis=-1)
 
 
 class GroupedAttention(nn.Module):
@@ -259,6 +323,72 @@ class GroupedAttention(nn.Module):
                 * jax.nn.sigmoid(gate.astype(jnp.float32))
         out = out.astype(cfg.dtype)
         return dense(features=cfg.d_model, axis=(-2, -1), name="wo")(out)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``"latent"``; DeepSeek-V3's, ISSUE 40),
+    ``x`` the normalised residual stream, h over ``num_heads``:
+
+    - ``c_q = RMSNorm(W_qa x)`` (``q_lora_rank``), ``[q_nope_h |
+      q_pe_h] = (W_qb c_q)_h`` (``qk_nope_head_dim``, ``qk_rope_head_dim``);
+    - ``[c_kv | k_pe] = W_kva x``, ``c_kv <- RMSNorm(c_kv)``
+      (``kv_lora_rank``): ``k_pe`` is one rotary key that every head
+      shares;
+    - ``[k_nope_h | v_h] = (W_kvb c_kv)_h`` (values ``attn_value_dim``);
+    - ``s_hj = (q_nope_h . k_nope_hj + rope(q_pe_h) . rope(k_pe_j)) tau``,
+      ``tau = latent_scale(cfg)``; ``o = W_o concat_h(sum_j p_hj v_hj)``.
+
+    Rotary positions with YaRN's frequencies where ``rope_scaling`` says
+    so, pairs ``(i, i + R / 2)`` (a checkpoint that interleaves its
+    pairs is a fixed permutation of ``W_qb``'s and ``W_kva``'s rotary
+    columns).  Without a cache, the expanded form; through it,
+    ``models/kvcache.py:latent_attention`` (the absorbed form at a
+    decode step, ``ops/mla.py``)."""
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, lengths=None) -> jax.Array:
+        cfg = self.cfg
+        dense = partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype)
+        norm = partial(RMSNorm, cfg.dtype, cfg.param_dtype, cfg.rms_norm_eps)
+        h, nope, rope = cfg.num_heads, cfg.qk_nope_head_dim, \
+            cfg.qk_rope_head_dim
+        rank, wide = cfg.kv_lora_rank, cfg.value_dim
+        q = dense(features=(h, nope + rope), name="wq_b")(
+            norm(name="q_norm")(dense(features=cfg.q_lora_rank,
+                                      name="wq_a")(x)))
+        kv = dense(features=rank + rope, name="wkv_a")(x)
+        q_nope, q_pe, c_kv, k_pe = q[..., :nope], q[..., nope:], \
+            kv[..., :rank], kv[..., rank:]
+        # What the softmax is fed (before the latent's norm and before
+        # positions), for a caller that replays a stream and checks what
+        # came out (nothing is kept else).
+        for name, fed in (("q_nope", q_nope), ("q_pe", q_pe),
+                          ("c_kv", c_kv), ("k_pe", k_pe)):
+            self.sow("attention", name, fed)
+        c_kv = norm(name="kv_norm")(c_kv)
+        w_kvb = self.param("wkv_b", nn.initializers.lecun_normal(
+            in_axis=0, out_axis=(1, 2)), (rank, h, nope + wide),
+            cfg.param_dtype)
+        w_uk, w_uv = w_kvb[..., :nope], w_kvb[..., nope:]
+        rotate = partial(_rotate, width=rope, theta=cfg.rope_theta,
+                         scaling=cfg.rope_scaling)
+        scale = latent_scale(cfg)
+        if cfg.decode and not self.is_initializing():
+            out = latent_attention(
+                self, q_nope, q_pe, c_kv, k_pe, w_uk, w_uv,
+                max_seq_len=cfg.max_seq_len, dtype=cfg.dtype, scale=scale,
+                rotate=rotate)
+        else:
+            positions = jnp.arange(x.shape[1])[None, :]
+            out = attend(*mla.expand(
+                q_nope, rotate(q_pe, positions), c_kv,
+                rotate(k_pe[:, :, None, :], positions)[:, :, 0], w_uk,
+                w_uv), positions, scale)
+        self.sow("attention", "out", out)
+        return dense(features=cfg.d_model, axis=(-2, -1), name="wo")(
+            out.astype(cfg.dtype))
 
 
 class Mamba2Mixer(nn.Module):
@@ -451,6 +581,8 @@ class HybridBlock(nn.Module):
         if self.kind in ("attention", "window"):
             mixed = GroupedAttention(cfg, self.kind == "window",
                                      name="attn")(mixed, lengths)
+        elif self.kind == "latent":
+            mixed = LatentAttention(cfg, name="attn")(mixed)
         else:
             mixer = Mamba2Mixer if self.kind == "mamba" else KDAMixer
             mixed = mixer(cfg, name=self.kind)(mixed, lengths)
@@ -460,7 +592,8 @@ class HybridBlock(nn.Module):
                 cfg.num_experts, cfg.experts_per_token, cfg.expert_ff,
                 tuple(cfg.experts_held), cfg.shared_experts, cfg.norm_topk,
                 cfg.routed_scaling, cfg.dtype, cfg.param_dtype,
-                cfg.interpret, bias=cfg.router_bias, name="moe")
+                cfg.interpret, bias=cfg.router_bias,
+                groups=(cfg.n_group, cfg.topk_group), name="moe")
         return x + cfg.residual_multiplier * ffn(norm(name="mlp_norm")(x))
 
 

@@ -11,7 +11,11 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
   ``ring_value``; fewer than 16 bfloat16 key-value heads whose value
   heads are whole lanes lie side by side in the lanes, [B, max_seq_len,
   KV * D], caches and rings alike
-  (``ops/decode_attention.py:lanes_layout``);
+  (``ops/decode_attention.py:lanes_layout``); a latent layer (ISSUE 40)
+  keeps one leaf, ``latent`` [B, max_seq_len, W], a position's
+  normalised latent beside its rotated shared key, zeros to whole lanes
+  (576 of A.X-K1's 640), read by every head
+  (``latent_attention``, ``ops/mla.py``);
 - paged: ``key_pool`` / ``value_pool`` [blocks + 1, block_tokens, KV, D]
   shared by every row and addressed through block tables, the last row
   a write sink for padded positions (``paged_attention``, ``paged_*``).
@@ -26,11 +30,14 @@ keys and values up to its own live length and writes the new row in
 place itself; elsewhere ``write_rows`` writes it, a serial loop over the
 rows, and the plain form attends.  A long prompt, and any prompt of a
 window layer, attends over its own keys and values in blocks
-(``attend_blocked``).
-A family's
-attention layer (``transformer.Attention``, ``hybrid.GroupedAttention``)
-brings its projections, its positional encoding and its score scale,
-and writes no cache code of its own.
+(``attend_blocked``).  A latent layer's decode step takes the absorbed
+form, ``hvd.mla_decode``, which writes the step's row the same way; its
+prompt writes its rows and attends in blocks over its keys and values
+expanded from the latent.
+A family's attention layer (``transformer.Attention``,
+``hybrid.GroupedAttention``, ``hybrid.LatentAttention``) brings its
+projections, its positional encoding and its score scale, and writes no
+cache code of its own.
 """
 from __future__ import annotations
 
@@ -41,7 +48,7 @@ from flax.core import unfreeze
 # The one attention over the cache, in its plain form, as a decode
 # step's kernel and in blocks over a whole prompt
 # (ops/decode_attention.py).
-from ..ops import decode_attention
+from ..ops import decode_attention, mla
 from ..ops.decode_attention import (attend_blocked, attend_plain as attend,
                                     decode_attend, write_rows)
 
@@ -131,6 +138,51 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
     if lanes:
         k, v = k.reshape(b, t, kv, d), v.reshape(b, t, kv, dv)
     return attend_blocked(q, k, v, scale, window=window, sink=sink)
+
+
+def latent_attention(module, q_nope: jax.Array, q_pe: jax.Array,
+                     c_kv: jax.Array, k_pe: jax.Array, w_uk: jax.Array,
+                     w_uv: jax.Array, *, max_seq_len: int, dtype,
+                     scale: float, rotate) -> jax.Array:
+    """A latent layer's attention over ``module``'s dense cache (ISSUE
+    40; ``ops/mla.py`` has the two forms): the leaf ``latent`` [B,
+    max_seq_len, W] keeps a position's normalised latent ``c_kv`` [B, T,
+    C] beside its rotated shared key ``k_pe`` [B, T, R], zeros to whole
+    lanes (``mla.row_width``), and ``cache_index`` [B] each row's write
+    cursor.  A decode step (one
+    token a row) takes the absorbed form, ``mla_decode``, which writes
+    the step's row; a prompt (a call that starts its rows) writes its
+    rows and attends in blocks over its own keys and values, expanded by
+    ``w_uk`` [C, H, N] and ``w_uv`` [C, H, V].  ``q_nope`` [B, T, H, N],
+    ``q_pe`` [B, T, H, R]; ``rotate(x, positions)`` the layer's positional
+    encoding -> float32 [B, T, H, V], each head's output before ``wo``.
+    A padded prompt needs no lengths: the write cursor rewinds past its
+    tail (``prefill``) and the decode steps overwrite it."""
+    b, t, rank = c_kv.shape
+    starts = not module.has_variable("cache", "latent")
+    latent = module.variable(
+        "cache", "latent", jnp.zeros,
+        (b, max_seq_len, mla.row_width(rank, k_pe.shape[-1])), dtype)
+    index = module.variable("cache", "cache_index",
+                            lambda: jnp.zeros((b,), jnp.int32))
+    idx = index.value                                       # [B]
+    positions = idx[:, None] + jnp.arange(t)[None, :]       # [B, T]
+    q_pe = rotate(q_pe, positions)
+    k_pe = rotate(k_pe[:, :, None, :], positions)[:, :, 0]
+    index.value = idx + t
+    row = mla.latent_row(c_kv, k_pe, dtype)
+    if t == 1:     # a decode step: each row up to its own length, no further
+        out, latent.value = mla.mla_decode(
+            mla.absorb(q_nope, q_pe, w_uk).astype(dtype), latent.value, row,
+            idx + 1, idx, scale, rank)
+        return mla.emit(out, w_uv, dtype)
+    if not starts:
+        raise ValueError("a latent layer's cache takes a whole prompt or "
+                         "one token")
+    latent.value = write_rows(latent.value, row, idx)
+    with jax.named_scope("hvd.mla_expand"):
+        q, k, v = mla.expand(q_nope, q_pe, c_kv, k_pe, w_uk, w_uv)
+    return attend_blocked(q, k, v, scale)
 
 
 def paged_attention(module, q: jax.Array, k: jax.Array, v: jax.Array,

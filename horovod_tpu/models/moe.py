@@ -170,7 +170,8 @@ def _on_tpu() -> bool:
 
 
 def route(scores: jax.Array, per_token: int, held: tuple, *,
-          norm: bool = True, scaling: float = 1.0, bias=None):
+          norm: bool = True, scaling: float = 1.0, bias=None,
+          groups: tuple = (1, 1)):
     """``scores`` [N, E] float32 (the router's, over every expert of the
     model) -> the ``per_token`` largest a token: ``(weights [N, k]
     float32, local [N, k], here [N, k])``: each chosen expert's weight
@@ -180,10 +181,23 @@ def route(scores: jax.Array, per_token: int, held: tuple, *,
     A token whose experts all live elsewhere gets nothing here; none is
     dropped for want of room.  ``bias`` [E] float32, a router's
     correction bias, enters the choice (the largest of ``scores +
-    bias``) and not the weights."""
+    bias``) and not the weights.  ``groups = (n_group, topk_group)``:
+    the experts lie in ``n_group`` groups of consecutive indices, a group
+    scores by the sum of its two largest (biased) scores, and a token
+    chooses among the experts of its ``topk_group`` best groups only
+    (DeepSeek-V3's group-limited routing)."""
     first, count = held
-    top, chosen = lax.top_k(scores if bias is None else scores + bias,
-                            per_token)
+    choice = scores if bias is None else scores + bias
+    n_group, topk_group = groups
+    if n_group > 1:
+        n, e = choice.shape
+        grouped = choice.reshape(n, n_group, e // n_group)
+        best = lax.top_k(jnp.sum(lax.top_k(grouped, 2)[0], -1),
+                         topk_group)[1]                     # [N, topk_group]
+        kept = jnp.any(best[..., None] == jnp.arange(n_group), -2)
+        choice = jnp.where(jnp.repeat(kept, e // n_group, axis=-1), choice,
+                           -jnp.inf)
+    top, chosen = lax.top_k(choice, per_token)
     if bias is not None:
         top = jnp.take_along_axis(scores, chosen, axis=-1)
     if norm:
@@ -332,7 +346,8 @@ class RoutedExperts(nn.Module):
 
     ``s = sigmoid(W_r x)`` over all ``num_experts`` of the model, the
     ``per_token`` largest (of ``s + c`` with a correction ``bias``
-    ``c``, which the weights leave out), weights ``s_e / sum of the
+    ``c``, which the weights leave out; among the best ``groups``' where
+    there are groups: ``route``), weights ``s_e / sum of the
     chosen`` (``norm_topk``) times ``scaling``; ``y = sum_e w_e E_e(x) +
     E_shared(x)`` with ``E(x) = W_down (silu(W_gate x) * W_up x)`` at
     width ``d_ff`` (the shared expert ``shared`` times as wide).  The
@@ -358,6 +373,7 @@ class RoutedExperts(nn.Module):
     param_dtype: Any = jnp.float32
     interpret: bool = False          # run hvd.moe_experts interpreted
     bias: bool = False               # a correction bias, float32 [E]
+    groups: tuple = (1, 1)           # (n_group, topk_group): see route
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -396,32 +412,83 @@ class RoutedExperts(nn.Module):
                 precision=lax.Precision.HIGHEST))
             weights, local, here = route(
                 scores, self.per_token, self.held, norm=self.norm_topk,
-                scaling=self.scaling, bias=bias)
-            tile = tile_rows(b * t, self.per_token, self.num_experts)
-            row_token, at, tile_expert, tiles, sizes = group_rows(
-                local, here, count, tile)
-            rows = jnp.take(tokens, row_token, axis=0)
-        for name, value in zip(COUNTERS, (
-                here.size, jnp.sum(here), jnp.sum(sizes > 0), count)):
-            self.sow("counters", name, jnp.asarray(value, jnp.int32))
+                scaling=self.scaling, bias=bias, groups=self.groups)
         # Which experts of the model each token took, [N, k], its scores
         # over all of them and the weights it gave the chosen: for a
         # caller that replays a stream and asks (nothing is kept else).
         self.sow("routing", "chosen", local + first)
         self.sow("routing", "scores", scores)
         self.sow("routing", "weights", weights)
-        gate, up, down = (w.astype(self.dtype) for w in (gate, up, down))
+        experts = tuple(w.astype(self.dtype) for w in (gate, up, down))
+        chunk = chunk_tokens(b * t, self.per_token, d)
+        if chunk:
+            routed, touched = _in_chunks(self._products, chunk, tokens,
+                                         weights, local, here, experts)
+        else:
+            routed, sizes = self._products(tokens, weights, local, here,
+                                           experts)
+            touched = jnp.sum(sizes > 0)
+        for name, value in zip(COUNTERS, (
+                here.size, jnp.sum(here), touched, count)):
+            self.sow("counters", name, jnp.asarray(value, jnp.int32))
+        return (shared + routed).reshape(b, t, d).astype(self.dtype)
+
+    def _products(self, tokens, weights, local, here, experts):
+        """The routed pairs of ``tokens`` [N, d] sorted by expert and run
+        grouped -> ``(sum_e w_e E_e(x) [N, d] float32, pairs of each held
+        expert)``."""
+        count = self.held[1]
+        n = tokens.shape[0]
+        with jax.named_scope("hvd.moe_route"):
+            tile = tile_rows(n, self.per_token, self.num_experts)
+            row_token, at, tile_expert, tiles, sizes = group_rows(
+                local, here, count, tile)
+            rows = jnp.take(tokens, row_token, axis=0)
         if _on_tpu() or self.interpret:
             routed = _experts_pallas(
-                rows, tile_expert, tiles, gate, up, down, tile=tile,
+                rows, tile_expert, tiles, *experts, tile=tile,
                 block=hidden_block(self.d_ff), interpret=self.interpret)
         else:
-            routed = experts_plain(rows, -(-sizes // tile) * tile, gate, up,
-                                   down)
+            routed = experts_plain(rows, -(-sizes // tile) * tile, *experts)
         # A row that no live tile wrote may hold anything: chosen, not
         # multiplied by 0.
         picked = jnp.where(here[..., None],
                            jnp.take(routed, jnp.minimum(at, rows.shape[0] - 1),
                                     axis=0), 0.0)
-        out = shared + jnp.sum(weights[..., None] * picked, axis=1)
-        return out.reshape(b, t, d).astype(self.dtype)
+        return jnp.sum(weights[..., None] * picked, axis=1), sizes
+
+
+# A call whose routed pairs' float32 rows pass this many bytes (a long
+# prefill: A.X-K1's prompts of 6,144 positions and more, 1.4 to 2.4 GB at
+# once where the chip has under 3 left beside its weights and cache)
+# runs the expert products over chunks of tokens of CHUNK_BYTES' worth;
+# at or under it (every decode step, MiMo's 8,192 prompt at 2^30 exactly)
+# a call's program is the one PRs up to 39 compiled.
+WHOLE_BYTES = 1 << 30
+CHUNK_BYTES = 1 << 29
+
+
+def chunk_tokens(tokens: int, per_token: int, d: int) -> int:
+    """Tokens a chunk of the expert products takes, 0 for all at once:
+    the largest power of two whose pairs' rows stay within
+    ``CHUNK_BYTES``, where all of them would pass ``WHOLE_BYTES``."""
+    row = per_token * d * 4
+    if tokens * row <= WHOLE_BYTES:
+        return 0
+    return 1 << (CHUNK_BYTES // row).bit_length() - 1
+
+
+def _in_chunks(products, chunk: int, tokens, weights, local, here,
+               experts):
+    """``products`` over consecutive chunks of ``chunk`` tokens, one after
+    the other (the last padded with tokens routed nowhere) -> ``(sum [N,
+    d] float32, held experts that some token chose)``."""
+    n = tokens.shape[0]
+    pad = -n % chunk
+    split = lambda x: jnp.pad(                                # noqa: E731
+        x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)).reshape(
+            (n + pad) // chunk, chunk, *x.shape[1:])
+    routed, sizes = lax.map(
+        lambda each: products(*each, experts),
+        (split(tokens), split(weights), split(local), split(here)))
+    return routed.reshape(n + pad, -1)[:n], jnp.sum(jnp.sum(sizes, 0) > 0)

@@ -109,11 +109,13 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float) -> jax.Array:
+               theta: float, freqs=None) -> jax.Array:
     """x: [B, T, H, D]; positions: [T] global token positions shared by
     the batch, or [B, T] per-element positions (KV-cache decode, where
-    every sequence in the continuous batch sits at its own depth)."""
-    freqs = rope_frequencies(x.shape[-1], theta)          # [D/2]
+    every sequence in the continuous batch sits at its own depth).
+    ``freqs`` [D/2], where given, replaces ``theta``'s (YaRN's)."""
+    if freqs is None:
+        freqs = rope_frequencies(x.shape[-1], theta)      # [D/2]
     if positions.ndim == 1:
         positions = positions[None, :]                    # [1,T]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B|1,T,D/2]

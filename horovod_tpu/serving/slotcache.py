@@ -34,14 +34,19 @@ import numpy as np
 
 from ..common.logging import logger
 from ..models.kvcache import summed
-from ..ops import decode_attention
+from ..ops import decode_attention, mla
 from ..telemetry.spans import span
 from .kvpool import FNV_SEED, KVBlockPool, chain_hash
 
 
 def prompt_bucket(cfg, n: int) -> int:
-    """The compiled prefill shape ``n`` prompt tokens pad to."""
-    return min(max(8, 1 << max(0, (n - 1)).bit_length()), cfg.max_seq)
+    """The compiled prefill shape ``n`` prompt tokens pad to: the next
+    power of two from 8 (at most ``max_seq``), or a warm-up bucket
+    between ``n`` and it where the configuration lists one (A.X-K1's
+    6,144 and 10,240)."""
+    power = min(max(8, 1 << max(0, (n - 1)).bit_length()), cfg.max_seq)
+    return min((b for b in cfg.warmup_buckets if n <= b <= power),
+               default=power)
 
 
 def _padded(cfg, toks: list) -> np.ndarray:
@@ -222,15 +227,21 @@ class DenseSlotCache(_SlotCache):
     def fresh(self, params) -> None:
         super().fresh(params)
         # The span and the block the decode program's attention reads
-        # each layer's key and value leaves in, by kind of layer; and
-        # the layers whose kernel writes the step's row itself (a
-        # layer's sink lies beside its leaves, in the parameters).
+        # each layer's key and value leaves (a latent layer's one leaf)
+        # in, by kind of layer; and the layers whose kernel writes the
+        # step's row itself (a layer's sink lies beside its leaves, in
+        # the parameters; ``hvd.mla_decode`` writes wherever it runs).
         kinds: dict = {}
         fused = 0
         leaves = {tuple(k.key for k in path): leaf for path, leaf in
                   jax.tree_util.tree_flatten_with_path(self.tree)[0]}
         for path, keys in leaves.items():
-            if path[-1] in ("cached_key", "ring_key"):
+            if path[-1] == "latent":
+                kind = (keys.shape[1], mla.kernel_block(keys.shape,
+                                                        keys.dtype))
+                kinds[kind] = kinds.get(kind, 0) + 1
+                fused += bool(kind[1])
+            elif path[-1] in ("cached_key", "ring_key"):
                 values = leaves[(*path[:-1],
                                  path[-1].replace("key", "value"))]
                 kind = (keys.shape[1], decode_attention.kernel_block(

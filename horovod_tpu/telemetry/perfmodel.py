@@ -248,12 +248,18 @@ def hybrid_decode_flops(cfg: Any, context_len: float) -> float:
     update's 6*H*P*N a Mamba layer and 8*H*K*V a delta-rule layer, and
     scores (over the keys' width) and values (over theirs) over the
     context in the attention layers, over its last ``window`` positions
-    in the window layers."""
+    in the window layers.  A latent layer decodes in the absorbed form
+    (``ops/mla.py``): its projections, ``W_kvb`` once as ``W_UK`` on the
+    query and once as ``W_UV`` on the output, and scores over the
+    latent row's ``kv_lora_rank + qk_rope_head_dim`` channels and values
+    over its ``kv_lora_rank``, a head and a position: 139,264 operations
+    a position at A.X-K1's widths, where the expanded form's keys and
+    values would cost 40,960 and the expansion of every cached latent."""
     d = cfg.d_model
     kinds = cfg.layer_types
-    mamba, delta, window = (kinds.count(kind)
-                            for kind in ("mamba", "kda", "window"))
-    attn = len(kinds) - mamba - delta - window
+    mamba, delta, window, latent = (
+        kinds.count(kind) for kind in ("mamba", "kda", "window", "latent"))
+    attn = len(kinds) - mamba - delta - window - latent
     width, wide = cfg.num_heads * cfg.head_dim, cfg.num_heads * cfg.value_dim
 
     def projections(kv_heads: int) -> int:
@@ -275,11 +281,27 @@ def hybrid_decode_flops(cfg: Any, context_len: float) -> float:
                + attn * projections(cfg.num_kv_heads)
                + window * projections(cfg.window_kv_heads
                                       or cfg.num_kv_heads)
+               + latent * _latent_projections(cfg)
                + ffn + d * cfg.vocab_size)
     update = 6.0 * mamba * cfg.mamba_heads * cfg.mamba_head_dim \
         * cfg.mamba_state + 8.0 * delta * cfg.kda_heads * cfg.kda_head_dim ** 2
     seen = attn * context_len + window * min(context_len, cfg.window)
-    return 2.0 * weights + update + 2.0 * (width + wide) * seen
+    row = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    return 2.0 * weights + update + 2.0 * (width + wide) * seen \
+        + 2.0 * latent * cfg.num_heads * (row + cfg.kv_lora_rank) \
+        * context_len
+
+
+def _latent_projections(cfg: Any) -> int:
+    """A latent layer's weights, every one of which a decoded token
+    meets once: ``W_qa``, ``W_qb``, ``W_kva``, ``W_kvb`` (absorbed: its
+    key rows on the query, its value rows on the output) and ``W_o``."""
+    d, h = cfg.d_model, cfg.num_heads
+    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    return d * cfg.q_lora_rank + cfg.q_lora_rank * h * cfg.head_dim \
+        + d * (rank + rope) + rank * h * (cfg.qk_nope_head_dim
+                                          + cfg.value_dim) \
+        + h * cfg.value_dim * d
 
 
 # ---------------------------------------------------------------------------

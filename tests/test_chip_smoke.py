@@ -453,6 +453,69 @@ def test_the_solar_decode_program_attends_through_the_kernel_on_v5e(
     assert memory.temp_size_in_bytes < leaf // 4
 
 
+def test_the_axk1_programs_fit_a_v5e_and_attend_through_the_kernel(
+        v5e, monkeypatch):
+    """``axk1_serve_longdoc_sat``'s programs (A.X-K1's widths, 5 latent
+    layers, 64 slots of 14,336 positions, bfloat16) compiled for the
+    v5e.  The decode program: one hvd.mla_decode custom call a latent
+    layer over its leaf of 640 lanes (576 and zeros: a leaf of 576 lay
+    position-minor and was copied every step), taking it as it lies and
+    writing the step's row (no ``while`` loop over the slots writes a
+    latent row), one hvd.moe_experts an expert layer; it updates the
+    whole cache in place with no copy of a leaf among its temporaries.
+    The longest prefill, 10,240 tokens, runs its expert products in
+    chunks and fits beside the weights and the cache."""
+    from horovod_tpu.models import hybrid, moe
+    from horovod_tpu.ops import decode_attention as da
+    from horovod_tpu.ops import mla
+    from horovod_tpu.serving import ServeConfig, slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks", "chip"))
+    import run as harness
+    for module in (da, moe, mla):           # the target, not the CPU
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    file = harness.load_json(harness.HERE, "configs", "A.X-K1.serve.json")
+    serve = {**file["serve"],
+             "warmup_buckets": tuple(file["serve"]["warmup_buckets"])}
+    cfg = ServeConfig(model_cfg=hybrid.HybridConfig(
+        **harness.build_args(file)), **serve)
+    slots, max_seq = cfg.slots, cfg.max_seq
+    assert (slots, max_seq) == (64, 14336)
+    model = hybrid.HybridLM(_decode_model_cfg(cfg))
+    cache = slotcache.DenseSlotCache(cfg, cfg.model_cfg.family, model, {})
+
+    placed = functools.partial(_placed, sharding=v5e)
+    params = placed(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    tree = placed(jax.eval_shape(cache._init_cache_impl, params))
+    leaf = slots * max_seq * 640 * 2
+    assert tree["layer_1"]["attn"]["latent"].shape == (slots, max_seq, 640)
+    assert _nbytes(tree) == 5 * (leaf + slots * 4)
+    assert 6.98e9 < _nbytes(params) < 6.99e9
+    compiled = cache._decode_jit.lower(
+        params, tree, *placed((jnp.zeros(slots + 4, jnp.int32),
+                               jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, bool)))).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if MOSAIC in line]
+    latent = [call for call in calls if "hvd.mla_decode" in call]
+    assert len(latent) == 5 and len(calls) == 5 + 4
+    assert all(call.count("bf16[64,14336,640]") == 2 for call in latent)
+    assert sum("hvd.moe_experts" in call for call in calls) == 4
+    assert not _loops_over(text, "bf16[64,14336,")
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= _nbytes(tree)
+    assert memory.temp_size_in_bytes < leaf // 20
+    prefill = cache._prefill_jit.lower(
+        params, placed(jnp.zeros((1, 10240), jnp.int32)),
+        placed(jnp.zeros((), jnp.int32))).compile().memory_analysis()
+    # 15.75 GiB usable: 16.91 GB.
+    assert _nbytes(params) + _nbytes(tree) + prefill.temp_size_in_bytes \
+        + prefill.output_size_in_bytes < 15.5e9
+
+
 def test_fit_block_follows_the_tpu_tiling_rule():
     assert fa._fit_block(2048, 1024) == 1024
     assert fa._fit_block(2000, 128) == 80       # not 125

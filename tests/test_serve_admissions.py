@@ -408,7 +408,9 @@ def test_the_mimo_rehearsals_pad_share_is_its_tables(capsys):
                                       "mixlen_sat.json")["rehearsal"]
     config = bench.harness.load_json(
         bench.HERE, "configs", "MiMo-V2.5.serve.json")["rehearsal"]
-    cfg = types.SimpleNamespace(max_seq=config["serve"]["max_seq"])
+    cfg = types.SimpleNamespace(
+        max_seq=config["serve"]["max_seq"],
+        warmup_buckets=tuple(config["serve"]["warmup_buckets"]))
     table = traffic["requests"]
     prompts = [table[i % len(table)][0] for i in range(admitted)]
     positions = sum(prompt_bucket(cfg, n) for n in prompts)
