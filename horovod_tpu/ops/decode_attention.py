@@ -15,11 +15,11 @@ key ``j`` is visible at ``i`` when ``i - window < j <= i``:
   one Pallas kernel, named ``hvd.decode_attend`` the way the flash
   kernels carry their names (a device trace selects an operation by
   ``<opcode> <name>`` only), which reads each slot's keys and values
-  in blocks **up to that slot's own live length and no further**: the
-  lengths are a runtime operand (scalar prefetch), the index of a block
-  past a slot's length repeats the one before it, so nothing is
-  fetched for it, and its arithmetic is skipped.  One compiled program
-  serves every mix of lengths.
+  in blocks **up to that slot's own live length and no further**: its
+  grid is a work list of the live blocks alone (``live_blocks``, a
+  scalar prefetch built from the lengths, its count the grid's runtime
+  bound), so a block past a slot's length costs no grid step at all.
+  One compiled program serves every mix of lengths.
 - ``attend_blocked``: a whole prompt over its own keys and values, in
   blocks of query positions with the online softmax across key blocks
   (plain ``jax.numpy`` under the scope ``hvd.prefill_attend``): a query
@@ -76,6 +76,15 @@ of 64, float32 leaves, a sink on heads in the sublanes) the path is
 ``write_rows``, then ``attend_plain``.  A prefill writes with
 ``write_rows`` always: one row, once a request.
 
+**The grid.**  The three decode kernels, these two and
+``ops/mla.py``'s, run through ``work_list_call``: one grid step a live
+block, slot after slot, the blocks of a slot in order, so a kernel's
+output block and its accumulators carry over from one step of a slot to
+the next.  A kernel body takes the step's ``Item`` (its block, the
+slot's length and row, whether it is the slot's first or last step) and
+its block specs map ``(slot, block, row)`` to a block; the list, the
+scalar prefetch and the index maps are the helper's alone.
+
 ``block_positions`` is the one rule for the block's length, and
 ``read_positions`` the count of what the compiled path reads for a set
 of lengths: the serving replica's ``attend_read_positions`` counter is
@@ -85,6 +94,7 @@ the kernel interpreted (the unit tests).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -98,7 +108,7 @@ _LANE = 128
 # time where the mean length is 585 (0.331 and 0.335 ms a layer), and the
 # smaller block reads less of the cache past a short context (0.157
 # against 0.184 ms where every slot holds one position); 512 is slower
-# (0.405).  A grid step that finds its block dead costs about 0.3 us.
+# (0.405).
 _BLOCK_BYTES = 1 << 20
 _VMEM_BYTES = 32 << 20     # those, and the scores and weights of a block
 
@@ -319,6 +329,96 @@ write_rows = jax.vmap(lambda leaf, new, at: jax.lax.dynamic_update_slice(
 
 
 # ---------------------------------------------------------------------------
+# The grid: a work list of live blocks
+# ---------------------------------------------------------------------------
+def live_blocks(lengths: jax.Array, max_seq: int, block: int) -> tuple:
+    """The decode kernels' work list for slots of live ``lengths`` [B]
+    (int32, each in 1..``max_seq``): every block of every slot up to its
+    length, slot after slot, a slot's blocks in order.  ->
+    ``(n, slot, index, first, last)``: ``n``, the count ``sum(ceil(
+    lengths / block))`` and the grid's bound; int32 arrays as long as
+    the static grid, ``B * max_seq // block``, of each item's slot, its
+    block of the slot, and 1 where it is its slot's first / last block.
+    Items from ``n`` on repeat the last; no grid step reads them.
+
+    Compares and sums over ``[items, B]`` only: a gather by slot (and
+    a cumulative sum) compiles to chains of scalar fusions on a v5e,
+    0.73 ms a step over the MiMo cell's seven calls by a device trace."""
+    b = lengths.shape[0]
+    blocks = (lengths + block - 1) // block
+    ends = jnp.sum(jnp.where(jnp.arange(b)[:, None] >= jnp.arange(b),
+                             blocks, 0), axis=1)     # of the slots up to b
+    n = ends[-1]
+    items = jnp.minimum(jnp.arange(b * (max_seq // block), dtype=jnp.int32),
+                        n - 1)
+    past = items[:, None] >= ends                    # the slots before it
+    slot = jnp.sum(past, axis=1, dtype=jnp.int32)
+    index = items - jnp.sum(jnp.where(past, blocks, 0), axis=1)
+    last = jnp.any(items[:, None] + 1 == ends, axis=1)
+    return n, slot, index, (index == 0).astype(jnp.int32), \
+        last.astype(jnp.int32)
+
+
+class Item(NamedTuple):
+    """What a kernel body knows of its grid step (scalars): ``block``,
+    the block of positions it holds; the slot's ``length`` and the
+    ``row`` its step's own row is written at; whether this is the slot's
+    ``first`` or ``last`` block."""
+    block: jax.Array
+    length: jax.Array
+    row: jax.Array
+    first: jax.Array
+    last: jax.Array
+
+
+def work_list_call(kernel, operands: tuple, *, lengths, at, max_seq: int,
+                   block: int, in_specs: list, out_specs: list,
+                   scratch_shapes: list, out_shape: list, aliases: dict,
+                   name: str, interpret: bool):
+    """``kernel(item, *refs)`` run once a live block of ``live_blocks``,
+    a one-dimensional grid of runtime bound.  A spec is ``(block shape,
+    where)``, ``where(slot, block, row)`` the block's index;
+    ``aliases``, operand -> result, count the ``operands`` alone.  The
+    lengths, the rows and the list go in by scalar prefetch.  A slot's
+    output blocks keep their index from its first step to its last, so
+    they and the scratch carry over; the dimension is ``arbitrary`` (a
+    v5e has one core)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, *items = live_blocks(lengths, max_seq, block)
+
+    def body(len_ref, at_ref, slot_ref, index_ref, first_ref, last_ref,
+             *refs):
+        i = pl.program_id(0)
+        slot = slot_ref[i]
+        kernel(Item(index_ref[i], len_ref[slot], at_ref[slot],
+                    first_ref[i] == 1, last_ref[i] == 1), *refs)
+
+    def spec(shape, where):
+        return pl.BlockSpec(shape, lambda i, lens, rows, slot, index, *_:
+                            where(slot[i], index[i], rows[slot[i]]))
+
+    prefetch = (lengths, at, *items)
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=(n,),
+            in_specs=[spec(*s) for s in in_specs],
+            out_specs=[spec(*s) for s in out_specs],
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        input_output_aliases={len(prefetch) + i: o
+                              for i, o in aliases.items()},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+        name=name,
+    )(*prefetch, *operands)
+
+
+# ---------------------------------------------------------------------------
 # The kernel
 # ---------------------------------------------------------------------------
 def _pieces(x: jax.Array) -> list:
@@ -338,10 +438,10 @@ def _fold(x: jax.Array, rows: int) -> jax.Array:
     return sum(x[i:i + rows] for i in range(0, x.shape[0], rows))
 
 
-def _attend_kernel(len_ref, at_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref,
-                   o_ref, ko_ref, vo_ref, qp_ref, m_ref, l_ref, acc_ref, *,
-                   scale: float, block: int, kv: int, group: int):
-    """One slot, one block of positions.  A block of a leaf is read as
+def _attend_kernel(item, q_ref, nk_ref, nv_ref, k_ref, v_ref, o_ref, ko_ref,
+                   vo_ref, qp_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                   block: int, kv: int, group: int):
+    """One slot, one live block of positions.  A block of a leaf is read as
     ``[block * KV, D]``, a row a (position, key-value head) pair, which
     is how it lies; query rows are ordered (group member, key-value
     head).  Scores come out ``[H, block * KV]``, every query against
@@ -351,7 +451,7 @@ def _attend_kernel(len_ref, at_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref,
     softmax runs in.  The weights go back the same way: spread over the
     rows, masked by ``mine``, one product with the values.  The step's
     own row (``nk_ref``, ``nv_ref``: [1, KV, D]) belongs at position
-    ``at_ref[slot]``: the block that holds it, always a live one, takes
+    ``item.row``: the block that holds it, always a live one, takes
     it in VMEM before the scores (the leaf in HBM has it only once the
     call is over), and ``ko_ref`` / ``vo_ref``, that one position of
     the leaves, carry it out.  Written with few operations: the kernel
@@ -360,9 +460,8 @@ def _attend_kernel(len_ref, at_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    row = at_ref[pl.program_id(0)] - j * block     # in this block, if 0..
+    j, length = item.block, item.length
+    row = item.row - j * block                     # in this block, if 0..
     h = kv * group
     lanes = block * kv
     d = k_ref.shape[-1]
@@ -397,7 +496,7 @@ def _attend_kernel(len_ref, at_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref,
             shift *= 2
         return x
 
-    @pl.when(j == 0)
+    @pl.when(item.first)
     def _init():
         qp_ref[...] = jnp.concatenate(_pieces(q_ref[0]), axis=0)
         ko_ref[0] = nk_ref[...]
@@ -416,44 +515,36 @@ def _attend_kernel(len_ref, at_ref, q_ref, nk_ref, nv_ref, k_ref, v_ref,
         k_ref[0, pl.ds(row, 1)] = nk_ref[...]
         v_ref[0, pl.ds(row, 1)] = nv_ref[...]
 
-    @pl.when(j * block < length)
-    def _accumulate():
-        # Pairs (position, head) of this block that are live: all of
-        # them but in the slot's last live block.  Masking every block
-        # costs nothing the chip shows (0.326 against 0.332 ms a layer
-        # for a second, unmasked copy of this body; a ``cond`` around
-        # the values' mask copies the block: 0.429), and lowers once.
-        live = (length - j * block) * kv
-        k = k_ref[0].reshape(lanes, d)
-        v = v_ref[0].reshape(lanes, d)
-        v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0)
-                      < live, v, jnp.zeros_like(v))        # 0 * NaN is NaN
-        s = jax.lax.dot_general(qp_ref[...], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(mine, _fold(s, h), 0.0)                  # [H, lanes]
-        s = jnp.sum(s.reshape(group, kv, lanes), axis=1) * scale
-        s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
-                      < live, s, NEG_INF)
-        m_prev = m_ref[...]                                  # [group, 128]
-        m_cur = jnp.maximum(m_prev, all_lanes(s, jnp.maximum))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - jnp.tile(m_cur, (1, lanes // _LANE)))
-        l_ref[...] = l_ref[...] * alpha + all_lanes(p, jnp.add)
-        m_ref[...] = m_cur
-        weights = jnp.where(mine, spread(p), 0.0)
-        pv = jnp.dot(jnp.concatenate(_pieces(weights), axis=0), v,
-                     preferred_element_type=jnp.float32)        # [3H, D]
-        acc_ref[...] = acc_ref[...] * a_head(alpha) + _fold(pv, h)
+    # Pairs (position, head) of this block that are live (every step's
+    # block is): all of them but in the slot's last block.  Masking
+    # every block costs nothing the chip shows (0.326 against 0.332 ms
+    # a layer for a second, unmasked copy of this body; a ``cond``
+    # around the values' mask copies the block: 0.429), and lowers once.
+    live = (length - j * block) * kv
+    k = k_ref[0].reshape(lanes, d)
+    v = v_ref[0].reshape(lanes, d)
+    v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (lanes, 1), 0)
+                  < live, v, jnp.zeros_like(v))        # 0 * NaN is NaN
+    s = jax.lax.dot_general(qp_ref[...], k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.where(mine, _fold(s, h), 0.0)                  # [H, lanes]
+    s = jnp.sum(s.reshape(group, kv, lanes), axis=1) * scale
+    s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+                  < live, s, NEG_INF)
+    m_prev = m_ref[...]                                  # [group, 128]
+    m_cur = jnp.maximum(m_prev, all_lanes(s, jnp.maximum))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - jnp.tile(m_cur, (1, lanes // _LANE)))
+    l_ref[...] = l_ref[...] * alpha + all_lanes(p, jnp.add)
+    m_ref[...] = m_cur
+    weights = jnp.where(mine, spread(p), 0.0)
+    pv = jnp.dot(jnp.concatenate(_pieces(weights), axis=0), v,
+                 preferred_element_type=jnp.float32)        # [3H, D]
+    acc_ref[...] = acc_ref[...] * a_head(alpha) + _fold(pv, h)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(item.last)
     def _finalize():
         o_ref[0] = (acc_ref[...] / a_head(l_ref[...])).astype(o_ref.dtype)
-
-
-def _live_block(slot, j, lens, *_, block: int) -> tuple:
-    """The block of a leaf that grid step ``(slot, j)`` holds: past the
-    slot's last live block the index repeats, and nothing is fetched."""
-    return (slot, jnp.minimum(j, (lens[slot] - 1) // block), 0, 0)
 
 
 # Jitted, so that the layers of a model, which call it with the same
@@ -463,7 +554,6 @@ def _live_block(slot, j, lens, *_, block: int) -> tuple:
 @functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
 def _decode_attend_pallas(q, keys, values, new_k, new_v, lengths, at, scale,
                           *, block: int, interpret: bool):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, _, h, d = q.shape
@@ -471,47 +561,38 @@ def _decode_attend_pallas(q, keys, values, new_k, new_v, lengths, at, scale,
     group = h // kv
     pieces = 1 if q.dtype == jnp.bfloat16 else 3
 
-    leaf = pl.BlockSpec((1, block, kv, d),
-                        functools.partial(_live_block, block=block))
-    head = pl.BlockSpec((1, h, d), lambda slot, j, *_: (slot, 0, 0))
-    new = pl.BlockSpec((1, kv, d), lambda slot, j, *_: (slot, 0, 0))
+    leaf = ((1, block, kv, d), lambda slot, j, row: (slot, j, 0, 0))
+    head = ((1, h, d), lambda slot, j, row: (slot, 0, 0))
+    new = ((1, kv, d), lambda slot, j, row: (slot, 0, 0))
     # A position is a major dimension here: the step's row is a block.
-    row = pl.BlockSpec((1, 1, kv, d),
-                       lambda slot, j, lens, at: (slot, at[slot], 0, 0))
+    written = ((1, 1, kv, d), lambda slot, j, row: (slot, row, 0, 0))
     # Query rows by (group member, key-value head): head kv * group + g.
     rows = q.reshape(b, kv, group, d).swapaxes(1, 2).reshape(b, h, d)
-    out, keys, values = pl.pallas_call(
+    out, keys, values = work_list_call(
         functools.partial(_attend_kernel, scale=scale, block=block, kv=kv,
                           group=group),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, s // block),
-            in_specs=[head, new, new, leaf, leaf],
-            out_specs=[head, row, row],
-            scratch_shapes=[
-                pltpu.VMEM((pieces * h, d), jnp.bfloat16),
-                pltpu.VMEM((group, _LANE), jnp.float32),
-                pltpu.VMEM((group, _LANE), jnp.float32),
-                pltpu.VMEM((h, d), jnp.float32)]),
+        (rows, new_k.reshape(b, kv, d), new_v.reshape(b, kv, d), keys,
+         values),
+        lengths=lengths, at=at, max_seq=s, block=block,
+        in_specs=[head, new, new, leaf, leaf],
+        out_specs=[head, written, written],
+        scratch_shapes=[pltpu.VMEM((pieces * h, d), jnp.bfloat16),
+                        pltpu.VMEM((group, _LANE), jnp.float32),
+                        pltpu.VMEM((group, _LANE), jnp.float32),
+                        pltpu.VMEM((h, d), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct((b, h, d), jnp.float32),
                    jax.ShapeDtypeStruct(keys.shape, keys.dtype),
                    jax.ShapeDtypeStruct(values.shape, values.dtype)],
-        input_output_aliases={5: 1, 6: 2},          # the leaves, in place
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_BYTES),
-        interpret=interpret,
-        name="hvd.decode_attend",
-    )(lengths, at, rows, new_k.reshape(b, kv, d), new_v.reshape(b, kv, d),
-      keys, values)
+        aliases={3: 1, 4: 2},                       # the leaves, in place
+        name="hvd.decode_attend", interpret=interpret)
     return out.reshape(b, group, kv, d).swapaxes(1, 2).reshape(b, 1, h, d), \
         keys, values
 
 
-def _lanes_kernel(len_ref, at_ref, q_ref, sink_ref, nk_ref, nv_ref, k_ref,
-                  v_ref, o_ref, ko_ref, vo_ref, m_ref, l_ref, acc_ref, *,
-                  scale: float, block: int, tile: int, kv: int, group: int):
-    """One slot, one block of positions of leaves with the heads in the
+def _lanes_kernel(item, q_ref, sink_ref, nk_ref, nv_ref, k_ref, v_ref, o_ref,
+                  ko_ref, vo_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                  block: int, tile: int, kv: int, group: int):
+    """One slot, one live block of positions of leaves with the heads in the
     lanes: ``k_ref`` [block, KV * D], ``v_ref`` [block, KV * Dv].  A
     query row holds its head's query at that head's lanes and zeros
     elsewhere, so one product with the key rows gives every head's
@@ -521,19 +602,18 @@ def _lanes_kernel(len_ref, at_ref, q_ref, sink_ref, nk_ref, nv_ref, k_ref,
     head's sink and the sum at 1 where there is one (its column has no
     value), at ``NEG_INF`` and 0 where not.  The step's own row
     (``nk_ref``, ``nv_ref``: [1, width]) belongs at position
-    ``at_ref[slot]``: the block that holds it, always a live one, takes
+    ``item.row``: the block that holds it, always a live one, takes
     it in VMEM before the scores (the leaf in HBM has it only once the
     call is over), and its aligned ``tile`` of positions goes out
     through ``ko_ref`` / ``vo_ref`` with the row in it."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    row = at_ref[pl.program_id(0)] - j * block     # in this block, if 0..
+    j, length = item.block, item.length
+    row = item.row - j * block                     # in this block, if 0..
     h = kv * group
     dv = v_ref.shape[-1] // kv
 
-    @pl.when(j == 0)
+    @pl.when(item.first)
     def _init():
         m_ref[...] = sink_ref[...]
         l_ref[...] = jnp.where(sink_ref[...] > NEG_INF / 2, 1.0, 0.0)
@@ -551,35 +631,33 @@ def _lanes_kernel(len_ref, at_ref, q_ref, sink_ref, nk_ref, nv_ref, k_ref,
             leaf[0, rows] = jnp.where(new, fresh[0], leaf[0, rows])
             out[0] = leaf[0, rows]
 
-    @pl.when(j * block < length)
-    def _accumulate():
-        live = length - j * block
-        v = v_ref[0]
-        v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-                      < live, v, jnp.zeros_like(v))        # 0 * NaN is NaN
-        s = jax.lax.dot_general(q_ref[0], k_ref[0],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = _fold(s, h) * scale                              # [H, block]
-        s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-                      < live, s, NEG_INF)
-        m_prev = m_ref[...]                                  # [H, 128]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, :1])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_cur
-        weights = _pieces(p)
-        for head in range(kv):
-            rows = slice(head * group, (head + 1) * group)
-            pv = jnp.dot(
-                jnp.concatenate([w[rows] for w in weights], axis=0),
-                v[:, head * dv:(head + 1) * dv],
-                preferred_element_type=jnp.float32)      # [3 group, Dv]
-            acc_ref[rows] = acc_ref[rows] * alpha[rows, :1] \
-                + _fold(pv, group)
+    live = length - j * block
+    v = v_ref[0]
+    v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+                  < live, v, jnp.zeros_like(v))        # 0 * NaN is NaN
+    s = jax.lax.dot_general(q_ref[0], k_ref[0],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = _fold(s, h) * scale                              # [H, block]
+    s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+                  < live, s, NEG_INF)
+    m_prev = m_ref[...]                                  # [H, 128]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur[:, :1])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_cur
+    weights = _pieces(p)
+    for head in range(kv):
+        rows = slice(head * group, (head + 1) * group)
+        pv = jnp.dot(
+            jnp.concatenate([w[rows] for w in weights], axis=0),
+            v[:, head * dv:(head + 1) * dv],
+            preferred_element_type=jnp.float32)      # [3 group, Dv]
+        acc_ref[rows] = acc_ref[rows] * alpha[rows, :1] \
+            + _fold(pv, group)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(item.last)
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
@@ -589,8 +667,7 @@ def _lanes_kernel(len_ref, at_ref, q_ref, sink_ref, nk_ref, nv_ref, k_ref,
 # (Pallas writes an output block back whole, so it is filled from the
 # block in VMEM: 49 KB of MiMo's 1,536 lanes, where the block is 786).
 # What the write costs on the chip is not these bytes but the five more
-# blocked operands' bookkeeping, 0.07 to 0.12 us a grid step, dead
-# steps too (PERF.md, PR 39).
+# blocked operands' bookkeeping, 0.07 to 0.12 us a grid step on a v5e.
 _WRITE_TILE = 16
 
 
@@ -599,7 +676,6 @@ _WRITE_TILE = 16
 def _decode_attend_lanes(q, keys, values, new_k, new_v, lengths, at, sink,
                          scale, *, block: int, interpret: bool,
                          name: str = "hvd.decode_attend"):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, _, h, d = q.shape
@@ -616,38 +692,31 @@ def _decode_attend_lanes(q, keys, values, new_k, new_v, lengths, at, sink,
         .reshape(b, h, width) for piece in _pieces(q)], axis=1)
     start = jnp.full((h,), NEG_INF, jnp.float32) if sink is None \
         else sink.astype(jnp.float32)
-    a_slot = lambda n, w: pl.BlockSpec(                      # noqa: E731
-        (1, n, w), lambda slot, j, *_: (slot, 0, 0))
-    leaf = lambda w: pl.BlockSpec(                           # noqa: E731
-        (1, block, w), lambda *at: _live_block(*at, block=block)[:3])
-    written = lambda w: pl.BlockSpec(                        # noqa: E731
-        (1, tile, w), lambda slot, j, lens, at: (slot, at[slot] // tile, 0))
-    out, keys, values = pl.pallas_call(
+    a_slot = lambda n, w: ((1, n, w),                        # noqa: E731
+                           lambda slot, j, row: (slot, 0, 0))
+    leaf = lambda w: ((1, block, w),                         # noqa: E731
+                      lambda slot, j, row: (slot, j, 0))
+    written = lambda w: ((1, tile, w),                       # noqa: E731
+                         lambda slot, j, row: (slot, row // tile, 0))
+    out, keys, values = work_list_call(
         functools.partial(_lanes_kernel, scale=scale, block=block, tile=tile,
                           kv=kv, group=group),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, s // block),
-            in_specs=[a_slot(rows.shape[1], width),
-                      pl.BlockSpec((h, _LANE), lambda slot, j, *_: (0, 0)),
-                      a_slot(1, width), a_slot(1, values.shape[-1]),
-                      leaf(width), leaf(values.shape[-1])],
-            out_specs=[a_slot(h, dv), written(width),
-                       written(values.shape[-1])],
-            scratch_shapes=[pltpu.VMEM((h, _LANE), jnp.float32),
-                            pltpu.VMEM((h, _LANE), jnp.float32),
-                            pltpu.VMEM((h, dv), jnp.float32)]),
+        (rows, jnp.broadcast_to(start[:, None], (h, _LANE)), new_k, new_v,
+         keys, values),
+        lengths=lengths, at=at, max_seq=s, block=block,
+        in_specs=[a_slot(rows.shape[1], width),
+                  ((h, _LANE), lambda slot, j, row: (0, 0)),
+                  a_slot(1, width), a_slot(1, values.shape[-1]),
+                  leaf(width), leaf(values.shape[-1])],
+        out_specs=[a_slot(h, dv), written(width), written(values.shape[-1])],
+        scratch_shapes=[pltpu.VMEM((h, _LANE), jnp.float32),
+                        pltpu.VMEM((h, _LANE), jnp.float32),
+                        pltpu.VMEM((h, dv), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
                    jax.ShapeDtypeStruct(keys.shape, keys.dtype),
                    jax.ShapeDtypeStruct(values.shape, values.dtype)],
-        input_output_aliases={6: 1, 7: 2},          # the leaves, in place
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_BYTES),
-        interpret=interpret,
-        name=name,
-    )(lengths, at, rows, jnp.broadcast_to(start[:, None], (h, _LANE)),
-      new_k, new_v, keys, values)
+        aliases={4: 1, 5: 2},                       # the leaves, in place
+        name=name, interpret=interpret)
     return out.reshape(b, 1, h, dv), keys, values
 
 

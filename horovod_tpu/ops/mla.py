@@ -25,9 +25,9 @@ A.X-K1's widths, against 64 heads x 320 expanded), zeros to whole lanes
 the unit tests) it is one Pallas kernel named ``hvd.mla_decode`` (a
 device trace selects an operation by ``<opcode> <name>``), which reads
 each slot's latent rows in blocks **up to the slot's own live length**
-(the lengths are a scalar prefetch; a block past a slot's length repeats
-the one before it, so nothing is fetched, and its arithmetic is
-skipped), computes ``[H x W] . [W x block]`` scores and ``[H x block] .
+(its grid is ``ops/decode_attention.py``'s work list of live blocks,
+built from the lengths: a block past a slot's length costs no grid
+step), computes ``[H x W] . [W x block]`` scores and ``[H x block] .
 [block x rank]`` outputs on the matrix unit with the online softmax
 across blocks, and **writes the step's latent row itself** (ISSUE 39's
 rule: it lays the row into the block that holds its position in VMEM,
@@ -44,13 +44,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .decode_attention import NEG_INF, _LANE, _VMEM_BYTES, write_rows
+from .decode_attention import NEG_INF, _LANE, work_list_call, write_rows
 
 KERNEL = "hvd.mla_decode"
 # One block of latent rows in VMEM (double-buffered: two of them): 1,024
 # positions of A.X-K1's 640 bfloat16 channels, so that a slot of 14,336
-# positions is 14 grid steps (a grid step costs some 0.35 us whatever it
-# reads: PERF.md, PRs 36 and 39).
+# positions is at most 14 grid steps (a grid step costs some 0.35 us on
+# a v5e whatever it reads).
 _BLOCK_BYTES = 2 << 20
 _WRITE_TILE = 16     # positions of bfloat16 rows a packed tile holds
 
@@ -153,24 +153,22 @@ def kernel_block(shape: tuple, dtype, interpret: bool = False) -> int:
 # ---------------------------------------------------------------------------
 # The kernel
 # ---------------------------------------------------------------------------
-def _mla_kernel(len_ref, at_ref, q_ref, new_ref, c_ref, o_ref, co_ref, m_ref,
-                l_ref, acc_ref, *, scale: float, block: int, tile: int,
-                rank: int):
-    """One slot, one block of latent rows ``c_ref`` [1, block, W]: scores
+def _mla_kernel(item, q_ref, new_ref, c_ref, o_ref, co_ref, m_ref, l_ref,
+                acc_ref, *, scale: float, block: int, tile: int, rank: int):
+    """One slot, one live block of latent rows ``c_ref`` [1, block, W]: scores
     ``[H, block]`` (a query head a sublane, a position a lane) from one
     product of the queries with the rows, the online softmax across
     blocks, the weights against the rows' first ``rank`` channels.  The
     step's own row (``new_ref`` [1, 1, W]) belongs at position
-    ``at_ref[slot]``: the block that holds it, always a live one, takes
+    ``item.row``: the block that holds it, always a live one, takes
     it in VMEM before the scores, and its aligned ``tile`` of positions
     goes out through ``co_ref`` with the row in it."""
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    row = at_ref[pl.program_id(0)] - j * block     # in this block, if 0..
+    j, length = item.block, item.length
+    row = item.row - j * block                     # in this block, if 0..
 
-    @pl.when(j == 0)
+    @pl.when(item.first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -183,27 +181,25 @@ def _mla_kernel(len_ref, at_ref, q_ref, new_ref, c_ref, o_ref, co_ref, m_ref,
         c_ref[0, rows] = jnp.where(new, new_ref[0], c_ref[0, rows])
         co_ref[0] = c_ref[0, rows]
 
-    @pl.when(j * block < length)
-    def _accumulate():
-        live = length - j * block
-        c = c_ref[0]
-        c = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-                      < live, c, jnp.zeros_like(c))        # 0 * NaN is NaN
-        s = jax.lax.dot_general(q_ref[0], c, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-                      < live, s, NEG_INF)                     # [H, block]
-        m_prev = m_ref[...]                                   # [H, 128]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, :1])
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_cur
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jnp.dot(
-            p.astype(c.dtype), c[:, :rank],
-            preferred_element_type=jnp.float32)
+    live = length - j * block
+    c = c_ref[0]
+    c = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+                  < live, c, jnp.zeros_like(c))        # 0 * NaN is NaN
+    s = jax.lax.dot_general(q_ref[0], c, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+                  < live, s, NEG_INF)                     # [H, block]
+    m_prev = m_ref[...]                                   # [H, 128]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_cur)
+    p = jnp.exp(s - m_cur[:, :1])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = m_cur
+    acc_ref[...] = acc_ref[...] * alpha[:, :1] + jnp.dot(
+        p.astype(c.dtype), c[:, :rank],
+        preferred_element_type=jnp.float32)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(item.last)
     def _finalize():
         o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
 
@@ -215,42 +211,31 @@ def _mla_kernel(len_ref, at_ref, q_ref, new_ref, c_ref, o_ref, co_ref, m_ref,
                    static_argnames=("scale", "rank", "block", "interpret"))
 def _mla_pallas(q, latent, new_row, lengths, at, scale, rank, *, block: int,
                 interpret: bool):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, _, h, width = q.shape
     s = latent.shape[1]
     tile = min(_WRITE_TILE, block)
-    a_slot = lambda n, w: pl.BlockSpec(                      # noqa: E731
-        (1, n, w), lambda slot, j, *_: (slot, 0, 0))
-    rows = pl.BlockSpec(
-        (1, block, width),
-        lambda slot, j, lens, at: (slot, jnp.minimum(
-            j, (lens[slot] - 1) // block), 0))
-    written = pl.BlockSpec(
-        (1, tile, width), lambda slot, j, lens, at: (slot, at[slot] // tile,
-                                                     0))
-    out, latent = pl.pallas_call(
+    a_slot = lambda n, w: ((1, n, w),                        # noqa: E731
+                           lambda slot, j, row: (slot, 0, 0))
+    out, latent = work_list_call(
         functools.partial(_mla_kernel, scale=scale, block=block, tile=tile,
                           rank=rank),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, s // block),
-            in_specs=[a_slot(h, width), a_slot(1, width), rows],
-            out_specs=[a_slot(h, rank), written],
-            scratch_shapes=[pltpu.VMEM((h, _LANE), jnp.float32),
-                            pltpu.VMEM((h, _LANE), jnp.float32),
-                            pltpu.VMEM((h, rank), jnp.float32)]),
+        (q.reshape(b, h, width).astype(latent.dtype),
+         new_row.astype(latent.dtype), latent),
+        lengths=lengths, at=at, max_seq=s, block=block,
+        in_specs=[a_slot(h, width), a_slot(1, width),
+                  ((1, block, width), lambda slot, j, row: (slot, j, 0))],
+        out_specs=[a_slot(h, rank),
+                   ((1, tile, width),
+                    lambda slot, j, row: (slot, row // tile, 0))],
+        scratch_shapes=[pltpu.VMEM((h, _LANE), jnp.float32),
+                        pltpu.VMEM((h, _LANE), jnp.float32),
+                        pltpu.VMEM((h, rank), jnp.float32)],
         out_shape=[jax.ShapeDtypeStruct((b, h, rank), jnp.float32),
                    jax.ShapeDtypeStruct(latent.shape, latent.dtype)],
-        input_output_aliases={4: 1},                 # the leaf, in place
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_BYTES),
-        interpret=interpret,
-        name=KERNEL,
-    )(lengths, at, q.reshape(b, h, width).astype(latent.dtype),
-      new_row.astype(latent.dtype), latent)
+        aliases={2: 1},                              # the leaf, in place
+        name=KERNEL, interpret=interpret)
     return out.reshape(b, 1, h, rank), latent
 
 

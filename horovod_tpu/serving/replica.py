@@ -249,6 +249,11 @@ class ReplicaExecutor:
                       # the compiled path reads for them (a layer).
                       "attend_live_positions": 0,
                       "attend_read_positions": 0,
+                      # The grid steps that reading takes, one a live
+                      # block, and the (slots, max_seq // block) a static
+                      # grid would take (the plain form: one a slot, of
+                      # one).
+                      "attend_grid_steps": 0, "attend_grid_full": 0,
                       # The dense cache's attention layers, and those of
                       # them whose decode kernel writes the step's key
                       # and value row itself (ops/decode_attention.py:

@@ -124,19 +124,26 @@ class _SlotCache:
         lengths = np.fromiter((slots[i].seq_len + 1 for i in active),
                               np.int64, len(active))
         # A layer's worth, the mean over the attention layers: a window
-        # layer's live context ends at its ring's span.
-        live = read = 0
+        # layer's live context ends at its ring's span.  The grid steps:
+        # a kernel's, one a live block (ops/decode_attention.py:
+        # live_blocks), of the span's blocks a slot; the plain form
+        # reads a slot's whole span, one step of one.
+        live = read = steps = full = 0
         for layers, span, block in self._attend_kinds:
             within = np.minimum(lengths, span)
             live += layers * int(within.sum())
-            read += layers * decode_attention.read_positions(within, span,
-                                                             block)
+            positions = decode_attention.read_positions(within, span, block)
+            read += layers * positions
+            unit = block or span
+            steps += layers * positions // unit
+            full += layers * len(within) * (span // unit)
         layers = sum(kind[0] for kind in self._attend_kinds)
         stats = self.stats
-        stats["attend_live_positions"] = \
-            stats.get("attend_live_positions", 0) + live // layers
-        stats["attend_read_positions"] = \
-            stats.get("attend_read_positions", 0) + read // layers
+        for key, count in (("attend_live_positions", live),
+                           ("attend_read_positions", read),
+                           ("attend_grid_steps", steps),
+                           ("attend_grid_full", full)):
+            stats[key] = stats.get(key, 0) + count // layers
         return self.result
 
     def fetch(self, result) -> np.ndarray:

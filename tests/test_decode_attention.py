@@ -5,7 +5,7 @@ that reads each slot's keys and values up to its own live length.
   ragged lengths, for the 7B head shape (32 heads of 128, group 1) and a
   grouped one (group 4 over 16 key-value heads of 128), bfloat16 leaves;
 - positions past a slot's length hold NaN and must not reach the output;
-- ``read_positions`` counts the blocks the kernel's index map visits;
+- ``read_positions`` counts the blocks the kernel's work list visits;
 - which leaves the kernel takes, and the block it reads them in;
 - the lanes kernel at 8 key-value heads (ISSUE 37): Solar's cache (8
   heads of 128 under 64 query heads) and MiMo's ring (keys 192, values
@@ -15,7 +15,10 @@ that reads each slot's keys and values up to its own live length.
   attend as if it were written and write it in place, to the bit what
   ``write_rows`` writes, on caches and on rings past their first lap,
   at a cache's last row, behind a float32 query and a sink; and which
-  leaves ``kernel_writes`` says so for.
+  leaves ``kernel_writes`` says so for;
+- the grid, a work list of live blocks: ``live_blocks`` itself,
+  and the three kernels (the sublanes, the lanes, ``hvd.mla_decode``)
+  over one batch of every edge against their plain forms.
 """
 from __future__ import annotations
 
@@ -38,8 +41,9 @@ LENGTHS = {"one_edge_past_full": (1, BLOCK, BLOCK + 1, S),
 
 
 def _bits(x) -> np.ndarray:
-    """A bfloat16 array's bits: NaN equals NaN there."""
-    return np.asarray(x).view(np.uint16)
+    """An array's bits: NaN equals NaN there."""
+    x = np.asarray(x)
+    return x.view(f"u{x.dtype.itemsize}")
 
 
 def _step(leaves, lengths):
@@ -153,21 +157,28 @@ def test_the_entry_point_takes_the_kernel_where_it_finds_a_block(
                                atol=3e-6, rtol=3e-6)
 
 
+def _visited(lens, max_seq: int, block: int) -> list:
+    """The ``(slot, block)`` of each step of the kernels' grid, in order:
+    the first ``n`` items of ``live_blocks``."""
+    n, slot, index, *_ = (np.asarray(x) for x in da.live_blocks(
+        jnp.asarray(lens, jnp.int32), max_seq, block))
+    return list(zip(slot[:n].tolist(), index[:n].tolist()))
+
+
 @pytest.mark.parametrize("block", [8, 16, 64])
 @pytest.mark.parametrize("lengths", sorted(LENGTHS))
 def test_read_positions_counts_the_blocks_the_index_map_visits(lengths,
                                                                block):
-    """Walking the kernel's grid through its index map, the distinct
-    blocks of a slot are the ones ``read_positions`` counts: a dead
-    block repeats the index before it and is not fetched."""
+    """Walking the kernel's grid, its work list, the blocks of a slot
+    are the ones ``read_positions`` counts, each once: no grid step
+    holds a dead block."""
     lens = np.asarray(LENGTHS[lengths], np.int32)
-    visited = {tuple(int(i) for i in da._live_block(slot, j, lens,
-                                                    block=block))
-               for slot in range(len(lens)) for j in range(S // block)}
-    assert all(at[2:] == (0, 0) for at in visited)
+    steps = _visited(lens, S, block)
+    visited = set(steps)
+    assert len(visited) == len(steps)
     assert da.read_positions(lens, S, block) == len(visited) * block
     for slot, length in enumerate(lens):
-        blocks = sorted(at[1] for at in visited if at[0] == slot)
+        blocks = [at[1] for at in steps if at[0] == slot]
         assert blocks == list(range(-(-int(length) // block)))
     # With no kernel, a slot is read whole.
     assert da.read_positions(lens, S, 0) == len(lens) * S
@@ -223,8 +234,9 @@ def test_the_kernel_carries_its_name_and_takes_the_leaves_as_they_lie():
     operands are the leaves themselves, four-dimensional, as the program
     was handed them (merged to [B, S, KV * D] they would be copied on
     the device every step), and come back as its second and third
-    results, aliased: the step's row is written in place; the lengths
-    and the rows' positions are its scalar prefetch."""
+    results, aliased: the step's row is written in place; the lengths,
+    the rows' positions and the work list are its scalar prefetch, the
+    list's count its grid's one, runtime, bound."""
     heads, kv = SHAPES["heads32_group1"]
     q, keys, values, step, _ = _operands(heads, kv, (1, S))
     jaxpr = jax.make_jaxpr(lambda *a: da.decode_attend(
@@ -236,13 +248,18 @@ def test_the_kernel_carries_its_name_and_takes_the_leaves_as_they_lie():
     inner = inner.params["jaxpr"].jaxpr
     call, = [eqn for eqn in inner.eqns if eqn.primitive.name == "pallas_call"]
     assert call.params["name"] == "hvd.decode_attend"
-    assert call.params["grid_mapping"].num_index_operands == 2
-    lengths, at, _, new_k, new_v, k_in, v_in = call.invars
+    grid = call.params["grid_mapping"]
+    assert grid.num_index_operands == 6 and grid.num_dynamic_grid_bounds == 1
+    n, lengths, at, *work, _, new_k, new_v, k_in, v_in = call.invars
+    assert n.aval.shape == () and n.aval.dtype == jnp.int32
     assert lengths.aval.shape == at.aval.shape == (2,)
     assert lengths.aval.dtype == at.aval.dtype == jnp.int32
+    # one block a slot of 64 positions here: the list is as long
+    assert [(x.aval.shape, x.aval.dtype) for x in work] \
+        == [((2,), jnp.int32)] * 4
     assert new_k.aval.shape == new_v.aval.shape == (2, kv, D)
     assert k_in is inner.invars[1] and v_in is inner.invars[2]
-    assert call.params["input_output_aliases"] == ((5, 1), (6, 2))
+    assert call.params["input_output_aliases"] == ((9, 1), (10, 2))
     assert [v.aval.shape for v in call.outvars[1:]] == [keys.shape] * 2
     assert "pallas_call" not in str(jax.make_jaxpr(
         lambda *a: da.decode_attend(*a, 0.1))(q, keys, values, *step))
@@ -309,7 +326,7 @@ def test_read_positions_is_the_block_the_entry_point_takes(shape,
     """``decode_attend`` interpreted hands the lanes kernel the block
     ``kernel_block`` names for the very leaves (what
     ``serving/slotcache.py`` counts with), under the layer kind's name;
-    walking the kernel's index map in that block visits the positions
+    walking the kernel's work list in that block visits the positions
     ``read_positions`` counts."""
     spec = KV8[shape]
     q, merged, step, sink, clean = _lanes_operands(shape)
@@ -334,10 +351,7 @@ def test_read_positions_is_the_block_the_entry_point_takes(shape,
     want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.09, sink)
     np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
     host = np.asarray(lens)
-    visited = {tuple(int(i) for i in da._live_block(slot, j, host,
-                                                    block=block))
-               for slot in range(len(host))
-               for j in range(spec["s"] // block)}
+    visited = _visited(host, spec["s"], block)
     assert da.read_positions(host, spec["s"], block) == len(visited) * block
     # The plain form over the same leaves reads every slot whole.
     assert da.read_positions(host, spec["s"], 0) == len(host) * spec["s"]
@@ -358,8 +372,8 @@ def test_a_cache_and_a_ring_carry_their_own_kernel_names(shape):
     """One pallas_call: ``hvd.decode_attend`` over a cache,
     ``hvd.window_attend`` over a ring (a device trace selects an
     operation by its name alone, and the two are counted apart); the
-    leaves go in as they lie and come back aliased, the lengths and the
-    rows' positions as the scalar prefetch."""
+    leaves go in as they lie and come back aliased, the lengths, the
+    rows' positions and the work list as the scalar prefetch."""
     spec = KV8[shape]
     q, merged, step, sink, _ = _lanes_operands(shape)
     jaxpr = jax.make_jaxpr(lambda q, k, v, *step: da.decode_attend(
@@ -370,11 +384,11 @@ def test_a_cache_and_a_ring_carry_their_own_kernel_names(shape):
     named = str(call.params["name"]) \
         + str(call.params.get("name_and_src_info"))
     assert spec["scope"] in named and other.pop() not in named
-    assert call.params["grid_mapping"].num_index_operands == 2
+    assert call.params["grid_mapping"].num_index_operands == 6
     assert [v.aval.shape for v in call.invars[-2:]] \
         == [v.aval.shape for v in call.outvars[1:]] \
         == [x.shape for x in merged]
-    assert call.params["input_output_aliases"] == ((6, 1), (7, 2))
+    assert call.params["input_output_aliases"] == ((10, 1), (11, 2))
 
 
 # --- the step's own row, written by the kernel (ISSUE 39) -------------------
@@ -490,3 +504,114 @@ def test_the_kernel_writes_wherever_a_kernel_attends(
     # the TPU are the kernel's).
     assert da.kernel_writes(keys, dtype, values, sink, interpret=True) \
         is (want or not on_tpu)
+
+
+# --- the grid, a work list of live blocks --------------------------------------
+@pytest.mark.parametrize("lengths, max_seq, block", [
+    ((1, 16, 17, 64), 64, 16),       # 1, a block, one past it, max_seq
+    ((64, 1, 1, 33), 64, 16),        # full first, then slots of one
+    ((1, 7, 16, 16), 16, 16),        # a ring of one block: the old grid
+    ((5,), 64, 8),
+    ((1, 1), 64, 64),
+], ids=["edges", "full_then_ones", "ring", "one_slot", "one_block"])
+def test_live_blocks_lists_every_live_block_once_in_order(lengths, max_seq,
+                                                          block):
+    """``live_blocks``: the count is ``sum(ceil(length / block))``; the
+    items go slot after slot, a slot's blocks in order; the first and
+    last flags mark each slot's ends (a slot of one position is one item,
+    both); the arrays are as long as the static grid, and the items past
+    the count repeat the last."""
+    n, slot, index, first, last = (np.asarray(x) for x in da.live_blocks(
+        jnp.asarray(lengths, jnp.int32), max_seq, block))
+    blocks = [-(-length // block) for length in lengths]
+    want = [(b, j) for b, k in enumerate(blocks) for j in range(k)]
+    assert int(n) == len(want) == sum(blocks)
+    assert n.dtype == slot.dtype == index.dtype == first.dtype \
+        == last.dtype == np.int32
+    for x in (slot, index, first, last):
+        assert x.shape == (len(lengths) * max_seq // block,)
+    assert list(zip(slot[:n].tolist(), index[:n].tolist())) == want
+    assert first[:n].tolist() == [int(j == 0) for _, j in want]
+    assert last[:n].tolist() == [int(j == blocks[b] - 1) for b, j in want]
+    for b, length in enumerate(lengths):
+        if length == 1:
+            mine = np.flatnonzero(slot[:n] == b)
+            assert len(mine) == 1 and first[mine[0]] == last[mine[0]] == 1
+    for x in (slot, index, first, last):
+        assert (x[n:] == x[n - 1]).all()
+    if max_seq == block:
+        assert int(n) == len(lengths)        # a ring: a slot, one step
+
+
+# kernel -> its shapes: query heads, key-value heads, key and value widths
+# (an MLA leaf: one row of ``width``, the first ``rank`` its value)
+GRID_KERNELS = {
+    "sublanes": dict(heads=64, kv=16, dk=D, dv=D),
+    "lanes": dict(heads=16, kv=8, dk=192, dv=128, sink=True),
+    "mla": dict(heads=16, width=128, rank=32),
+}
+# layout -> (positions a slot, lengths, rows): lengths 1, a block, one
+# past it, max_seq, and a full slot whose row lands in its first block
+# (a ring past its lap writes so) beside one whose row ends its last; a
+# ring whose span is one block, past its first lap in its last slot.
+GRID_LAYOUTS = {
+    "cache": (S, (1, BLOCK, BLOCK + 1, S, S), (0, BLOCK - 1, BLOCK, S - 1, 3)),
+    "ring": (BLOCK, (1, 7, BLOCK, BLOCK), (0, 6, BLOCK - 1, 2)),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(GRID_LAYOUTS))
+@pytest.mark.parametrize("kernel", sorted(GRID_KERNELS))
+def test_the_work_list_kernels_are_the_plain_forms(kernel, layout):
+    """Each of the three kernels, interpreted in blocks of 16, over one
+    batch of every edge of the work list: the leaves hold NaN past each
+    length and where the step's row goes.  The output is the plain
+    form's over the leaves ``write_rows`` writes; the leaves that come
+    back are those, to the bit: the row where it goes, nothing else
+    changed."""
+    from horovod_tpu.ops import mla
+
+    spec = GRID_KERNELS[kernel]
+    s, lengths, at = GRID_LAYOUTS[layout]
+    b = len(lengths)
+    lens, rows_at = (jnp.asarray(x, jnp.int32) for x in (lengths, at))
+    rng = np.random.default_rng(42)
+    dead = (np.arange(s)[None, :] >= np.asarray(lengths)[:, None]) \
+        | (np.arange(s)[None, :] == np.asarray(at)[:, None])
+    dtype = jnp.float32 if kernel == "mla" else jnp.bfloat16
+    widths = [(spec["width"],)] if kernel == "mla" else \
+        [(spec["kv"] * w,) if kernel == "lanes" else (spec["kv"], w)
+         for w in (spec["dk"], spec["dv"])]
+    leaves = [jnp.asarray(np.where(
+        dead.reshape(b, s, *(1,) * len(w)), np.nan,
+        rng.standard_normal((b, s, *w))), dtype) for w in widths]
+    new = [jnp.asarray(rng.standard_normal((b, 1, *w)), dtype)
+           for w in widths]
+    written = [da.write_rows(leaf, row, rows_at)
+               for leaf, row in zip(leaves, new)]
+    clean = [jnp.nan_to_num(x) for x in written]  # the plain form reads all
+    q = jnp.asarray(rng.standard_normal(
+        (b, 1, spec["heads"], spec.get("dk", spec.get("width")))), dtype)
+    if kernel == "mla":
+        got, *back = mla._mla_pallas(q, *leaves, *new, lens, rows_at, 0.1,
+                                     spec["rank"], block=BLOCK,
+                                     interpret=True)
+        want = mla.mla_plain(q, *clean, lens[:, None] - 1, 0.1, spec["rank"])
+    elif kernel == "lanes":
+        sink = jnp.asarray(rng.standard_normal(spec["heads"]), jnp.float32)
+        got, *back = da._decode_attend_lanes(
+            q, *leaves, *new, lens, rows_at, sink, 0.1, block=BLOCK,
+            interpret=True)
+        want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.1, sink)
+    else:
+        got, *back = da._decode_attend_pallas(
+            q, *leaves, *new, lens, rows_at, 0.1, block=BLOCK, interpret=True)
+        want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.1)
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
+    row = np.arange(s)[None, :] == np.asarray(at)[:, None]
+    for mine, leaf, theirs in zip(back, leaves, written):
+        np.testing.assert_array_equal(_bits(mine), _bits(theirs))
+        np.testing.assert_array_equal(_bits(mine)[~row],
+                                      _bits(leaf)[~row])
+        assert not np.isnan(np.asarray(mine, np.float32)[row]).any()

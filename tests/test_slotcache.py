@@ -331,6 +331,8 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
     with the plain form, past a wrap of the ring; ``stats`` counts the
     live positions and what the compiled path reads for them in the
     blocks it really takes, a layer's worth (the mean over the layers),
+    the grid steps, one a live block, beside the static grid's (the
+    plain form: one a slot of one),
     and the layers whose kernel writes the step's row itself (ISSUE 39:
     all of them with the kernel, none on the CPU path); a slot released
     and admitted again serves its new request's tokens; one decode
@@ -391,7 +393,7 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
             last[rid] = cache.admit(params, rid, prompt, 8)
             slots[rid] = types.SimpleNamespace(seq_len=len(prompt))
             got[rid] = [int(last[rid])]
-        live = read = 0
+        live = read = grid = full = 0
         for step in range(steps):
             # The host's tokens first, then the last result's own.
             nxt = cache.fetch(cache.decode(
@@ -402,6 +404,10 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
             live += sum(n * held for n, held, _ in within) // layers
             read += sum(n * (-(-held // block) * block if kernel else span)
                         for n, held, span in within) // layers
+            grid += sum(n * (-(-held // block) if kernel else 1)
+                        for n, held, _ in within) // layers
+            full += sum(n * (span // block if kernel else 1)
+                        for n, _, span in within) // layers
             for rid in prompts:
                 last[rid] = -1
                 slots[rid].seq_len += 1
@@ -410,6 +416,15 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
         assert stats["attend_live_positions"] == live
         assert stats["attend_read_positions"] == read
         assert read > live
+        # Every attention layer is a kernel's here, or none is: the plain
+        # form takes one step of one a slot.
+        assert (stats["attend_grid_steps"], stats["attend_grid_full"]) \
+            == (grid, full)
+        assert 0 < grid < full if kernel else grid == full == steps * 3
+        # 18..21, 4..7 and 32..35 positions are 2, 1 and 2 (then 3)
+        # blocks of 16 a step, of the static grid's 4 a slot.
+        assert not (kernel and decoder == "heads16") \
+            or (grid, full) == (5 + 3 * 6, steps * 3 * 4)
         # 18..21 and 4..7 positions read 32 and 16 a step in blocks of
         # 16; 32 positions read 32, then 33..35 read 48.
         assert not (kernel and decoder == "heads16") \
