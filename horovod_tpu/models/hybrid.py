@@ -45,6 +45,14 @@ switch there:
   frequencies and score scale (``rope_scaling``), and in the router
   expert groups (``n_group``, ``topk_group``).
 
+- for ByteDance's Ouro (``ouro``, a looped language model): the stack
+  run ``loops`` times a token with the same weights, ``final_norm``
+  after every pass and the head after the last, each pass with a cache
+  of its own (``models/kvcache.py``: pass ``u`` > 1 keeps its leaves
+  under the scope ``pass_<u>``), and ``sandwich_norm``: an RMSNorm after
+  each sub-layer as well as before it,
+  ``h + norm_post(f(norm_pre(h)))``.
+
 RMSNorm, the gated MLP and ``apply_rope`` are ``transformer.py``'s.
 
 Serving (``decode=True``): the cache collection keeps the attention
@@ -150,6 +158,10 @@ class HybridConfig:
     embedding_multiplier: float = 1.0
     logits_scaling: float = 1.0
     tie_embeddings: bool = True
+    # passes of the whole stack a token, the weights shared, final_norm
+    # after each; a norm after each sub-layer too (sandwich)
+    loops: int = 1
+    sandwich_norm: bool = False
     rms_norm_eps: float = 1e-5
     max_seq_len: int = 256
     dtype: Any = jnp.bfloat16
@@ -177,6 +189,13 @@ class HybridConfig:
         if self.attn_rotary_dim % 2 or self.attn_rotary_dim > self.head_dim:
             raise ValueError(f"{self.attn_rotary_dim} rotary channels of "
                              f"{self.head_dim}")
+        # A pass keeps a cache of its own in cached_attention's leaves
+        # alone: a recurrent state or a latent leaf would be shared.
+        looped = set(self.layer_types) - {"attention", "window"}
+        if self.loops < 1 or (self.loops > 1 and looped):
+            raise ValueError(f"loops={self.loops}: a stack runs at least "
+                             "once, and more often only where every layer "
+                             f"is attention (not {sorted(looped)})")
 
     @property
     def head_dim(self) -> int:
@@ -275,12 +294,14 @@ class GroupedAttention(nn.Module):
     values ``cfg.value_dim``; no position term unless
     ``attn_rotary_dim``.  ``windowed`` is the ``"window"`` kind: the
     last ``cfg.window`` keys, and the window's own key-value heads,
-    rotary base and sink."""
+    rotary base and sink.  ``loop``: the pass of the stack this call
+    belongs to, whose cache it reads and writes."""
     cfg: HybridConfig
     windowed: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array, lengths=None) -> jax.Array:
+    def __call__(self, x: jax.Array, lengths=None, loop: int = 0
+                 ) -> jax.Array:
         cfg = self.cfg
         dense = partial(nn.DenseGeneral, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype)
@@ -307,7 +328,8 @@ class GroupedAttention(nn.Module):
             out = cached_attention(
                 self, q, k, v, max_seq_len=cfg.max_seq_len,
                 dtype=cfg.dtype, scale=cfg.attention_multiplier,
-                rotate=rotate, window=window, sink=sink, lengths=lengths)
+                rotate=rotate, window=window, sink=sink, lengths=lengths,
+                loop=loop)
         else:
             positions = jnp.arange(x.shape[1])[None, :]
             if rotate is not None:
@@ -573,19 +595,22 @@ class HybridBlock(nn.Module):
     dense: bool = False     # the MLP of d_ff, whatever num_experts says
 
     @nn.compact
-    def __call__(self, x: jax.Array, lengths=None) -> jax.Array:
+    def __call__(self, x: jax.Array, lengths=None, loop: int = 0
+                 ) -> jax.Array:
         cfg = self.cfg
         norm = partial(RMSNorm, cfg.dtype, cfg.param_dtype,
                        cfg.rms_norm_eps)
         mixed = norm(name="mixer_norm")(x)
         if self.kind in ("attention", "window"):
             mixed = GroupedAttention(cfg, self.kind == "window",
-                                     name="attn")(mixed, lengths)
+                                     name="attn")(mixed, lengths, loop)
         elif self.kind == "latent":
             mixed = LatentAttention(cfg, name="attn")(mixed)
         else:
             mixer = Mamba2Mixer if self.kind == "mamba" else KDAMixer
             mixed = mixer(cfg, name=self.kind)(mixed, lengths)
+        if cfg.sandwich_norm:
+            mixed = norm(name="mixer_post_norm")(mixed)
         x = x + cfg.residual_multiplier * mixed
         ffn = MLP(cfg, name="mlp") if self.dense or not cfg.num_experts \
             else moe.RoutedExperts(
@@ -594,13 +619,17 @@ class HybridBlock(nn.Module):
                 cfg.routed_scaling, cfg.dtype, cfg.param_dtype,
                 cfg.interpret, bias=cfg.router_bias,
                 groups=(cfg.n_group, cfg.topk_group), name="moe")
-        return x + cfg.residual_multiplier * ffn(norm(name="mlp_norm")(x))
+        out = ffn(norm(name="mlp_norm")(x))
+        if cfg.sandwich_norm:
+            out = norm(name="mlp_post_norm")(out)
+        return x + cfg.residual_multiplier * out
 
 
 class HybridLM(nn.Module):
     """``apply(variables, tokens [B, T]) -> logits [B, T, vocab]`` in
     ``cfg.dtype``; ``lengths`` gives the true lengths of right-padded
-    rows to a prefill through the cache."""
+    rows to a prefill through the cache.  The layers run ``cfg.loops``
+    times over, the same modules each pass, ``final_norm`` after each."""
     cfg: HybridConfig
 
     @nn.compact
@@ -610,11 +639,15 @@ class HybridLM(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="embed")
         x = embed(tokens) * cfg.embedding_multiplier
-        for i, kind in enumerate(cfg.layer_types):
-            x = HybridBlock(cfg, kind, i in cfg.dense_layers,
-                            name=f"layer_{i}")(x, lengths)
-        x = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.rms_norm_eps,
-                    name="final_norm")(x)
+        blocks = [HybridBlock(cfg, kind, i in cfg.dense_layers,
+                              name=f"layer_{i}")
+                  for i, kind in enumerate(cfg.layer_types)]
+        final_norm = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.rms_norm_eps,
+                             name="final_norm")
+        for loop in range(cfg.loops):
+            for block in blocks:
+                x = block(x, lengths, loop)
+            x = final_norm(x)
         logits = embed.attend(x) if cfg.tie_embeddings \
             else nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                           param_dtype=cfg.param_dtype, name="lm_head")(x)

@@ -15,7 +15,9 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
   keeps one leaf, ``latent`` [B, max_seq_len, W], a position's
   normalised latent beside its rotated shared key, zeros to whole lanes
   (576 of A.X-K1's 640), read by every head
-  (``latent_attention``, ``ops/mla.py``);
+  (``latent_attention``, ``ops/mla.py``); a looped stack (Ouro's) keeps
+  a layer's leaves once a pass, those of the passes after the first
+  under ``pass_<u>`` (``pass_scope``);
 - paged: ``key_pool`` / ``value_pool`` [blocks + 1, block_tokens, KV, D]
   shared by every row and addressed through block tables, the last row
   a write sink for padded positions (``paged_attention``, ``paged_*``).
@@ -59,10 +61,25 @@ from ..ops.decode_attention import (attend_blocked, attend_plain as attend,
 PLAIN_PREFILL_BYTES = 1 << 30
 
 
+PASS_SCOPE = "pass_"
+
+
+def pass_scope(loop: int) -> str:
+    """The scope under which a layer's leaves of pass ``loop`` of a looped
+    stack lie (``loop`` from 0; the first pass keeps the layer's own)."""
+    return f"{PASS_SCOPE}{loop + 1}"
+
+
+def later_pass(path: tuple) -> bool:
+    """Whether the cache leaf at ``path`` (its keys, outermost first)
+    belongs to a pass of a looped stack after the first."""
+    return any(key.startswith(PASS_SCOPE) for key in path)
+
+
 def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
                      max_seq_len: int, dtype, scale: float,
                      rotate=None, window: int = 0, sink=None,
-                     lengths=None) -> jax.Array:
+                     lengths=None, loop: int = 0) -> jax.Array:
     """Incremental attention over ``module``'s dense cache: write this
     call's K/V at each row's own depth, attend over the cached prefix
     (a decode step: ``decode_attend`` does both, in one kernel where it
@@ -82,21 +99,27 @@ def cached_attention(module, q: jax.Array, k: jax.Array, v: jax.Array, *,
     whole prompt or one token) attends over its own keys and values in
     blocks, then the ring takes the last ``window`` positions before
     ``lengths``, the true length of a right-padded row, never the
-    padding."""
+    padding.
+
+    ``loop``: the pass of a looped stack (``HybridConfig.loops``) this
+    call belongs to.  Every pass keeps leaves of its own, of the same
+    names: the first the module's, pass ``u`` > 1 under ``pass_<u>``
+    (``pass_scope``), so a slot cache holds them as further layers."""
     b, t, kv, d = k.shape
     h, dv = q.shape[2], v.shape[-1]
     rows = window or max_seq_len
     names = ("ring_key", "ring_value") if window \
         else ("cached_key", "cached_value")
-    starts = not module.has_variable("cache", names[0])
+    holder = module.scope.push(pass_scope(loop)) if loop else module
+    starts = not holder.has_variable("cache", names[0])
     lanes = decode_attention.lanes_layout(kv, d, dv, dtype)
 
     def leaf(name, wide):
         shape = (b, rows, kv * wide) if lanes else (b, rows, kv, wide)
-        return module.variable("cache", name, jnp.zeros, shape, dtype)
+        return holder.variable("cache", name, jnp.zeros, shape, dtype)
 
     cached_k, cached_v = leaf(names[0], d), leaf(names[1], dv)
-    index = module.variable("cache", "cache_index",
+    index = holder.variable("cache", "cache_index",
                             lambda: jnp.zeros((b,), jnp.int32))
     idx = index.value                                       # [B]
     positions = idx[:, None] + jnp.arange(t)[None, :]       # [B, T]

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..common.logging import logger
-from ..models.kvcache import summed
+from ..models.kvcache import later_pass, summed
 from ..ops import decode_attention, mla
 from ..telemetry.spans import span
 from .kvpool import FNV_SEED, KVBlockPool, chain_hash
@@ -173,10 +173,14 @@ class _SlotCache:
         stats["state_bytes"] = sum(
             leaf.nbytes for path, leaf in leaves
             if path[-1].key in self.family.state_leaves)
-        # Of the rest (keys, values, cursors), the window layers' rings.
+        # Of the rest (keys, values, cursors), the window layers' rings,
+        # and a looped stack's leaves of the passes after its first.
         stats["window_bytes"] = sum(
             leaf.nbytes for path, leaf in leaves
             if path[-1].key in ("ring_key", "ring_value"))
+        stats["loop_cache_bytes"] = sum(
+            leaf.nbytes for path, leaf in leaves
+            if later_pass(tuple(k.key for k in path)))
         stats["cache_aliased_bytes"] = \
             decode_program.memory_analysis().alias_size_in_bytes
         logger.info("serving: slot cache %.2f of %.2f GB aliased by the "
@@ -254,9 +258,10 @@ class DenseSlotCache(_SlotCache):
                 kind = (keys.shape[1], decode_attention.kernel_block(
                     keys.shape, keys.dtype, values=values.shape))
                 kinds[kind] = kinds.get(kind, 0) + 1
-                layer = params
+                layer = params          # a later pass's leaves: its layer's
                 for key in path[:-1]:
-                    layer = layer.get(key, {})
+                    if not later_pass((key,)):
+                        layer = layer.get(key, {})
                 fused += decode_attention.kernel_writes(
                     keys.shape, keys.dtype, values.shape, "sink" in layer)
         self._attend_kinds = [(layers, span, block)

@@ -254,7 +254,10 @@ def hybrid_decode_flops(cfg: Any, context_len: float) -> float:
     latent row's ``kv_lora_rank + qk_rope_head_dim`` channels and values
     over its ``kv_lora_rank``, a head and a position: 139,264 operations
     a position at A.X-K1's widths, where the expanded form's keys and
-    values would cost 40,960 and the expansion of every cached latent."""
+    values would cost 40,960 and the expansion of every cached latent.
+    A looped stack (``loops``) runs every layer that many times a token,
+    each pass over a cache of its own: all of the above but the head
+    counts once a pass."""
     d = cfg.d_model
     kinds = cfg.layer_types
     mamba, delta, window, latent = (
@@ -274,22 +277,23 @@ def hybrid_decode_flops(cfg: Any, context_len: float) -> float:
             d * cfg.num_experts + 3 * d * cfg.expert_ff * (
                 cfg.experts_per_token * held + cfg.shared_experts))
     inner, rank = cfg.kda_inner, cfg.kda_rank
-    weights = (mamba * (d * (2 * cfg.d_inner + 2 * cfg.mamba_state
-                             + cfg.mamba_heads) + cfg.d_inner * d)
-               + delta * (d * (3 * inner + 2 * rank + cfg.kda_heads)
-                          + 2 * rank * inner + inner * d)
-               + attn * projections(cfg.num_kv_heads)
-               + window * projections(cfg.window_kv_heads
-                                      or cfg.num_kv_heads)
-               + latent * _latent_projections(cfg)
-               + ffn + d * cfg.vocab_size)
+    layers = (mamba * (d * (2 * cfg.d_inner + 2 * cfg.mamba_state
+                            + cfg.mamba_heads) + cfg.d_inner * d)
+              + delta * (d * (3 * inner + 2 * rank + cfg.kda_heads)
+                         + 2 * rank * inner + inner * d)
+              + attn * projections(cfg.num_kv_heads)
+              + window * projections(cfg.window_kv_heads
+                                     or cfg.num_kv_heads)
+              + latent * _latent_projections(cfg)
+              + ffn)
     update = 6.0 * mamba * cfg.mamba_heads * cfg.mamba_head_dim \
         * cfg.mamba_state + 8.0 * delta * cfg.kda_heads * cfg.kda_head_dim ** 2
     seen = attn * context_len + window * min(context_len, cfg.window)
     row = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-    return 2.0 * weights + update + 2.0 * (width + wide) * seen \
+    a_pass = 2.0 * layers + update + 2.0 * (width + wide) * seen \
         + 2.0 * latent * cfg.num_heads * (row + cfg.kv_lora_rank) \
         * context_len
+    return cfg.loops * a_pass + 2.0 * d * cfg.vocab_size
 
 
 def _latent_projections(cfg: Any) -> int:

@@ -516,6 +516,62 @@ def test_the_axk1_programs_fit_a_v5e_and_attend_through_the_kernel(
         + prefill.output_size_in_bytes < 15.5e9
 
 
+def test_the_ouro_decode_program_attends_through_the_kernel_every_pass(
+        v5e, monkeypatch):
+    """``ouro26b_serve_shortreason_sat``'s decode program (Ouro-2.6B's
+    widths, 48 layers run 4 times, 8 slots of 640 positions, bfloat16)
+    compiled for the v5e: every (pass, layer) keeps leaves of its own,
+    ``[8, 640, 16, 128]``, and one hvd.decode_attend custom call each,
+    192, takes them as they lie and writes the step's row (no ``while``
+    loop over the slots); the program updates the whole cache in place
+    and fits beside the weights."""
+    from horovod_tpu.models import hybrid
+    from horovod_tpu.ops import decode_attention as da
+    from horovod_tpu.serving import ServeConfig, slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks", "chip"))
+    import run as harness
+    monkeypatch.setattr(da, "_on_tpu", lambda: True)   # the target
+    file = harness.load_json(harness.HERE, "configs", "Ouro-2.6B.serve.json")
+    serve = {**file["serve"],
+             "warmup_buckets": tuple(file["serve"]["warmup_buckets"])}
+    cfg = ServeConfig(model_cfg=hybrid.HybridConfig(
+        **harness.build_args(file)), **serve)
+    slots, max_seq = cfg.slots, cfg.max_seq
+    assert (slots, max_seq) == (8, 640)
+    model = hybrid.HybridLM(_decode_model_cfg(cfg))
+    cache = slotcache.DenseSlotCache(cfg, cfg.model_cfg.family, model, {})
+
+    placed = functools.partial(_placed, sharding=v5e)
+    params = placed(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    tree = placed(jax.eval_shape(cache._init_cache_impl, params))
+    leaf = slots * max_seq * 16 * 128 * 2            # one key leaf, bytes
+    attn = tree["layer_47"]["attn"]
+    assert attn["pass_4"]["cached_key"].shape == attn["cached_value"].shape \
+        == (slots, max_seq, 16, 128)
+    assert _nbytes(tree) == 192 * (2 * leaf + slots * 4)
+    assert _nbytes(params) == 5_335_945_216
+    compiled = cache._decode_jit.lower(
+        params, tree, *placed((jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, bool)))).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if MOSAIC in line]
+    assert len(calls) == 192
+    assert all("hvd.decode_attend" in call
+               and call.count("bf16[8,640,16,128]") == 4 for call in calls)
+    assert not _loops_over(text, "bf16[8,640,")
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= _nbytes(tree)
+    assert memory.temp_size_in_bytes < 0.5e9
+    # 15.75 GiB usable: 16.91 GB.
+    assert _nbytes(params) + _nbytes(tree) + memory.temp_size_in_bytes \
+        < 14e9
+
+
 def test_fit_block_follows_the_tpu_tiling_rule():
     assert fa._fit_block(2048, 1024) == 1024
     assert fa._fit_block(2000, 128) == 80       # not 125
