@@ -8,9 +8,10 @@ Two layouts, chosen by the replica (``serving/slotcache.py``):
   ``cache_index`` [B], each row's write cursor (``cached_attention``,
   ``prefill``, ``decode_step``); values may be narrower than keys; a
   window layer keeps rings of ``window`` rows instead, ``ring_key`` /
-  ``ring_value``; fewer than 16 bfloat16 key-value heads whose value
-  heads are whole lanes lie side by side in the lanes, [B, max_seq_len,
-  KV * D], caches and rings alike
+  ``ring_value``; fewer than 16 bfloat16 key-value heads whose rows
+  come to whole lanes and whose value heads are whole lanes or divide
+  them (granite's 8 of 64) lie side by side in the lanes, [B,
+  max_seq_len, KV * D], caches and rings alike
   (``ops/decode_attention.py:lanes_layout``); a latent layer (ISSUE 40)
   keeps one leaf, ``latent`` [B, max_seq_len, W], a position's
   normalised latent beside its rotated shared key, zeros to whole lanes
@@ -27,15 +28,15 @@ decode step on the dense layout (one query position a row): that goes
 through ``decode_attend``, which is handed the step's row and where it
 belongs and returns the leaves with it written.  On a TPU, where a
 kernel takes the leaves (``ops/decode_attention.py:kernel_writes``: the
-7B's, MiMo's caches and rings, Solar's), the kernel reads each row's
-keys and values up to its own live length and writes the new row in
-place itself; elsewhere ``write_rows`` writes it, a serial loop over the
-rows, and the plain form attends.  A long prompt, and any prompt of a
-window layer, attends over its own keys and values in blocks
-(``attend_blocked``).  A latent layer's decode step takes the absorbed
-form, ``hvd.mla_decode``, which writes the step's row the same way; its
-prompt writes its rows and attends in blocks over its keys and values
-expanded from the latent.
+7B's, MiMo's caches and rings, Solar's, granite's, Ouro's), the kernel
+reads each row's keys and values up to its own live length and writes
+the new row in place itself; elsewhere ``write_rows`` writes it, a
+serial loop over the rows, and the plain form attends.  A long prompt,
+and any prompt of a window layer, attends over its own keys and values
+in blocks (``attend_blocked``).  A latent layer's decode step takes the
+absorbed form, ``hvd.mla_decode``, which writes the step's row the same
+way; its prompt writes its rows and attends in blocks over its keys and
+values expanded from the latent.
 A family's attention layer (``transformer.Attention``,
 ``hybrid.GroupedAttention``, ``hybrid.LatentAttention``) brings its
 projections, its positional encoding and its score scale, and writes no

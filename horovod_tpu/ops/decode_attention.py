@@ -33,13 +33,13 @@ of sublanes lie ``[B, S, KV, D]``: a tile of bfloat16 holds 16 rows, so
 part of every tile empty (or lie position-minor), so their leaves keep
 the heads side by side in the lanes, ``[B, S, KV * D]`` keys and ``[B,
 S, KV * Dv]`` values, nothing padded, where the rows come to whole
-lanes and each value head is whole lanes (MiMo's 4 heads of 192 and
-128, the 8 of its rings, Solar's 8 of 128; granite's 8 of 64 are not,
-and keep the plain form): there the kernel multiplies every query, laid
+lanes and each value head is whole lanes or a part of one that divides
+it (MiMo's 4 heads of 192 and 128, the 8 of its rings, Solar's 8 of
+128, granite's 8 of 64): there the kernel multiplies every query, laid
 at its own head's lanes of a zero row, with the whole key row (the
 matrix unit loads the same key tiles either way), runs the softmax a
-query head a sublane, and meets each head's values, an aligned slice of
-lanes, in one product a head.  A window layer's ring goes through the
+query head a sublane, and meets each head's values, a slice of lanes,
+in one product a head.  A window layer's ring goes through the
 same kernel under its own name, ``hvd.window_attend``: a device trace
 tells the rings' calls from the caches' by name alone.
 
@@ -71,10 +71,10 @@ holds the row: the one position ``[1, 1, KV, D]`` where a position is a
 major dimension, a tile of 16 positions ``[1, 16, KV * D]``, filled
 from the block in VMEM, where the heads lie in the lanes.  The rule is
 ``kernel_writes``, the very condition under which ``decode_attend``
-takes a kernel: elsewhere (every backend but the TPU, granite's 8 heads
-of 64, float32 leaves, a sink on heads in the sublanes) the path is
-``write_rows``, then ``attend_plain``.  A prefill writes with
-``write_rows`` always: one row, once a request.
+takes a kernel: elsewhere (every backend but the TPU, float32 leaves, a
+sink on heads in the sublanes) the path is ``write_rows``, then
+``attend_plain``.  A prefill writes with ``write_rows`` always: one row,
+once a request.
 
 **The grid.**  The three decode kernels, these two and
 ``ops/mla.py``'s, run through ``work_list_call``: one grid step a live
@@ -245,13 +245,15 @@ def lanes_layout(kv_heads: int, head_dim: int, value_dim: int,
     lanes, ``[B, S, KV * D]`` and ``[B, S, KV * Dv]``: bfloat16 heads
     too few to fill a sublane tile of 16 rows (``[B, S, KV, D]`` would
     be padded there, or lie position-minor and be copied every step)
-    whose key rows come to whole lanes and whose value heads are each
-    whole lanes, the slices the kernel meets them in.  Solar's 8 heads
-    of 128 and the 8 of MiMo's rings are such; granite's 8 of 64 are
-    not, and stay ``[B, S, KV, D]`` under the plain form."""
+    whose key and value rows come to whole lanes and whose value heads
+    are each whole lanes or a part of one that divides it, the slices
+    the kernel meets them in.  Solar's 8 heads of 128, the 8 of MiMo's
+    rings and granite's 8 of 64 (two value heads to a lane tile) are
+    such."""
     return jnp.dtype(dtype) == jnp.bfloat16 and kv_heads < 16 \
         and not (kv_heads * head_dim) % _LANE \
-        and not value_dim % _LANE
+        and not (kv_heads * value_dim) % _LANE \
+        and not (value_dim % _LANE and _LANE % value_dim)
 
 
 def block_positions(max_seq: int, kv_heads: int, head_dim: int,
@@ -260,7 +262,8 @@ def block_positions(max_seq: int, kv_heads: int, head_dim: int,
     ``[B, max_seq, kv_heads, head_dim]`` of ``dtype``: the largest
     power of two that divides ``max_seq`` and keeps a key block within
     ``_BLOCK_BYTES`` (128 positions of the 7B shape, 512 of MiMo's four
-    heads of 192 and of Solar's eight of 128, a ring's 128 whole).  0,
+    heads of 192, of Solar's eight of 128 and of granite's eight of 64,
+    a ring's 128 whole).  0,
     and the plain form runs, where the kernel cannot
     take the leaves as they lie: it wants bfloat16 and either
     ``lanes_layout`` or, heads in the sublanes, values as wide as the
@@ -648,6 +651,9 @@ def _lanes_kernel(item, q_ref, sink_ref, nk_ref, nv_ref, k_ref, v_ref, o_ref,
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_ref[...] = m_cur
     weights = _pieces(p)
+    # A value head narrower than a lane tile (granite's 64) is a part of
+    # one: on a v5e this body takes 0.062 ms a call at granite's shape,
+    # and a variant that meets two heads in one whole tile 0.063.
     for head in range(kv):
         rows = slice(head * group, (head + 1) * group)
         pv = jnp.dot(

@@ -389,6 +389,67 @@ def test_the_mimo_programs_fit_a_v5e_and_attend_through_the_kernel(
         + prefill.output_size_in_bytes < 14.5e9
 
 
+def test_the_granite_decode_program_attends_through_the_kernel_on_v5e(
+        v5e, monkeypatch):
+    """``granite4hm_serve_agent_sat``'s decode program (granite-4.0-h-micro
+    whole: 36 Mamba-2 and 4 attention layers, 32 slots of 2,560
+    positions, bfloat16) compiled for the v5e: each attention layer's 8
+    key-value heads of 64 lie in the lanes, ``[32, 2560, 512]``, and one
+    hvd.decode_attend custom call a layer takes the two leaves as they
+    lie and writes the step's row, beside 36 hvd.ssm_update; no ``while``
+    loop over the slots is left, the cache is as large as before and the
+    program updates it in place."""
+    from horovod_tpu.models import hybrid
+    from horovod_tpu.ops import decode_attention as da
+    from horovod_tpu.ops import ssm
+    from horovod_tpu.serving import ServeConfig, slotcache
+    from horovod_tpu.serving.replica import _decode_model_cfg
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks", "chip"))
+    import run as harness
+    for module in (da, ssm):                 # the target, not the CPU
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    file = harness.load_json(harness.HERE, "configs",
+                             "granite-4.0-h-micro.serve.json")
+    serve = {**file["serve"],
+             "warmup_buckets": tuple(file["serve"]["warmup_buckets"])}
+    cfg = ServeConfig(model_cfg=hybrid.HybridConfig(
+        **harness.build_args(file)), **serve)
+    slots, max_seq = cfg.slots, cfg.max_seq
+    assert (slots, max_seq) == (32, 2560)
+    model = hybrid.HybridLM(_decode_model_cfg(cfg))
+    cache = slotcache.DenseSlotCache(cfg, cfg.model_cfg.family, model, {})
+
+    placed = functools.partial(_placed, sharding=v5e)
+    params = placed(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))["params"]))
+    tree = placed(jax.eval_shape(cache._init_cache_impl, params))
+    attn = tree["layer_5"]["attn"]
+    assert attn["cached_key"].shape == attn["cached_value"].shape \
+        == (slots, max_seq, 8 * 64)
+    assert da.kernel_block(attn["cached_key"].shape, jnp.bfloat16,
+                           values=attn["cached_value"].shape) == 512
+    cache_bytes = _nbytes(tree)
+    assert cache_bytes == 3_117_089_280              # as the parent's
+    compiled = cache._decode_jit.lower(
+        params, tree, *placed((jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, jnp.int32),
+                               jnp.zeros(slots, bool)))).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if MOSAIC in line]
+    attend = [call for call in calls if "hvd.decode_attend" in call]
+    # Two operands, and two results that alias them.
+    assert len(attend) == 4
+    assert all(call.count("bf16[32,2560,512]") == 4 for call in attend)
+    assert sum("hvd.ssm_update" in call for call in calls) == 36
+    assert len(calls) == 4 + 36
+    assert " while(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    assert memory.temp_size_in_bytes < 0.1e9
+
+
 def test_the_solar_decode_program_attends_through_the_kernel_on_v5e(
         v5e, monkeypatch):
     """``solaropen2_serve_reason_sat``'s decode program (Solar-Open2's

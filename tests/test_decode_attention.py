@@ -8,9 +8,11 @@ that reads each slot's keys and values up to its own live length.
 - ``read_positions`` counts the blocks the kernel's work list visits;
 - which leaves the kernel takes, and the block it reads them in;
 - the lanes kernel at 8 key-value heads (ISSUE 37): Solar's cache (8
-  heads of 128 under 64 query heads) and MiMo's ring (keys 192, values
-  128, a sink, one block a slot), the block the entry point really takes
-  against ``read_positions``, and the custom call's two names;
+  heads of 128 under 64 query heads), MiMo's ring (keys 192, values
+  128, a sink, one block a slot) and granite's cache (8 heads of 64
+  under 32 query heads, two value heads a lane tile), the block the
+  entry point really takes against ``read_positions``, and the custom
+  call's two names; which serving cells' leaves the rule moved;
 - the step's own row (ISSUE 39): the kernels take it beside the leaves,
   attend as if it were written and write it in place, to the bit what
   ``write_rows`` writes, on caches and on rings past their first lap,
@@ -188,16 +190,18 @@ def test_read_positions_counts_the_blocks_the_index_map_visits(lengths,
 @pytest.mark.parametrize("shape, dtype, rule, block", [
     ((16, 4096, 32, 128), jnp.bfloat16, 128, 128),  # deepseek-llm-7b: 1 MB
     ((8, 8192, 16, 128), jnp.bfloat16, 256, 256),
-    ((32, 2560, 8, 64), jnp.bfloat16, 0, 0),     # granite: lies position-minor
-    # Half a sublane tile of heads: they belong in the lanes (Solar), and
-    # are read there in the rule's block; a ring of MiMo's whole.
+    # Half a sublane tile of heads: they belong in the lanes (Solar, and
+    # granite's of 64, two value heads a lane tile), and are read there
+    # in the rule's block; a ring of MiMo's whole.
+    ((32, 2560, 8, 64), jnp.bfloat16, 512, 0),
+    ((32, 2560, 8 * 64), jnp.bfloat16, 512, 512),
     ((16, 4096, 8, 128), jnp.bfloat16, 512, 0),
     ((16, 4096, 8 * 128), jnp.bfloat16, 512, 512),
     ((64, 128, 8 * 192), jnp.bfloat16, 128, 128),
     ((16, 4096, 32, 128), jnp.float32, 0, 0),
     ((2, 64, 4, 16), jnp.bfloat16, 0, 0),           # the test-sized decoder
-], ids=["lm7b", "kv16", "granite", "kv8", "kv8_lanes", "ring_lanes",
-        "float32", "tiny"])
+], ids=["lm7b", "kv16", "granite", "granite_lanes", "kv8", "kv8_lanes",
+        "ring_lanes", "float32", "tiny"])
 def test_the_block_follows_the_leaves_shape(shape, dtype, rule, block):
     """``rule``: ``block_positions`` for these heads; ``block``: what the
     compiled path reads a leaf of this very shape in."""
@@ -212,18 +216,20 @@ def test_the_block_follows_the_leaves_shape(shape, dtype, rule, block):
 
 
 def test_a_narrow_head_keeps_the_plain_form():
-    """The hybrid family's shape (32 query heads over 8 key-value heads
-    of 64): ``decode_attend`` is ``attend``, interpreted or not."""
+    """The test-sized decoder's shape (4 query heads over 4 key-value
+    heads of 16: rows of 64 lanes, neither a sublane tile nor whole
+    lanes): ``decode_attend`` is ``attend``, interpreted or not."""
     rng = np.random.default_rng(5)
-    q = jnp.asarray(rng.standard_normal((3, 1, 32, 64)), jnp.bfloat16)
-    keys, values = (jnp.asarray(rng.standard_normal((3, 48, 8, 64)),
+    q = jnp.asarray(rng.standard_normal((3, 1, 4, 16)), jnp.bfloat16)
+    keys, values = (jnp.asarray(rng.standard_normal((3, 48, 4, 16)),
                                 jnp.bfloat16) for _ in range(2))
+    assert not da.lanes_layout(4, 16, 16, jnp.bfloat16)
     step = _step((keys, values), [1, 17, 48])
-    want = kvcache.attend(q, keys, values, step[3][:, None], 1 / 64)
+    want = kvcache.attend(q, keys, values, step[3][:, None], 1 / 16)
     for interpret in (False, True):
         assert not da.kernel_writes(keys.shape, keys.dtype,
                                     interpret=interpret)
-        got = _written(da.decode_attend(q, keys, values, *step, 1 / 64,
+        got = _written(da.decode_attend(q, keys, values, *step, 1 / 16,
                                         interpret=interpret),
                        (keys, values), step)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -268,13 +274,19 @@ def test_the_kernel_carries_its_name_and_takes_the_leaves_as_they_lie():
 
 # --- 8 key-value heads in the lanes (ISSUE 37) ------------------------------
 # Solar's attention layer: 64 query heads over 8 heads of 128; MiMo's
-# ring: keys 192 and values 128 wide, a sink, the window one block.
+# ring: keys 192 and values 128 wide, a sink, the window one block;
+# granite's attention layer: 32 query heads over 8 heads of 64, two
+# value heads a lane tile.
 KV8 = {"solar": dict(heads=64, kv=8, dk=128, dv=128, s=64, block=16,
                      sink=False, scope="hvd.decode_attend"),
        "ring": dict(heads=64, kv=8, dk=192, dv=128, s=16, block=16,
-                    sink=True, scope="hvd.window_attend")}
-# 1, mid-block, a whole block, full (a ring at and under its window)
-KV8_LENGTHS = {"solar": (1, 7, 16, 41, 64), "ring": (1, 7, 15, 16, 16)}
+                    sink=True, scope="hvd.window_attend"),
+       "granite": dict(heads=32, kv=8, dk=64, dv=64, s=64, block=16,
+                       sink=False, scope="hvd.decode_attend")}
+# 1, mid-block, a whole block, full (a ring at and under its window);
+# 1, a block's edge, one past it, full
+KV8_LENGTHS = {"solar": (1, 7, 16, 41, 64), "ring": (1, 7, 15, 16, 16),
+               "granite": (1, 16, 17, 64)}
 
 
 def _lanes_operands(shape, qdtype=jnp.bfloat16):
@@ -314,7 +326,8 @@ def test_the_lanes_kernel_serves_eight_heads(shape, qdtype):
         q, *merged, *step, sink, 0.09, block=spec["block"], interpret=True),
         merged, step)
     want = da.attend_plain(q, *clean, lens[:, None] - 1, 0.09, sink)
-    assert got.shape == want.shape == (len(lens), 1, 64, spec["dv"])
+    assert got.shape == want.shape == (len(lens), 1, spec["heads"],
+                                       spec["dv"])
     assert got.dtype == jnp.float32
     assert not np.isnan(np.asarray(got)).any()
     np.testing.assert_allclose(got, want, atol=5e-6, rtol=5e-6)
@@ -400,6 +413,7 @@ WRITES = {
                   sink=False, window=0, lanes=False)
        for name, (heads, kv) in SHAPES.items()},
     "solar": {**KV8["solar"], "window": 0, "lanes": True},
+    "granite": {**KV8["granite"], "window": 0, "lanes": True},
     "ring": {**KV8["ring"], "window": 16, "lanes": True},
     "ring128": dict(heads=64, kv=8, dk=192, dv=128, s=128, block=128,
                     sink=True, window=128, lanes=True,
@@ -481,6 +495,8 @@ def test_the_kernel_writes_the_steps_row_and_attends_over_it(
     ((16, 4096, 32, 128), None, jnp.bfloat16, False, False, False),
     ((16, 4096, 32, 128), None, jnp.bfloat16, True, True, False),
     ((16, 4096, 32, 128), None, jnp.float32, False, True, False),
+    ((32, 2560, 512), None, jnp.bfloat16, False, True, True),
+    ((32, 2560, 512), None, jnp.bfloat16, False, False, False),
     ((32, 2560, 8, 64), None, jnp.bfloat16, False, True, False),
     ((80, 4608, 8, 128), None, jnp.bfloat16, False, True, False),
     ((80, 4608, 1024), None, jnp.bfloat16, False, True, True),
@@ -488,14 +504,16 @@ def test_the_kernel_writes_the_steps_row_and_attends_over_it(
     ((64, 128, 1536), (64, 128, 1024), jnp.bfloat16, True, True, True),
     ((64, 128, 1536), (64, 128, 1024), jnp.bfloat16, True, False, False),
 ], ids=["lm7b", "lm7b_off_the_tpu", "sublanes_with_a_sink", "float32",
-        "granite", "kv8_not_in_the_lanes", "solar", "mimo_global",
+        "granite", "granite_off_the_tpu", "granite_not_in_the_lanes",
+        "kv8_not_in_the_lanes", "solar", "mimo_global",
         "mimo_ring_with_its_sink", "mimo_ring_off_the_tpu"])
 def test_the_kernel_writes_wherever_a_kernel_attends(
         keys, values, dtype, sink, on_tpu, want, monkeypatch):
     """``kernel_writes`` is ``decode_attend``'s own choice of path: yes
-    where ``kernel_block`` finds a block, but for a sink on heads in the
-    sublanes; no for granite's 8 heads of 64, float32 leaves, heads that
-    belong in the lanes and do not lie there, and off the TPU."""
+    where ``kernel_block`` finds a block (granite's 8 heads of 64 among
+    them, in the lanes), but for a sink on heads in the sublanes; no for
+    float32 leaves, heads that belong in the lanes and do not lie there,
+    and off the TPU."""
     monkeypatch.setattr(da, "_on_tpu", lambda: on_tpu)
     assert da.kernel_writes(keys, dtype, values, sink) is want
     block = da.kernel_block(keys, dtype, values=values)
@@ -504,6 +522,38 @@ def test_the_kernel_writes_wherever_a_kernel_attends(
     # the TPU are the kernel's).
     assert da.kernel_writes(keys, dtype, values, sink, interpret=True) \
         is (want or not on_tpu)
+
+
+# The six serving cells' attention leaves: key-value heads, key and value
+# widths, slots, positions, a sink; and what the rule gave each before
+# value heads narrower than a lane tile went into the lanes: whether they
+# lie in the lanes, the block the compiled path reads them in, whether
+# the kernel writes the step's row.  (A.X-K1's latent leaf is not asked.)
+SERVING_LEAVES = {
+    "lm7b": ((32, 128, 128, 16, 4096, False), (False, 128, True)),
+    "granite": ((8, 64, 64, 32, 2560, False), (False, 0, False)),
+    "solar": ((8, 128, 128, 80, 4608, False), (True, 512, True)),
+    "mimo_global": ((4, 192, 128, 64, 12288, False), (True, 512, True)),
+    "mimo_ring": ((8, 192, 128, 64, 128, True), (True, 128, True)),
+    "ouro": ((16, 128, 128, 8, 640, False), (False, 128, True)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVING_LEAVES))
+def test_only_granites_leaves_move(cell, monkeypatch):
+    """``lanes_layout``, ``kernel_block`` and ``kernel_writes`` on the
+    TPU give every serving cell's leaves the answers they gave before,
+    but granite's: its 8 value heads of 64 now lie in the lanes, ``[32,
+    2560, 512]``, read in blocks of 512 by the kernel, which writes the
+    step's row."""
+    (kv, dk, dv, slots, max_seq, sink), before = SERVING_LEAVES[cell]
+    monkeypatch.setattr(da, "_on_tpu", lambda: True)
+    lanes = da.lanes_layout(kv, dk, dv, jnp.bfloat16)
+    keys, values = ((slots, max_seq, kv * w) if lanes
+                    else (slots, max_seq, kv, w) for w in (dk, dv))
+    now = (lanes, da.kernel_block(keys, jnp.bfloat16, values=values),
+           da.kernel_writes(keys, jnp.bfloat16, values, sink))
+    assert now == (before if cell != "granite" else (True, 512, True))
 
 
 # --- the grid, a work list of live blocks --------------------------------------

@@ -203,13 +203,16 @@ def test_the_entry_point_reads_lanes_leaves_in_the_block_the_rule_gives(
     """MiMo's global leaves, [B, S, 4 x 192] and [B, S, 4 x 128] in
     bfloat16: ``decode_attend`` interpreted goes through the lanes kernel
     in ``block_positions``' block, with the sink; elsewhere the plain
-    form reads the same leaves; the rule leaves the other families'
-    leaves where they were."""
+    form reads the same leaves; the rule leaves the 7B's and the toy's
+    leaves where they were, and takes granite's value heads of 64, two
+    to a lane tile."""
     assert da.lanes_layout(4, 192, 128, jnp.bfloat16)
     assert da.lanes_layout(8, 128, 128, jnp.bfloat16)         # Solar
     assert da.lanes_layout(8, 192, 128, jnp.bfloat16)         # the rings
     assert not da.lanes_layout(16, 128, 128, jnp.bfloat16)    # a whole tile
-    assert not da.lanes_layout(8, 64, 64, jnp.bfloat16)       # granite
+    assert da.lanes_layout(8, 64, 64, jnp.bfloat16)           # granite
+    assert not da.lanes_layout(8, 64, 48, jnp.bfloat16)       # 128 % 48
+    assert not da.lanes_layout(2, 64, 32, jnp.bfloat16)       # 64 lanes
     assert not da.lanes_layout(4, 192, 128, jnp.float32)
     assert not da.lanes_layout(1, 24, 16, jnp.bfloat16)       # the toy
     assert da.block_positions(12288, 4, 192, jnp.bfloat16, 128) == 512
