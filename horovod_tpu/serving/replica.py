@@ -29,9 +29,11 @@ Execution model (ISSUE 9 tentpole, extended by ISSUE 14):
   the end-of-sequence test) at fetch.  So a token is visible one
   ``_serve_step`` after the one that enqueued its program, and a request
   is complete only once every row issued for it has been fetched.  A
-  step that admits settles the step in flight first, and so does
-  whatever reads or replaces the cache, the parameters or the world from
-  outside a step (``_settle``).
+  step's first admission enqueues its prefill and insert behind the
+  step in flight and only then waits for that step, so the host lowers
+  and enqueues the prompt under the device's step; whatever reads or
+  replaces the cache, the parameters or the world from outside a step
+  settles the step in flight first (``_settle``).
 - **The slot cache** is an object of ``serving/slotcache.py``, chosen
   once from ``HOROVOD_SERVE_PAGED``: dense per-slot arrays, or paged
   blocks with prefix reuse (ISSUE 14).  The executor knows neither
@@ -283,6 +285,9 @@ class ReplicaExecutor:
                       # seconds inside hvd.serve.admit, which every
                       # running stream waits.
                       "admissions": 0, "admit_s": 0.0,
+                      # Of those, the ones whose prefill was enqueued
+                      # while a decode step was in flight.
+                      "admit_overlapped": 0,
                       # By the slot cache's admit: prompt tokens computed
                       # anew (a prefix hit's are not) and the positions
                       # the prefill program ran over (their bucket).
@@ -434,7 +439,10 @@ class ReplicaExecutor:
 
     def _apply_plan(self, plan: BatchPlan, parts: StepParts) -> int:
         """Execute the plan's assignments; returns how many requests
-        this replica admitted into a slot."""
+        this replica admitted into a slot.  A local admission settles the
+        decode step in flight between the dispatch of its prefill and the
+        fetch of its first token (``_prefill_slot``); one parked for a
+        streamed prefill settles before it begins."""
         now = time.monotonic()
         admits = 0
         if plan.swap_version:
@@ -446,7 +454,8 @@ class ReplicaExecutor:
                 continue
             if a.replica != self.group:
                 continue
-            self._settle(parts)        # a no-op after the first
+            if a.prefill >= 0:
+                self._settle(parts)    # a parked admission settles first
             slot = next(i for i, s in enumerate(self.slots) if s is None)
             admits += 1
             # Every stream that is decoding waits for this admission.
@@ -464,7 +473,7 @@ class ReplicaExecutor:
                 if a.prefill >= 0:
                     self._admit_disaggregated(slot, a, now)
                 else:
-                    self._prefill_slot(slot, a, now)
+                    self._prefill_slot(slot, a, now, parts)
                 # What the cache's admit ran: on the paged layout the
                 # prefix cache's hits are neither computed nor padded.
                 tokens = stats["prefill_prompt_tokens"] - before[0]
@@ -480,9 +489,23 @@ class ReplicaExecutor:
             by_bucket[2] += admit.seconds
         return admits
 
-    def _prefill_slot(self, slot: int, a: Assignment, now: float) -> None:
+    def _prefill_slot(self, slot: int, a: Assignment, now: float,
+                      parts: StepParts | None = None) -> None:
+        """Prefill ``a`` into ``slot``; the slot is its once the first
+        token is fetched.  Given the step's ``parts`` (an admission of the
+        plan), the decode step in flight is settled in between: the host
+        lowers and enqueues the prefill and the insert while the device
+        runs that step, and the device goes from it to the prefill.  The
+        slot is in no step in flight (it was free when that step was
+        enqueued), so nothing the prefill reads waits for the settle.  One
+        prefill at most is unfetched: its first token is fetched before
+        the next admission begins."""
         toks = self._clamped_tokens(a)
         first = self.cache.admit(self.params, slot, toks, a.max_new_tokens)
+        if parts is not None:
+            self.stats["admit_overlapped"] += self._in_flight is not None
+            self._settle(parts)        # a no-op after the first
+        first = self.cache.first_token(first)
         self._last_tokens[slot] = first
         self._token_on_host[slot] = True
         self.slots[slot] = _Slot(
@@ -652,9 +675,10 @@ class ReplicaExecutor:
 
     def _settle(self, parts: StepParts | None = None) -> None:
         """Leave nothing in flight: fetch the decode step that is, and
-        advance its slots.  Before an admission, and before anything
-        outside a step reads or replaces the cache, the parameters or
-        the world."""
+        advance its slots.  Inside a step's first admission, between its
+        prefill's dispatch and its first token's fetch (a parked one's,
+        before it begins), and before anything outside a step reads or
+        replaces the cache, the parameters or the world."""
         self._advance_slots(*self._fetch_in_flight(parts))
 
     def _collect_completions(self) -> None:

@@ -3,28 +3,23 @@ state of its in-flight requests live on the device, in one of two
 layouts behind one interface.
 
 ``ReplicaExecutor`` picks a class once, from ``ServeConfig.paged``, and
-from then on asks only: ``warm`` (compile every program, note how much
-of the cache the decode program updates in place), ``admit`` (prefill a
-prompt into a slot, return its first token), ``decode`` (enqueue one
-step for the slot array; its result stays on the device), ``fetch``
-(wait for a step's result), ``release``, ``fresh``, ``kv_stats``,
-``close``, and ``block_capacity`` for the batcher.  ``tree`` is the
-cache itself: every program that writes it takes it donated and its
-result is rebound, so nobody else may hold it.  ``result`` is the last
-decode step's result, on the device: the next step reads a slot's input
-token from it wherever the host has none newer, so a step can be
-enqueued before the one before it has been fetched.  Both classes reach
-the model through its family (``models/family.py``); the leaves of
-either layout are ``models/kvcache.py``'s.
+asks only: ``warm`` (compile every program, note the cache's aliasing),
+``admit`` (enqueue a prompt's prefill into a slot; its first token stays
+on the device until ``first_token``), ``decode`` (enqueue one step for
+the slot array), ``fetch`` (wait for a step's result), ``release``,
+``fresh``, ``kv_stats``, ``close``, ``block_capacity`` for the batcher.
+``tree`` is the cache: every program that writes it takes it donated, so
+it runs behind the one before, and rebinds its result, so nobody else
+may hold it.  ``result``, the last decode step's, stays on the device:
+the next step reads a slot's input token from it where the host has none
+newer.  The model is reached through its family (``models/family.py``).
 
 - :class:`DenseSlotCache`: the slot on axis 0 of every leaf; an
   admission prefills one row and inserts it as row ``slot``.
-- :class:`PagedSlotCache` (ISSUE 14): blocks from a
-  :class:`~.kvpool.KVBlockPool` addressed through per-slot block tables,
-  so live tokens, not the batch shape, bound concurrency; prefix reuse
-  by content address, copy-on-write, LRU eviction.  It alone offers what
-  disaggregated prefill streams: ``holds_prompt``, ``prefill_image``,
-  ``land``.
+- :class:`PagedSlotCache`: per-slot block tables into a
+  :class:`~.kvpool.KVBlockPool`; prefix reuse by content address,
+  copy-on-write, LRU eviction.  It alone offers what disaggregated
+  prefill streams: ``holds_prompt``, ``prefill_image``, ``land``.
 """
 from __future__ import annotations
 
@@ -157,6 +152,11 @@ class _SlotCache:
             self.stats[name] += int(value)
         return fetched[:self.cfg.slots]
 
+    def first_token(self, first) -> int:
+        """Wait for the prefill ``admit`` enqueued: its first token."""
+        with span("serve.first_token_fetch"):
+            return int(first)          # waits for the device
+
     def _input_tokens(self, result, last_tokens, from_host):
         """Inside the decode program: each slot's input token, [slots,
         1], the host's where ``from_host`` and else the last step's."""
@@ -270,14 +270,19 @@ class DenseSlotCache(_SlotCache):
         self.stats["attend_write_fused_layers"] = fused
 
     def _warm_prefill(self, params, toks: list) -> None:
-        self.admit(params, 0, toks, 1)   # the insert compiles once
+        # The insert compiles once.
+        jax.block_until_ready(self.admit(params, 0, toks, 1))
 
     def _decode_call(self, params, last_tokens, from_host):
         # Copies: the host's arrays change while the step is in flight.
         return self._decode_jit, (params, self.tree, self.result,
                                   last_tokens.copy(), from_host.copy())
 
-    def admit(self, params, slot: int, toks: list, max_new: int) -> int:
+    def admit(self, params, slot: int, toks: list, max_new: int):
+        """Enqueue the prefill of ``toks`` and its insert as row ``slot``:
+        the first token, on the device.  The insert takes the cache
+        donated, so it runs behind the decode step in flight, which
+        writes the cache it is given; the prefill reads no cache."""
         padded = _padded(self.cfg, toks)
         self._count_prefill(len(toks), padded.shape[1])
         with span("serve.prefill_dispatch"):
@@ -285,8 +290,7 @@ class DenseSlotCache(_SlotCache):
                 params, jnp.asarray(padded), jnp.int32(len(toks)))
         with span("serve.cache_insert"):     # a dispatch: nothing waits
             self.tree = self._insert_jit(self.tree, cache1, np.int32(slot))
-        with span("serve.first_token_fetch"):
-            return int(first)          # waits for the device
+        return first
 
 
 class PagedSlotCache(_SlotCache):
@@ -403,7 +407,11 @@ class PagedSlotCache(_SlotCache):
         return copied
 
     # -- the interface ---------------------------------------------------
-    def admit(self, params, slot: int, toks: list, max_new: int) -> int:
+    def admit(self, params, slot: int, toks: list, max_new: int):
+        """Enqueue the prefill of ``toks`` into ``slot``'s blocks: the
+        first token, on the device.  The program takes the pool donated,
+        so it runs behind the decode step in flight; that step has its
+        own copies of the tables and cursors pointed at here."""
         bt = self.cfg.block_tokens
         hits, pos = self._lookup_prefix(toks)
         new = len(toks) - pos          # what the prefix cache lacks
@@ -423,8 +431,7 @@ class PagedSlotCache(_SlotCache):
         with span("serve.cache_insert"):
             self._publish_prompt(toks, blocks)
             self._point(slot, blocks)
-        with span("serve.first_token_fetch"):
-            return int(first)          # waits for the device
+        return first
 
     def _block_run(self, slot: int, toks: list, max_new: int,
                    hits: list) -> list:

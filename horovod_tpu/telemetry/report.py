@@ -230,7 +230,8 @@ _ADMIT = "hvd.serve.admit"
 def _requests(spans: list[dict]) -> dict[int, dict]:
     """rid -> the spans of one request that the session holds (``spans``
     by start): its ``enqueue`` and ``complete`` marks, its ``admit`` with
-    the spans inside it and the settle before it, and the serve steps
+    the spans inside it (a step's first admission holds the settle of
+    the decode step in flight, a ``token_fetch``), and the serve steps
     that began after the one that admitted it."""
     found: dict[int, dict] = {}
     for span in spans:
@@ -252,13 +253,6 @@ def _requests(spans: list[dict]) -> dict[int, dict]:
             req["inside"] = [s for s in spans if s is not admit
                              and admit["start"] <= s["start"]
                              and s["end"] <= admit["end"]]
-            # The first admission of a step waits for the decode step in
-            # flight before it begins: that step's only token_fetch.
-            before = [s for s in spans if s["root"] is admit["root"]
-                      and s["end"] <= admit["start"]]
-            req["settle"] = [
-                s for s in before if s["name"] == "hvd.serve.token_fetch"
-                and not any(o["name"] == _ADMIT for o in before)]
     return found
 
 
@@ -283,15 +277,15 @@ def request_report(found: dict[int, dict], rid: int) -> str:
             else "enqueued before the session opened"
         rows.append(["queue wait", str(stats.get("queue_wait_ms", "-")),
                      f"by the admission's own count; {seen}"])
-        for s in req["settle"]:
-            rows.append(["  settle", _ms(s["end"] - s["start"]),
-                         "the decode step in flight, before the admission"])
         rows.append(["admission", _ms(admit["end"] - admit["start"]),
                      ", ".join(f"{key} {stats[key]}" for key in (
                          "bucket", "prompt_tokens", "slot", "running")
                          if key in stats)])
         rows += [["  " + s["name"].rsplit(".", 1)[1],
-                  _ms(s["end"] - s["start"]), ""] for s in req["inside"]]
+                  _ms(s["end"] - s["start"]),
+                  "the decode step in flight, behind the prefill's dispatch"
+                  if s["name"] == "hvd.serve.token_fetch" else ""]
+                 for s in req["inside"]]
     else:
         rows.append(["admission", "-", "still queued at the session's end"
                      if req["queued"] else "before the session opened"])

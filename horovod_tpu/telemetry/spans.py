@@ -22,7 +22,9 @@ opens ``hvd.<family>.token_fetch`` and adds the ``time.perf_counter()``
 difference to that part's slot, so the part timers are on whether or not
 anybody traces.  ``close()`` returns ``{part: seconds}`` with the step's
 ``total`` and the remainder as ``other``: the parts always sum to the
-step.
+step.  A part opened inside another (the settle of the decode step in
+flight inside an admission) is taken out of the enclosing part's slot,
+so they still do; the enclosing part's own ``seconds`` keep it.
 """
 from __future__ import annotations
 
@@ -68,19 +70,46 @@ class timed:
         return self._span.__exit__(*exc)
 
 
+class _Part(timed):
+    """A part of a step: while it is open, the parts opened inside it
+    take their seconds out of its slot."""
+    __slots__ = ("_open",)
+
+    def __init__(self, open_parts: list, slots: dict, key: str, name: str,
+                 **args) -> None:
+        super().__init__(slots, key, name, **args)
+        self._open = open_parts
+
+    def __enter__(self):
+        self._open.append(self._key)
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return super().__exit__(*exc)
+        finally:
+            self._open.pop()
+            if self._open:
+                outer = self._open[-1]
+                self._slots[outer] = self._slots.get(outer, 0.0) \
+                    - self.seconds
+
+
 class StepParts:
     """The span and the part timers of one step of ``family``."""
-    __slots__ = ("_family", "_seconds", "_span", "_t0")
+    __slots__ = ("_family", "_seconds", "_span", "_t0", "_open")
 
     def __init__(self, family: str, **args) -> None:
         self._family = family
         self._seconds: dict[str, float] = {}
+        self._open: list[str] = []     # the parts open, innermost last
         self._span = span(family + ".step", **args)
         self._span.__enter__()
         self._t0 = time.perf_counter()
 
     def __call__(self, part: str, **args) -> timed:
-        return timed(self._seconds, part, f"{self._family}.{part}", **args)
+        return _Part(self._open, self._seconds, part,
+                     f"{self._family}.{part}", **args)
 
     def elapsed(self) -> float:
         """Seconds since the step began."""

@@ -19,7 +19,7 @@ LAYOUTS = pytest.mark.parametrize("paged", [False, True],
 # What ``executor.stats`` counts of the admissions: each a plain number
 # that a per-layer metric reads.
 COUNTERS = ("admissions", "admit_s", "prefill_prompt_tokens",
-            "prefill_bucket_positions")
+            "prefill_bucket_positions", "admit_overlapped")
 
 
 @pytest.fixture
@@ -104,6 +104,50 @@ def test_prompt_tokens_and_bucket_positions_of_three_prompts(solo, paged):
                                     "ms_per_ktoken", "padding_%"]
         assert [row.split()[0] for row in table[2:]] == ["8", "16", "64"]
         assert table[-1].split()[-1] == f"{100 * (1 - 33 / 64):.2f}"
+    finally:
+        ex.close()
+
+
+@LAYOUTS
+def test_an_admission_behind_a_step_in_flight_is_counted_once(
+        solo, monkeypatch, paged):
+    """Two slots, outputs of 4 and 12: the third request is admitted
+    while the second decodes, its prefill enqueued behind the step in
+    flight (``admit_overlapped``).  Each admission is counted once; the
+    settle inside it is that step's ``token_fetch`` and not its
+    ``admit`` part, so every step's parts, ``other`` not negative, sum to
+    it, while ``admit_s`` (what the streams waited) holds the settle."""
+    ex = executor(paged, paged_slots=2)
+    seen = []
+    note = ex._note_step_parts
+    monkeypatch.setattr(
+        ex, "_note_step_parts",
+        lambda step, seconds, admits, before: (
+            seen.append((dict(seconds), admits)),
+            note(step, seconds, admits, before)))
+    try:
+        serve(ex, [prompt(5), prompt(9, 40), prompt(33, 80)], (4, 12, 4))
+        stats = ex.stats
+        assert (stats["admissions"], stats["admit_overlapped"]) == (3, 1)
+        assert stats["prefill_prompt_tokens"] == 47
+        assert stats["prefill_bucket_positions"] == 8 + 16 + 64
+        by_bucket = stats["prefill_by_bucket"]
+        assert {b: row[:2] for b, row in by_bucket.items()} \
+            == {8: [1, 5], 16: [1, 9], 64: [1, 33]}
+        assert sum(row[2] for row in by_bucket.values()) \
+            == pytest.approx(stats["admit_s"])
+        admitting = [seconds for seconds, admits in seen if admits]
+        assert len(admitting) == stats["steps"]["admit"] == 2
+        for seconds in admitting:
+            total = seconds.pop("total")
+            assert seconds["other"] >= 0
+            assert sum(seconds.values()) == pytest.approx(total, abs=1e-9)
+        # The one settle of an admit step lies inside its admission.
+        assert "token_fetch" not in admitting[0]
+        settle = admitting[1]["token_fetch"]
+        parts_s = stats["step_parts_s"]["admit"]
+        assert parts_s["token_fetch"] == pytest.approx(settle)
+        assert stats["admit_s"] == pytest.approx(parts_s["admit"] + settle)
     finally:
         ex.close()
 
@@ -225,6 +269,9 @@ def test_the_disaggregated_path_counts_an_admission_once(solo):
         assert ex.slots[1].pending is None
         assert (stats["admissions"], stats["prefill_prompt_tokens"],
                 stats["prefill_skipped"]) == (2, 12, 1)
+        # A parked admission settles before it begins; these two found
+        # nothing in flight.
+        assert stats["admit_overlapped"] == 0
         # Parked, it prefilled nothing in its admission (bucket 0: the
         # fallback's tokens came later, outside it); the full hit ran the
         # last token again in the smallest bucket.
@@ -305,6 +352,11 @@ def test_report_prints_one_requests_phases(session, capsys):
                   "cache_insert", "first_token_fetch", "decode steps",
                   "tokens", "first to last token"):
         assert phase in rows, (phase, text)
+    # Request 0 left its slot while request 1 decoded: request 2's
+    # admission holds the settle of the decode step in flight.
+    assert "behind the prefill's dispatch" in rows["token_fetch"]
+    assert list(rows).index("cache_insert") < list(rows).index(
+        "token_fetch") < list(rows).index("first_token_fetch")
     assert "from its enqueue mark" in rows["queue wait"]
     assert "bucket 64, prompt_tokens 33" in rows["admission"]
     assert rows["tokens"].split()[1] == "6"

@@ -1344,17 +1344,34 @@ def _drive(ex, waves, each_step=lambda: None) -> dict:
     return asked
 
 
-@pytest.mark.parametrize("kind", list(_LOOKAHEAD))
+def _short_requests():
+    """Twelve requests of 2 to 4 tokens out, in one wave: a slot frees
+    every few steps while the others decode, so most steps that admit
+    find a decode step in flight."""
+    import random
+
+    rng = random.Random(45)
+    return [([rng.randrange(2, 256) for _ in range(n)], new)
+            for n, new in zip((4, 9, 2, 14, 6, 3, 11, 5, 8, 1, 13, 7),
+                              (2, 3, 4, 2, 4, 3, 2, 3, 4, 2, 3, 2))]
+
+
+@pytest.mark.parametrize("kind", [*_LOOKAHEAD, "paged-short-outputs"])
 def test_the_lookahead_serves_what_a_plain_greedy_loop_serves(kind):
     """Admissions into a decoding batch, unequal output lengths, slots
     used again: request by request the replica serves exactly the tokens
     of a one-request greedy loop over the family's own decode_step, and
     nearly every decode program was enqueued with the one before it
-    still unfetched."""
-    hvd, ex = _lookahead_executor(kind)
+    still unfetched.  With short outputs most steps that admit enqueue
+    their first prefill while a decode step is in flight."""
+    short = kind == "paged-short-outputs"
+    hvd, ex = _lookahead_executor("paged-transformer" if short else kind)
     try:
-        requests = _lookahead_requests()
-        asked = _drive(ex, [requests[:4], requests[4:6], requests[6:]])
+        if short:
+            asked = _drive(ex, [_short_requests()])
+        else:
+            requests = _lookahead_requests()
+            asked = _drive(ex, [requests[:4], requests[4:6], requests[6:]])
         greedy = _reference(ex)
         for rid, (prompt, new) in asked.items():
             assert ex.completed[rid]["generated"] == greedy(prompt, new), rid
@@ -1362,6 +1379,10 @@ def test_the_lookahead_serves_what_a_plain_greedy_loop_serves(kind):
         stats = ex.stats
         assert stats["decode_dispatches"] > stats["decode_overlapped"] \
             >= stats["decode_dispatches"] - stats["steps"]["admit"] > 0
+        assert stats["admissions"] == len(asked)
+        assert 0 < stats["admit_overlapped"] < stats["admissions"]
+        if short:              # a step's first admission, in most steps
+            assert stats["admit_overlapped"] > stats["steps"]["admit"] / 2
         assert ex._in_flight is None and ex.slots == [None] * 3
     finally:
         ex.close()
@@ -1425,13 +1446,32 @@ def test_a_stream_ends_at_its_end_token_and_the_row_behind_it_is_dropped(
 
 
 class _Recorded:
-    """The cache's ``decode`` and ``fetch`` with their order kept: each
-    decode gets a number, and a fetch names the decode it waits for."""
+    """The cache's ``admit``, ``decode`` and ``fetch`` with their order
+    kept: an admission is ``("prefill", slot)``, each decode gets a
+    number, and a fetch names the decode it waits for; with
+    ``first_tokens`` a first token's fetch is ``("first", slot)``."""
 
-    def __init__(self, ex):
+    def __init__(self, ex, first_tokens=False):
         self.calls, self._ids = [], {}
         self._kept = []               # alive, so that no id comes twice
         decode, fetch = ex.cache.decode, ex.cache.fetch
+        admit, first_token = ex.cache.admit, ex.cache.first_token
+        slots = {}                    # id of a first token -> its slot
+
+        def recorded_admit(params, slot, *args):
+            first = admit(params, slot, *args)
+            self._kept.append(first)
+            slots[id(first)] = slot
+            self.calls.append(("prefill", slot))
+            return first
+
+        def recorded_first_token(first):
+            if first_tokens:
+                self.calls.append(("first", slots[id(first)]))
+            return first_token(first)
+
+        ex.cache.admit, ex.cache.first_token = \
+            recorded_admit, recorded_first_token
 
         def recorded_decode(*args):
             result = decode(*args)
@@ -1450,9 +1490,10 @@ class _Recorded:
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 def test_a_step_enqueues_the_next_decode_before_it_fetches_the_last(paged):
     """In a step that admits nothing the dispatch of step k+1 precedes
-    the fetch of step k; a step that admits fetches first and starts the
-    chain again; a request's last step has only a fetch left; the two
-    counters count what happened."""
+    the fetch of step k; a step that admits enqueues its prefill, then
+    fetches the step in flight, and starts the chain again; a request's
+    last step has only a fetch left; the three counters count what
+    happened."""
     hvd, ex = _executor(paged, max_batch=3)
     try:
         seen = _Recorded(ex)
@@ -1463,11 +1504,13 @@ def test_a_step_enqueues_the_next_decode_before_it_fetches_the_last(paged):
             return seen.calls
 
         _submit(ex, [[5, 9, 200], [31, 77, 3, 18]], 6)
-        assert step() == [("decode", 0)]                  # admits both
+        assert step() == [("prefill", 0), ("prefill", 1),  # admits both
+                          ("decode", 0)]
         assert step() == [("decode", 1), ("fetch", 0)]
         assert step() == [("decode", 2), ("fetch", 1)]
         _submit(ex, [[64, 120]], 3)
-        assert step() == [("fetch", 2), ("decode", 3)]    # admits the third
+        # Admits the third: its prefill is enqueued behind step 2.
+        assert step() == [("prefill", 2), ("fetch", 2), ("decode", 3)]
         assert step() == [("decode", 4), ("fetch", 3)]
         assert sorted(len(s.generated) for s in ex.slots if s is not None) \
             == [2, 5, 5]
@@ -1477,7 +1520,45 @@ def test_a_step_enqueues_the_next_decode_before_it_fetches_the_last(paged):
         assert [ex.completed[rid]["tokens"] for rid in range(3)] == [6, 6, 3]
         assert (ex.stats["decode_dispatches"],
                 ex.stats["decode_overlapped"]) == (5, 3)
+        assert (ex.stats["admissions"], ex.stats["admit_overlapped"]) \
+            == (3, 1)
         assert ex.stats["steps"] == {"admit": 2, "decode": 4}
+    finally:
+        ex.close()
+        hvd.shutdown()
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_a_step_that_admits_two_leaves_one_prefill_unfetched_at_most(paged):
+    """One step admits two requests with a decode step in flight: the
+    first prefill is enqueued, the step in flight fetched, the first
+    token fetched, and only then is the second prefill enqueued; the
+    second finds nothing in flight.  Both are served the greedy loop's
+    tokens."""
+    hvd, ex = _executor(paged, max_batch=3)
+    try:
+        seen = _Recorded(ex, first_tokens=True)
+        _submit(ex, [[5, 9, 200]], 8)
+        assert ex._serve_step() and ex._serve_step()
+        assert ex._in_flight is not None
+        del seen.calls[:]
+        _submit(ex, [[31, 77, 3, 18], [64, 120]], 4)
+        assert ex._serve_step()
+        assert seen.calls == [("prefill", 1), ("fetch", 1), ("first", 1),
+                              ("prefill", 2), ("first", 2), ("decode", 2)]
+        unfetched = 0
+        for call, _ in seen.calls:
+            unfetched += {"prefill": 1, "first": -1}.get(call, 0)
+            assert unfetched <= 1
+        assert (ex.stats["admissions"], ex.stats["admit_overlapped"]) \
+            == (3, 1)
+        for _ in range(12):
+            assert ex._serve_step()
+        greedy = _reference(ex)
+        for rid, (prompt, new) in enumerate([([5, 9, 200], 8),
+                                             ([31, 77, 3, 18], 4),
+                                             ([64, 120], 4)]):
+            assert ex.completed[rid]["generated"] == greedy(prompt, new)
     finally:
         ex.close()
         hvd.shutdown()

@@ -200,7 +200,9 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
     the one ``tfm.prefill`` and ``tfm.decode_step`` give the request on
     a cache of its own.  A step is enqueued before the one before it is
     fetched (ISSUE 35): a slot's input token is the host's after an
-    admission and the last step's result, on the device, otherwise."""
+    admission and the last step's result, on the device, otherwise.  The
+    second admission's prefill is enqueued behind a step in flight, which
+    is fetched before its first token."""
     from horovod_tpu.serving import slotcache
     from horovod_tpu.serving.replica import _decode_model_cfg, _seeded_params
 
@@ -222,8 +224,10 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
     got: dict[int, list] = {}
     flying: list = []                 # (result, the rids it decodes for)
 
-    def admit(slot, rid, prompt):
-        last[slot] = cache.admit(params, slot, prompt, 8)
+    def admit(slot, rid, prompt, in_flight=lambda: None):
+        first = cache.admit(params, slot, prompt, 8)
+        in_flight()                   # fetched behind the prefill
+        last[slot] = cache.first_token(first)
         from_host[slot] = True
         slots[slot] = types.SimpleNamespace(seq_len=len(prompt), rid=rid)
         got[rid] = [int(last[slot])]
@@ -274,16 +278,17 @@ def test_a_layout_driven_through_the_interface_alone_serves_the_model(paged):
         admit(1, 1, prompts[1])
         for _ in range(3):
             step()
-        fetch()                        # an admission settles what flies
+        fetch()                        # request 0's last row
         cache.release(0)
         slots[0] = None
-        admit(0, 2, prompts[2])        # the same slot, a longer prompt
+        step()                         # request 1 alone, in flight
+        admit(0, 2, prompts[2], fetch)  # the same slot, a longer prompt
         for _ in range(3):
             step()
         fetch()
         for rid, prompt in prompts.items():
             assert got[rid] == reference(prompt, len(got[rid])), rid
-        assert [len(got[rid]) for rid in (0, 1, 2)] == [4, 7, 4]
+        assert [len(got[rid]) for rid in (0, 1, 2)] == [4, 8, 4]
         if paged:
             cache.release(0)
             cache.release(1)
@@ -390,7 +395,8 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
             assert attn[1]["ring_value"].shape == (3, 16, 8 * 128)
             assert stats["window_bytes"] == 2 * 3 * 16 * 1024 * 2
         for rid, prompt in prompts.items():
-            last[rid] = cache.admit(params, rid, prompt, 8)
+            last[rid] = cache.first_token(
+                cache.admit(params, rid, prompt, 8))
             slots[rid] = types.SimpleNamespace(seq_len=len(prompt))
             got[rid] = [int(last[rid])]
         live = read = grid = full = 0
@@ -440,7 +446,7 @@ def test_the_dense_layout_serves_the_same_tokens_through_the_kernel(
         # on the last one's, the other two slots decoding beside it.
         cache.release(1)
         again = [7, 8, 9, 10, 11]
-        last[1] = cache.admit(params, 1, again, 8)
+        last[1] = cache.first_token(cache.admit(params, 1, again, 8))
         slots[1].seq_len = len(again)
         after = [int(last[1])]
         for step in range(3):

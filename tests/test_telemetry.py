@@ -470,6 +470,37 @@ def test_step_parts_time_every_part_and_the_remainder():
         == pytest.approx(seconds["total"], abs=1e-12)
 
 
+def test_a_part_inside_another_is_counted_once():
+    """A part opened inside another (the settle inside an admission)
+    leaves the enclosing part's slot, so the parts and ``other`` still
+    sum to the step; the enclosing timer's own seconds keep it."""
+    import time
+
+    from horovod_tpu.telemetry.spans import StepParts
+
+    parts = StepParts("serve", step=3)
+    admit = parts("admit", rid=1)
+    with admit:
+        time.sleep(0.004)
+        inner = parts("token_fetch")
+        with inner:
+            time.sleep(0.006)
+        time.sleep(0.002)
+    outer = parts("token_fetch")
+    with outer:                        # the same part, at the top level
+        time.sleep(0.003)
+    seconds = parts.close(admits=1)
+    assert set(seconds) == {"admit", "token_fetch", "other", "total"}
+    assert seconds["admit"] == pytest.approx(admit.seconds - inner.seconds,
+                                             abs=1e-12)
+    assert seconds["admit"] >= 0.006 and admit.seconds >= 0.012
+    assert seconds["token_fetch"] == pytest.approx(
+        inner.seconds + outer.seconds, abs=1e-12)
+    assert seconds["other"] >= 0
+    assert seconds["admit"] + seconds["token_fetch"] + seconds["other"] \
+        == pytest.approx(seconds["total"], abs=1e-12)
+
+
 def test_profiler_annotation_is_the_span_helper():
     import horovod_tpu as hvd
     from horovod_tpu.telemetry.spans import span
