@@ -261,7 +261,7 @@ METRICS_WINDOW = register(
     "names the slowest rank once per window.")
 STRAGGLER_THRESHOLD_MS = register(
     "HOROVOD_STRAGGLER_THRESHOLD_MS", 5.0, float,
-    "Mean arrival lag (ms behind the fastest rank, per window) above "
+    "Median arrival lag (ms behind the fastest rank, per window) above "
     "which the coordinator logs a structured straggler warning and sets "
     "the straggler-rank gauge.")
 

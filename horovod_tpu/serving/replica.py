@@ -741,6 +741,12 @@ class ReplicaExecutor:
         common = set.intersection(
             *(set(p.get("staged") or ()) for p in per_rank))
         self._fleet_common = max(common) if common else 0
+        with self._fleet_lock:
+            # Only a version every rank holds can be scheduled; the rest
+            # may be evicted again.  Kept exempt, two ranks whose windows
+            # filled with different versions refuse every newer head and
+            # never swap.
+            self._fleet_reported &= common
         return [rec for p in per_rank for rec in p["done"]]
 
     def _account(self, completions: list[dict]) -> None:
@@ -799,7 +805,8 @@ class ReplicaExecutor:
     # images.  At the cap, a staged version never REPORTED in a
     # completions exchange is evicted for a newer one (the front cannot
     # have scheduled what it never saw); once every staged version has
-    # been reported the puller is refused and retries.
+    # been reported the puller is refused and retries.  An exchange
+    # leaves exempt only the versions every rank reported.
     _FLEET_STAGE_CAP = 4
 
     def _fleet_stage(self, version: int, image, meta) -> bool:
@@ -819,8 +826,9 @@ class ReplicaExecutor:
         never intersect, and no swap ever frees the window.  A version
         that HAS been reported may already be scheduled, so once every
         staged version is reported the puller is refused (False) and
-        retries — a reported image is only ever dropped by the swap
-        path."""
+        retries — until the exchange's intersection frees the versions
+        no other rank holds, a reported image is only ever dropped by
+        the swap path."""
         from ..statesync.snapshot import unflatten_state
 
         if version <= self.weight_version:
@@ -858,7 +866,8 @@ class ReplicaExecutor:
         """The versions this rank holds staged, for the completions
         exchange: the front schedules the newest version present in
         EVERY rank's report.  Reported versions become eviction-exempt
-        — from here on only the swap path may drop them."""
+        until the exchange's intersection, which keeps only those every
+        rank holds."""
         with self._fleet_lock:
             versions = tuple(sorted(self._fleet_staged))
             self._fleet_reported.update(versions)

@@ -14,9 +14,12 @@ collectives, no extra sockets):
    rank's request arrival is that tensor's negotiation skew, and the
    last-arriving rank is its straggler.  Aggregated over a window of
    ``HOROVOD_METRICS_WINDOW`` released tensors into min/mean/max/p99
-   gauges; a rank whose mean lag exceeds
+   gauges; a rank whose median lag exceeds
    ``HOROVOD_STRAGGLER_THRESHOLD_MS`` is named in a structured warning
    and a gauge — long before it would ever trip the stall inspector.
+   The median, not the mean: a rank that arrives late at every tensor
+   is named, and one long stall of another rank (a loaded host, a
+   page fault) does not outweigh it.
 
 2. **Per-rank self-reported snapshots** (bounded: four scalars) ride each
    worker's RequestList (message.py ``tm_*`` fields): cycle count, summed
@@ -31,6 +34,8 @@ natural negotiation (new tensor, cache invalidation, autotune heartbeat)
 or every cycle under ``HOROVOD_FINGERPRINT=strict``.
 """
 from __future__ import annotations
+
+import statistics
 
 from ..common import config
 from ..common.logging import logger
@@ -55,8 +60,7 @@ class StragglerAggregator:
         self.last_skew_ms = 0.0
         self.windows_completed = 0
         # Window accumulators.
-        self._lag_sum = [0.0] * size
-        self._lag_count = [0] * size
+        self._lags: list[list[float]] = [[] for _ in range(size)]
         self._lag_samples: list[float] = []
         self._tensors_seen = 0
         # Gauges (created once; labels stat= keeps one metric family).
@@ -68,10 +72,10 @@ class StragglerAggregator:
                     labels={"stat": stat})
             for stat in ("min", "mean", "max", "p99")}
         self._g_rank = g("horovod_controller_straggler_rank",
-                         "Rank with the highest mean negotiation lag in "
+                         "Rank with the highest median negotiation lag in "
                          "the last window (-1 = none)")
         self._g_lag = g("horovod_controller_straggler_lag_ms",
-                        "Mean lag of the straggler rank in the last "
+                        "Median lag of the straggler rank in the last "
                         "window, ms behind the fastest rank")
         self._c_windows = registry.counter(
             "horovod_controller_straggler_windows_total",
@@ -90,8 +94,7 @@ class StragglerAggregator:
         for rank, t in arrival_times.items():
             lag_ms = (t - first) * 1e3
             if 0 <= rank < self.size:
-                self._lag_sum[rank] += lag_ms
-                self._lag_count[rank] += 1
+                self._lags[rank].append(lag_ms)
             self._lag_samples.append(lag_ms)
         self._tensors_seen += 1
         if self._tensors_seen >= self.window:
@@ -110,10 +113,10 @@ class StragglerAggregator:
             }
             for stat, gauge in self._g_stats.items():
                 gauge.set(stats[stat])
-        means = [self._lag_sum[r] / self._lag_count[r]
-                 if self._lag_count[r] else 0.0 for r in range(self.size)]
-        straggler = max(range(self.size), key=lambda r: means[r])
-        skew = means[straggler] - min(means)
+        medians = [statistics.median(lags) if lags else 0.0
+                   for lags in self._lags]
+        straggler = max(range(self.size), key=lambda r: medians[r])
+        skew = medians[straggler] - min(medians)
         self.windows_completed += 1
         if skew > self.threshold_ms:
             self.last_straggler = straggler
@@ -123,7 +126,7 @@ class StragglerAggregator:
             self._c_windows.inc()
             logger.warning(
                 "telemetry: rank %d is the slowest rank this window — its "
-                "collective submissions arrive %.1f ms (mean) behind the "
+                "collective submissions arrive %.1f ms (median) behind the "
                 "fastest rank over %d negotiated tensors (window lag "
                 "min/mean/max/p99 = %.1f/%.1f/%.1f/%.1f ms). A persistent "
                 "straggler caps the whole pod at its pace (arxiv "
@@ -134,8 +137,7 @@ class StragglerAggregator:
         else:
             self._g_rank.set(-1.0)
             self._g_lag.set(skew)
-        self._lag_sum = [0.0] * self.size
-        self._lag_count = [0] * self.size
+        self._lags = [[] for _ in range(self.size)]
         self._lag_samples = []
         self._tensors_seen = 0
 

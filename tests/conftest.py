@@ -17,15 +17,20 @@ if "xla_force_host_platform_device_count" not in _flags:
 # Persistent XLA compile cache: the suite's wall clock is dominated by
 # XLA-CPU compiles of the model-train-step tests (Inception train step
 # alone ~200 s cold, ~24 s warm); repeat runs on one box hit the disk
-# cache and skip them.  Set through the environment (not jax.config) so
-# every spawned worker subprocess — multiprocess batteries, estimators,
-# multihost tests — inherits it.  Opt out with
-# HOROVOD_TEST_COMPILE_CACHE=0 (e.g. when bisecting a compiler issue).
+# cache and skip them.  Every compile is kept, however short: the toy
+# programs of the serving configurations compile in well under a second
+# each but by the thousand (the Solar file's tests: 332 s of junit time
+# cold, 160 s with its programs cached).  The cache lies in the checkout
+# (tests/.jax_cache, ignored by git), so two checkouts never share one.
+# Set through the environment (not jax.config) so every spawned worker
+# subprocess — multiprocess batteries, estimators, multihost tests —
+# inherits it.  Opt out with HOROVOD_TEST_COMPILE_CACHE=0 (e.g. when
+# bisecting a compiler issue).
 if os.environ.get("HOROVOD_TEST_COMPILE_CACHE", "1") != "0":
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          "/tmp/horovod_tpu_test_jax_cache")
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), ".jax_cache"))
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
-                          "2.0")
+                          "0")
     os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES",
                           "-1")
 
@@ -48,7 +53,7 @@ try:
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs",
             float(os.environ.get(
-                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2.0")))
+                "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")))
         jax.config.update(
             "jax_persistent_cache_min_entry_size_bytes",
             int(os.environ.get(

@@ -2913,7 +2913,7 @@ def _battery_fleet_train(port):
     departed = False
     step = 0
     deadline = _time.monotonic() + 300.0
-    while _time.monotonic() < deadline:
+    while True:
         # Bare collectives: any RanksFailedError fails the battery —
         # the migration must ride the orderly-departure boundary.
         _statesync_train_step(hvd, state)
@@ -2939,14 +2939,19 @@ def _battery_fleet_train(port):
             directive = poll_depart(kv, "train", hvd.rank())
             if directive is not None:
                 svc.request_depart()
-        if shrunk:
-            # The survivors stop at the SAME step: each polls the flag on
-            # its own, and one that saw it a poll earlier would shut down
-            # under its peer's next allreduce.
-            seen = float(kv.get("fleet.test", "done") is not None)
-            if hvd.allreduce(np.full(1, seen, np.float32), op=hvd.Sum,
-                             name="fleet.stop")[0] > 0:
-                break
+        # The trainers stop at the SAME step, on a vote that every step
+        # takes in the order of their collectives: each polls the front's
+        # flag (and rank 0 its journal record) on its own, and one that
+        # left a step earlier, or on its own clock, would shut down under
+        # its peer's next allreduce.  Ready once the front posted done and
+        # the controller closed the record; the deadline only guards.
+        ready = kv.get("fleet.test", "done") is not None and (
+            launch_rank != 0 or ctl.stats["completed"] > 0)
+        late = _time.monotonic() > deadline
+        votes = hvd.allreduce(np.asarray([ready, late], np.float32),
+                              op=hvd.Sum, name="fleet.stop")
+        if votes[0] == hvd.size() or votes[1] > 0:
+            break
         _time.sleep(0.1)
     if departed:
         assert launch_rank == 2 and directive is not None, \
@@ -2955,13 +2960,11 @@ def _battery_fleet_train(port):
         hvd.shutdown()
         return _battery_fleet_mover(port, int(directive["mid"]))
     assert shrunk, "the migration never happened"
+    assert votes[0] == hvd.size(), \
+        f"no done flag and closed journal record in 300 s: {votes}"
     if launch_rank == 0:
         # The controller observed the joined mark and closed the
         # journal record (done) — one migration, zero aborts.
-        ctl_deadline = _time.monotonic() + 60.0
-        while not ctl.stats["completed"] \
-                and _time.monotonic() < ctl_deadline:
-            _time.sleep(0.1)
         assert ctl.stats["migrations"] == 1, ctl.stats
         assert ctl.stats["completed"] == 1, ctl.stats
         assert ctl.stats["aborted"] == 0, ctl.stats

@@ -11,11 +11,8 @@ from __future__ import annotations
 
 import copy
 import dataclasses
-import functools
 import math
-import os
 import random
-import sys
 import types
 
 import jax
@@ -24,65 +21,17 @@ import numpy as np
 import pytest
 from flax import linen as nn
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for path in (os.path.join(REPO, "benchmarks", "chip"), REPO):
-    if path not in sys.path:
-        sys.path.insert(0, path)
+import servekit as kit
+from horovod_tpu.models import hybrid, moe, transformer
+from horovod_tpu.ops import mla
+from horovod_tpu.serving import slotcache
+from servekit import harness, model_config, solo_world, tokens_of  # noqa: F401
+from test_decode_attention import pallas_calls
 
-import axk1_counts  # noqa: E402
-import axk1_reference as ref  # noqa: E402
-import run as harness  # noqa: E402
-
-from horovod_tpu.models import hybrid, moe, transformer  # noqa: E402
-from horovod_tpu.ops import mla  # noqa: E402
-from horovod_tpu.serving import slotcache  # noqa: E402
-from test_decode_attention import pallas_calls  # noqa: E402
-
-CONFIG = "A.X-K1.serve"
-CELL = "axk1_serve_longdoc_sat"
-F32 = {"dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}
-
-
-def load(name: str = CONFIG) -> dict:
-    return harness.load_json(harness.HERE, "configs", name + ".json")
-
-
-def toy_config() -> dict:
-    """The configuration's file at its rehearsal sizes, in float32 so
-    that the program and the reference differ by rounding alone."""
-    cfg = load()
-    cfg = harness.merged(cfg, cfg["rehearsal"])
-    cfg["model"] = {**cfg["model"], "args": {**cfg["model"]["args"], **F32}}
-    return cfg
-
-
-@pytest.fixture(scope="module")
-def toy() -> dict:
-    return toy_config()
-
-
-def seeded(cfg: dict, seed: int = 40, held=None) -> dict:
-    return ref.weights(types.SimpleNamespace(
-        config=cfg, seed=seed, resolve=harness.resolve), held)
-
-
-@pytest.fixture(scope="module")
-def params(toy):
-    return seeded(toy)
-
-
-def model_config(cfg: dict, **overrides) -> hybrid.HybridConfig:
-    return hybrid.HybridConfig(**{**harness.build_args(cfg), **overrides})
-
-
-def reference_logits(params, tokens, cfg):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, t: ref.logits(p, t, cfg))(
-            params, jnp.asarray(tokens))
-
-
-def tokens_of(seed: int, *shape) -> jax.Array:
-    return jax.random.randint(jax.random.key(seed), shape, 2, 256)
+FAMILY = "axk1"
+ref, axk1_counts = kit.module(FAMILY), kit.module(FAMILY, "counts")
+toy, params = kit.fixtures(FAMILY)
+slot_programs = kit.slot_programs_fixture()
 
 
 def interpreted(monkeypatch):
@@ -99,7 +48,7 @@ def test_yarn_frequencies_and_the_score_scale_at_the_published_widths():
     """low 10 and high 23 of the 32 rotary pairs (base 10,000, factor 32
     over 4,096 positions, beta 32 and 1), and tau = 192^-1/2 x m^2 =
     0.130861, in the program and in the reference alike."""
-    cfg = load()
+    cfg = kit.load(FAMILY)
     yarn = cfg["rope_scaling"]
     plain = 10000.0 ** -(np.arange(0, 64, 2) / 64)
     ramp = np.clip((np.arange(32) - 10) / (23 - 10), 0, 1)
@@ -271,7 +220,7 @@ def test_the_shares_of_all_16_chips_add_up_to_the_uncut_layer(toy):
     layer and the reference's, add up to what the reference gives the
     layer with every expert in one place."""
     cfg = {**toy, "experts_held": [0, 16], "n_routed_experts": 16}
-    whole = seeded(cfg)["layer_1"]
+    whole = kit.seeded_layer(FAMILY, cfg)
     x = jax.random.normal(jax.random.key(3), (2, 11, 64))
     shared_w = {name: whole["moe"][name] for name in
                 ("shared_gate", "shared_up", "shared_down")}
@@ -286,7 +235,7 @@ def test_the_shares_of_all_16_chips_add_up_to_the_uncut_layer(toy):
         program = reference = once
         for first in range(16):
             held = (first, 1)
-            mine = seeded({**toy, "experts_held": list(held)})["layer_1"]
+            mine = kit.seeded_layer(FAMILY, toy, experts_held=list(held))
             np.testing.assert_array_equal(
                 mine["moe"]["experts_gate"],
                 whole["moe"]["experts_gate"][first:first + 1])
@@ -304,16 +253,7 @@ def test_the_shares_of_all_16_chips_add_up_to_the_uncut_layer(toy):
 
 
 # ------------------------------------------------------------------ the model
-def test_the_seeded_weights_have_the_models_own_tree(toy, params):
-    model = hybrid.HybridLM(model_config(toy))
-    own = jax.eval_shape(lambda: model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    assert jax.tree_util.tree_structure(own) \
-        == jax.tree_util.tree_structure(params)
-    assert [(leaf.shape, leaf.dtype) for leaf in
-            jax.tree_util.tree_leaves(own)] \
-        == [(leaf.shape, leaf.dtype) for leaf in
-            jax.tree_util.tree_leaves(params)]
+def test_the_toy_has_latent_attention_and_a_shared_expert(params):
     attn = params["layer_1"]["attn"]
     assert attn["wq_b"]["kernel"].shape == (32, 4, 24)
     assert attn["wkv_a"]["kernel"].shape == (64, 40)
@@ -329,7 +269,7 @@ def test_the_whole_forward_pass_agrees_with_the_reference(length, toy,
     model = hybrid.HybridLM(model_config(toy))
     tokens = tokens_of(length, 2, length)
     got = jax.jit(model.apply)({"params": params}, tokens)
-    want = reference_logits(params, tokens, toy)
+    want = kit.reference_logits(ref, params, tokens, toy)
     assert got.shape == want.shape == (2, length, 256)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -337,7 +277,7 @@ def test_the_whole_forward_pass_agrees_with_the_reference(length, toy,
 @pytest.mark.parametrize("kernel", [False, True])
 @pytest.mark.parametrize("n, bucket", [(1, 8), (8, 8), (13, 16), (50, 64)])
 def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
-        n, bucket, kernel, toy, params, monkeypatch):
+        n, bucket, kernel, toy, params, slot_programs, monkeypatch):
     """A prompt of ``n`` right-padded to ``bucket`` (the expanded form,
     in blocks), inserted as row 2 of a ``DenseSlotCache`` of 3 rows whose
     last occupant was another stream, then 20 tokens decoded there in
@@ -346,31 +286,10 @@ def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
     full forward pass."""
     if kernel:
         interpreted(monkeypatch)
-    steps = 20
-    config = model_config(toy, decode=True, max_seq_len=128)
-    family = config.family
-    model = family.build(config)
-    serve = types.SimpleNamespace(slots=3, max_seq=128, warmup_buckets=())
-    cache = slotcache.DenseSlotCache(serve, family, model, {})
-    cache.fresh(params)
-    stale = tokens_of(99, 1, 64)
-    _, old = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=jnp.int32(60)))(params, stale)
-    cache.tree = cache._insert_jit(cache.tree, old, np.int32(2))
-    tokens = tokens_of(n, 1, n + steps)
-    want = reference_logits(params, tokens, toy)[0]
-    padded = jnp.ones((1, bucket), jnp.int32).at[:, :n].set(tokens[:, :n])
-    logits, row = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=jnp.int32(n)))(params, padded)
-    np.testing.assert_allclose(logits[0, n - 1], want[n - 1], atol=2e-5)
-    cache.tree = cache._insert_jit(cache.tree, row, np.int32(2))
+    cache, model, tokens = kit.decode_through_the_slot_cache(
+        ref, toy, params, slot_programs("kernel" if kernel else ""), n,
+        bucket, 20)
     assert cache.tree["layer_0"]["attn"]["latent"].shape == (3, 128, 128)
-    step = jax.jit(lambda p, c, t: family.decode_step(
-        model, {"params": p}, c, t))
-    for at in range(n, n + steps):
-        fed = jnp.zeros((3, 1), jnp.int32).at[2, 0].set(tokens[0, at])
-        logits, cache.tree = step(params, cache.tree, fed)
-        np.testing.assert_allclose(logits[2, 0], want[at], atol=2e-5)
     assert cache._attend_kinds == [(5, 128, 128 if kernel else 0)]
     assert cache.stats["attend_layers"] == 5
     assert cache.stats["attend_write_fused_layers"] == (5 if kernel else 0)
@@ -382,7 +301,7 @@ def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
 # -------------------------------------------------------------- the counts
 def test_the_counts_at_the_published_widths():
     """ISSUE 40's arithmetic, from the configuration's own file."""
-    cfg = load()
+    cfg = kit.load(FAMILY)
     counts = axk1_counts
     assert counts.layers(cfg) == (5, 4)
     assert counts.row_width(cfg) == 576
@@ -441,29 +360,6 @@ def test_the_counts_at_the_published_widths():
         == 5 * 139_264 * 1000.0
 
 
-def test_the_traffic_table_is_the_laws_quantiles():
-    def quantiles(low, high, points):
-        return [round(low * (high / low) ** ((i + 0.5) / points))
-                for i in range(points)]
-    traffic = harness.load_json(harness.HERE, "traffic", "longdoc_sat.json")
-    table = traffic["requests"]
-    prompts, outputs = quantiles(2048, 10240, 64), quantiles(1024, 4096, 64)
-    rng = random.Random(40)
-    rng.shuffle(prompts)
-    rng.shuffle(outputs)
-    assert table == [list(pair) for pair in zip(prompts, outputs)]
-    assert traffic["clients"] == 64
-    cfg = load()
-    serve = types.SimpleNamespace(
-        max_seq=cfg["serve"]["max_seq"],
-        warmup_buckets=tuple(cfg["serve"]["warmup_buckets"]))
-    buckets = sorted({slotcache.prompt_bucket(serve, p) for p, _ in table})
-    assert buckets == cfg["serve"]["warmup_buckets"] \
-        == [3072, 4096, 6144, 8192, 10240]
-    assert max(p + o for p, o in table) <= cfg["serve"]["max_seq"] == 14336
-    assert cfg["serve"]["token_budget"] >= max(buckets) + 64
-
-
 def test_a_warm_up_bucket_below_the_power_of_two_takes_the_prompt():
     """``prompt_bucket``: the next power of two, or a listed warm-up
     bucket between the prompt and it; lists of powers of two (every
@@ -481,24 +377,6 @@ def test_a_warm_up_bucket_below_the_power_of_two_takes_the_prompt():
 
 
 # ------------------------------------------------------------- the replica
-@pytest.fixture
-def solo_world():
-    import horovod_tpu as hvd
-    hvd.shutdown()
-    for var in ("HOROVOD_RANK", "HOROVOD_SIZE"):
-        os.environ.pop(var, None)
-    hvd.init()
-    yield hvd
-    hvd.shutdown()
-
-
-def executor(model_cfg, params=None, **kw):
-    from horovod_tpu.serving import ReplicaExecutor, ServeConfig
-    return ReplicaExecutor(ServeConfig(**{**dict(
-        model_cfg=model_cfg, max_batch=3, token_budget=64, max_seq=64,
-        slo_ms=60000.0, warmup_buckets=(8, 16, 32)), **kw}), params=params)
-
-
 def test_the_replica_serves_the_references_best_and_counts_latent_layers(
         toy, params, solo_world):
     """Six requests over three slots on the normal path: every served
@@ -508,7 +386,7 @@ def test_the_replica_serves_the_references_best_and_counts_latent_layers(
     prompts = [[rng.randrange(2, 256) for _ in range(n)]
                for n in (1, 3, 9, 17, 26, 30)]
     new = [12, 30, 7, 25, 5, 21]
-    ex = executor(model_config(toy), params)
+    ex = kit.executor(FAMILY, model_config(toy), params)
     try:
         assert ex.family is hybrid.ROUTED_FAMILY
         stats = ex.stats
@@ -516,39 +394,14 @@ def test_the_replica_serves_the_references_best_and_counts_latent_layers(
             == 5 * 3 * 64 * 128 * 4 + 5 * 3 * 4
         assert stats["state_bytes"] == stats["window_bytes"] == 0
         assert stats["attend_layers"] == 5
-        for prompt, count in zip(prompts, new):
-            ex.stats["offered"] += 1
-            assert ex.queue.submit(list(prompt), count) is not None
-        ex.serve_loop(stop_when=lambda: True)
-        streams = [ex.completed[rid]["generated"]
-                   for rid in sorted(ex.completed)]
+        streams = kit.serve(ex, prompts, new)
     finally:
         ex.close()
     assert [len(s) for s in streams] == new
     for prompt, served in zip(prompts, streams):
-        logits = reference_logits(params, [prompt + served], toy)[0]
-        at = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
-        assert float(jnp.max(jnp.max(logits[at], -1)
-                             - logits[at, np.asarray(served)])) <= 1e-5
+        assert kit.widest_gap(ref, params, toy, prompt, served) <= 1e-5
     assert 0 < stats["attend_live_positions"] < stats["attend_read_positions"]
     assert stats["moe_expert_slots"] % (4 * 4) == 0
-
-
-def test_the_programs_carry_the_scope_and_kernel_names(toy, solo_world):
-    ex = executor(model_config(toy))
-    try:
-        decode, args = ex.cache._decode_call(
-            ex.params, ex._last_tokens, ex._token_on_host)
-        decode = decode.lower(*args)
-        prefill = ex.cache._prefill_jit.lower(
-            ex.params, jnp.zeros((1, 16), jnp.int32), jnp.int32(11))
-        for program, scopes in ((decode, ("hvd.mla_decode",)),
-                                (prefill, ("hvd.prefill_attend",))):
-            named = program.as_text(debug_info=True)
-            for scope in (*scopes, "hvd.moe_route"):
-                assert scope in named, scope
-    finally:
-        ex.close()
 
 
 def test_a_long_prefill_runs_the_expert_products_in_chunks(monkeypatch):
@@ -578,26 +431,6 @@ def test_a_long_prefill_runs_the_expert_products_in_chunks(monkeypatch):
 
 
 # --------------------------------------------- the benchmark's own comparison
-def check_control(monkeypatch, capsys, seed: int) -> dict:
-    load_json = harness.load_json
-
-    def patched(*parts):
-        data = copy.deepcopy(load_json(*parts))
-        for over in ({"served_check": {"requests": 64}},
-                     {"trace_steps": 300}):
-            if set(over) <= set(data):
-                data["rehearsal"] = harness.merged(data["rehearsal"], over)
-        return data
-
-    monkeypatch.setattr(harness, "load_json", patched)
-    code = harness.main(["--workload", CELL, "--seed", str(seed),
-                         "--trace", "1", "--rehearse-cpu", "--check",
-                         "control"])
-    line, = [ln for ln in capsys.readouterr().out.splitlines()
-             if " check {" in ln]
-    return {"code": code, **harness.json.loads(line[line.index("{"):])}
-
-
 @pytest.mark.parametrize("seed", [3, 2147483659])
 def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
                                                          capsys):
@@ -605,7 +438,7 @@ def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
     served tokens, their replay, the attention over what the program fed
     its own and the router's rule stay inside the toy limits, and the
     reference computed in int8 does not, by the logits' limits."""
-    seen = check_control(monkeypatch, capsys, seed)
+    seen = kit.check_control(monkeypatch, capsys, FAMILY, seed)
     assert seen["code"] == 0 and seen["ok"] and not seen["problems"]
     assert seen["served_tokens"] > 150
     assert all(seen[key] <= limit for key, limit in seen["limits"].items())
@@ -674,18 +507,8 @@ FAULTS = {
 ALONE = ("replay_err", "attend_gap", "route_gap")
 
 
-def program_against_reference(cfg: dict, params) -> dict:
-    """``served_gap`` on one stream of 21 prompt tokens (in the widest
-    bucket, so right-padded) and 40 more: the three numbers that do not
-    ask who chose the tokens."""
-    tokens = np.zeros((1, 256), np.int32)
-    tokens[0, :61] = np.asarray(tokens_of(5, 61))
-    seen = ref.served_gap(cfg)(params, tokens, np.int32(21), np.int32(61))
-    return {key: float(seen[key]) for key in ALONE}
-
-
 def test_the_sound_program_reads_rounding_alone(toy, params):
-    seen = program_against_reference(toy, params)
+    seen = kit.program_against_reference(ref, toy, params, ALONE)
     limits = toy["served_check"]["limits"]
     assert all(seen[key] <= limits[key] / 10 for key in ALONE), seen
 
@@ -699,7 +522,7 @@ def test_a_planted_fault_comes_out_over_a_toy_limit(fault, toy, params,
     the logits, ``route_gap`` the router's rule)."""
     plant, must = FAULTS[fault]
     plant(monkeypatch)
-    seen = program_against_reference(toy, params)
+    seen = kit.program_against_reference(ref, toy, params, ALONE)
     limits = toy["served_check"]["limits"]
     over = {key for key in ALONE if seen[key] > limits[key]}
     assert over and must <= over, (seen, limits)
@@ -714,7 +537,7 @@ def test_the_reference_follows_the_programs_groups_in_bfloat16(toy):
     this stream read ``replay_err`` 0.545)."""
     cfg = copy.deepcopy(toy)
     cfg["model"]["args"]["dtype"] = "@jax.numpy:bfloat16"
-    params = seeded(cfg, seed=2)
+    params = kit.seeded(FAMILY, cfg, seed=2)
     tokens = np.zeros((1, 256), np.int32)
     tokens[0, :120] = np.asarray(jax.random.randint(jax.random.key(2),
                                                     (120,), 2, 256))

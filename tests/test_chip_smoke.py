@@ -133,8 +133,11 @@ def test_cache_default_is_one_fixed_place_in_the_checkout(children):
 @pytest.fixture(scope="module")
 def v5e():
     """One device of libtpu's compile-only v5e:2x2 topology: lowers and
-    compiles (Mosaic included) for the chip, runs nothing."""
+    compiles (Mosaic included) for the chip, runs nothing.  The
+    persistent compile cache is off meanwhile: without a chip no process
+    here can load what such a compile would leave in it."""
     from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
     try:
         topo = topologies.get_topology_desc(topology_name="v5e:2x2",
                                             platform="tpu")
@@ -142,8 +145,15 @@ def v5e():
         pytest.skip(f"compile-only v5e:2x2 topology unavailable: "
                     f"{type(exc).__name__}: {exc}")
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    return NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)),
-                         PartitionSpec())
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)),
+                            PartitionSpec())
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
 
 
 def _compile_attention_grad(v5e, monkeypatch, shape, **blocks) -> str:

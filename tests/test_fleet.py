@@ -546,6 +546,44 @@ def test_replica_stage_window_evicts_unreported_refuses_reported():
     assert ex._fleet_stage(cap + 2, image, {"step": 9, "digest": 9})
 
 
+def test_replicas_whose_windows_hold_different_versions_still_swap():
+    """The front's window froze at the grow's first exchange while the
+    joiner pulled only newer heads: two full windows of reported
+    versions, none in common.  The exchange that finds no common
+    version frees both windows, so the next head both pull is staged
+    by both, becomes common and is scheduled, where refusing it for
+    ever would leave the group on its old weights."""
+    import types
+
+    front, joiner = _bare_replica(), _bare_replica()
+    image = bytes(flatten_state({"params": front.params}))
+    cap = front._FLEET_STAGE_CAP
+    for ex, first in ((front, 1), (joiner, cap + 1)):
+        for v in range(first, first + cap):
+            assert ex._fleet_stage(v, image, {"step": v, "digest": v})
+    for ex, peer in ((front, joiner), (joiner, front)):
+        ex.size, ex._gen, ex.slots, ex._unreported = 2, 0, [], []
+        ex.stats.update(exchanges=0, local_exchanges=0)
+        ex.hvd = types.SimpleNamespace(
+            allgather_object=lambda mine, name, peer=peer: [
+                mine, {"done": [], "staged": tuple(peer._fleet_staged)}])
+    for ex in (front, joiner):
+        ex._exchange_completions()
+    assert front._fleet_common == joiner._fleet_common == 0
+    for head in range(2 * cap + 1, 2 * cap + 4):
+        staged = [ex._fleet_stage(head, image, {"step": head, "digest": 1})
+                  for ex in (front, joiner)]
+        for ex in (front, joiner):
+            ex._exchange_completions()
+        if front._fleet_common:
+            break
+    assert staged == [True, True]
+    assert front._fleet_common == joiner._fleet_common == 2 * cap + 1
+    front._fleet_swap(front._fleet_common)
+    joiner._fleet_swap(joiner._fleet_common)
+    assert front.weight_version == joiner.weight_version == 2 * cap + 1
+
+
 # ---------------------------------------------------------------------------
 # loadgen staleness accounting
 # ---------------------------------------------------------------------------

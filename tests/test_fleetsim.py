@@ -250,6 +250,10 @@ def test_chaos_grammar_composes_virtualized(tmp_path, monkeypatch):
 
 
 def test_straggler_attributed_at_fleet_scale(tmp_path, monkeypatch):
+    """Rank 11 sleeps 40 ms more than its peers every step, and the
+    coordinator's aggregator names it by its median lag over a window:
+    a loaded host that stalls another rank at one boundary of a window
+    does not name that rank."""
     report = _in_proc_fleet(tmp_path, monkeypatch, ranks=16, steps=8,
                             straggler_vid=11, straggler_ms=40.0)
     assert report.failed_steps == 0

@@ -5,78 +5,29 @@ granite_reference.py) on its seeded weights, comparing logits.  Toy
 widths: the rehearsal sizes of the configuration's own file."""
 from __future__ import annotations
 
-import copy
-import os
 import random
-import sys
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for path in (os.path.join(REPO, "benchmarks", "chip"), REPO):
-    if path not in sys.path:
-        sys.path.insert(0, path)
+import servekit as kit
+from horovod_tpu.models import hybrid
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import ssm
+from horovod_tpu.serving.slotcache import prompt_bucket
+from servekit import model_config, solo_world, tokens_of  # noqa: F401
 
-import granite_counts  # noqa: E402
-import granite_reference as ref  # noqa: E402
-import run as harness  # noqa: E402
-
-from horovod_tpu.models import hybrid  # noqa: E402
-from horovod_tpu.models import transformer as tfm  # noqa: E402
-from horovod_tpu.ops import ssm  # noqa: E402
-from horovod_tpu.serving.slotcache import prompt_bucket  # noqa: E402
-
-CONFIG = "granite-4.0-h-micro.serve"
-F32 = {"dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}
+FAMILY = "granite"
+ref, granite_counts = kit.module(FAMILY), kit.module(FAMILY, "counts")
+toy, params = kit.fixtures(FAMILY)
 
 
-@pytest.fixture(scope="module")
-def toy() -> dict:
-    """The configuration's file at its rehearsal sizes (hidden 64, layers
-    mamba, attention, mamba, 4 query heads over 2 key-value heads, 8
-    Mamba heads of 16, state 16, chunk 8, vocabulary 256), in float32 so
-    that the program and the reference differ by rounding alone."""
-    cfg = harness.load_json(harness.HERE, "configs", CONFIG + ".json")
-    cfg = harness.merged(cfg, cfg["rehearsal"])
-    cfg["model"] = {**cfg["model"], "args": F32}
-    return cfg
-
-
-def model_config(cfg: dict, **overrides) -> hybrid.HybridConfig:
-    return hybrid.HybridConfig(**{**harness.build_args(cfg), **overrides})
-
-
-@pytest.fixture(scope="module")
-def params(toy):
-    return ref.weights(types.SimpleNamespace(
-        config=toy, seed=29, resolve=harness.resolve))
-
-
-def reference_logits(params, tokens, cfg):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, t: ref.logits(p, t, cfg))(
-            params, jnp.asarray(tokens))
-
-
-def tokens_of(seed: int, *shape) -> jax.Array:
-    return jax.random.randint(jax.random.key(seed), shape, 2, 256)
+programs = kit.programs_fixture(max_seq_len=32, interpret=True)
 
 
 # ------------------------------------------------------------------ the model
-def test_the_seeded_weights_have_the_models_own_tree(toy, params):
-    model = hybrid.HybridLM(model_config(toy))
-    own = jax.eval_shape(lambda: model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    assert jax.tree_util.tree_structure(own) \
-        == jax.tree_util.tree_structure(params)
-    assert [leaf.shape for leaf in jax.tree_util.tree_leaves(own)] \
-        == [leaf.shape for leaf in jax.tree_util.tree_leaves(params)]
-
-
 @pytest.mark.parametrize("length", [1, 2, 3, 7, 8, 9, 23])
 def test_the_chunked_scan_agrees_with_the_sequential_reference(
         length, toy, params):
@@ -86,30 +37,21 @@ def test_the_chunked_scan_agrees_with_the_sequential_reference(
     model = hybrid.HybridLM(model_config(toy))
     tokens = tokens_of(length, 2, length)
     got = jax.jit(model.apply)({"params": params}, tokens)
-    want = reference_logits(params, tokens, toy)
+    want = kit.reference_logits(ref, params, tokens, toy)
     assert got.shape == want.shape == (2, length, 256)
     np.testing.assert_allclose(got, want, atol=2e-6)
 
 
 @pytest.mark.parametrize("n", [1, 3, 8, 13])
 def test_prefill_of_a_padded_bucket_then_decode_through_the_cache(
-        n, toy, params):
+        n, toy, params, programs):
     """A prompt of ``n`` in a bucket of 16 with ``lengths = n``, then 6
     decode steps in slot 2 of a four-slot cache: every logit row against
     the reference's full pass over n + 6 tokens, and the state after
     prefill equal to the reference's at ``n`` (which fails if the
     recurrence runs into the padding)."""
-    steps = 6
-    config = model_config(toy, decode=True, max_seq_len=32,
-                          interpret=True)
-    family = config.family
-    model = family.build(config)
-    tokens = tokens_of(100 + n, 1, n + steps)
-    padded = jnp.full((1, 16), 7, jnp.int32).at[:, :n].set(tokens[:, :n])
-    logits, row = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=n))(params, padded)
-    rows = [logits[0, n - 1]]
-
+    row, tokens, _ = kit.prefill_then_decode(ref, toy, params, programs,
+                                             n, atol=2e-6)
     with jax.default_matmul_precision("highest"):
         full = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
                                       params)
@@ -120,18 +62,6 @@ def test_prefill_of_a_padded_bucket_then_decode_through_the_cache(
     assert stored.shape == ssm.state_shape(1, 8, 16, 16) == (1, 1, 16, 128)
     np.testing.assert_allclose(ssm.unpack_state(stored, 8), state,
                                atol=1e-6)
-
-    cache = jax.tree_util.tree_map(
-        lambda big, small: big.at[2].set(small[0]),
-        family.fresh_cache(model, params, 4), row)
-    decode = jax.jit(lambda p, c, t: family.decode_step(
-        model, {"params": p}, c, t))
-    for at in range(n, n + steps - 1):
-        fed = jnp.zeros((4, 1), jnp.int32).at[2, 0].set(tokens[0, at])
-        logits, cache = decode(params, cache, fed)
-        rows.append(logits[2, 0])
-    want = reference_logits(params, tokens, toy)[0, n - 1:n + steps - 1]
-    np.testing.assert_allclose(jnp.stack(rows), want, atol=2e-6)
 
 
 def test_the_convolution_window_is_the_last_real_positions(toy, params):
@@ -217,7 +147,7 @@ def test_off_the_tpu_the_plain_form_runs():
 def test_the_counts_at_the_published_widths():
     """The issue's own reckoning: 3.19 G parameters of which the tied
     matrix is 205.5 M, 75.5 MB of state a slot, 11.5 GB a decode step."""
-    cfg = harness.load_json(harness.HERE, "configs", CONFIG + ".json")
+    cfg = kit.load(FAMILY)
     assert granite_counts.layers(cfg) == (36, 4)
     assert granite_counts.matmul_params(cfg) == 3_190_292_480
     contexts = [730] * 32
@@ -236,42 +166,6 @@ def test_the_counts_at_the_published_widths():
 
 
 # ---------------------------------------------------------------- the replica
-@pytest.fixture
-def solo_world():
-    import horovod_tpu as hvd
-    hvd.shutdown()
-    for var in ("HOROVOD_RANK", "HOROVOD_SIZE"):
-        os.environ.pop(var, None)
-    hvd.init()
-    yield hvd
-    hvd.shutdown()
-
-
-def executor(model_cfg, params=None, **kw):
-    from horovod_tpu.serving import ReplicaExecutor, ServeConfig
-    return ReplicaExecutor(ServeConfig(**{**dict(
-        model_cfg=model_cfg, max_batch=3, token_budget=64, max_seq=64,
-        slo_ms=60000.0, warmup_buckets=(8, 16)), **kw}), params=params)
-
-
-def serve(ex, prompts, max_new) -> list[list[int]]:
-    for prompt, new in zip(prompts, max_new):
-        ex.stats["offered"] += 1
-        assert ex.queue.submit(list(prompt), new) is not None
-    ex.serve_loop(stop_when=lambda: True)
-    assert ex.stats["served"] == len(prompts)
-    return [ex.completed[rid]["generated"] for rid in sorted(ex.completed)]
-
-
-def widest_gap(params, cfg, prompt, served) -> float:
-    """By how much a served token's logit lies below the reference's
-    best at its position, at worst."""
-    logits = reference_logits(params, [prompt + served], cfg)[0]
-    at = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
-    return float(jnp.max(jnp.max(logits[at], -1)
-                         - logits[at, np.asarray(served)]))
-
-
 def test_requests_of_different_lengths_share_the_slot_array(
         toy, params, solo_world):
     """Seven requests over three slots, admitted into a half-decoded
@@ -281,7 +175,7 @@ def test_requests_of_different_lengths_share_the_slot_array(
     prompts = [[rng.randrange(2, 256) for _ in range(n)]
                for n in (1, 2, 5, 8, 9, 13, 16)]
     new = [9, 4, 7, 12, 5, 8, 6]
-    ex = executor(model_config(toy), params)
+    ex = kit.executor(FAMILY, model_config(toy), params)
     try:
         assert ex.family is hybrid.FAMILY
         stats = ex.stats
@@ -290,12 +184,12 @@ def test_requests_of_different_lengths_share_the_slot_array(
         assert "kv_bytes" not in stats     # cache_bytes less state_bytes
         mamba = 3 * (8 * 16 * 16 * 4 + 3 * (128 + 32) * 4)
         assert stats["state_bytes"] == 2 * mamba
-        streams = serve(ex, prompts, new)
+        streams = kit.serve(ex, prompts, new)
     finally:
         ex.close()
     assert [len(s) for s in streams] == new
     for prompt, served in zip(prompts, streams):
-        assert widest_gap(params, toy, prompt, served) <= 1e-6
+        assert kit.widest_gap(ref, params, toy, prompt, served) <= 1e-6
 
 
 def test_a_reused_slot_carries_nothing_of_its_last_occupant(
@@ -309,9 +203,9 @@ def test_a_reused_slot_carries_nothing_of_its_last_occupant(
                for n in (14, 3, 6)]
     new = [10, 8, 8]
     config = model_config(toy)
-    ex = executor(config, params, max_batch=1)
+    ex = kit.executor(FAMILY, config, params, max_batch=1)
     try:
-        streams = serve(ex, prompts, new)
+        streams = kit.serve(ex, prompts, new)
         model = ex.model
     finally:
         ex.close()
@@ -334,41 +228,12 @@ def test_a_reused_slot_carries_nothing_of_its_last_occupant(
         assert served == fresh
 
 
-def test_the_hybrid_programs_carry_the_scope_names(toy, solo_world):
-    """hvd.ssm_update and hvd.ssm_conv are in the decode program's debug
-    info, hvd.ssm_scan in the prefill's, beside hvd.decode_attend, and
-    nowhere in the programs themselves; hvd.sample, which reached no
-    device event, is gone."""
-    ex = executor(model_config(toy))
-    try:
-        decode, args = ex.cache._decode_call(
-            ex.params, ex._last_tokens, ex._token_on_host)
-        decode = decode.lower(*args)
-        prefill = ex.cache._prefill_jit.lower(
-            ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))
-        for program, scopes in (
-                (decode, ("hvd.ssm_update", "hvd.ssm_conv")),
-                (prefill, ("hvd.ssm_scan", "hvd.ssm_conv"))):
-            named = program.as_text(debug_info=True)
-            for scope in (*scopes, "hvd.decode_attend"):
-                assert scope in named, scope
-            assert "hvd.sample" not in named
-            assert "hvd." not in program.as_text()
-    finally:
-        ex.close()
-
-
-def test_a_paged_cache_cannot_hold_recurrent_state_yet(toy, solo_world):
-    with pytest.raises(ValueError, match="recurrent state in KVBlockPool"):
-        executor(model_config(toy), paged=True)
-
-
 def test_the_decoders_programs_are_what_they_were(solo_world):
     """TransformerLM is the protocol's first implementation: the replica
     lowers the decode and prefill programs that tfm.decode_step and
     tfm.prefill lower when called directly, as before the protocol."""
     from horovod_tpu.serving.slotcache import _sample
-    ex = executor(None, max_batch=2)
+    ex = kit.executor(FAMILY, None, max_batch=2)
     try:
         assert ex.family is tfm.FAMILY
         model = ex.model
@@ -411,10 +276,10 @@ def test_flops_per_token_come_from_the_model_family(
     monkeypatch.setenv("HOROVOD_METRICS", "on")
     registry = telemetry.configure()
     try:
-        ex = executor(*((model_config(toy), params) if family == "hybrid"
-                        else (None,)))
+        ex = kit.executor(FAMILY, *((model_config(toy), params)
+                                    if family == "hybrid" else (None,)))
         try:
-            serve(ex, [[5, 6, 7, 8]], [4])
+            kit.serve(ex, [[5, 6, 7, 8]], [4])
             config = ex.model.cfg
         finally:
             ex.close()
@@ -439,24 +304,8 @@ def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
     served tokens stay inside the toy limits, and the tokens that the
     reference computed in int8 (the state left in float32) puts first do
     not."""
-    load = harness.load_json
-
-    def patched(*parts):
-        data = copy.deepcopy(load(*parts))
-        for over in ({"served_check": {"requests": 64}},
-                     {"trace_steps": 500}):
-            if set(over) <= set(data):
-                data["rehearsal"] = harness.merged(data["rehearsal"], over)
-        return data
-
-    monkeypatch.setattr(harness, "load_json", patched)
-    code = harness.main(["--workload", "granite4hm_serve_agent_sat",
-                         "--seed", str(seed), "--trace", "1",
-                         "--rehearse-cpu", "--check", "control"])
-    line, = [ln for ln in capsys.readouterr().out.splitlines()
-             if " check {" in ln]
-    seen = harness.json.loads(line[line.index("{"):])
-    assert code == 0 and seen["ok"] and not seen["problems"]
+    seen = kit.check_control(monkeypatch, capsys, FAMILY, seed)
+    assert seen["code"] == 0 and seen["ok"] and not seen["problems"]
     assert seen["served_tokens"] > 400
     assert any(seen[key] <= limit < seen["control_" + key]
                for key, limit in seen["limits"].items())

@@ -8,82 +8,26 @@ logits.  Toy widths: the rehearsal sizes of the configuration's own
 file (a window of 8, keys 24 and values 16 wide, 8 rotary channels)."""
 from __future__ import annotations
 
-import copy
 import dataclasses
-import os
+import functools
 import random
-import sys
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for path in (os.path.join(REPO, "benchmarks", "chip"), REPO):
-    if path not in sys.path:
-        sys.path.insert(0, path)
+import servekit as kit
+from horovod_tpu.models import hybrid, kvcache, moe
+from horovod_tpu.ops import decode_attention as da
+from servekit import harness, model_config, solo_world, tokens_of  # noqa: F401
+from test_decode_attention import pallas_calls
 
-import mimo_v2_counts  # noqa: E402
-import mimo_v2_reference as ref  # noqa: E402
-import run as harness  # noqa: E402
-
-from horovod_tpu.models import hybrid, kvcache, moe  # noqa: E402
-from horovod_tpu.ops import decode_attention as da  # noqa: E402
-from horovod_tpu.serving import slotcache  # noqa: E402
-from test_decode_attention import pallas_calls  # noqa: E402
-
-CONFIG = "MiMo-V2.5.serve"
-CELL = "mimov25_serve_mixlen_sat"
-F32 = {"dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}
+FAMILY = "mimo"
+ref, mimo_v2_counts = kit.module(FAMILY), kit.module(FAMILY, "counts")
+toy, params = kit.fixtures(FAMILY)
+slot_programs = kit.slot_programs_fixture()
 WINDOW = 8
-
-
-def load(name: str = CONFIG) -> dict:
-    return harness.load_json(harness.HERE, "configs", name + ".json")
-
-
-def toy_config() -> dict:
-    """The configuration's file at its rehearsal sizes (hidden 64; 4 query
-    heads over 1 key-value head in the two global layers and over 2 in
-    the five window layers of 8 positions; keys 24, values 16, 8 rotary
-    channels; a dense MLP of 128 in layer 0, then 16 experts of width
-    32, top-2, experts 4 to 7 held; vocabulary 256), in float32 so that
-    the program and the reference differ by rounding alone."""
-    cfg = load()
-    cfg = harness.merged(cfg, cfg["rehearsal"])
-    cfg["model"] = {**cfg["model"], "args": {**cfg["model"]["args"], **F32}}
-    return cfg
-
-
-@pytest.fixture(scope="module")
-def toy() -> dict:
-    return toy_config()
-
-
-def seeded(cfg: dict, seed: int = 36, held=None) -> dict:
-    return ref.weights(types.SimpleNamespace(
-        config=cfg, seed=seed, resolve=harness.resolve), held)
-
-
-@pytest.fixture(scope="module")
-def params(toy):
-    return seeded(toy)
-
-
-def model_config(cfg: dict, **overrides) -> hybrid.HybridConfig:
-    return hybrid.HybridConfig(**{**harness.build_args(cfg), **overrides})
-
-
-def reference_logits(params, tokens, cfg):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, t: ref.logits(p, t, cfg))(
-            params, jnp.asarray(tokens))
-
-
-def tokens_of(seed: int, *shape) -> jax.Array:
-    return jax.random.randint(jax.random.key(seed), shape, 2, 256)
 
 
 # ------------------------------------------------- the attention's three forms
@@ -348,8 +292,6 @@ def test_a_ring_in_the_lanes_through_the_kernel(n, bucket, monkeypatch):
     past two wraps, each through the lanes kernel (interpreted) under
     the ring's own name with ``lengths = min(index + 1, window)``;
     against the same layer over the whole sequence, the window a mask."""
-    import functools
-
     steps = 2 * WINDOW + 3
     cfg = hybrid.HybridConfig(
         d_model=64, num_heads=16, num_kv_heads=4, window_kv_heads=8,
@@ -424,7 +366,7 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
     the reference gives the layer with every expert in one place;
     through the program's layer and the reference's."""
     cfg = {**toy, "experts_held": [0, 16], "n_routed_experts": 16}
-    whole = seeded(cfg)["layer_1"]
+    whole = kit.seeded_layer(FAMILY, cfg)
     x = jax.random.normal(jax.random.key(3), (2, 11, 64))
     with jax.default_matmul_precision("highest"):
         uncut = ref.feed_forward(
@@ -435,7 +377,7 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
         total_program = total_reference = 0.0
         for first in (0, 4, 8, 12):
             held = (first, 4)
-            mine = seeded({**toy, "experts_held": list(held)})["layer_1"]
+            mine = kit.seeded_layer(FAMILY, toy, experts_held=list(held))
             for name in ("experts_gate", "experts_up", "experts_down"):
                 np.testing.assert_array_equal(
                     mine["moe"][name], whole["moe"][name][first:first + 4])
@@ -452,16 +394,13 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
 
 
 # ------------------------------------------------------------------ the model
-def test_the_seeded_weights_have_the_models_own_tree(toy, params):
-    model = hybrid.HybridLM(model_config(toy))
-    own = jax.eval_shape(lambda: model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    assert jax.tree_util.tree_structure(own) \
-        == jax.tree_util.tree_structure(params)
-    assert [(leaf.shape, leaf.dtype) for leaf in
-            jax.tree_util.tree_leaves(own)] \
-        == [(leaf.shape, leaf.dtype) for leaf in
-            jax.tree_util.tree_leaves(params)]
+def test_the_toy_has_five_window_layers_between_two_global_ones(toy,
+                                                                params):
+    """The rehearsal sizes: hidden 64; 4 query heads over 1 key-value head
+    in the two global layers and over 2 in the five window layers of 8
+    positions; keys 24, values 16, 8 rotary channels; a dense MLP of 128
+    in layer 0, then 16 experts of width 32, top-2, experts 4 to 7
+    held; vocabulary 256."""
     assert toy["layer_types"] == ["attention"] + ["window"] * 5 \
         + ["attention"]
     assert "mlp" in params["layer_0"] and "moe" not in params["layer_0"]
@@ -479,7 +418,7 @@ def test_the_whole_forward_pass_agrees_with_the_reference(length, toy,
     model = hybrid.HybridLM(model_config(toy))
     tokens = tokens_of(length, 2, length)
     got = jax.jit(model.apply)({"params": params}, tokens)
-    want = reference_logits(params, tokens, toy)
+    want = kit.reference_logits(ref, params, tokens, toy)
     assert got.shape == want.shape == (2, length, 256)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -487,37 +426,15 @@ def test_the_whole_forward_pass_agrees_with_the_reference(length, toy,
 @pytest.mark.parametrize("n, bucket", [(1, 8), (5, 8), (8, 8), (13, 16),
                                        (29, 32), (50, 64)])
 def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
-        n, bucket, toy, params):
+        n, bucket, toy, params, slot_programs):
     """A prompt of ``n`` (shorter than the window of 8, as long, longer)
     right-padded to ``bucket`` with ``lengths = n``, inserted as row 2
     of a ``DenseSlotCache`` of 3 rows whose last occupant was another
     stream, then 30 tokens decoded there, the rings going round three
     times and more: every row of logits against the reference's full
     forward pass."""
-    steps = 30
-    config = model_config(toy, decode=True, max_seq_len=128)
-    family = config.family
-    model = family.build(config)
-    serve = types.SimpleNamespace(slots=3, max_seq=128, warmup_buckets=())
-    cache = slotcache.DenseSlotCache(serve, family, model, {})
-    cache.fresh(params)
-    stale = tokens_of(99, 1, 64)
-    _, old = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=jnp.int32(60)))(params, stale)
-    cache.tree = cache._insert_jit(cache.tree, old, np.int32(2))
-    tokens = tokens_of(n, 1, n + steps)
-    want = reference_logits(params, tokens, toy)[0]
-    padded = jnp.ones((1, bucket), jnp.int32).at[:, :n].set(tokens[:, :n])
-    logits, row = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=jnp.int32(n)))(params, padded)
-    np.testing.assert_allclose(logits[0, n - 1], want[n - 1], atol=2e-5)
-    cache.tree = cache._insert_jit(cache.tree, row, np.int32(2))
-    step = jax.jit(lambda p, c, t: family.decode_step(
-        model, {"params": p}, c, t))
-    for at in range(n, n + steps):
-        fed = jnp.zeros((3, 1), jnp.int32).at[2, 0].set(tokens[0, at])
-        logits, cache.tree = step(params, cache.tree, fed)
-        np.testing.assert_allclose(logits[2, 0], want[at], atol=2e-5)
+    cache, _, _ = kit.decode_through_the_slot_cache(
+        ref, toy, params, slot_programs(), n, bucket, 30)
     assert [kind for kind in sorted(cache._attend_kinds)] \
         == [(2, 128, 0), (5, WINDOW, 0)]
 
@@ -525,7 +442,7 @@ def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
 # -------------------------------------------------------------- the counts
 def test_the_counts_at_the_published_widths():
     """ISSUE 36's arithmetic, from the configuration's own file."""
-    cfg = load()
+    cfg = kit.load(FAMILY)
     counts = mimo_v2_counts
     assert counts.layers(cfg) == (2, 5, 6)
     assert counts.attention_params(cfg, 8) == 94_371_840
@@ -577,55 +494,7 @@ def test_the_counts_at_the_published_widths():
         == 2.0 * 64 * 320 * 2 * 1000
 
 
-def test_the_traffic_tables_are_the_laws_quantiles():
-    def quantiles(low, high, points):
-        return [round(low * (high / low) ** ((i + 0.5) / points))
-                for i in range(points)]
-    traffic = harness.load_json(harness.HERE, "traffic", "mixlen_sat.json")
-    table = traffic["requests"]
-    assert sorted(p for p, _ in table) == quantiles(512, 8192, 64)
-    assert sorted(o for _, o in table) == quantiles(1024, 4096, 64)
-    prompts, outputs = quantiles(512, 8192, 64), quantiles(1024, 4096, 64)
-    rng = random.Random(36)
-    rng.shuffle(prompts)
-    rng.shuffle(outputs)
-    assert table == [list(pair) for pair in zip(prompts, outputs)]
-    cfg = load()
-    buckets = cfg["serve"]["warmup_buckets"]
-    assert sorted({max(8, 1 << (p - 1).bit_length()) for p, _ in table}) \
-        == buckets == [1024, 2048, 4096, 8192]
-    assert max(p + o for p, o in table) <= cfg["serve"]["max_seq"]
-    assert cfg["serve"]["token_budget"] >= max(buckets) + 64
-
-
 # ------------------------------------------------------------- the replica
-@pytest.fixture
-def solo_world():
-    import horovod_tpu as hvd
-    hvd.shutdown()
-    for var in ("HOROVOD_RANK", "HOROVOD_SIZE"):
-        os.environ.pop(var, None)
-    hvd.init()
-    yield hvd
-    hvd.shutdown()
-
-
-def executor(model_cfg, params=None, **kw):
-    from horovod_tpu.serving import ReplicaExecutor, ServeConfig
-    return ReplicaExecutor(ServeConfig(**{**dict(
-        model_cfg=model_cfg, max_batch=3, token_budget=64, max_seq=64,
-        slo_ms=60000.0, warmup_buckets=(8, 16, 32)), **kw}), params=params)
-
-
-def serve(ex, prompts, max_new) -> list[list[int]]:
-    for prompt, new in zip(prompts, max_new):
-        ex.stats["offered"] += 1
-        assert ex.queue.submit(list(prompt), new) is not None
-    ex.serve_loop(stop_when=lambda: True)
-    assert ex.stats["served"] == len(prompts)
-    return [ex.completed[rid]["generated"] for rid in sorted(ex.completed)]
-
-
 def test_the_replica_serves_the_references_best_and_counts_by_layer_kind(
         toy, params, solo_world):
     """Seven requests over three slots on the normal path, prompts
@@ -637,7 +506,7 @@ def test_the_replica_serves_the_references_best_and_counts_by_layer_kind(
     prompts = [[rng.randrange(2, 256) for _ in range(n)]
                for n in (1, 3, 8, 9, 17, 26, 30)]
     new = [12, 30, 7, 25, 5, 21, 9]
-    ex = executor(model_config(toy), params)
+    ex = kit.executor(FAMILY, model_config(toy), params)
     try:
         assert ex.family is hybrid.ROUTED_FAMILY
         stats = ex.stats
@@ -648,15 +517,12 @@ def test_the_replica_serves_the_references_best_and_counts_by_layer_kind(
         whole = 3 * 64 * 1 * (24 + 16) * 4
         assert stats["window_bytes"] == 5 * ring
         assert stats["cache_bytes"] == 5 * ring + 2 * whole + 7 * 3 * 4
-        streams = serve(ex, prompts, new)
+        streams = kit.serve(ex, prompts, new)
     finally:
         ex.close()
     assert [len(s) for s in streams] == new
     for prompt, served in zip(prompts, streams):
-        logits = reference_logits(params, [prompt + served], toy)[0]
-        at = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
-        assert float(jnp.max(jnp.max(logits[at], -1)
-                             - logits[at, np.asarray(served)])) <= 1e-5
+        assert kit.widest_gap(ref, params, toy, prompt, served) <= 1e-5
     # A layer's worth: (2 global x length + 5 window x min(length, 8)) / 7,
     # and the plain form reads 64 positions there and 8 here.
     assert 0 < stats["attend_live_positions"] < stats["attend_read_positions"]
@@ -665,46 +531,7 @@ def test_the_replica_serves_the_references_best_and_counts_by_layer_kind(
     assert stats["moe_expert_slots"] % (6 * 4) == 0
 
 
-def test_the_programs_carry_the_scope_and_kernel_names(toy, solo_world):
-    ex = executor(model_config(toy))
-    try:
-        decode, args = ex.cache._decode_call(
-            ex.params, ex._last_tokens, ex._token_on_host)
-        decode = decode.lower(*args)
-        prefill = ex.cache._prefill_jit.lower(
-            ex.params, jnp.zeros((1, 16), jnp.int32), jnp.int32(11))
-        for program, scopes in (
-                (decode, ("hvd.window_attend", "hvd.decode_attend")),
-                (prefill, ("hvd.prefill_attend", "hvd.decode_attend"))):
-            named = program.as_text(debug_info=True)
-            for scope in (*scopes, "hvd.moe_route"):
-                assert scope in named, scope
-            assert "hvd.sample" not in named   # it reached no device event
-    finally:
-        ex.close()
-
-
 # --------------------------------------------- the benchmark's own comparison
-def check_control(monkeypatch, capsys, seed: int) -> dict:
-    load_json = harness.load_json
-
-    def patched(*parts):
-        data = copy.deepcopy(load_json(*parts))
-        for over in ({"served_check": {"requests": 64}},
-                     {"trace_steps": 300}):
-            if set(over) <= set(data):
-                data["rehearsal"] = harness.merged(data["rehearsal"], over)
-        return data
-
-    monkeypatch.setattr(harness, "load_json", patched)
-    code = harness.main(["--workload", CELL, "--seed", str(seed),
-                         "--trace", "1", "--rehearse-cpu", "--check",
-                         "control"])
-    line, = [ln for ln in capsys.readouterr().out.splitlines()
-             if " check {" in ln]
-    return {"code": code, **harness.json.loads(line[line.index("{"):])}
-
-
 @pytest.mark.parametrize("seed", [3, 2147483659])
 def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
                                                          capsys):
@@ -714,7 +541,7 @@ def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
     own and the router's rule stay inside the toy limits, and the
     reference computed in int8 does not, by the logits' limits (what the
     program fed its own is not the control's to round)."""
-    seen = check_control(monkeypatch, capsys, seed)
+    seen = kit.check_control(monkeypatch, capsys, FAMILY, seed)
     assert seen["code"] == 0 and seen["ok"] and not seen["problems"]
     assert seen["served_tokens"] > 200
     assert all(seen[key] <= limit for key, limit in seen["limits"].items())
@@ -783,18 +610,8 @@ FAULTS = faults(WINDOW, 24)
 ALONE = ("replay_err", "attend_gap", "route_gap")
 
 
-def program_against_reference(cfg: dict, params) -> dict:
-    """``served_gap`` on one stream of 21 prompt tokens (in the widest
-    bucket, so right-padded) and 40 more: the three numbers that do not
-    ask who chose the tokens."""
-    tokens = np.zeros((1, 256), np.int32)
-    tokens[0, :61] = np.asarray(tokens_of(5, 61))
-    seen = ref.served_gap(cfg)(params, tokens, np.int32(21), np.int32(61))
-    return {key: float(seen[key]) for key in ALONE}
-
-
 def test_the_sound_program_reads_rounding_alone(toy, params):
-    seen = program_against_reference(toy, params)
+    seen = kit.program_against_reference(ref, toy, params, ALONE)
     limits = toy["served_check"]["limits"]
     assert all(seen[key] <= limits[key] / 10 for key in ALONE), seen
 
@@ -809,7 +626,7 @@ def test_a_planted_fault_comes_out_over_a_toy_limit(fault, toy, params,
     logits alone hardly see an attention layer)."""
     plant, must = FAULTS[fault]
     plant(monkeypatch)
-    seen = program_against_reference(toy, params)
+    seen = kit.program_against_reference(ref, toy, params, ALONE)
     limits = toy["served_check"]["limits"]
     over = {key for key in ALONE if seen[key] > limits[key]}
     assert over and must <= over, (seen, limits)

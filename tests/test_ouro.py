@@ -10,10 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 import random
-import sys
-import types
 from functools import partial
 
 import jax
@@ -22,69 +19,22 @@ import numpy as np
 import pytest
 from flax import linen as nn
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for path in (os.path.join(REPO, "benchmarks", "chip"), REPO):
-    if path not in sys.path:
-        sys.path.insert(0, path)
+import servekit as kit
+from horovod_tpu.models import hybrid, kvcache
+from horovod_tpu.models.transformer import MLP, RMSNorm
+from horovod_tpu.ops import decode_attention as da
+from servekit import harness, model_config, solo_world, tokens_of  # noqa: F401
 
-import ouro_counts  # noqa: E402
-import ouro_reference as ref  # noqa: E402
-import run as harness  # noqa: E402
-
-from horovod_tpu.models import hybrid, kvcache  # noqa: E402
-from horovod_tpu.models.transformer import MLP, RMSNorm  # noqa: E402
-from horovod_tpu.ops import decode_attention as da  # noqa: E402
-from horovod_tpu.serving import slotcache  # noqa: E402
-
-CONFIG = "Ouro-2.6B.serve"
-CELL = "ouro26b_serve_shortreason_sat"
-F32 = {"dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}
+FAMILY = "ouro"
+ref, ouro_counts = kit.module(FAMILY), kit.module(FAMILY, "counts")
+toy, params = kit.fixtures(FAMILY)
+slot_programs = kit.slot_programs_fixture()
 PASSES, LAYERS = 3, 2
-
-
-def load(name: str = CONFIG) -> dict:
-    return harness.load_json(harness.HERE, "configs", name + ".json")
-
-
-@pytest.fixture(scope="module")
-def toy() -> dict:
-    """The configuration's file at its rehearsal sizes and 3 passes, in
-    float32 so that the program and the reference differ by rounding
-    alone."""
-    cfg = load()
-    cfg = harness.merged(cfg, cfg["rehearsal"])
-    cfg["total_ut_steps"] = PASSES
-    cfg["model"] = {**cfg["model"], "args": {**cfg["model"]["args"], **F32}}
-    return cfg
-
-
-def seeded(cfg: dict, seed: int = 43) -> dict:
-    return ref.weights(types.SimpleNamespace(
-        config=cfg, seed=seed, resolve=harness.resolve))
-
-
-@pytest.fixture(scope="module")
-def params(toy):
-    return seeded(toy)
-
-
-def model_config(cfg: dict, **overrides) -> hybrid.HybridConfig:
-    return hybrid.HybridConfig(**{**harness.build_args(cfg), **overrides})
-
-
-def reference_logits(params, tokens, cfg):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, t: ref.logits(p, t, cfg))(
-            params, jnp.asarray(tokens))
-
-
-def tokens_of(seed: int, *shape) -> jax.Array:
-    return jax.random.randint(jax.random.key(seed), shape, 2, 256)
 
 
 # ------------------------------------------------------------------ the model
 def test_the_configuration_maps_the_published_keys(toy):
-    cfg = load()
+    cfg = kit.load(FAMILY)
     config = model_config(cfg)
     assert (config.loops, config.sandwich_norm) == (4, True)
     assert config.layer_types == ("attention",) * 48
@@ -108,18 +58,9 @@ def test_a_looped_stack_takes_attention_layers_alone():
         hybrid.HybridConfig(loops=0)
 
 
-def test_the_seeded_weights_have_the_models_own_tree(toy, params):
+def test_the_toy_has_one_set_of_weights_a_layer_and_four_norms(params):
     """One set of weights a layer, whatever the passes; four norms a
     layer, each scale away from ones."""
-    model = hybrid.HybridLM(model_config(toy))
-    own = jax.eval_shape(lambda: model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    assert jax.tree_util.tree_structure(own) \
-        == jax.tree_util.tree_structure(params)
-    assert [(leaf.shape, leaf.dtype) for leaf in
-            jax.tree_util.tree_leaves(own)] \
-        == [(leaf.shape, leaf.dtype) for leaf in
-            jax.tree_util.tree_leaves(params)]
     assert sorted(params) == ["embed", "final_norm", "layer_0", "layer_1",
                               "lm_head"]
     layer = params["layer_1"]
@@ -135,7 +76,7 @@ def test_the_whole_forward_pass_agrees_with_the_reference(length, toy,
     model = hybrid.HybridLM(model_config(toy))
     tokens = tokens_of(length, 2, length)
     got = jax.jit(model.apply)({"params": params}, tokens)
-    want = reference_logits(params, tokens, toy)
+    want = kit.reference_logits(ref, params, tokens, toy)
     assert got.shape == want.shape == (2, length, 256)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
@@ -147,7 +88,7 @@ def cache_paths(tree) -> list[tuple]:
 
 @pytest.mark.parametrize("n, bucket", [(1, 8), (8, 8), (13, 16), (50, 64)])
 def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
-        n, bucket, toy, params):
+        n, bucket, toy, params, slot_programs):
     """A prompt of ``n`` right-padded to ``bucket``, inserted as row 2 of
     a ``DenseSlotCache`` of 3 rows whose last occupant was another
     stream, then 20 tokens decoded there, every pass through leaves of
@@ -156,29 +97,8 @@ def test_prefill_of_a_padded_bucket_then_decode_through_the_slot_cache(
     after the first under ``pass_<u>``, and after a step each pass's
     keys differ."""
     steps = 20
-    config = model_config(toy, decode=True, max_seq_len=128)
-    family = config.family
-    model = family.build(config)
-    serve = types.SimpleNamespace(slots=3, max_seq=128, warmup_buckets=())
-    cache = slotcache.DenseSlotCache(serve, family, model, {})
-    cache.fresh(params)
-    stale = tokens_of(99, 1, 64)
-    _, old = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=jnp.int32(60)))(params, stale)
-    cache.tree = cache._insert_jit(cache.tree, old, np.int32(2))
-    tokens = tokens_of(n, 1, n + steps)
-    want = reference_logits(params, tokens, toy)[0]
-    padded = jnp.ones((1, bucket), jnp.int32).at[:, :n].set(tokens[:, :n])
-    logits, row = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=jnp.int32(n)))(params, padded)
-    np.testing.assert_allclose(logits[0, n - 1], want[n - 1], atol=2e-5)
-    cache.tree = cache._insert_jit(cache.tree, row, np.int32(2))
-    step = jax.jit(lambda p, c, t: family.decode_step(
-        model, {"params": p}, c, t))
-    for at in range(n, n + steps):
-        fed = jnp.zeros((3, 1), jnp.int32).at[2, 0].set(tokens[0, at])
-        logits, cache.tree = step(params, cache.tree, fed)
-        np.testing.assert_allclose(logits[2, 0], want[at], atol=2e-5)
+    cache, _, _ = kit.decode_through_the_slot_cache(
+        ref, toy, params, slot_programs(), n, bucket, steps)
     keys = [path for path in cache_paths(cache.tree)
             if path[-1] == "cached_key"]
     assert sorted(keys) == sorted(
@@ -311,24 +231,6 @@ def test_one_pass_without_sandwich_norms_is_the_unlooped_model_bit_for_bit(
 
 
 # ------------------------------------------------------------- the replica
-@pytest.fixture
-def solo_world():
-    import horovod_tpu as hvd
-    hvd.shutdown()
-    for var in ("HOROVOD_RANK", "HOROVOD_SIZE"):
-        os.environ.pop(var, None)
-    hvd.init()
-    yield hvd
-    hvd.shutdown()
-
-
-def executor(model_cfg, params=None, **kw):
-    from horovod_tpu.serving import ReplicaExecutor, ServeConfig
-    return ReplicaExecutor(ServeConfig(**{**dict(
-        model_cfg=model_cfg, max_batch=3, token_budget=64, max_seq=64,
-        slo_ms=60000.0, warmup_buckets=(8, 16, 32)), **kw}), params=params)
-
-
 def test_the_replica_serves_the_references_best_and_counts_every_pass(
         toy, params, solo_world):
     """Six requests over three slots on the normal path: every served
@@ -339,7 +241,7 @@ def test_the_replica_serves_the_references_best_and_counts_every_pass(
     prompts = [[rng.randrange(2, 256) for _ in range(n)]
                for n in (1, 3, 9, 17, 26, 30)]
     new = [12, 30, 7, 25, 5, 21]
-    ex = executor(model_config(toy), params)
+    ex = kit.executor(FAMILY, model_config(toy), params)
     try:
         assert ex.family is hybrid.FAMILY
         stats = ex.stats
@@ -349,20 +251,12 @@ def test_the_replica_serves_the_references_best_and_counts_every_pass(
         assert stats["loop_cache_bytes"] == (PASSES - 1) * LAYERS * a_layer
         assert stats["state_bytes"] == stats["window_bytes"] == 0
         assert stats["attend_layers"] == PASSES * LAYERS
-        for prompt, count in zip(prompts, new):
-            ex.stats["offered"] += 1
-            assert ex.queue.submit(list(prompt), count) is not None
-        ex.serve_loop(stop_when=lambda: True)
-        streams = [ex.completed[rid]["generated"]
-                   for rid in sorted(ex.completed)]
+        streams = kit.serve(ex, prompts, new)
     finally:
         ex.close()
     assert [len(s) for s in streams] == new
     for prompt, served in zip(prompts, streams):
-        logits = reference_logits(params, [prompt + served], toy)[0]
-        at = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
-        assert float(jnp.max(jnp.max(logits[at], -1)
-                             - logits[at, np.asarray(served)])) <= 1e-5
+        assert kit.widest_gap(ref, params, toy, prompt, served) <= 1e-5
     assert 0 < stats["attend_live_positions"] <= stats["attend_read_positions"]
 
 
@@ -383,7 +277,7 @@ def test_the_loop_cache_share_reads_three_quarters_at_the_published_passes():
 def test_the_counts_at_the_published_widths():
     """The reckoning of the configuration's memory and traffic, from
     its own file."""
-    cfg = load()
+    cfg = kit.load(FAMILY)
     counts = ouro_counts
     assert counts.layer_params(cfg) == 51_388_416
     assert counts.layer_matmul_params(cfg) == 16_777_216 + 34_603_008
@@ -424,30 +318,6 @@ def test_the_programs_count_of_a_token_counts_every_pass():
     assert perfmodel.hybrid_decode_flops(looped, 57) \
         - perfmodel.hybrid_decode_flops(looped, 1) \
         == 3 * 2 * 2.0 * (2 * 4 * 16) * 56
-
-
-def test_the_traffic_table_is_the_laws_quantiles():
-    def quantiles(low, high, points):
-        return [round(low * (high / low) ** ((i + 0.5) / points))
-                for i in range(points)]
-    traffic = harness.load_json(harness.HERE, "traffic",
-                                "shortreason_sat.json")
-    table = traffic["requests"]
-    prompts, outputs = quantiles(40, 192, 32), quantiles(256, 448, 32)
-    rng = random.Random(43)
-    rng.shuffle(prompts)
-    rng.shuffle(outputs)
-    assert table == [list(pair) for pair in zip(prompts, outputs)]
-    assert traffic["clients"] == 8
-    cfg = load()
-    serve = types.SimpleNamespace(
-        max_seq=cfg["serve"]["max_seq"],
-        warmup_buckets=tuple(cfg["serve"]["warmup_buckets"]))
-    buckets = sorted({slotcache.prompt_bucket(serve, p) for p, _ in table})
-    assert buckets == cfg["serve"]["warmup_buckets"] == [64, 128, 256]
-    assert max(p + o for p, o in table) == 614 <= cfg["serve"]["max_seq"]
-    assert cfg["serve"]["token_budget"] == max(buckets) + 8
-    assert cfg["serve"]["max_batch"] == traffic["clients"]
 
 
 # --------------------------------------------- planted faults, at toy size
@@ -532,39 +402,11 @@ FAULTS = {
 }
 
 
-def served_stream(cfg: dict, params, prompt: int = 21, new: int = 40):
-    """What the program serves one stream, greedy: the prompt prefilled
-    in a bucket of 32 (right-padded, its true length passed), then
-    ``new`` tokens decoded one by one through the cache -> the tokens
-    [1, 256] (prompt, served, padding) and where the served ones start
-    and end."""
-    config = model_config(cfg, decode=True, max_seq_len=128)
-    model = config.family.build(config)
-    tokens = np.zeros((1, 256), np.int32)
-    tokens[0, :prompt] = np.asarray(tokens_of(5, prompt))
-    padded = jnp.asarray(tokens[:, :32])
-    logits, cache = jax.jit(lambda p, t: kvcache.prefill(
-        model, {"params": p}, t, lengths=jnp.int32(prompt)))(params, padded)
-    token = jnp.argmax(logits[:, prompt - 1], -1)
-    step = jax.jit(lambda p, c, t: kvcache.decode_step(
-        model, {"params": p}, c, t))
-    for at in range(prompt, prompt + new):
-        tokens[0, at] = int(token[0])
-        logits, cache = step(params, cache, token[:, None])
-        token = jnp.argmax(logits[:, 0], -1)
-    return tokens, np.int32(prompt), np.int32(prompt + new)
-
-
-def compared(cfg: dict, params) -> dict:
-    tokens, first, length = served_stream(cfg, params)
-    seen = ref.served_gap(cfg)(params, tokens, first, length)
-    return {"gap_mean": float(seen["gap_sum"]) / int(length - first),
-            **{key: float(seen[key])
-               for key in ("gap", "replay_err", "attend_gap")}}
+KEYS = ("gap", "replay_err", "attend_gap")
 
 
 def test_the_sound_program_reads_rounding_alone(toy, params):
-    seen = compared(toy, params)
+    seen = kit.compared(ref, toy, params, KEYS)
     limits = toy["served_check"]["limits"]
     assert all(seen[key] <= limits[key] / 10 for key in limits), seen
 
@@ -581,7 +423,7 @@ def test_a_planted_fault_comes_out_over_a_toy_limit(fault, toy, params,
     that repeats one token hardly moves)."""
     plant, must = FAULTS[fault]
     plant(monkeypatch)
-    seen = compared(toy, params)
+    seen = kit.compared(ref, toy, params, KEYS)
     limits = toy["served_check"]["limits"]
     over = {key for key in limits if seen[key] > limits[key]}
     assert must <= over, (seen, limits)
@@ -593,24 +435,8 @@ def test_the_cells_control_in_int8_comes_out_not_correct(monkeypatch,
     """``--check control`` of the cell at its rehearsal sizes: the served
     tokens stay inside the toy limits, and the tokens that the reference
     computed in int8 puts first do not."""
-    load_json = harness.load_json
-
-    def patched(*parts):
-        data = load_json(*parts)
-        for over in ({"served_check": {"requests": 64}},
-                     {"trace_steps": 300}):
-            if set(over) <= set(data):
-                data["rehearsal"] = harness.merged(data["rehearsal"], over)
-        return data
-
-    monkeypatch.setattr(harness, "load_json", patched)
-    code = harness.main(["--workload", CELL, "--seed", "2147483659",
-                         "--trace", "1", "--rehearse-cpu", "--check",
-                         "control"])
-    line, = [ln for ln in capsys.readouterr().out.splitlines()
-             if " check {" in ln]
-    seen = harness.json.loads(line[line.index("{"):])
-    assert code == 0 and seen["ok"] and not seen["problems"]
+    seen = kit.check_control(monkeypatch, capsys, FAMILY, 2147483659)
+    assert seen["code"] == 0 and seen["ok"] and not seen["problems"]
     assert seen["served_tokens"] > 150
     assert all(seen[key] <= limit for key, limit in seen["limits"].items())
     over = {key for key, limit in seen["limits"].items()

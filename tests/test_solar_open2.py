@@ -6,10 +6,7 @@ solar_open2_reference.py) on its seeded weights, comparing logits.  Toy
 widths: the rehearsal sizes of the configuration's own file."""
 from __future__ import annotations
 
-import copy
-import os
 import random
-import sys
 import types
 
 import jax
@@ -17,62 +14,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for path in (os.path.join(REPO, "benchmarks", "chip"), REPO):
-    if path not in sys.path:
-        sys.path.insert(0, path)
+import servekit as kit
+from horovod_tpu.models import hybrid, kvcache, moe
+from horovod_tpu.ops import kda
+from servekit import harness, model_config, solo_world, tokens_of  # noqa: F401
+from test_decode_attention import pallas_calls
 
-import run as harness  # noqa: E402
-import solar_open2_counts  # noqa: E402
-import solar_open2_reference as ref  # noqa: E402
-
-from horovod_tpu.models import hybrid, moe  # noqa: E402
-from horovod_tpu.ops import kda  # noqa: E402
-
-CONFIG = "Solar-Open2-250B.serve"
-CELL = "solaropen2_serve_reason_sat"
-F32 = {"dtype": "@jax.numpy:float32", "param_dtype": "@jax.numpy:float32"}
-
-
-def load(name: str = CONFIG) -> dict:
-    return harness.load_json(harness.HERE, "configs", name + ".json")
-
-
-@pytest.fixture(scope="module")
-def toy() -> dict:
-    """The configuration's file at its rehearsal sizes (hidden 64; one
-    gated softmax layer of 4 query heads over 2 key-value heads of 32,
-    then three KDA layers of 4 heads of 16, chunks of 8; 16 experts of
-    width 32, top-2, experts 4 to 7 held; vocabulary 256), in float32 so
-    that the program and the reference differ by rounding alone."""
-    cfg = load()
-    cfg = harness.merged(cfg, cfg["rehearsal"])
-    cfg["model"] = {**cfg["model"], "args": F32}
-    return cfg
-
-
-def seeded(cfg: dict, seed: int = 34, held=None) -> dict:
-    return ref.weights(types.SimpleNamespace(
-        config=cfg, seed=seed, resolve=harness.resolve), held)
-
-
-@pytest.fixture(scope="module")
-def params(toy):
-    return seeded(toy)
-
-
-def model_config(cfg: dict, **overrides) -> hybrid.HybridConfig:
-    return hybrid.HybridConfig(**{**harness.build_args(cfg), **overrides})
-
-
-def reference_logits(params, tokens, cfg):
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda p, t: ref.logits(p, t, cfg))(
-            params, jnp.asarray(tokens))
-
-
-def tokens_of(seed: int, *shape) -> jax.Array:
-    return jax.random.randint(jax.random.key(seed), shape, 2, 256)
+FAMILY = "solar"
+ref, solar_open2_counts = kit.module(FAMILY), kit.module(FAMILY, "counts")
+toy, params = kit.fixtures(FAMILY)
 
 
 # ------------------------------------------------------------- the recurrence
@@ -156,19 +106,6 @@ def test_kda_update_interpreted_agrees_with_the_plain_form(heads, d,
         * jnp.einsum("bhk,bhkv->bhv", k, decayed)[..., None, :] \
         + beta[..., None, None] * k[..., :, None] * v[..., None, :]
     np.testing.assert_allclose(new, by_hand, atol=2e-6)
-
-
-def pallas_calls(jaxpr) -> list:
-    """The pallas_call equations of a jaxpr, those inside its jitted
-    calls too."""
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append(eqn)
-        for inner in eqn.params.values():
-            if hasattr(inner, "jaxpr"):
-                found += pallas_calls(inner.jaxpr)
-    return found
 
 
 def test_kda_update_writes_the_state_in_place_under_its_own_name():
@@ -284,7 +221,7 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
     once, add up to what the reference gives the layer with every expert
     in one place; through the program's layer and the reference's."""
     cfg = {**toy, "experts_held": [0, 16], "n_routed_experts": 16}
-    whole = seeded(cfg)["layer_1"]
+    whole = kit.seeded_layer(FAMILY, cfg)
     x = jax.random.normal(jax.random.key(3), (2, 11, 64))
     with jax.default_matmul_precision("highest"):
         uncut = ref.experts(
@@ -298,7 +235,7 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
         total_program, total_reference = shared, shared
         for first in (0, 4, 8, 12):
             held = (first, 4)
-            mine = seeded({**toy, "experts_held": list(held)})["layer_1"]
+            mine = kit.seeded_layer(FAMILY, toy, experts_held=list(held))
             for name in ("experts_gate", "experts_up", "experts_down"):
                 np.testing.assert_array_equal(
                     mine["moe"][name], whole["moe"][name][first:first + 4])
@@ -315,14 +252,12 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer(toy):
 
 
 # ------------------------------------------------------------------ the model
-def test_the_seeded_weights_have_the_models_own_tree(toy, params):
-    model = hybrid.HybridLM(model_config(toy))
-    own = jax.eval_shape(lambda: model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    assert jax.tree_util.tree_structure(own) \
-        == jax.tree_util.tree_structure(params)
-    assert [leaf.shape for leaf in jax.tree_util.tree_leaves(own)] \
-        == [leaf.shape for leaf in jax.tree_util.tree_leaves(params)]
+def test_the_toy_is_a_gated_softmax_layer_then_three_kda_layers(toy,
+                                                                 params):
+    """The rehearsal sizes: hidden 64; one gated softmax layer of 4 query
+    heads over 2 key-value heads of 32, then three KDA layers of 4 heads
+    of 16, chunks of 8; 16 experts of width 32, top-2, experts 4 to 7
+    held; vocabulary 256; an untied head."""
     assert "lm_head" in params and "wg" in params["layer_0"]["attn"]
     assert [kind for kind in toy["layer_types"]] \
         == ["attention", "kda", "kda", "kda"]
@@ -334,30 +269,29 @@ def test_the_whole_forward_pass_agrees_with_the_reference(length, toy,
     model = hybrid.HybridLM(model_config(toy))
     tokens = tokens_of(length, 2, length)
     got = jax.jit(model.apply)({"params": params}, tokens)
-    want = reference_logits(params, tokens, toy)
+    want = kit.reference_logits(ref, params, tokens, toy)
     assert got.shape == want.shape == (2, length, 256)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+programs = kit.programs_fixture(max_seq_len=32, interpret=True)
+
+
 @pytest.mark.parametrize("n", [1, 3, 8, 13])
 def test_prefill_of_a_padded_bucket_then_decode_through_the_cache(
-        n, toy, params):
+        n, toy, params, programs):
     """A prompt of ``n`` in a bucket of 16 with ``lengths = n``, then
     decode steps in slot 2 of a four-slot cache, the kernels interpreted:
     every logit row against the reference's full pass, and the delta-rule
     state after prefill equal to the reference's at ``n`` (which fails if
     the recurrence runs into the padding)."""
-    steps = 6
-    config = model_config(toy, decode=True, max_seq_len=32,
-                          interpret=True)
-    family = config.family
-    assert family is hybrid.ROUTED_FAMILY
-    model = family.build(config)
-    tokens = tokens_of(100 + n, 1, n + steps)
-    padded = jnp.full((1, 16), 7, jnp.int32).at[:, :n].set(tokens[:, :n])
-    logits, row = jax.jit(lambda p, t: family.prefill(
-        model, {"params": p}, t, lengths=n))(params, padded)
-    rows = [logits[0, n - 1]]
+    assert programs[0] is hybrid.ROUTED_FAMILY
+    row, tokens, counts = kit.prefill_then_decode(ref, toy, params,
+                                                  programs, n, atol=2e-5)
+    for counted in counts:
+        # Four slots, top-2, four layers of four held experts.
+        assert int(counted[0]) == 32 and int(counted[3]) == 16
+        assert 0 <= int(counted[2]) <= int(counted[1]) <= 32
 
     with jax.default_matmul_precision("highest"):
         def first_kda(p, t):
@@ -370,39 +304,15 @@ def test_prefill_of_a_padded_bucket_then_decode_through_the_cache(
     np.testing.assert_allclose(stored, state, atol=2e-6)
     assert row["layer_1"]["kda"]["conv_state"].shape == (1, 3, 3 * 64)
 
-    cache = jax.tree_util.tree_map(
-        lambda big, small: big.at[2].set(small[0]),
-        family.fresh_cache(model, params, 4), row)
-    from horovod_tpu.models import kvcache
-
-    def counted(p, c, t):
-        sown = {"counters": {}}
-        logits, c = family.decode_step(model, {"params": p}, c, t,
-                                       sown=sown)
-        return logits, c, jnp.concatenate(kvcache.summed(
-            sown["counters"], family.decode_counters))
-    decode = jax.jit(counted)
-    for at in range(n, n + steps - 1):
-        fed = jnp.zeros((4, 1), jnp.int32).at[2, 0].set(tokens[0, at])
-        logits, cache, counts = decode(params, cache, fed)
-        rows.append(logits[2, 0])
-        # Four slots, top-2, four layers of four held experts.
-        assert int(counts[0]) == 32 and int(counts[3]) == 16
-        assert 0 <= int(counts[2]) <= int(counts[1]) <= 32
-    want = reference_logits(params, tokens, toy)[0, n - 1:n + steps - 1]
-    np.testing.assert_allclose(jnp.stack(rows), want, atol=2e-5)
-
 
 def test_granites_toy_model_is_what_it_was():
     """The edits to models/hybrid.py leave the second family's first
     member alone: the same parameter tree, and, to the bit, the logits of
     the formulas as they stood (the convolution written out here as
     Mamba2Mixer had it before it was shared with KDAMixer)."""
-    cfg = load("granite-4.0-h-micro.serve")
+    cfg = kit.load("granite")
     cfg = harness.merged(cfg, cfg["rehearsal"])
-    import granite_reference
-    weights = granite_reference.weights(types.SimpleNamespace(
-        config=cfg, seed=29, resolve=harness.resolve))
+    weights = kit.seeded("granite", cfg)
     config = hybrid.HybridConfig(**harness.build_args(cfg))
     assert config.family is hybrid.FAMILY and config.head_dim == 16
     assert not hybrid.FAMILY.decode_counters
@@ -442,7 +352,7 @@ def test_the_counts_at_the_published_widths():
     even routing; the expert kernel's roofline takes what a step would
     move if every held expert were touched and every pair were local,
     times the shares that the program counted."""
-    cfg = load()
+    cfg = kit.load(FAMILY)
     counts = solar_open2_counts
     assert "moe_experts_touched_share" not in cfg       # measured, not set
     assert counts.moe_held_expert_bytes_per_step(cfg, [1024] * 80) \
@@ -494,7 +404,6 @@ def test_a_step_hands_out_what_its_layers_sowed_and_stays_a_pair(toy,
     asked; a dict given as ``sown`` receives the collections it names: a
     layer's counters (summed by name over the layers; the first family
     names none) and the experts each token took."""
-    from horovod_tpu.models import kvcache
     config = model_config(toy, decode=True, max_seq_len=32)
     family = config.family
     model = family.build(config)
@@ -586,7 +495,7 @@ def test_the_replay_shows_the_logits_the_routing_and_the_final_state(
         tokens_of(11, 1, length))
     program = ref.replay(toy)(params, tokens, np.int32(first),
                               np.int32(length))
-    want = reference_logits(params, tokens[:, :length], toy)[0]
+    want = kit.reference_logits(ref, params, tokens[:, :length], toy)[0]
     live = slice(first - 1, length - 1)
     np.testing.assert_allclose(program["logits"][live], want[live],
                                atol=2e-5)
@@ -617,33 +526,6 @@ def test_the_replay_shows_the_logits_the_routing_and_the_final_state(
 
 
 # ---------------------------------------------------------------- the replica
-@pytest.fixture
-def solo_world():
-    import horovod_tpu as hvd
-    hvd.shutdown()
-    for var in ("HOROVOD_RANK", "HOROVOD_SIZE"):
-        os.environ.pop(var, None)
-    hvd.init()
-    yield hvd
-    hvd.shutdown()
-
-
-def executor(model_cfg, params=None, **kw):
-    from horovod_tpu.serving import ReplicaExecutor, ServeConfig
-    return ReplicaExecutor(ServeConfig(**{**dict(
-        model_cfg=model_cfg, max_batch=3, token_budget=64, max_seq=64,
-        slo_ms=60000.0, warmup_buckets=(8, 16)), **kw}), params=params)
-
-
-def serve(ex, prompts, max_new) -> list[list[int]]:
-    for prompt, new in zip(prompts, max_new):
-        ex.stats["offered"] += 1
-        assert ex.queue.submit(list(prompt), new) is not None
-    ex.serve_loop(stop_when=lambda: True)
-    assert ex.stats["served"] == len(prompts)
-    return [ex.completed[rid]["generated"] for rid in sorted(ex.completed)]
-
-
 def test_the_replica_serves_the_references_best_and_counts_the_routing(
         toy, params, solo_world):
     """Seven requests over three slots on the normal path: every served
@@ -655,7 +537,7 @@ def test_the_replica_serves_the_references_best_and_counts_the_routing(
     prompts = [[rng.randrange(2, 256) for _ in range(n)]
                for n in (1, 2, 5, 8, 9, 13, 16)]
     new = [9, 4, 7, 12, 5, 8, 6]
-    ex = executor(model_config(toy), params)
+    ex = kit.executor(FAMILY, model_config(toy), params)
     try:
         assert ex.family is hybrid.ROUTED_FAMILY
         stats = ex.stats
@@ -666,16 +548,13 @@ def test_the_replica_serves_the_references_best_and_counts_the_routing(
         assert stats["state_bytes"] == 3 * 3 * a_layer
         # Warm-up's step is not fetched by the serve loop: not counted.
         assert all(stats[name] == 0 for name in moe.COUNTERS)
-        streams = serve(ex, prompts, new)
+        streams = kit.serve(ex, prompts, new)
         dispatches = stats["steps"]["decode"] + stats["steps"]["admit"]
     finally:
         ex.close()
     assert [len(s) for s in streams] == new
     for prompt, served in zip(prompts, streams):
-        logits = reference_logits(params, [prompt + served], toy)[0]
-        at = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
-        assert float(jnp.max(jnp.max(logits[at], -1)
-                             - logits[at, np.asarray(served)])) <= 1e-5
+        assert kit.widest_gap(ref, params, toy, prompt, served) <= 1e-5
     routed_steps = stats["moe_routed_pairs"] // (3 * 2 * 4)
     assert 0 < routed_steps <= dispatches
     assert stats["moe_expert_slots"] == routed_steps * 16
@@ -683,48 +562,7 @@ def test_the_replica_serves_the_references_best_and_counts_the_routing(
         < stats["moe_routed_pairs"]
 
 
-def test_the_programs_carry_the_scope_and_kernel_names(toy, solo_world):
-    ex = executor(model_config(toy))
-    try:
-        decode, args = ex.cache._decode_call(
-            ex.params, ex._last_tokens, ex._token_on_host)
-        decode = decode.lower(*args)
-        prefill = ex.cache._prefill_jit.lower(
-            ex.params, jnp.zeros((1, 8), jnp.int32), jnp.int32(3))
-        for program, scopes in (
-                (decode, ("hvd.kda_update", "hvd.kda_conv")),
-                (prefill, ("hvd.kda_scan", "hvd.kda_conv"))):
-            named = program.as_text(debug_info=True)
-            for scope in (*scopes, "hvd.moe_route"):
-                assert scope in named, scope
-            assert "hvd.sample" not in named   # it reached no device event
-        with pytest.raises(ValueError, match="recurrent state"):
-            executor(model_config(toy), paged=True)
-    finally:
-        ex.close()
-
-
 # --------------------------------------------- the benchmark's own comparison
-def check_control(monkeypatch, capsys, seed: int) -> dict:
-    load_json = harness.load_json
-
-    def patched(*parts):
-        data = copy.deepcopy(load_json(*parts))
-        for over in ({"served_check": {"requests": 64}},
-                     {"trace_steps": 500}):
-            if set(over) <= set(data):
-                data["rehearsal"] = harness.merged(data["rehearsal"], over)
-        return data
-
-    monkeypatch.setattr(harness, "load_json", patched)
-    code = harness.main(["--workload", CELL, "--seed", str(seed),
-                         "--trace", "1", "--rehearse-cpu", "--check",
-                         "control"])
-    line, = [ln for ln in capsys.readouterr().out.splitlines()
-             if " check {" in ln]
-    return {"code": code, **harness.json.loads(line[line.index("{"):])}
-
-
 @pytest.mark.parametrize("seed", [3, 2147483659, 2000000011])
 def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
                                                          capsys):
@@ -733,59 +571,88 @@ def test_the_cells_control_in_int8_comes_out_not_correct(seed, monkeypatch,
     the toy limits, and the reference computed in the precision below
     (linear maps in int8, the state in bfloat16) does not, by any of
     them."""
-    seen = check_control(monkeypatch, capsys, seed)
+    seen = kit.check_control(monkeypatch, capsys, FAMILY, seed)
     assert seen["code"] == 0 and seen["ok"] and not seen["problems"]
     assert seen["served_tokens"] > 400
     assert all(seen[key] <= limit < seen["control_" + key]
                for key, limit in seen["limits"].items())
 
 
-@pytest.mark.parametrize("fault", ["state_in_bfloat16", "an_expert_left_out",
-                                   "no_attention_gate", "b_without_its_2"])
+def state_in_bfloat16(monkeypatch):
+    """The delta-rule state rounded to bfloat16 after every step."""
+    def rounded(fn):
+        def run(*args, **kw):
+            o, state = fn(*args, **kw)
+            return o, jax.lax.reduce_precision(state, 8, 7)
+        return run
+    monkeypatch.setattr(kda, "kda_scan", rounded(kda.kda_scan))
+    monkeypatch.setattr(kda, "kda_update", rounded(kda.kda_update))
+
+
+def an_expert_left_out(monkeypatch):
+    """The contribution of the first expert held left out."""
+    route = moe.route
+
+    def without_the_first(scores, per_token, held, **kw):
+        weights, local, here = route(scores, per_token, held, **kw)
+        return weights, local, here & (local != 0)
+    monkeypatch.setattr(moe, "route", without_the_first)
+
+
+def b_without_its_2(monkeypatch):
+    """The write strength without its factor 2."""
+    monkeypatch.setattr(hybrid, "_WRITE_SCALE", 1.0)
+
+
+TOKENS = {"gap", "gap_mean", "replay_err"}
+# name -> (how it is planted, the numbers it pushes over their limits)
+FAULTS = {
+    "state_in_bfloat16": (state_in_bfloat16, {"state_gap", "replay_err"}),
+    "an_expert_left_out": (an_expert_left_out, TOKENS),
+    "b_without_its_2": (b_without_its_2, TOKENS),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_planted_fault_comes_out_over_a_toy_limit(fault, toy, params,
+                                                    monkeypatch):
+    """The program broken underneath, three ways, on one stream that it
+    serves and its replay: by ``state_gap`` alone of the numbers of a
+    state, which the other faults leave alone (they feed the recurrence
+    something else, and it carries that as it should; and at this size,
+    in float32, by the replay's logits), and by the logits' numbers."""
+    plant, over = FAULTS[fault]
+    plant(monkeypatch)
+    seen = kit.compared(ref, toy, params, ("gap", "replay_err", "state_gap"))
+    limits = toy["served_check"]["limits"]
+    assert {key for key in seen if seen[key] > limits[key]} == over, seen
+
+
+def no_attention_gate(monkeypatch):
+    """The softmax layer's gate left out of the replica's model alone."""
+    configured = harness.Run.model_config
+    monkeypatch.setattr(
+        harness.Run, "model_config", lambda self, **over: configured(
+            self, **{**over, "attn_gate": False}))
+
+
+# name -> (how it is planted, the numbers it pushes over their limits): a
+# fault that only the replica's served streams show
+SERVED = {"no_attention_gate": (no_attention_gate,
+                                {"gap_mean", "replay_miss_mean"})}
+
+
+@pytest.mark.parametrize("fault", sorted(SERVED))
 def test_a_planted_fault_comes_out_not_correct(fault, monkeypatch, capsys):
-    """The timed path broken underneath, four ways, each by one of the
-    cell's toy limits: the delta-rule state rounded to bfloat16 after
-    every step (by ``state_gap``, which the other faults leave alone:
-    they feed the recurrence something else, and it carries that as it
-    should; and at this size, in float32, by the replay's logits), the
-    contribution of the first expert held left out, the softmax layer's
-    gate left out (of the replica's model only: the replay, which has
-    it, no longer reproduces the stream), the write strength without its
-    factor 2.  The run ends, and ``correct`` is false by the comparison
-    with the reference alone."""
-    if fault == "state_in_bfloat16":
-        scan, update = kda.kda_scan, kda.kda_update
-
-        def rounded(fn):
-            def run(*args, **kw):
-                o, state = fn(*args, **kw)
-                return o, jax.lax.reduce_precision(state, 8, 7)
-            return run
-        monkeypatch.setattr(kda, "kda_scan", rounded(scan))
-        monkeypatch.setattr(kda, "kda_update", rounded(update))
-    elif fault == "an_expert_left_out":
-        route = moe.route
-
-        def without_the_first(scores, per_token, held, **kw):
-            weights, local, here = route(scores, per_token, held, **kw)
-            return weights, local, here & (local != 0)
-        monkeypatch.setattr(moe, "route", without_the_first)
-    elif fault == "no_attention_gate":
-        configured = harness.Run.model_config
-        monkeypatch.setattr(
-            harness.Run, "model_config", lambda self, **over: configured(
-                self, **{**over, "attn_gate": False}))
-    else:
-        monkeypatch.setattr(hybrid, "_WRITE_SCALE", 1.0)
-    seen = check_control(monkeypatch, capsys, 2147483659)
+    """The timed path broken underneath where the replay, which builds its
+    model from the configuration's file, still has it right: it no
+    longer reproduces the streams served.  The run ends, and ``correct``
+    is false by the comparison with the reference alone."""
+    plant, over = SERVED[fault]
+    plant(monkeypatch)
+    seen = kit.check_control(monkeypatch, capsys, FAMILY, 2147483659)
     assert seen["code"] == 1 and not seen["ok"]
     assert seen["problems"] and all("below the reference" in p
                                     for p in seen["problems"])
-    over = {key for key, limit in seen["limits"].items()
-            if seen[key] > limit}
-    tokens = {"gap", "gap_mean", "replay_err"}
-    assert over == {"state_in_bfloat16": {"state_gap", "replay_err"},
-                    "an_expert_left_out": tokens,
-                    "no_attention_gate": {"gap_mean", "replay_miss_mean"},
-                    "b_without_its_2": tokens}[fault]
-
+    assert {key for key, limit in seen["limits"].items()
+            if seen[key] > limit} == over

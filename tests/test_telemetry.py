@@ -254,6 +254,23 @@ def test_straggler_window_names_slowest_rank():
     assert 45.0 < p99 < 55.0
 
 
+def test_one_long_stall_does_not_outweigh_a_persistent_straggler():
+    """Rank 3 arrives 40 ms late at every tensor of the window; rank 1
+    is stalled 200 ms at one of them, as a loaded host stalls a thread.
+    Rank 3 is named, by its 40 ms (a mean over the window would name
+    rank 1, at 50 ms)."""
+    reg = MetricsRegistry(0)
+    agg = StragglerAggregator(4, reg, window=4, threshold_ms=5.0)
+    t0 = 1000.0
+    for stalled in (0.0, 0.2, 0.0, 0.0):
+        agg.observe_tensor({0: t0, 1: t0 + 0.001 + stalled,
+                            2: t0 + 0.002, 3: t0 + 0.040})
+        t0 += 1.0
+    assert agg.last_straggler == 3
+    assert 38.0 < agg.last_skew_ms < 42.0
+    assert reg.gauge("horovod_controller_straggler_rank").value == 3.0
+
+
 def test_straggler_below_threshold_clears_gauge():
     reg = MetricsRegistry(0)
     agg = StragglerAggregator(2, reg, window=2, threshold_ms=5.0)
